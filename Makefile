@@ -5,7 +5,7 @@
 PYTHON ?= python
 PYTEST ?= $(PYTHON) -m pytest
 
-.PHONY: test test-all test-inproc bench chaos chaos-multihost chaos-elastic chaos-sdc chaos-replace serve-smoke serve-chaos router-chaos handoff-smoke ckpt-smoke obs-smoke supervisor-smoke fleet-smoke store-chaos lint dryrun tpu-watch
+.PHONY: test test-all test-inproc bench chaos chaos-multihost chaos-elastic chaos-sdc chaos-replace serve-smoke serve-chaos router-chaos handoff-smoke ckpt-smoke obs-smoke supervisor-smoke fleet-smoke store-chaos lint dryrun chip-smoke
 
 # Per-file subprocess isolation: XLA:CPU's in-process multi-device runtime
 # can SIGABRT nondeterministically mid-suite (scripts/run_tests.py docstring);
@@ -232,13 +232,13 @@ chaos-sdc:
 
 dryrun:
 	JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-	python -c "import jax; jax.config.update('jax_platforms','cpu'); \
-	import __graft_entry__ as g; g.dryrun_multichip(8)"
+	python -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
 
 lint:
-	python -m compileall -q torchacc_tpu benchmarks bench.py __graft_entry__.py
+	python -m compileall -q torchacc_tpu benchmarks bench.py chip_smoke.py __graft_entry__.py
 
-# probe the TPU transport until it recovers, then capture a profiled
-# bench run + the 8B-geometry row (writes docs/last_good_bench.json)
-tpu-watch:
-	nohup bash scripts/tpu_watch.sh >/dev/null 2>&1 &
+# the quickest proof that train and serve still start on the chip
+# (one TPU v5e; `python chip_smoke.py --chips 4` is the sharded-training
+# phase on four).  Exits non-zero without a TPU.
+chip-smoke:
+	python chip_smoke.py

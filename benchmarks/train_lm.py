@@ -153,8 +153,10 @@ def main(argv=None) -> int:
     flops_per_token = (6.0 * mc.num_params()
                        + 6.0 * mc.num_layers * mc.hidden_size * args.seq)
     from bench import peak_flops  # repo-root bench helpers
-    mfu = (flops_per_token * tokens_per_sec
-           / (peak_flops(jax.devices()[0]) * n_chips))
+    dev = jax.devices()[0]
+    # a CPU run is a functional one: it has no MFU
+    mfu = (flops_per_token * tokens_per_sec / (peak_flops(dev) * n_chips)
+           if dev.platform != "cpu" else None)
 
     result = {
         "model": args.model,
@@ -162,7 +164,7 @@ def main(argv=None) -> int:
         "step_time_s": round(dt, 4),
         "tokens_per_sec": round(tokens_per_sec, 1),
         "tokens_per_sec_per_chip": round(tokens_per_sec / n_chips, 1),
-        "mfu": round(mfu, 4),
+        "mfu": None if mfu is None else round(mfu, 4),
         "params_m": round(mc.num_params() / 1e6, 1),
         "mesh": dict(trainer.mesh.shape),
         "dtype": dtype,

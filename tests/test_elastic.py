@@ -632,7 +632,6 @@ import os, sys, json, itertools
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%d"
 import jax
-jax.config.update("jax_platforms", "cpu")
 import numpy as np
 import jax.numpy as jnp
 import optax

@@ -243,7 +243,6 @@ def test_streamed_peak_rss_bounded(tmp_path):
             pass
         os.environ["JAX_PLATFORMS"] = "cpu"
         import jax
-        jax.config.update("jax_platforms", "cpu")
         import jax.numpy as jnp
         import numpy as np
         from torchacc_tpu.models.hf import config_from_hf

@@ -21,7 +21,6 @@ port, pid = sys.argv[1], int(sys.argv[2])
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax
-jax.config.update("jax_platforms", "cpu")
 from torchacc_tpu.parallel.distributed import initialize_distributed, is_primary
 initialize_distributed(coordinator_address=f"localhost:{port}",
                        num_processes=2, process_id=pid)
@@ -98,7 +97,6 @@ port, pid, base = sys.argv[1], int(sys.argv[2]), sys.argv[3]
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax
-jax.config.update("jax_platforms", "cpu")
 from torchacc_tpu.parallel.distributed import initialize_distributed
 initialize_distributed(coordinator_address=f"localhost:{port}",
                        num_processes=2, process_id=pid)
@@ -214,7 +212,6 @@ port, pid, path = sys.argv[1], int(sys.argv[2]), sys.argv[3]
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax
-jax.config.update("jax_platforms", "cpu")
 from torchacc_tpu.parallel.distributed import initialize_distributed
 initialize_distributed(coordinator_address=f"localhost:{port}",
                        num_processes=2, process_id=pid)

@@ -28,7 +28,6 @@ from torchacc_tpu.config import Config, ServeConfig
 from torchacc_tpu.models import TransformerLM, get_preset
 from torchacc_tpu.models.generate import generate
 from torchacc_tpu.serve import BlockPool, PrefixIndex, Request, ServeEngine
-from torchacc_tpu.serve import engine as engine_mod
 
 pytestmark = pytest.mark.serving
 
@@ -466,24 +465,22 @@ def test_load_params_flushes_prefix_cache_token_identical_to_cold(tiny):
 # TPU block-size hygiene
 # ---------------------------------------------------------------------------
 
-def test_tpu_block_size_warns_once(tiny, monkeypatch):
+def test_tpu_block_size_is_an_error_not_a_warning(tiny, monkeypatch):
+    # on a TPU backend 'auto' resolves to the paged kernel, which tiles
+    # a block as (block_size, head_dim): a size it cannot tile is a
+    # typed error at construction — there is no gather fallback to
+    # warn about.  The tiny model's pool is f32: multiples of 8.
+    import torchacc_tpu as ta
+    import torchacc_tpu.serve.scheduler as sched_mod
     model, params = tiny
-    warned = []
-    monkeypatch.setattr(engine_mod, "_tpu_block_size_warned", False)
-    monkeypatch.setattr(engine_mod.logger, "warning",
-                        lambda msg, *a, **k: warned.append(str(msg)))
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    ServeEngine(model, params, _cfg(block_size=8, prefix_cache=False))
-    ServeEngine(model, params, _cfg(block_size=8, prefix_cache=False))
-    hits = [m for m in warned if "multiple of 128" in m]
-    assert len(hits) == 1                    # once per process, not per engine
-    warned.clear()
-    monkeypatch.setattr(engine_mod, "_tpu_block_size_warned", False)
-    ServeEngine(model, params,
-                _cfg(block_size=128, num_blocks=8, prefix_cache=False))
-    assert not [m for m in warned if "multiple of 128" in m]
-    # and never on a non-TPU backend
-    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
-    monkeypatch.setattr(engine_mod, "_tpu_block_size_warned", False)
-    ServeEngine(model, params, _cfg(block_size=8, prefix_cache=False))
-    assert not [m for m in warned if "multiple of 128" in m]
+    monkeypatch.setattr(sched_mod, "on_tpu", lambda: True)
+    with pytest.raises(ta.ConfigError, match="multiple of 8"):
+        ServeEngine(model, params, _cfg(block_size=4, prefix_cache=False))
+    eng = ServeEngine(model, params, _cfg(block_size=8, prefix_cache=False))
+    assert eng.scheduler.decoder.impl == "pallas"
+    eng.close()
+    # off the chip 'auto' is the jnp gather path, which takes any size
+    monkeypatch.setattr(sched_mod, "on_tpu", lambda: False)
+    eng = ServeEngine(model, params, _cfg(block_size=4, prefix_cache=False))
+    assert eng.scheduler.decoder.impl == "xla"
+    eng.close()

@@ -143,11 +143,19 @@ def test_kernel_vs_xla_bitwise_and_f32_reference():
     for fmt in ("int8", "fp8"):
         y_xla = quantized_dot(x, w, 1, fmt=fmt, impl="xla")
         y_pal = quantized_dot(x, w, 1, fmt=fmt, impl="pallas")
-        # int8 accumulates exact int32 on both paths; fp8 f32 on both —
-        # kernel (interpret mode) and XLA dot agree bitwise
-        np.testing.assert_array_equal(np.asarray(y_xla),
-                                      np.asarray(y_pal), err_msg=fmt)
         y_ref = quantized_matmul_reference(x, w, 1, fmt=fmt)
+        if fmt == "int8":
+            # exact int32 accumulation on both paths: kernel (interpret
+            # mode) and XLA dot agree bitwise
+            np.testing.assert_array_equal(np.asarray(y_xla),
+                                          np.asarray(y_pal), err_msg=fmt)
+        else:
+            # fp8 accumulates in f32 on both paths, in different orders:
+            # measured on the jax 0.9.0 CPU backend, <= 10 of 5280
+            # elements differ by one f32 ulp (6e-8, 4.5e-8 of the output
+            # scale); two ulps of the scale is the bound
+            diff = float(jnp.max(jnp.abs(y_xla - y_pal)))
+            assert diff <= 2.0 ** -22 * float(jnp.max(jnp.abs(y_ref))), diff
         # reference differs only by accumulation order (f32 sums);
         # documented tolerance relative to the output scale
         scale = float(jnp.max(jnp.abs(y_ref))) + 1e-9

@@ -148,8 +148,10 @@ def _random_paged_case(rng, *, slots, heads, kv_heads, d, bs, mb, t=1,
             [v_pool[b] for b in blks], axis=0)[:ctx[s]])
     q = rng.standard_normal((slots, t, heads, d)).astype(np.float32)
     q_start = np.asarray([max(c - t, 0) for c in ctx], np.int32)
-    paged = (jnp.asarray(q, dtype), jnp.asarray(k_pool, dtype),
-             jnp.asarray(v_pool, dtype), jnp.asarray(tables),
+    # the pool the kernel reads is [NB, KH, BS, D]
+    paged = (jnp.asarray(q, dtype),
+             jnp.asarray(k_pool.swapaxes(1, 2), dtype),
+             jnp.asarray(v_pool.swapaxes(1, 2), dtype), jnp.asarray(tables),
              jnp.asarray(ctx, np.int32), jnp.asarray(q_start))
     return paged, (q, dense_k, dense_v, q_start)
 
@@ -225,13 +227,13 @@ def test_paged_attention_pallas_interpret_matches_xla():
 
 def test_paged_attention_validation_errors():
     q = jnp.zeros((2, 1, 4, 8))
-    kp = jnp.zeros((4, 8, 2, 8))
+    kp = jnp.zeros((4, 2, 8, 8))
     tables = jnp.zeros((2, 2), jnp.int32)
     lens = jnp.zeros((2,), jnp.int32)
     with pytest.raises(ValueError):          # 3 q heads not multiple of 2
         paged_attention(jnp.zeros((2, 1, 3, 8)), kp, kp, tables, lens, lens)
     with pytest.raises(ValueError):          # k/v pool mismatch
-        paged_attention(q, kp, jnp.zeros((4, 8, 4, 8)), tables, lens, lens)
+        paged_attention(q, kp, jnp.zeros((4, 4, 8, 8)), tables, lens, lens)
     with pytest.raises(ValueError):          # slot-count mismatch
         paged_attention(q, kp, kp, tables[:1], lens, lens)
     with pytest.raises(ValueError):

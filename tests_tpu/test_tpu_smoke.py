@@ -1,14 +1,11 @@
-"""On-chip smoke checks (see conftest docstring for why these exist).
+"""On-chip checks (see conftest docstring for why these exist).
 
 Each test targets a path that CPU interpret-mode testing cannot validate:
 Mosaic compilation of the Pallas flash kernel at the bench's block sizes,
 execution (not just lowering) of pinned_host offload placement, the
 vocab-parallel fused-CE shard_map lowering, and one end-to-end train step
-plus a cached greedy decode on the real chip.
-
-Kept deliberately fast: the whole file should finish in a few minutes on
-a warm compile cache so `scripts/tpu_watch.sh` can run it ahead of the
-long bench inside the same recovery window.
+plus a cached greedy decode on the real chip.  The whole file takes a
+few minutes.
 """
 
 import jax
@@ -49,27 +46,11 @@ def _xla_attention(q, k, v, *, causal, window=(-1, -1), scale=None,
 def test_flash_kernel_bench_shapes(chip):
     """Pallas flash fwd+bwd compiles under Mosaic and matches XLA at the
     HEADLINE BENCH geometry (seq 2048, head_dim 128 — the shapes whose
-    block sizes the perf claims in docs/PERF.md depend on).
-
-    TPU_SMOKE_SMALL=1 shrinks the geometry so the TEST LOGIC (reference
-    math, tolerances, grad-norm gate) is executable in interpret mode
-    off-chip — a logic bug must not wait for a transport-recovery
-    window to surface."""
-    import os
-
+    block sizes the perf claims in docs/PERF.md depend on)."""
     from torchacc_tpu.ops.flash_attention import flash_attention
 
-    # CPU-only knob: on the real chip the whole point is the headline
-    # geometry — a stray env var must not silently shrink it
-    small = (chip.platform == "cpu"
-             and os.environ.get("TPU_SMOKE_SMALL", "") not in ("", "0"))
-    if chip.platform == "cpu" and not small:
-        pytest.skip("interpret-mode flash at bench shapes takes minutes; "
-                    "set TPU_SMOKE_SMALL=1 to drive the test logic on "
-                    "a reduced geometry")
-
     rng = np.random.default_rng(0)
-    b, s, h, d = (1, 256, 2, 64) if small else (2, 2048, 8, 128)
+    b, s, h, d = 2, 2048, 8, 128
     q = jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.bfloat16)
     k = jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.bfloat16)
     v = jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.bfloat16)
@@ -101,24 +82,15 @@ def test_flash_kernel_bench_shapes(chip):
 def test_flash_kernel_gemma_features(chip):
     """GQA + sliding window + soft-capping (the gemma2/3 decode-path
     feature set) compile and match XLA on-chip."""
-    import os
-
     from torchacc_tpu.ops.flash_attention import flash_attention
 
-    small = (chip.platform == "cpu"
-             and os.environ.get("TPU_SMOKE_SMALL", "") not in ("", "0"))
-    if chip.platform == "cpu" and not small:
-        pytest.skip("interpret-mode flash is too slow for the debug run; "
-                    "set TPU_SMOKE_SMALL=1 to drive the test logic on "
-                    "a reduced geometry (full coverage lives in tests/)")
-
     rng = np.random.default_rng(1)
-    b, s, hq, hk, d = (1, 256, 4, 2, 64) if small else (2, 512, 8, 2, 128)
+    b, s, hq, hk, d = 2, 512, 8, 2, 128
     q = jnp.asarray(rng.standard_normal((b, s, hq, d)), jnp.bfloat16)
     k = jnp.asarray(rng.standard_normal((b, s, hk, d)), jnp.bfloat16)
     v = jnp.asarray(rng.standard_normal((b, s, hk, d)), jnp.bfloat16)
-    win = (64, -1) if small else (256, -1)  # keep window < seq: the
-    # sliding mask must actually cut keys, or the feature is untested
+    win = (256, -1)  # keep window < seq: the sliding mask must
+    # actually cut keys, or the feature is untested
     kw = dict(causal=True, window=win, logit_softcap=50.0)
     out = jax.jit(lambda q, k, v: flash_attention(q, k, v, **kw))(q, k, v)
     ref = _xla_attention(q, k, v, causal=True, window=win,
@@ -158,18 +130,17 @@ def test_fused_ce_tp_lowers_and_matches(chip):
 
 
 def test_offload_placement_executes(chip):
-    """pinned_host offload EXECUTES (VERDICT r4 missing-3: every prior
-    round could only show compile/lowering evidence because XLA:CPU
-    cannot run memory-space placement).  Lowered module must place the
-    annotated residuals in host memory, and grads must match the plain
-    'dots' policy bit-for-bit (offload changes residency, not math)."""
+    """pinned_host offload EXECUTES (XLA:CPU cannot run memory-space
+    placement, so tests/ only see it lower).  The compiled module must
+    place the annotated residuals in host memory, and grads must match
+    the plain 'dots' policy bit-for-bit (offload changes residency, not
+    math)."""
     from jax.ad_checkpoint import checkpoint_name
 
     from torchacc_tpu.utils.remat import _host_memory_available, remat_policy
 
-    if not _host_memory_available():
-        pytest.skip("backend exposes no pinned_host memory space "
-                    "(offload_dots falls back to 'dots' here)")
+    assert _host_memory_available(), (
+        "the chip exposes no pinned_host memory space")
 
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.standard_normal((64, 256)), jnp.float32)
@@ -190,13 +161,9 @@ def test_offload_placement_executes(chip):
 
     compiled_off, g_off = run("offload_dots")
     _, g_dots = run("dots")
-    if chip.platform != "cpu":
-        # XLA:CPU silently drops memory-space annotations from the
-        # compiled module (everything is host memory there) — the
-        # placement check is only meaningful compiled for the chip
-        txt = compiled_off.as_text()
-        assert "pinned_host" in txt or "S(5)" in txt, (
-            "offload policy compiled without a host memory-space placement")
+    txt = compiled_off.as_text()
+    assert "pinned_host" in txt or "S(5)" in txt, (
+        "offload policy compiled without a host memory-space placement")
     for a, b in zip(g_off, g_dots):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 

@@ -16,10 +16,6 @@ optimizers run inside the compiled program (no syncfree variants needed).
 
 __version__ = "0.1.0"
 
-from torchacc_tpu.utils import compat as _compat
-
-_compat.install()
-
 from torchacc_tpu import data, errors, models, ops, parallel, resilience
 from torchacc_tpu.config import (
     ComputeConfig,

@@ -77,6 +77,8 @@ def _schema_from_metadata(ckpt_dir: str):
 
     meta = ocp.StandardCheckpointer().metadata(os.path.abspath(ckpt_dir))
     meta = getattr(meta, "item_metadata", meta)
+    # installed orbax wraps the tree in a metadata pytree node
+    meta = getattr(meta, "tree", meta)
     schema = state_schema(meta)
     # orbax metadata carries neither live shardings nor the writing
     # pod's size — report "unknown", never the inspecting process's own
@@ -225,6 +227,8 @@ def _cmd_replay(args) -> int:
         # tool), not just one with the writing pod's device count
         meta = ckptr.metadata(os.path.abspath(d))
         meta = getattr(meta, "item_metadata", meta)
+        # installed orbax wraps the tree in a metadata pytree node
+        meta = getattr(meta, "tree", meta)
         dev = jax.sharding.SingleDeviceSharding(jax.devices("cpu")[0])
         abstract = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(tuple(x.shape), x.dtype,
@@ -511,6 +515,8 @@ def main(argv=None) -> int:
     # it in a metadata object)
     meta = ocp.StandardCheckpointer().metadata(os.path.abspath(args.ckpt_dir))
     meta = getattr(meta, "item_metadata", meta)
+    # installed orbax wraps the tree in a metadata pytree node
+    meta = getattr(meta, "tree", meta)
 
     def absify(x):
         shape = tuple(x.shape)

@@ -425,10 +425,12 @@ class ServeConfig:
     """
 
     # tokens per KV block.  Small blocks waste less memory on the last
-    # partial block per sequence; large blocks mean fewer gather steps
-    # per attention call.  On real TPU the Pallas paged-attention kernel
-    # wants a multiple of 128 (lane dim); the jnp fallback takes any
-    # value (CPU tests use 8-16).
+    # partial block per sequence; large blocks mean fewer, larger pool
+    # reads per attention call.  The Pallas paged-attention kernel tiles
+    # a block as (block_size, head_dim), so it needs a multiple of the
+    # pool dtype's sublane count (8 for f32, 16 for bf16 — the default
+    # tiles both); the engine raises ConfigError otherwise.  The jnp
+    # gather path takes any value.
     block_size: int = 16
     # blocks in the pool.  Per-layer KV bytes = num_blocks * block_size
     # * kv_heads * head_dim * 2 (k+v) * dtype_bytes.  Block 0 is

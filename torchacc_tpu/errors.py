@@ -133,7 +133,7 @@ class StoreError(TorchAccTPUError):
 
     Base for the write-side and commit-protocol errors; the read side
     keeps raising :class:`ShardCorruptionError` / ``OSError`` so the
-    streaming data plane's quarantine taxonomy is unchanged."""
+    streaming data plane's quarantine classification is unchanged."""
 
 
 class StoreWriteError(StoreError, OSError):

@@ -31,6 +31,7 @@ from torchacc_tpu.ops.attention import (
     attention_reference,
     attention_reference_bwd,
 )
+from torchacc_tpu.ops._common import ambient_mesh
 from torchacc_tpu.ops.attn import attention
 from torchacc_tpu.ops.context_parallel.ring import (
     _ring_fwd_impl,
@@ -41,16 +42,6 @@ from torchacc_tpu.ops.flash_attention import (
     flash_attention,
     flash_attention_bwd,
 )
-
-
-def _ambient_mesh() -> Optional[Mesh]:
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and m.shape:
-            return m
-    except Exception:
-        pass
-    return None
 
 
 def _axis_index(mesh, name: str):
@@ -85,7 +76,7 @@ def cp_attention(
     """[b, s, h, d] attention with the sequence dim context-parallel over
     (ring_axis, a2a_axis).  Falls back to plain attention when both axes
     have extent 1 (or no mesh is active)."""
-    mesh = mesh or _ambient_mesh()
+    mesh = mesh or ambient_mesh()
     ring_n = int(mesh.shape.get(ring_axis, 1)) if mesh is not None else 1
     ul_n = int(mesh.shape.get(a2a_axis, 1)) if mesh is not None else 1
     if ring_n * ul_n == 1:

@@ -7,28 +7,32 @@ module computes attention of per-slot queries over that paged layout —
 the vLLM PagedAttention computation expressed TPU-natively:
 
 - ``_paged_attention_pallas``: a Pallas TPU kernel (one program per
-  (slot, q head, kv block); the block table + context lengths ride as
-  scalar-prefetch operands so each grid step's BlockSpec index map can
-  address the pool block directly — no gather materialisation in HBM).
+  (slot, group of kv heads, kv block); the block table + context
+  lengths ride as scalar-prefetch operands so each grid step's
+  BlockSpec index map can address the pool block directly — no gather
+  materialisation in HBM).  The q heads that share a kv head are
+  stacked into the row dim, so a pool block is read once per kv head.
   Online softmax over the block sweep, exactly the flash-attention
   decomposition used by ops/flash_attention.py.
-- ``_paged_attention_xla``: a pure-jnp gather fallback, numerically
+- ``_paged_attention_xla``: a pure-jnp gather path, numerically
   matched to ops/attention.attention_reference (f32 scores, NEG_INF
   mask, masked probabilities zeroed) — the correctness anchor the
   kernel is tested against and the path CPU runs take.
 
 ``impl`` selection follows ops/attn.py: 'auto' = pallas on TPU, xla
 elsewhere; 'pallas' forces the kernel (interpret mode off-TPU);
-'xla' forces the fallback.
+'xla' forces the gather path.
 
 Geometry: queries are ``[S, T, H, D]`` — S slots, T tokens per slot
 (T=1 for decode, T=chunk for chunked prefill), already rope-rotated.
-The pool is ``[NB, BS, KH, D]`` (blocks, block size, kv heads, head
-dim) per layer.  ``context_lens[s]`` counts ALL banked tokens of slot s
-including the T chunk tokens (the cache write happens before the
-attention call), and ``q_start[s]`` is the global position of the
-slot's first query row — causality is ``kv_pos <= q_start + t``.
-Slots with ``context_lens == 0`` (free slots parked on the null block)
+The pool is ``[NB, KH, BS, D]`` (blocks, kv heads, block size, head
+dim) per layer — ``(block size, head dim)`` last, so one kv head's page
+is a legal TPU tile whenever ``block_size`` is a multiple of the
+dtype's sublane count (:func:`min_block_size`).  ``context_lens[s]``
+counts ALL banked tokens of slot s including the T chunk tokens (the
+cache write happens before the attention call), and ``q_start[s]`` is
+the global position of the slot's first query row — causality is
+``kv_pos <= q_start + t``.  Slots with ``context_lens == 0`` (free slots parked on the null block)
 produce all-zero outputs.
 """
 
@@ -41,8 +45,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 from torchacc_tpu.ops._common import NEG_INF, interpret_mode as _interpret
+from torchacc_tpu.ops._common import ambient_mesh, needs_shard_map
 from torchacc_tpu.ops._common import on_tpu as _on_tpu
 
 
@@ -56,8 +62,15 @@ def _repeat_kv_heads(x: jax.Array, num_q_heads: int) -> jax.Array:
     return jnp.repeat(x, num_q_heads // kh, axis=-2)
 
 
+def min_block_size(dtype) -> int:
+    """Smallest ``block_size`` step the kernel tiles for a pool of
+    ``dtype``: the TPU's sublane count for that width (8 rows of 32
+    bits; narrower types pack 16 or 32 rows into a tile)."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
 # ---------------------------------------------------------------------------
-# jnp gather fallback (the correctness anchor; runs everywhere)
+# jnp gather path (the correctness anchor; runs everywhere)
 # ---------------------------------------------------------------------------
 
 @functools.partial(
@@ -66,13 +79,13 @@ def _repeat_kv_heads(x: jax.Array, num_q_heads: int) -> jax.Array:
 def _paged_attention_xla(q, k_pool, v_pool, block_tables, context_lens,
                          q_start, scale, window, logit_softcap):
     s_, t_, h, d = q.shape
-    nb, bs, kh, _ = k_pool.shape
+    nb, kh, bs, _ = k_pool.shape
     mb = block_tables.shape[1]
     # gather each slot's pages into a dense [S, MB*BS, ...] view; the
-    # pool read is O(S * MB * BS) — fine for the fallback, the kernel
+    # pool read is O(S * MB * BS) — fine for the reference, the kernel
     # never materialises this
-    k = k_pool[block_tables].reshape(s_, mb * bs, kh, d)
-    v = v_pool[block_tables].reshape(s_, mb * bs, kh, d)
+    k = k_pool[block_tables].swapaxes(2, 3).reshape(s_, mb * bs, kh, d)
+    v = v_pool[block_tables].swapaxes(2, 3).reshape(s_, mb * bs, kh, d)
     k = _repeat_kv_heads(k, h)
     v = _repeat_kv_heads(v, h)
     scores = jnp.einsum("sthd,skhd->shtk", q.astype(jnp.float32),
@@ -102,8 +115,8 @@ def _paged_attention_xla(q, k_pool, v_pool, block_tables, context_lens,
 
 def _paged_fwd_kernel(tbl_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
                       m_scr, l_scr, acc_scr,
-                      *, scale, block_size, t_len, num_kv_blocks,
-                      window, logit_softcap):
+                      *, scale, block_size, t_len, rows, heads_per_step,
+                      num_kv_blocks, window, logit_softcap):
     si = pl.program_id(0)
     bi = pl.program_id(2)
 
@@ -119,95 +132,147 @@ def _paged_fwd_kernel(tbl_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(k_start < ctx)
     def _compute():
-        q = q_ref[0, 0, :, :]                               # [T, D]
-        k = k_ref[0, :, 0, :]                               # [BS, D]
-        v = v_ref[0, :, 0, :]                               # [BS, D]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # [T, BS]
-        if logit_softcap > 0.0:
-            s = logit_softcap * jnp.tanh(s / logit_softcap)
         kv_pos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (t_len, block_size), 1)
-        q_pos = q0 + jax.lax.broadcasted_iota(
-            jnp.int32, (t_len, block_size), 0)
+            jnp.int32, (rows, block_size), 1)
+        # row r holds q head (r // T) of the group at chunk token r % T
+        q_pos = q0 + jax.lax.rem(jax.lax.broadcasted_iota(
+            jnp.int32, (rows, block_size), 0), t_len)
         mask = (kv_pos < ctx) & (kv_pos <= q_pos)
         left, right = window
         if left >= 0:
             mask &= kv_pos >= q_pos - left
         if right >= 0:
             mask &= kv_pos <= q_pos + right
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        alpha = jnp.where(m_prev == NEG_INF, 0.0, alpha)
-        l_scr[...] = jnp.broadcast_to(
-            (alpha * l_scr[:, 0] + jnp.sum(p, axis=1))[:, None],
-            l_scr.shape)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
+        for hi in range(heads_per_step):
+            q = q_ref[0, hi]                                # [R, D]
+            k = k_ref[0, hi]                                # [BS, D]
+            v = v_ref[0, hi]                                # [BS, D]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [R, BS]
+            if logit_softcap > 0.0:
+                s = logit_softcap * jnp.tanh(s / logit_softcap)
+            s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_scr[hi, :, 0]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+            p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            alpha = jnp.where(m_prev == NEG_INF, 0.0, alpha)
+            l_scr[hi] = jnp.broadcast_to(
+                (alpha * l_scr[hi, :, 0] + jnp.sum(p, axis=1))[:, None],
+                l_scr.shape[1:])
+            acc_scr[hi] = acc_scr[hi] * alpha[:, None] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[hi] = jnp.broadcast_to(m_new[:, None], m_scr.shape[1:])
 
     @pl.when(bi == num_kv_blocks - 1)
     def _finalize():
-        l = l_scr[:, 0]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0, :, :] = (acc_scr[...] / l_safe[:, None]).astype(
-            o_ref.dtype)
+        for hi in range(heads_per_step):
+            l = l_scr[hi, :, 0]
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, hi] = (acc_scr[hi] / l_safe[:, None]).astype(
+                o_ref.dtype)
 
 
 _LANES = 128
+# What one grid step may hold in VMEM.  The compiler's scoped default on
+# the chips this targets is 16 MiB; the rest is left to its own
+# temporaries.
+_VMEM_BUDGET = 10 * 1024 * 1024
+
+
+def _heads_per_step(kh: int, rows: int, bs: int, d: int,
+                    itemsize: int) -> int:
+    """Largest divisor of ``kh`` whose blocks fit the VMEM budget: q and
+    out blocks [rows, d] and the k/v pages [bs, d] double-buffered, the
+    f32 m/l/acc scratch, and the [rows, bs] f32 score temporaries (one
+    head's worth — heads run one after another inside a step)."""
+    lanes_d = max(d, _LANES)
+    per_head = (2 * 2 * rows * lanes_d * itemsize            # q, out
+                + 2 * 2 * bs * lanes_d * itemsize            # k, v
+                + rows * (2 * _LANES + lanes_d) * 4)         # m, l, acc
+    temps = 3 * rows * max(bs, _LANES) * 4
+    for hb in range(kh, 0, -1):
+        if kh % hb == 0 and hb * per_head + temps <= _VMEM_BUDGET:
+            return hb
+    raise ValueError(
+        f"paged attention: one kv head's blocks ({rows} q rows x "
+        f"head_dim {d}, block_size {bs}) need "
+        f"{(per_head + temps) / 2**20:.1f} MiB of VMEM, over the "
+        f"{_VMEM_BUDGET / 2**20:.0f} MiB budget — lower "
+        f"serve.prefill_chunk or serve.block_size")
 
 
 def _paged_attention_pallas(q, k_pool, v_pool, block_tables, context_lens,
                             q_start, scale, window, logit_softcap):
     s_, t_, h, d = q.shape
-    nb, bs, kh, _ = k_pool.shape
+    nb, kh, bs, _ = k_pool.shape
     mb = block_tables.shape[1]
     group = h // kh
+    if bs % min_block_size(k_pool.dtype):
+        raise ValueError(
+            f"paged attention kernel: block_size {bs} is not a multiple "
+            f"of {min_block_size(k_pool.dtype)}, the TPU sublane tile of a "
+            f"{jnp.dtype(k_pool.dtype).name} pool")
     # lens = [S, 2] (context_len, q_start) scalar-prefetch operand; the
     # block table prefetches alongside so every BlockSpec index map can
     # address the pool block for (slot, kv-block) before the body runs
     lens = jnp.stack([context_lens.astype(jnp.int32),
                       q_start.astype(jnp.int32)], axis=1)
-    qT = q.swapaxes(1, 2)                                   # [S, H, T, D]
+    # stack each kv head's q group into the row dim: [S, KH, G*T, D]
+    rows = group * t_
+    qg = q.reshape(s_, t_, kh, group, d).transpose(0, 2, 3, 1, 4).reshape(
+        s_, kh, rows, d)
+    hb = _heads_per_step(kh, rows, bs, d, jnp.dtype(q.dtype).itemsize)
 
+    q_spec = pl.BlockSpec((1, hb, rows, d),
+                          lambda s, g, b, tbl, lens: (s, g, 0, 0))
+    kv_spec = pl.BlockSpec((1, hb, bs, d),
+                           lambda s, g, b, tbl, lens: (tbl[s, b], g, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(s_, h, mb),
-        in_specs=[
-            pl.BlockSpec((1, 1, t_, d),
-                         lambda s, hh, b, tbl, lens: (s, hh, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda s, hh, b, tbl, lens:
-                         (tbl[s, b], 0, hh // group, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda s, hh, b, tbl, lens:
-                         (tbl[s, b], 0, hh // group, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, t_, d),
-                               lambda s, hh, b, tbl, lens: (s, hh, 0, 0)),
+        grid=(s_, kh // hb, mb),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((t_, _LANES), jnp.float32),
-            pltpu.VMEM((t_, _LANES), jnp.float32),
-            pltpu.VMEM((t_, d), jnp.float32),
+            pltpu.VMEM((hb, rows, _LANES), jnp.float32),
+            pltpu.VMEM((hb, rows, _LANES), jnp.float32),
+            pltpu.VMEM((hb, rows, d), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _paged_fwd_kernel, scale=scale, block_size=bs, t_len=t_,
-        num_kv_blocks=mb, window=window, logit_softcap=logit_softcap)
+        _paged_fwd_kernel, scale=scale, block_size=bs, t_len=t_, rows=rows,
+        heads_per_step=hb, num_kv_blocks=mb, window=window,
+        logit_softcap=logit_softcap)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qT.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
-    )(block_tables.astype(jnp.int32), lens, qT, k_pool, v_pool)
-    return out.swapaxes(1, 2)
+        name="paged_attention",
+    )(block_tables.astype(jnp.int32), lens, qg, k_pool, v_pool)
+    return out.reshape(s_, kh, group, t_, d).transpose(0, 3, 1, 2, 4).reshape(
+        s_, t_, h, d)
+
+
+def _paged_attention_pallas_sharded(mesh, q, k_pool, v_pool, block_tables,
+                                    context_lens, q_start, *static):
+    """The kernel per shard of ``mesh``: GSPMD cannot partition a Mosaic
+    kernel.  Heads split over 'tp' where it divides the kv heads (the
+    layout serve/kv_cache.make_pools gives the pool); slots, tables and
+    lengths are replicated."""
+    tp = (1 if "tp" in mesh.manual_axes else int(mesh.shape.get("tp", 1)))
+    h_axis = "tp" if tp > 1 and k_pool.shape[1] % tp == 0 else None
+    q_spec = P(None, None, h_axis, None)
+    pool_spec = P(None, h_axis, None, None)
+    return jax.shard_map(
+        lambda *a: _paged_attention_pallas(*a, *static), mesh=mesh,
+        in_specs=(q_spec, pool_spec, pool_spec, P(), P(), P()),
+        out_specs=q_spec, check_vma=False,
+    )(q, k_pool, v_pool, block_tables, context_lens, q_start)
 
 
 # ---------------------------------------------------------------------------
@@ -229,7 +294,7 @@ def paged_attention(
 ) -> jax.Array:
     """Causal attention of ``q [S, T, H, D]`` over a paged KV pool.
 
-    ``k_pool``/``v_pool``: [num_blocks, block_size, kv_heads, head_dim]
+    ``k_pool``/``v_pool``: [num_blocks, kv_heads, block_size, head_dim]
     (one layer's pool).  ``block_tables [S, MB]`` maps slot-s logical
     block j to a pool block; ``context_lens [S]`` is the total banked
     length per slot (chunk included); ``q_start [S]`` the global
@@ -245,7 +310,7 @@ def paged_attention(
     if k_pool.shape != v_pool.shape:
         raise ValueError(f"k_pool {k_pool.shape} != v_pool {v_pool.shape}")
     s_, t_, h, d = q.shape
-    kh = k_pool.shape[2]
+    kh = k_pool.shape[1]
     if h % kh != 0:
         raise ValueError(
             f"num q heads ({h}) must be a multiple of kv heads ({kh})")
@@ -257,10 +322,13 @@ def paged_attention(
         scale = d ** -0.5
     if impl == "auto":
         impl = "pallas" if _on_tpu() else "xla"
-    fn = (_paged_attention_pallas if impl == "pallas"
-          else _paged_attention_xla)
     if impl not in ("pallas", "xla"):
         raise ValueError(f"impl must be auto|pallas|xla, got {impl!r}")
+    fn = (_paged_attention_pallas if impl == "pallas"
+          else _paged_attention_xla)
+    mesh = ambient_mesh()
+    if impl == "pallas" and needs_shard_map(mesh):
+        fn = functools.partial(_paged_attention_pallas_sharded, mesh)
     return fn(q, k_pool, v_pool, block_tables.astype(jnp.int32),
               context_lens.astype(jnp.int32), q_start.astype(jnp.int32),
               float(scale), tuple(window), float(logit_softcap))

@@ -21,7 +21,9 @@ Two executable paths, selected like ``ops/flash_attention.py``:
 - ``impl='xla'`` — ``lax.dot_general(preferred_element_type=...)`` on
   explicitly quantized operands; XLA fuses the casts.  This is the CPU
   path and the semantics anchor: for int8 both paths accumulate in
-  exact int32 arithmetic, so kernel and fallback agree **bitwise**.
+  exact int32 arithmetic, so the kernel and the XLA dot agree
+  **bitwise**; fp8 accumulates in f32 on both, in different orders, so
+  they agree to about one f32 ulp (tests/test_quant.py).
 
 Numerics are anchored to :func:`quantized_matmul_reference` (an f32
 dequantize-then-matmul mirror) the same way ``ops/paged_attention.py``
@@ -52,6 +54,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from torchacc_tpu.ops._common import ambient_mesh, needs_shard_map
 from torchacc_tpu.ops._common import interpret_mode as _interpret
 from torchacc_tpu.ops._common import on_tpu as _on_tpu
 from torchacc_tpu.ops._common import round_up as _round_up
@@ -312,6 +315,15 @@ def quantized_dot(
         impl = "pallas" if _on_tpu() else "xla"
     if impl not in ("pallas", "xla"):
         raise ValueError(f"impl must be auto|pallas|xla, got {impl!r}")
+    if impl == "pallas" and needs_shard_map(ambient_mesh()):
+        # GSPMD cannot partition a Mosaic kernel, and this one has no
+        # shard_map region yet (fsdp/tp-sharded weights need their
+        # gathers and reductions placed by hand)
+        from torchacc_tpu.config import ConfigError
+        raise ConfigError(
+            "the quantized-matmul Pallas kernel does not run under a "
+            "mesh of more than one device; set compute.quant_impl='xla' "
+            "(ops.quantized_dot(impl='xla')) for multi-chip runs")
     cd = int(contract_ndim)
     if cd < 1 or cd > min(x.ndim, kernel.ndim - 1):
         raise ValueError(

@@ -24,7 +24,6 @@ from jax.experimental import mesh_utils
 from jax.sharding import Mesh
 
 from torchacc_tpu.config import DistConfig
-from torchacc_tpu.utils.logger import logger
 
 
 def build_mesh(
@@ -43,29 +42,24 @@ def build_mesh(
     axis_names = tuple(dist.topology)
     shape = tuple(sizes[a] for a in axis_names)
 
-    if dist.num_slices > 1:
+    # mesh_utils places axes on the physical ICI/DCN topology.  Virtual
+    # CPU devices have none, so there a row-major reshape (fastest-
+    # varying axes on adjacent device ids) is the mesh; on real chips a
+    # placement failure is an error — a reshape would hide a wrong layout.
+    on_chips = devices[0].platform == "tpu"
+    if dist.num_slices > 1 and on_chips:
         # Multi-slice (DCN-connected) topology: split the leading axes
         # across slices, the rest within a slice over ICI.  Mirrors the
         # reference's node-boundary-aware axis placement.
         per_slice = world // dist.num_slices
         dcn_shape, ici_shape = _split_shape_for_dcn(shape, dist.num_slices, per_slice)
-        try:
-            dev_array = mesh_utils.create_hybrid_device_mesh(
-                ici_shape, dcn_shape, devices=devices
-            )
-            return Mesh(dev_array.reshape(shape), axis_names)
-        except Exception as e:  # pragma: no cover - depends on real topology
-            logger.warning(f"hybrid mesh construction failed ({e}); "
-                           "falling back to flat mesh")
-
-    try:
-        dev_array = mesh_utils.create_device_mesh(shape, devices=np.asarray(devices))
-    except Exception as e:
-        # CPU emulation or exotic topologies: plain row-major reshape keeps
-        # the fastest-varying (last) axes on adjacent device ids.
-        logger.warning(
-            f"create_device_mesh failed for shape {shape} ({e}); falling back "
-            "to row-major device order — ICI-aware placement is lost")
+        dev_array = mesh_utils.create_hybrid_device_mesh(
+            ici_shape, dcn_shape, devices=devices)
+        return Mesh(dev_array.reshape(shape), axis_names)
+    if on_chips:
+        dev_array = mesh_utils.create_device_mesh(
+            shape, devices=np.asarray(devices))
+    else:
         dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, axis_names)
 

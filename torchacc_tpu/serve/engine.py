@@ -109,24 +109,6 @@ def _percentile(xs: List[float], q: float) -> float:
 #: filtering the exported timeline mixes two requests' spans
 _trace_seq = itertools.count()
 
-_tpu_block_size_warned = False
-
-
-def _warn_tpu_block_size(block_size: int, backend: str) -> None:
-    """Warn once per process when serving on a real TPU with a block
-    size the Pallas paged-attention kernel cannot tile on the 128-lane
-    dim (docs/serving.md "ServeConfig tuning")."""
-    global _tpu_block_size_warned
-    if backend != "tpu" or block_size % 128 == 0 or _tpu_block_size_warned:
-        return
-    _tpu_block_size_warned = True
-    logger.warning(
-        f"serve.block_size={block_size} is not a multiple of 128 on a "
-        f"TPU backend: the Pallas paged-attention kernel tiles the "
-        f"block (lane) dim at 128, so this forces the slower jnp "
-        f"gather fallback / padded kernel blocks.  Use 128 (or a "
-        f"multiple) on real TPU; small sizes are for CPU tests.")
-
 
 class ServeEngine:
     """Continuous-batching serving engine over a paged KV cache.
@@ -146,11 +128,9 @@ class ServeEngine:
 
     def __init__(self, model, params, config: Optional[Config] = None,
                  mesh=None, metrics_dir: Optional[str] = None):
-        import jax
         cfg = getattr(model, "cfg", model)
         config = config or Config()
         config.serve.validate()
-        _warn_tpu_block_size(config.serve.block_size, jax.default_backend())
         self.cfg = cfg
         self.config = config
         self.mesh = mesh

@@ -1,11 +1,12 @@
 """Paged KV cache: the preallocated block pool + host-side allocator.
 
 Memory layout (the vLLM PagedAttention idea expressed as JAX arrays):
-ONE pool per layer of shape ``[num_blocks, block_size, kv_heads,
+ONE pool per layer of shape ``[num_blocks, kv_heads, block_size,
 head_dim]`` for keys and the same for values, stacked over layers into
-``[L, NB, BS, KH, D]``.  A sequence's cache is a list of blocks named
-by its BLOCK TABLE; sequences of wildly different lengths share the
-pool with at most ``block_size - 1`` wasted slots each, and a finished
+``[L, NB, KH, BS, D]`` — ``(block_size, head_dim)`` last, the tile the
+paged-attention kernel reads (ops/paged_attention.py).  A sequence's
+cache is a list of blocks named by its BLOCK TABLE; sequences of wildly
+different lengths share the pool with at most ``block_size - 1`` wasted slots each, and a finished
 sequence's blocks return to the free list as soon as every in-flight
 iteration that could still write through its table has resolved (at
 most ``decode_depth - 1`` iterations — scheduler._release_matured) —
@@ -260,17 +261,17 @@ class BlockPool:
 
 
 def make_pools(model_cfg, serve_cfg, dtype=None):
-    """(k_pools, v_pools) of shape [L, NB, BS, KH, D] in the model's
+    """(k_pools, v_pools) of shape [L, NB, KH, BS, D] in the model's
     compute dtype, kv heads sharded over 'tp' when a mesh is live (the
     same activation-constraint seam the model layers use, so the TP
     head composes — parallel/sharding.py)."""
     from torchacc_tpu.parallel.sharding import activation_constraint
 
     shape = (model_cfg.num_layers, serve_cfg.num_blocks,
-             serve_cfg.block_size, model_cfg.kv_heads,
+             model_cfg.kv_heads, serve_cfg.block_size,
              model_cfg.head_size)
     dt = dtype or model_cfg.dtype
-    axes = (None, None, None, "heads", None)
+    axes = (None, None, "heads", None, None)
     k = activation_constraint(jnp.zeros(shape, dt), axes)
     v = activation_constraint(jnp.zeros(shape, dt), axes)
     return k, v

@@ -67,8 +67,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from torchacc_tpu.config import ConfigError
 from torchacc_tpu.obs import tracing
-from torchacc_tpu.ops.paged_attention import paged_attention
+from torchacc_tpu.ops._common import on_tpu
+from torchacc_tpu.ops.paged_attention import min_block_size, paged_attention
 from torchacc_tpu.resilience.chaos import failpoint
 from torchacc_tpu.serve.kv_cache import (
     BlockPool,
@@ -156,6 +158,14 @@ def _check_supported(cfg) -> None:
             "model zoo).")
 
 
+def _upload(host_mirror: np.ndarray) -> jax.Array:
+    """Device copy of a host array the scheduler goes on mutating in
+    place.  The CPU backend may alias a numpy buffer instead of copying
+    it, and an in-flight step would then read the mutation; a private
+    copy that nothing else touches makes the alias harmless."""
+    return jnp.asarray(host_mirror.copy())
+
+
 class PagedDecoder:
     """The jitted device steps: a raw-params transformer forward over
     the paged pool (the established raw-params idiom of
@@ -166,7 +176,20 @@ class PagedDecoder:
         _check_supported(cfg)
         self.cfg = cfg
         self.serve_cfg = serve_cfg
-        self.impl = attention_impl or cfg.attention_impl
+        # resolve 'auto' once, here, so a block size the kernel cannot
+        # tile is a typed error at construction and not a lowering
+        # failure inside the first request
+        impl = attention_impl or cfg.attention_impl
+        if impl == "auto":
+            impl = "pallas" if on_tpu() else "xla"
+        step = min_block_size(cfg.dtype)
+        if impl == "pallas" and serve_cfg.block_size % step:
+            raise ConfigError(
+                f"serve.block_size={serve_cfg.block_size} cannot be tiled "
+                f"by the paged-attention kernel for a "
+                f"{jnp.dtype(cfg.dtype).name} KV pool: it must be a "
+                f"multiple of {step}")
+        self.impl = impl
         self.block_size = serve_cfg.block_size
         self.chunk = serve_cfg.prefill_chunk
         self.max_slots = serve_cfg.max_slots
@@ -242,10 +265,11 @@ class PagedDecoder:
         # attend over the updated pool — same write-before-read order
         # as the module's dense-cache decode branch
         flat_b, flat_o = blk.reshape(-1), off.reshape(-1)
-        kh, d = kp.shape[2], kp.shape[3]
-        kp = kp.at[flat_b, flat_o].set(
+        kh, d = kp.shape[1], kp.shape[3]
+        # pool is [NB, KH, BS, D]: index (block, :, offset) -> [N, KH, D]
+        kp = kp.at[flat_b, :, flat_o].set(
             k.reshape(s_ * t_, kh, d).astype(kp.dtype))
-        vp = vp.at[flat_b, flat_o].set(
+        vp = vp.at[flat_b, :, flat_o].set(
             v.reshape(s_ * t_, kh, d).astype(vp.dtype))
         out = paged_attention(
             q, kp, vp, tables, ctx_lens, positions[:, 0],
@@ -792,7 +816,7 @@ class Scheduler:
                           tokens=n_valid, batched=False,
                           trace=seq.trace_id):
             pools, last_logits = self.decoder._prefill(
-                self.params, pools, jnp.asarray(self.tables[seq.slot]),
+                self.params, pools, _upload(self.tables[seq.slot]),
                 jnp.asarray(t0, jnp.int32), jnp.asarray(chunk, jnp.int32),
                 jnp.asarray(n_valid, jnp.int32), final)
         self.k_pools, self.v_pools = pools
@@ -875,10 +899,9 @@ class Scheduler:
 
     def _dev_stable_arrays(self):
         if self._dev_stable is None:
-            self._dev_stable = (
-                jnp.asarray(self.tables), jnp.asarray(self.active),
-                jnp.asarray(self.temp), jnp.asarray(self.top_k),
-                jnp.asarray(self.top_p))
+            self._dev_stable = tuple(
+                _upload(x) for x in (self.tables, self.active, self.temp,
+                                     self.top_k, self.top_p))
         return self._dev_stable
 
     def _decode_once(self) -> None:
@@ -902,7 +925,7 @@ class Scheduler:
                           slots=len(snapshot), traces=_traces):
             pools, self.carry, toks = self.decoder._decode(
                 self.params, pools, self.carry,
-                tables, jnp.asarray(self.seq_lens),
+                tables, _upload(self.seq_lens),
                 active, temp, top_k, top_p, all_greedy)
         self.k_pools, self.v_pools = pools
         # host mirror: every active slot banked one more token
