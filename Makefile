@@ -32,7 +32,7 @@ bench:
 bench-smoke:
 	JAX_PLATFORMS=cpu python bench.py --fast --platform cpu --iters 2
 	JAX_PLATFORMS=cpu python bench.py --fast --platform cpu --iters 2 \
-		--quant int8 --no-decode --no-idle-probe
+		--quant int8 --no-decode
 
 # serving gate (docs/serving.md): drive the continuous-batching engine
 # on a mixed-length staggered workload on CPU, PLUS the shared-prefix
@@ -155,7 +155,7 @@ chaos:
 			tests/test_serving.py tests/test_prefix_cache.py \
 			tests/test_quant.py \
 			tests/test_handoff.py tests/test_tiered.py \
-			tests/test_obs.py tests/test_profiling.py \
+			tests/test_obs.py tests/test_tracing_timeline.py \
 			tests/test_supervisor.py tests/test_fleet.py \
 			tests/test_serve_resilience.py \
 			tests/test_router.py \

@@ -100,10 +100,6 @@ def main() -> int:
                          "Pallas kernel on TPU, XLA dot on CPU — the "
                          "CPU leg is the numerics/plumbing gate, the "
                          "TPU leg the MFU number")
-    ap.add_argument("--no-idle-probe", action="store_true",
-                    help="skip the profiled device_idle_ms window "
-                         "(a few extra steps traced with jax.profiler "
-                         "after the timed loop)")
     ap.add_argument("--dispatch-depth", type=int, default=2,
                     help="perf.dispatch_depth: train steps the host may "
                          "keep in flight (lagged readback; 1 = resolve "
@@ -298,33 +294,6 @@ def _bench(args) -> int:
         host_blocked_ms = trainer.blocked.take_ms() / iters
         trainer.drain()  # resolve any still-in-flight verdicts
 
-    # profiled idle window (separate from the timed loop so tracing
-    # overhead never pollutes the MFU number): a few steps under
-    # jax.profiler, then gap-sum between device ops — overlap wins
-    # (dispatch pipelining, overlap_fsdp) become measurable instead of
-    # inferred from MFU alone
-    device_idle_ms = None
-    idle_detail = None
-    if not args.no_idle_probe:
-        import shutil
-        import tempfile
-        from torchacc_tpu.utils.profiling import device_idle_from_trace
-        idle_iters = min(3, max(1, iters))
-        tdir = tempfile.mkdtemp(prefix="bench_idle_")
-        try:
-            _stage("idle_probe")
-            with jax.profiler.trace(tdir):
-                for _ in range(idle_iters):
-                    m = trainer.step(batch_data)
-                jax.block_until_ready(m["loss"])
-                trainer.drain()
-            idle_detail = device_idle_from_trace(tdir)
-            if idle_detail is not None:
-                device_idle_ms = round(
-                    idle_detail["device_idle_ms"] / idle_iters, 3)
-        finally:
-            shutil.rmtree(tdir, ignore_errors=True)
-
     decode_tps = None
     if not args.no_decode:
         # Decode throughput row (VERDICT r4 next-8): generate() is a
@@ -393,11 +362,6 @@ def _bench(args) -> int:
             "dispatch_depth": max(1, args.dispatch_depth),
             "host_blocked_ms_per_step": round(host_blocked_ms, 3),
             "quant": args.quant,
-            # per-step device idle in the profiled window (gap-sum
-            # between device ops; on CPU an XLA-thread proxy —
-            # device_idle_source 1.0 means a real device plane)
-            "device_idle_ms": device_idle_ms,
-            "device_idle_source": (idle_detail or {}).get("source"),
             "guards": bool(args.guards),
             "fast": bool(args.fast),
             "profile": args.profile,

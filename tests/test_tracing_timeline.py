@@ -1,0 +1,325 @@
+"""One timeline (obs/tracing.py, PR 24): the program's host spans land in
+any open ``jax.profiler`` trace, its device work carries the registered
+``jax.named_scope`` names, and the registries are the one source of both
+lists.
+
+- every registered device scope appears in the op_name metadata of the
+  compiled toy train step / decode / prefill programs (one case a scope);
+- with a profiler trace open on CPU, a toy ``ServeEngine`` and a toy
+  ``Trainer`` put every registered hot-path span on the ``/host:CPU``
+  plane, parents cover their children (one case a span; ONE
+  module-scoped trace serves them all);
+- with both sinks idle ``span()`` is the shared no-op, and the ring
+  stays off while only the profiler sink is live;
+- docs/observability.md's span table lists exactly the registry;
+- the private seams the on-chip benchmark reads keep their names.
+"""
+
+import glob
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+import torchacc_tpu as ta
+from torchacc_tpu.models import TransformerLM, get_preset
+from torchacc_tpu.obs import tracing
+from torchacc_tpu.train import accelerate
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# where each registered device scope has to show up
+TRAIN_SCOPES = ("embed_tokens", "layers", "ln1", "ln2", "attn", "o_proj",
+                "mlp", "final_norm", "flash_fwd", "flash_dq", "flash_dkv",
+                "fused_ce", "optimizer")
+DECODE_SCOPES = ("embed", "layers", "ln1", "ln2", "qkv", "kv_write",
+                 "paged_attn", "o_proj", "mlp", "head", "sample")
+PREFILL_SCOPES = ("embed", "layers", "qkv", "kv_write", "paged_attn",
+                  "mlp", "head")
+
+# the spans a traced serve loop / fit has to leave on the host plane
+SERVE_SPANS = ("serve/step", "serve/sweep", "serve/admit", "serve/prefill",
+               "serve/decode", "serve/deliver", "serve/wait")
+TRAIN_SPANS = ("train/step", "train/dispatch", "train/resolve",
+               "train/wait", "train/data_wait")
+
+
+def _model_cfg(**kw):
+    return get_preset(
+        "llama-tiny", dtype=jnp.float32, num_layers=2, hidden_size=128,
+        num_heads=2, num_kv_heads=2, intermediate_size=256, vocab_size=256,
+        max_seq_len=256, **kw)
+
+
+def _scopes_in(hlo_text):
+    """Every registered scope named by some op_name of a compiled
+    program (a transform wraps the first name under it:
+    ``jvp(fused_ce)``)."""
+    found = set()
+    for path in set(re.findall(r'op_name="([^"]+)"', hlo_text)):
+        for part in path.split("/"):
+            name = re.sub(r"^(?:[\w.\-]+\()+|\)+$", "", part)
+            if name in tracing.DEVICE_SCOPES:
+                found.add(name)
+    return found
+
+
+@pytest.fixture(scope="module")
+def program_scopes():
+    """Scopes in the compiled toy programs: the train step with the
+    Pallas flash kernels (interpret mode) and fused CE, and the paged
+    decoder's decode and prefill programs."""
+    cfg = ta.Config()
+    cfg.compute.attention_impl = "pallas"
+    trainer, _ = accelerate(_model_cfg(), None, cfg,
+                            optimizer=optax.adamw(1e-3))
+    batch = {"input_ids": jnp.zeros((len(jax.devices()), 256), jnp.int32)}
+    shardings = trainer._batch_shardings(batch)
+    batch = {k: jax.device_put(v, shardings[k]) for k, v in batch.items()}
+    trainer.init()
+    trainer._ensure_compiled(batch)
+    assert trainer._use_fused_ce
+    with jax.sharding.set_mesh(trainer.mesh):
+        train = trainer._train_step.lower(trainer.state, batch) \
+            .compile().as_text()
+
+    from torchacc_tpu.serve.scheduler import PagedDecoder
+    mc = _model_cfg()
+    sc = ta.config.ServeConfig(block_size=8, num_blocks=16, max_slots=2,
+                               prefill_chunk=8)
+    decoder = PagedDecoder(mc, sc, "xla")
+    params = jax.eval_shape(
+        lambda: TransformerLM(mc).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    pool = jax.ShapeDtypeStruct((2, 16, 2, 8, 64), mc.dtype)
+    sds = jax.ShapeDtypeStruct
+    carry = {"tok": sds((2,), jnp.int32), "key": sds((2, 2), jnp.uint32)}
+    decode = decoder._decode.lower(
+        params, (pool, pool), carry, sds((2, 15), jnp.int32),
+        sds((2,), jnp.int32), sds((2,), jnp.bool_), sds((2,), jnp.float32),
+        sds((2,), jnp.int32), sds((2,), jnp.float32), False
+    ).compile().as_text()
+    i32 = sds((), jnp.int32)
+    prefill = decoder._prefill.lower(
+        params, (pool, pool), sds((15,), jnp.int32), i32,
+        sds((8,), jnp.int32), i32, True).compile().as_text()
+    return {"train": _scopes_in(train), "decode": _scopes_in(decode),
+            "prefill": _scopes_in(prefill)}
+
+
+@pytest.mark.parametrize("program,scope", [
+    *(("train", s) for s in TRAIN_SCOPES),
+    *(("decode", s) for s in DECODE_SCOPES),
+    *(("prefill", s) for s in PREFILL_SCOPES)])
+def test_device_scope_in_compiled_program(program_scopes, program, scope):
+    assert scope in tracing.DEVICE_SCOPES
+    assert scope in program_scopes[program]
+
+
+def test_every_registered_scope_is_placed():
+    placed = set(TRAIN_SCOPES) | set(DECODE_SCOPES) | set(PREFILL_SCOPES)
+    assert placed == set(tracing.DEVICE_SCOPES)
+
+
+# -- host spans in an open profiler trace -------------------------------------
+
+def _host_events(trace_dir):
+    """(name, start_ns, end_ns) of every /host:CPU event named in the
+    span registry, from the one xplane under ``trace_dir``."""
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in tracing.SPAN_NAMES:
+                    events.append((e.name, e.start_ns,
+                                   e.start_ns + e.duration_ns,
+                                   dict(e.stats)))
+    return events
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """One profiler trace over a toy serve loop and a toy fit, with the
+    ring OFF: what lands in the trace came through the profiler sink."""
+    from torchacc_tpu.serve import Request, ServeEngine
+    mc = get_preset(
+        "llama-tiny", dtype=jnp.float32, num_layers=2, hidden_size=64,
+        num_heads=4, num_kv_heads=2, intermediate_size=128,
+        vocab_size=257, max_seq_len=128)
+    model = TransformerLM(mc)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    engine = ServeEngine(model, params, ta.Config(
+        serve=ta.config.ServeConfig(block_size=8, num_blocks=64,
+                                    max_slots=4, prefill_chunk=8,
+                                    decode_depth=2)))
+    rng = np.random.default_rng(0)
+    reqs = [Request(prompt_ids=rng.integers(1, 257, size=n).tolist(),
+                    max_new_tokens=4) for n in (5, 9)]
+    engine.generate(reqs[:1])              # compile outside the trace
+
+    tmc = get_preset("llama-tiny", vocab_size=64, hidden_size=32,
+                     num_layers=1, num_heads=2, num_kv_heads=2,
+                     intermediate_size=64, dtype=jnp.float32)
+    trainer, _ = accelerate(tmc, None, ta.Config(),
+                            optimizer=optax.adam(1e-3))
+    batches = [{"input_ids": rng.integers(0, 64, size=(8, 16))
+                .astype(np.int32)} for _ in range(3)]
+    trainer.fit(batches[:1], log_every=1)  # compile outside the trace
+
+    assert not tracing.enabled()
+    tracing.clear()
+    trace_dir = str(tmp_path_factory.mktemp("timeline"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        engine.generate(reqs)
+        trainer.fit(batches, log_every=1)
+    finally:
+        jax.profiler.stop_trace()
+    engine.close()
+    return {"events": _host_events(trace_dir),
+            "ring": tracing.snapshot()}
+
+
+@pytest.mark.parametrize("span", SERVE_SPANS + TRAIN_SPANS)
+def test_span_on_the_profilers_host_plane(traced, span):
+    assert span in tracing.SPAN_NAMES
+    assert any(name == span for name, *_ in traced["events"])
+
+
+def _covered(events, child, parent):
+    parents = [(a, b) for n, a, b, _ in events if n == parent]
+    return all(any(pa <= a and b <= pb for pa, pb in parents)
+               for n, a, b, _ in events if n == child)
+
+
+@pytest.mark.parametrize("child,parent", [
+    ("serve/decode", "serve/step"), ("serve/prefill", "serve/step"),
+    ("serve/admit", "serve/step"), ("serve/deliver", "serve/step"),
+    ("serve/wait", "serve/deliver"), ("train/dispatch", "train/step")])
+def test_parent_span_covers_child(traced, child, parent):
+    assert _covered(traced["events"], child, parent)
+
+
+def test_profiler_sink_carries_scalars_and_leaves_the_ring_off(traced):
+    assert traced["ring"] == []            # ObsConfig gates the ring only
+    admits = [st for n, _, _, st in traced["events"] if n == "serve/admit"]
+    assert any(str(st.get("admitted")) == "1" and "queue_ms" in st
+               for st in admits)
+    decodes = [st for n, _, _, st in traced["events"] if n == "serve/decode"]
+    assert decodes and all("slots" in st and "traces" not in st
+                           for st in decodes)
+
+
+# -- the idle path and the ring ------------------------------------------------
+
+def test_span_is_the_shared_noop_with_both_sinks_idle():
+    assert not tracing.enabled()
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    assert tracing.span("serve/decode", iter=1) is tracing._NULL
+    assert tracing.span("train/step") is tracing._NULL
+    with tracing.span("serve/admit") as sp:
+        sp.set(admitted=0)
+        sp.discard()                       # the no-op takes both calls
+    assert tracing.snapshot() == []
+
+
+def test_discarded_span_stays_out_of_the_ring():
+    tracing.configure(enabled=True)
+    try:
+        tracing.clear()
+        with tracing.span("serve/admit", sid=1) as sp:
+            sp.discard()
+            with tracing.span("serve/prefill") as inner:
+                pass
+        with tracing.span("serve/admit", sid=2):
+            pass
+        names = [(s["name"], s["attrs"].get("sid"))
+                 for s in tracing.snapshot()]
+        assert names == [("serve/prefill", None), ("serve/admit", 2)]
+        assert inner.parent is None        # a discarded span is no parent
+    finally:
+        tracing.configure(enabled=False)
+        tracing.clear()
+
+
+# -- one registry ----------------------------------------------------------------
+
+def _doc_table(heading):
+    text = open(os.path.join(ROOT, "docs", "observability.md")).read()
+    section = text.split(heading, 1)[1].split("\n##", 1)[0]
+    return re.findall(r"^\| `([^`]+)` \|", section, flags=re.M)
+
+
+def test_docs_span_table_is_the_registry():
+    assert sorted(_doc_table("### Span registry")) == \
+        sorted(tracing.SPAN_NAMES)
+
+
+def test_docs_scope_table_is_the_registry():
+    assert sorted(_doc_table("### Device scope registry")) == \
+        sorted(tracing.DEVICE_SCOPES)
+
+
+def test_every_span_name_used_in_the_package_is_registered():
+    used = set()
+    for path in glob.glob(os.path.join(ROOT, "torchacc_tpu", "**", "*.py"),
+                          recursive=True):
+        if path.endswith(os.path.join("obs", "tracing.py")):
+            continue                       # its docstrings show the call
+        used |= set(re.findall(
+            r'(?:tracing\.|\b)(?:span|record_span)\(\s*"([^"]+)"',
+            open(path).read()))
+    assert used == set(tracing.SPAN_NAMES)
+
+
+# -- the seams the on-chip benchmark reads (chipbench/, PERF.md section 7) ----
+
+def test_benchmark_seams_keep_their_names():
+    """``chipbench/`` reads these privates and a later PR may not edit
+    it: a rename has to fail here first."""
+    from torchacc_tpu.serve import ServeEngine
+    from torchacc_tpu.serve.scheduler import PagedDecoder, Scheduler
+    from torchacc_tpu.train.trainer import Trainer
+
+    assert callable(Trainer._batch_shardings)
+    assert callable(Trainer._build_train_step)
+    trainer, _ = accelerate(
+        get_preset("llama-tiny", vocab_size=64, hidden_size=32,
+                   num_layers=1, num_heads=2, num_kv_heads=2,
+                   intermediate_size=64, dtype=jnp.float32),
+        None, ta.Config(), optimizer=optax.adam(1e-3))
+    assert hasattr(trainer, "_train_step") and hasattr(trainer, "blocked")
+    assert hasattr(trainer, "state_shardings")
+
+    mc = get_preset(
+        "llama-tiny", dtype=jnp.float32, num_layers=1, hidden_size=64,
+        num_heads=4, num_kv_heads=2, intermediate_size=128,
+        vocab_size=257, max_seq_len=64)
+    model = TransformerLM(mc)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    engine = ServeEngine(model, params, ta.Config(
+        serve=ta.config.ServeConfig(block_size=8, num_blocks=16,
+                                    max_slots=2, prefill_chunk=8)))
+    sched = engine.scheduler
+    assert isinstance(sched, Scheduler)
+    assert isinstance(sched.decoder, PagedDecoder)
+    assert sched.decoder.impl in ("pallas", "xla")
+    assert len(sched.slot_seq) == 2 and sched._iter == 0
+    assert sched.seq_lens.shape == (2,) and sched.active.shape == (2,)
+    for name in ("_decode", "_prefill"):
+        assert hasattr(sched.decoder, name)
+    engine.close()

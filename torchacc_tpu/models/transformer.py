@@ -966,8 +966,15 @@ class TransformerLM(nn.Module):
             length=cfg.num_layers,
             metadata_params={nn.PARTITION_NAME: "layers"},
         )(cfg, name="layers")
+        # every application path below runs under the registered device
+        # scope "layers" (obs/tracing.py DEVICE_SCOPES): the loop's own
+        # ops — per-layer slices of the stack, the scan's saved-residual
+        # stacking — read under it in a profiler trace, the blocks'
+        # parts under their module names inside it
         if self.is_initializing():
-            (x, _, _), _ = scan_mod((x, positions, segment_ids), seeds_xs)
+            with jax.named_scope("layers"):
+                (x, _, _), _ = scan_mod((x, positions, segment_ids),
+                                        seeds_xs)
         elif cfg.layer_pattern and cfg.pp_size <= 1:
             # heterogeneous layers (gemma2-style sliding/global
             # alternation): the pattern is param-free, so params keep the
@@ -992,9 +999,10 @@ class TransformerLM(nn.Module):
                 if _block_remat(cfg):
                     fn = jax.checkpoint(fn, policy=_rp(cfg.remat_policy),
                                         prevent_cse=False)
-                p_i = jax.tree.map(lambda a, i=i: a[i], layer_params)
-                s_i = None if seeds_xs is None else seeds_xs[i]
-                carry, aux = fn(p_i, carry, s_i)
+                with jax.named_scope("layers"):
+                    p_i = jax.tree.map(lambda a, i=i: a[i], layer_params)
+                    s_i = None if seeds_xs is None else seeds_xs[i]
+                    carry, aux = fn(p_i, carry, s_i)
                 aux_total = aux_total + aux
             if cfg.num_experts > 0:
                 self.sow("intermediates", "moe_aux_loss", aux_total)
@@ -1048,15 +1056,16 @@ class TransformerLM(nn.Module):
 
             apply_arg, unroll = pp_block_appliers(cfg, mk_apply)
             from torchacc_tpu.utils.remat import remat_policy
-            res = pipeline_blocks(
-                apply_arg, stacked, carry0,
-                pp_size=cfg.pp_size, num_micro=cfg.pp_num_micro,
-                virtual_stages=cfg.pp_virtual,
-                remat=cfg.remat,
-                remat_policy=(remat_policy(cfg.remat_policy)
-                              if cfg.remat else None),
-                aux_from_block=moe_on,
-                unroll_stage=unroll)
+            with jax.named_scope("layers"):
+                res = pipeline_blocks(
+                    apply_arg, stacked, carry0,
+                    pp_size=cfg.pp_size, num_micro=cfg.pp_num_micro,
+                    virtual_stages=cfg.pp_virtual,
+                    remat=cfg.remat,
+                    remat_policy=(remat_policy(cfg.remat_policy)
+                                  if cfg.remat else None),
+                    aux_from_block=moe_on,
+                    unroll_stage=unroll)
             if moe_on:
                 x, aux_total = res
                 if aux_weighted:
@@ -1168,14 +1177,15 @@ class TransformerLM(nn.Module):
             n_gc = cfg.num_layers if split_n is None else split_n
             for i in range(cfg.num_layers):
                 fn = raw_gc if (i < n_gc and cfg.remat) else raw_plain
-                p_i = slice_i(layer_params, i)
-                seed_i = None if seeds_xs is None else seeds_xs[i]
-                if quant_blocks:
-                    carry, aux, q_i = fn(p_i, slice_i(layer_quant, i),
-                                         carry, seed_i)
-                    new_quant.append(q_i)
-                else:
-                    carry, aux = fn(p_i, carry, seed_i)
+                with jax.named_scope("layers"):
+                    p_i = slice_i(layer_params, i)
+                    seed_i = None if seeds_xs is None else seeds_xs[i]
+                    if quant_blocks:
+                        carry, aux, q_i = fn(p_i, slice_i(layer_quant, i),
+                                             carry, seed_i)
+                        new_quant.append(q_i)
+                    else:
+                        carry, aux = fn(p_i, carry, seed_i)
                 aux_total = aux_total + aux
             if quant_blocks and self.is_mutable_collection("quant"):
                 self.put_variable(
@@ -1216,18 +1226,21 @@ class TransformerLM(nn.Module):
             carry = (x, positions, segment_ids)
             aux_total = jnp.zeros((), jnp.float32)
             if split_n > 0:
-                carry, aux = seg(apply_gc, head, 0, split_n, carry)
+                with jax.named_scope("layers"):
+                    carry, aux = seg(apply_gc, head, 0, split_n, carry)
                 aux_total = aux_total + jnp.sum(aux)
             if split_n < cfg.num_layers:
-                carry, aux = seg(apply_plain, tail, split_n,
-                                 cfg.num_layers, carry)
+                with jax.named_scope("layers"):
+                    carry, aux = seg(apply_plain, tail, split_n,
+                                     cfg.num_layers, carry)
                 aux_total = aux_total + jnp.sum(aux)
             if cfg.num_experts > 0:
                 self.sow("intermediates", "moe_aux_loss", aux_total)
             x = carry[0]
         else:
-            (x, _, _), _ = scan_mod((x, positions, segment_ids),
-                                    seeds_xs)
+            with jax.named_scope("layers"):
+                (x, _, _), _ = scan_mod((x, positions, segment_ids),
+                                        seeds_xs)
 
         x = scale_hidden(cfg, Norm(cfg, name="final_norm")(x))
         if return_hidden:

@@ -11,32 +11,25 @@ and the whole buffer exports as Chrome-trace / Perfetto JSON
 (``export_chrome_trace``) so spans land on the same timeline viewers
 that already open ``jax.profiler`` traces.
 
-Zero-cost when disabled: ``span()`` returns a shared no-op context
-manager — one dict lookup and one ``if`` per call site, no allocation,
-no lock — so instrumentation stays in the hot path unconditionally and
-``ObsConfig.enabled`` is the only switch (bench.py --obs measures the
-residual as ``telemetry_overhead_ms_per_step``).
+Two sinks, one interval.  A span is recorded into the ring (gated by
+``ObsConfig.enabled/trace`` — the operator's always-on flight recorder)
+AND, whenever anyone has a ``jax.profiler`` trace open (an operator's
+``utils/profiling.trace``, a benchmark's traced run), as a
+``jax.profiler.TraceAnnotation`` on the ``/host:CPU`` plane of that
+trace: the same nanoseconds as the device ops, no merge step.  Only
+scalar attributes reach the profiler sink; lists stay ring-only.
 
-Span-name registry (one home; docs/observability.md has the table):
+Near-free with both sinks idle: ``span()`` returns a shared no-op
+context manager — one ``if`` and one ``TraceAnnotation.is_enabled()``
+(an atomic load) per call site, no allocation, no lock — so
+instrumentation stays in the hot path unconditionally (PERF.md has the
+nanoseconds).
 
-==================  =========================================================
-span                emitted by
-==================  =========================================================
-train/dispatch      Trainer.step — enqueue of one jitted train step
-train/resolve       Trainer.resolve_oldest — lagged readback of step N-k
-train/verdict       inside resolve — guard + SDC verdict fetch/compare
-train/save          Trainer.fit — snapshot + checkpoint hand-off on a
-                    writing step
-ckpt/tier0_fetch    tiered writer thread — device -> host RAM fetch
-ckpt/tier1_commit   tiered writer/pump — orbax commit-marker write
-ckpt/mirror         tiered writer — tier-2 mirror copy
-serve/queue         admission — submit -> slot (recorded at admit time)
-serve/admit         Scheduler.admit — block reservation + prefix match
-serve/prefill       Scheduler — one prefill chunk (single or batched)
-serve/decode        Scheduler._decode_once — one batched decode dispatch
-serve/deliver       Scheduler._resolve_one — token readback + stream
-                    callbacks for one ring entry
-==================  =========================================================
+``SPAN_NAMES`` (the keys of the ``SPANS`` table) is the one registry
+of host span names and ``DEVICE_SCOPES`` (of ``SCOPES``) the one
+registry of ``jax.named_scope`` names the device programs carry;
+docs/observability.md's tables, the tests and the benchmark's trace
+reader all take the names from here.
 """
 
 from __future__ import annotations
@@ -48,7 +41,84 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
+#: every host span name -> where it is emitted
+SPANS: Dict[str, str] = {
+    "train/step": "Trainer.step — one whole step call (parent of "
+                  "dispatch, resolve and wait)",
+    "train/dispatch": "Trainer.step — enqueue of one jitted train step",
+    "train/resolve": "Trainer.resolve_oldest — lagged readback of step "
+                     "N-k",
+    "train/verdict": "inside resolve — guard + SDC verdict "
+                     "fetch/compare",
+    "train/wait": "every blocking device fetch Trainer.blocked meters",
+    "train/data_wait": "Trainer.fit — next(loader): the input "
+                       "pipeline's share of a step",
+    "train/save": "Trainer.fit — snapshot + checkpoint hand-off on a "
+                  "writing step",
+    "ckpt/tier0_fetch": "tiered writer thread — device -> host RAM "
+                        "fetch",
+    "ckpt/tier0_shard_fetch": "tiered writer thread — this host's "
+                              "shards only (sharded tier-0)",
+    "ckpt/tier1_commit": "tiered writer/pump — orbax commit-marker "
+                         "write",
+    "ckpt/mirror": "tiered writer — tier-2 mirror copy",
+    "serve/step": "ServeEngine.step — one engine iteration (parent of "
+                  "everything below)",
+    "serve/sweep": "ServeEngine.step — deadline shed/preempt sweeps + "
+                   "completion accounting",
+    "serve/queue": "admission — submit -> slot (ring only, recorded at "
+                   "admit time; the profiler sink carries it as "
+                   "serve/admit's queue_ms)",
+    "serve/admit": "Scheduler.admit — block reservation + prefix match "
+                   "(ring: successful admissions only)",
+    "serve/prefill": "Scheduler — one prefill chunk (single or batched)",
+    "serve/decode": "Scheduler._decode_once — one batched decode "
+                    "dispatch",
+    "serve/deliver": "Scheduler._resolve_one — token readback + stream "
+                     "callbacks for one ring entry",
+    "serve/wait": "inside deliver — the one blocking token fetch",
+}
+
+SPAN_NAMES = tuple(SPANS)
+
+#: every ``jax.named_scope`` the device programs carry -> the work under
+#: it.  An op belongs to the INNERMOST registered name on its op_name
+#: path, so these partition the device's leaf-op time; the same name is
+#: the same work in training (Flax module names) and serving (scopes in
+#: ``PagedDecoder``).
+SCOPES: Dict[str, str] = {
+    "embed_tokens": "token embedding lookup (training: the Flax module)",
+    "embed": "token (+ position) embedding lookup (serving)",
+    "layers": "the layer scan's own ops: per-layer slices of stacked "
+              "weights and pools, carry copies",
+    "ln1": "pre-attention norm",
+    "ln2": "pre-MLP norm",
+    "attn": "attention block outside its kernels: projections, rope, "
+            "relayouts (training)",
+    "qkv": "q/k/v projections, qk-norm and rope (serving)",
+    "kv_write": "the two scatter updates of the paged KV pool",
+    "paged_attn": "the paged-attention kernel and its relayouts",
+    "o_proj": "attention output projection + residual (serving)",
+    "mlp": "feed-forward block",
+    "final_norm": "norm before the head",
+    "head": "vocabulary projection (serving)",
+    "sample": "next-token choice from the logits (serving)",
+    "flash_fwd": "flash-attention forward kernel",
+    "flash_dq": "flash-attention backward kernel, dq",
+    "flash_dkv": "flash-attention backward kernel, dk and dv",
+    "fused_ce": "chunked head matmul + cross-entropy, forward, "
+                "backward and rematerialised chunks",
+    "optimizer": "gradient norm, clipping, optimizer update and "
+                 "parameter apply",
+}
+
+DEVICE_SCOPES = tuple(SCOPES)
+
 _DEFAULT_BUFFER = 4096
+_SCALARS = (int, float, str, bool)
+_profiling = TraceAnnotation.is_enabled
 
 _enabled = False
 _buf: "deque[Dict[str, Any]]" = deque(maxlen=_DEFAULT_BUFFER)
@@ -106,35 +176,71 @@ class _NullSpan:
     def set(self, **attrs) -> None:
         pass
 
+    def discard(self) -> None:
+        pass
+
 
 _NULL = _NullSpan()
 
 
+def _scalars(attrs: Dict[str, Any]) -> Dict[str, Any]:
+    return {k: v for k, v in attrs.items() if isinstance(v, _SCALARS)}
+
+
 class _Span:
-    __slots__ = ("name", "attrs", "id", "parent", "_t0")
+    """One open interval feeding whichever sinks are live: ``ring``
+    (record into the buffer on exit) and/or a profiler annotation."""
+
+    __slots__ = ("name", "attrs", "id", "parent", "_t0", "_ring",
+                 "_to_profiler", "_prof")
 
     def __init__(self, name: str, attrs: Dict[str, Any],
-                 parent: Optional[int]):
+                 parent: Optional[int], ring: bool, prof: bool):
         self.name = name
         self.attrs = attrs
-        self.id = next(_ids)
         self.parent = parent
         self._t0 = 0.0
+        self._ring = ring
+        self.id = next(_ids) if ring else 0
+        self._to_profiler = prof
+        self._prof = None       # the annotation, while entered
 
     def set(self, **attrs) -> None:
         """Attach attributes after entry (e.g. a result computed
         inside the span)."""
         self.attrs.update(attrs)
+        if self._prof is not None:
+            self._prof.set_metadata(**_scalars(attrs))
+
+    def discard(self) -> None:
+        """Keep this interval out of the ring (the profiler sink, whose
+        events cannot be withdrawn, still shows it)."""
+        if self._ring:
+            self._ring = False
+            st = _stack()
+            if st and st[-1] == self.id:
+                st.pop()
 
     def __enter__(self) -> "_Span":
-        st = _stack()
-        if self.parent is None and st:
-            self.parent = st[-1]
-        st.append(self.id)
-        self._t0 = time.perf_counter()
+        if self._ring:
+            st = _stack()
+            if self.parent is None and st:
+                self.parent = st[-1]
+            st.append(self.id)
+            self._t0 = time.perf_counter()
+        if self._to_profiler:
+            # built here, not in __init__: a TraceMe's clock starts
+            # when it is built
+            self._prof = TraceAnnotation(self.name, **_scalars(self.attrs))
+            self._prof.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
+        if self._prof is not None:
+            self._prof.__exit__(*exc)
+            self._prof = None
+        if not self._ring:
+            return False
         t1 = time.perf_counter()
         st = _stack()
         if st and st[-1] == self.id:
@@ -156,10 +262,11 @@ def span(name: str, *, parent: Optional[int] = None, **attrs):
     """Nestable tracing span.  ``parent`` overrides the thread-stack
     parent (cross-thread linking: pass :func:`current_span_id` captured
     on the submitting thread).  No-op (shared singleton, no allocation)
-    while tracing is disabled."""
-    if not _enabled:
+    while the ring is disabled and no ``jax.profiler`` trace is open."""
+    prof = _profiling()
+    if not (_enabled or prof):
         return _NULL
-    return _Span(name, attrs, parent)
+    return _Span(name, attrs, parent, _enabled, prof)
 
 
 def record_span(name: str, start: float, end: float, *,

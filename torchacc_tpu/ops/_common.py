@@ -2,9 +2,24 @@
 
 from __future__ import annotations
 
+import functools
+
 import jax
 
 NEG_INF = -1e30
+
+
+def scoped(name: str):
+    """Decorator: trace the function's ops under ``jax.named_scope(name)``
+    (a registered device scope, obs/tracing.py ``DEVICE_SCOPES``) — its
+    forward, its autodiff backward and any rematerialisation inherit it."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return wrapped
+    return deco
 
 
 def on_tpu() -> bool:

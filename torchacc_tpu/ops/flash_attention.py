@@ -348,7 +348,7 @@ def _fwd(q, k, v, q_segment_ids, kv_segment_ids, alibi_slopes, meta, scale,
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         args.append(meta)
 
-    o, lse4 = pl.pallas_call(
+    fwd_call = pl.pallas_call(
         kernel,
         grid=(b, hq, nq, nk),
         in_specs=in_specs,
@@ -371,7 +371,12 @@ def _fwd(q, k, v, q_segment_ids, kv_segment_ids, alibi_slopes, meta, scale,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=_interpret(),
-    )(*args)
+        name="flash_fwd",
+    )
+    # scoped apart from the rope/transposes around it (obs/tracing.py
+    # DEVICE_SCOPES): the kernel's device time reads under its own name
+    with jax.named_scope("flash_fwd"):
+        o, lse4 = fwd_call(*args)
     return o, lse4[..., 0]
 
 
@@ -599,7 +604,7 @@ def _bwd(res, do, *, scale, causal, window, block_q, block_k, qk_shift=0,
                      lambda b_, h, qi, ki: (b_, h, qi, 0)),
     ]
     args += [do, lse4, delta4]
-    dq = pl.pallas_call(
+    dq_call = pl.pallas_call(
         _mk_kernel(_bwd_dq_kernel, has_seg, has_alibi, has_meta,
                    num_kv_blocks=nk, **common),
         grid=(b, hq, nq, nk),
@@ -612,7 +617,10 @@ def _bwd(res, do, *, scale, causal, window, block_q, block_k, qk_shift=0,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=_interpret(),
-    )(*args)
+        name="flash_dq",
+    )
+    with jax.named_scope("flash_dq"):
+        dq = dq_call(*args)
 
     # ---- dk/dv: grid (b, hk, nk, group, nq) — the (group, q-block) inner
     # sweep accumulates in VMEM scratch, writing dk/dv once per kv head ----
@@ -650,7 +658,7 @@ def _bwd(res, do, *, scale, causal, window, block_q, block_k, qk_shift=0,
                      lambda b_, hkv, ki, g, qi: (b_, hkv * group + g, qi, 0)),
     ]
     args += [do, lse4, delta4]
-    dk, dv = pl.pallas_call(
+    dkv_call = pl.pallas_call(
         _mk_kernel(_bwd_dkv_kernel, has_seg, has_alibi, has_meta,
                    num_q_blocks=nq, group=group, **common),
         grid=(b, hk, nk, group, nq),
@@ -673,7 +681,10 @@ def _bwd(res, do, *, scale, causal, window, block_q, block_k, qk_shift=0,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary", "arbitrary")),
         interpret=_interpret(),
-    )(*args)
+        name="flash_dkv",
+    )
+    with jax.named_scope("flash_dkv"):
+        dk, dv = dkv_call(*args)
     return (dq, dk, dv, None, None, None, None)
 
 

@@ -19,6 +19,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from torchacc_tpu.ops._common import scoped
+
 
 def _scan_free_chunk(n: int, chunk_rows: int) -> int:
     """Pick the scan_free chunk size: the divisor of n nearest chunk_rows.
@@ -37,6 +39,7 @@ def _scan_free_chunk(n: int, chunk_rows: int) -> int:
     return min([d for d in divisors if d >= chunk_rows] or [n])
 
 
+@scoped("fused_ce")
 def fused_linear_cross_entropy(
     hidden: jax.Array,
     w_head: jax.Array,
@@ -137,6 +140,7 @@ def fused_linear_cross_entropy(
     return loss_sum, count
 
 
+@scoped("fused_ce")
 def fused_linear_cross_entropy_tp(
     hidden: jax.Array,
     w_head: jax.Array,

@@ -44,6 +44,7 @@ from typing import Any, Dict, List, Optional, Sequence as Seq
 import numpy as np
 
 from torchacc_tpu.config import Config
+from torchacc_tpu.obs import tracing
 from torchacc_tpu.serve.journal import RequestJournal, read_journal, replay_state
 from torchacc_tpu.serve.scheduler import Scheduler, Sequence, priority_key
 from torchacc_tpu.utils.logger import logger
@@ -629,15 +630,21 @@ class ServeEngine:
     def step(self) -> bool:
         """One engine iteration (admission + scheduler.step + completion
         accounting).  Returns True while there is work anywhere."""
-        self._shed_expired()
-        self._preempt_expired()
+        with tracing.span("serve/step"):
+            return self._step_impl()
+
+    def _step_impl(self) -> bool:
+        with tracing.span("serve/sweep"):
+            self._shed_expired()
+            self._preempt_expired()
         with self._mesh_ctx():
             # admission inside the mesh context too: a fully-cached
             # prompt's admit dispatches the copy-on-write program over
             # the (possibly tp-sharded) pools
             self._admit()
             self.scheduler.step()
-        self._drain_events()
+        with tracing.span("serve/sweep"):
+            self._drain_events()
         # liveness heartbeat (the serve /healthz check): every completed
         # iteration proves the loop is alive; a decode wedged on device
         # blocks INSIDE this method, so the age grows while it hangs

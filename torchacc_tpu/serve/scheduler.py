@@ -235,13 +235,52 @@ class PagedDecoder:
         [S, T] name the pool slot every token writes its k/v to (the
         null block for masked tokens); ``ctx_lens`` is the post-write
         context length per slot."""
-        from torchacc_tpu.models.transformer import Norm, _rope
+        from torchacc_tpu.models.transformer import Norm
 
+        # the named scopes are registered device scopes (obs/tracing.py
+        # DEVICE_SCOPES): a profiler trace reads each part's device
+        # time under the same names the training step's modules carry
         cfg = self.cfg
         kp, vp = pools_l
         s_, t_ = x.shape[:2]
-        h = Norm(cfg).apply({"params": p["ln1"]}, x)
+        with jax.named_scope("ln1"):
+            h = Norm(cfg).apply({"params": p["ln1"]}, x)
         attn = p["attn"]
+        with jax.named_scope("qkv"):
+            q, k, v = self._qkv(attn, h, positions)
+        # bank this chunk's (rotated) k / raw v into the pool, THEN
+        # attend over the updated pool — same write-before-read order
+        # as the module's dense-cache decode branch
+        flat_b, flat_o = blk.reshape(-1), off.reshape(-1)
+        kh, d = kp.shape[1], kp.shape[3]
+        # pool is [NB, KH, BS, D]: index (block, :, offset) -> [N, KH, D]
+        with jax.named_scope("kv_write"):
+            kp = kp.at[flat_b, :, flat_o].set(
+                k.reshape(s_ * t_, kh, d).astype(kp.dtype))
+            vp = vp.at[flat_b, :, flat_o].set(
+                v.reshape(s_ * t_, kh, d).astype(vp.dtype))
+        with jax.named_scope("paged_attn"):
+            out = paged_attention(
+                q, kp, vp, tables, ctx_lens, positions[:, 0],
+                scale=cfg.query_scale, window=cfg.window,
+                logit_softcap=cfg.attn_logit_softcap, impl=self.impl)
+        with jax.named_scope("o_proj"):
+            x = x + self._dense(
+                out.reshape(s_, t_, -1),
+                attn["o_proj"]["kernel"].reshape(-1, cfg.hidden_size),
+                attn["o_proj"].get("bias"))
+        with jax.named_scope("ln2"):
+            h2 = Norm(cfg).apply({"params": p["ln2"]}, x)
+        with jax.named_scope("mlp"):
+            x = x + self._mlp(p["mlp"], h2)
+        return x, (kp, vp)
+
+    def _qkv(self, attn, h, positions):
+        """q/k/v projections, qk-norm and rope of one layer."""
+        from torchacc_tpu.models.transformer import Norm, _rope
+
+        cfg = self.cfg
+        s_, t_ = h.shape[:2]
         q = self._dense(h, attn["q_proj"]["kernel"],
                         attn["q_proj"].get("bias"))
         k = self._dense(h, attn["k_proj"]["kernel"],
@@ -261,27 +300,13 @@ class PagedDecoder:
             rp = (positions.astype(jnp.float32) / cfg.rope_scale
                   if cfg.rope_scale != 1.0 else positions)
             q, k = _rope(q, k, rp, cfg)
-        # bank this chunk's (rotated) k / raw v into the pool, THEN
-        # attend over the updated pool — same write-before-read order
-        # as the module's dense-cache decode branch
-        flat_b, flat_o = blk.reshape(-1), off.reshape(-1)
-        kh, d = kp.shape[1], kp.shape[3]
-        # pool is [NB, KH, BS, D]: index (block, :, offset) -> [N, KH, D]
-        kp = kp.at[flat_b, :, flat_o].set(
-            k.reshape(s_ * t_, kh, d).astype(kp.dtype))
-        vp = vp.at[flat_b, :, flat_o].set(
-            v.reshape(s_ * t_, kh, d).astype(vp.dtype))
-        out = paged_attention(
-            q, kp, vp, tables, ctx_lens, positions[:, 0],
-            scale=cfg.query_scale, window=cfg.window,
-            logit_softcap=cfg.attn_logit_softcap, impl=self.impl)
-        x = x + self._dense(
-            out.reshape(s_, t_, -1),
-            attn["o_proj"]["kernel"].reshape(-1, cfg.hidden_size),
-            attn["o_proj"].get("bias"))
-        h2 = Norm(cfg).apply({"params": p["ln2"]}, x)
-        mlp = p["mlp"]
+        return q, k, v
+
+    def _mlp(self, mlp, h2):
+        """The feed-forward block's output (before the residual add)."""
         import flax.linen as nn
+
+        cfg = self.cfg
         if cfg.activation in ("swiglu", "geglu"):
             gate = self._dense(h2, mlp["gate_proj"]["kernel"],
                                mlp["gate_proj"].get("bias"))
@@ -298,9 +323,8 @@ class PagedDecoder:
                 ff = nn.gelu(up, approximate=False)
             else:
                 ff = nn.gelu(up)
-        x = x + self._dense(ff, mlp["down_proj"]["kernel"],
-                            mlp["down_proj"].get("bias"))
-        return x, (kp, vp)
+        return self._dense(ff, mlp["down_proj"]["kernel"],
+                           mlp["down_proj"].get("bias"))
 
     def _forward(self, params, pools, ids, positions, tables, ctx_lens,
                  blk, off):
@@ -312,7 +336,8 @@ class PagedDecoder:
         but one)."""
         from torchacc_tpu.models.generate import _zoo_embed
 
-        x = _zoo_embed(self.cfg, params, ids, positions)
+        with jax.named_scope("embed"):
+            x = _zoo_embed(self.cfg, params, ids, positions)
         k_pools, v_pools = pools
 
         def body(carry, per):
@@ -321,8 +346,9 @@ class PagedDecoder:
                                       (kp, vp), tables, ctx_lens, blk, off)
             return y, (kp, vp)
 
-        x, (k_pools, v_pools) = jax.lax.scan(
-            body, x, (params["layers"], k_pools, v_pools))
+        with jax.named_scope("layers"):
+            x, (k_pools, v_pools) = jax.lax.scan(
+                body, x, (params["layers"], k_pools, v_pools))
         return (k_pools, v_pools), x
 
     # -- sampling -----------------------------------------------------------
@@ -379,13 +405,15 @@ class PagedDecoder:
                                  positions, tables, ctx,
                                  blk[:, None], off[:, None])
         from torchacc_tpu.models.transformer import head_logits
-        logits = head_logits(self.cfg, params, x)
-        split = jax.vmap(jax.random.split)(carry["key"])
-        if all_greedy:
-            toks = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-        else:
-            toks = self._sample_slots(logits[:, 0], split[:, 1], temp,
-                                      top_k, top_p)
+        with jax.named_scope("head"):
+            logits = head_logits(self.cfg, params, x)
+        with jax.named_scope("sample"):
+            split = jax.vmap(jax.random.split)(carry["key"])
+            if all_greedy:
+                toks = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+            else:
+                toks = self._sample_slots(logits[:, 0], split[:, 1], temp,
+                                          top_k, top_p)
         return pools, {"tok": toks, "key": split[:, 0]}, toks
 
     def _prefill_impl(self, params, pools, table_row, t0, tokens, n_valid,
@@ -413,10 +441,11 @@ class PagedDecoder:
         if not is_final:
             return pools, None
         from torchacc_tpu.models.transformer import head_logits
-        logits = head_logits(self.cfg, params, x)
-        last = jnp.take_along_axis(
-            logits[0], jnp.maximum(n_valid - 1, 0)[None, None],
-            axis=0)[0]                                             # [V]
+        with jax.named_scope("head"):
+            logits = head_logits(self.cfg, params, x)
+            last = jnp.take_along_axis(
+                logits[0], jnp.maximum(n_valid - 1, 0)[None, None],
+                axis=0)[0]                                         # [V]
         return pools, last
 
     def _prefill_batch_impl(self, params, pools, table_rows, t0s, tokens,
@@ -443,9 +472,10 @@ class PagedDecoder:
         pools, x = self._forward(params, pools, tokens, positions,
                                  table_rows, ctx, blk, off)
         from torchacc_tpu.models.transformer import head_logits
-        last = jnp.take_along_axis(
-            x, jnp.maximum(n_valids - 1, 0)[:, None, None], axis=1)
-        logits = head_logits(self.cfg, params, last)             # [PB, 1, V]
+        with jax.named_scope("head"):
+            last = jnp.take_along_axis(
+                x, jnp.maximum(n_valids - 1, 0)[:, None, None], axis=1)
+            logits = head_logits(self.cfg, params, last)         # [PB, 1, V]
         return pools, logits[:, 0]
 
     def _cow_impl(self, pools, src, dst):
@@ -460,8 +490,9 @@ class PagedDecoder:
         return kp, vp
 
     def _sample_first_impl(self, logits, key, temp, top_k, top_p):
-        return self._sample_slots(logits[None], key[None], temp[None],
-                                  top_k[None], top_p[None])[0]
+        with jax.named_scope("sample"):
+            return self._sample_slots(logits[None], key[None], temp[None],
+                                      top_k[None], top_p[None])[0]
 
     def _set_slot_impl(self, carry, slot, token, key):
         return {"tok": carry["tok"].at[slot].set(token),
@@ -643,29 +674,30 @@ class Scheduler:
         retries next iteration).  With the prefix cache on, the longest
         token-hash-chain match replaces that many fresh blocks with
         refcounted shared ones and prefill starts past them."""
-        if not tracing.enabled():
-            return self._admit_impl(seq)
-        t0 = time.perf_counter()
-        ok = self._admit_impl(seq)
-        # spans only for SUCCESSFUL admissions: a saturated engine
-        # re-attempts its queue head every iteration, and one
-        # admitted=False span per retry would evict the useful spans
-        # from the bounded ring exactly when an operator exports it
-        # (failed-admission pressure is visible as serve_queue_depth
-        # + kv_pool_free_blocks instead)
-        if ok:
+        with tracing.span("serve/admit", sid=seq.sid,
+                          trace=seq.trace_id) as sp:
+            ok = self._admit_impl(seq)
+            if not ok:
+                # the ring keeps SUCCESSFUL admissions only: a saturated
+                # engine re-attempts its queue head every iteration, and
+                # one admitted=False span per retry would evict the
+                # useful spans from the bounded ring exactly when an
+                # operator exports it (failed-admission pressure is
+                # visible as serve_queue_depth + kv_pool_free_blocks
+                # instead).  A profiler trace shows the attempt, marked.
+                sp.discard()
+                sp.set(admitted=0)
+                return False
+            # the queue wait, known only now (submit -> slot admission)
+            queue_s = (max(seq.t_admit - seq.t_submit, 0.0)
+                       if seq.t_submit else 0.0)
+            sp.set(admitted=1, cached_tokens=seq.cached_tokens,
+                   queue_ms=queue_s * 1e3)
+        if seq.t_submit and tracing.enabled():
             now = time.perf_counter()
-            tracing.record_span("serve/admit", t0, now, sid=seq.sid,
-                                trace=seq.trace_id,
-                                cached_tokens=seq.cached_tokens)
-            if seq.t_submit:
-                # the queue-wait interval, recorded at the only moment
-                # both endpoints are known (submit -> slot admission)
-                tracing.record_span(
-                    "serve/queue",
-                    now - max(seq.t_admit - seq.t_submit, 0.0), now,
-                    sid=seq.sid, trace=seq.trace_id)
-        return ok
+            tracing.record_span("serve/queue", now - queue_s, now,
+                                sid=seq.sid, trace=seq.trace_id)
+        return True
 
     def _admit_impl(self, seq: Sequence) -> bool:
         slot = self.free_slot()
@@ -1019,11 +1051,14 @@ class Scheduler:
                        else [s.trace_id for _, s in entry.slots])
         with tracing.span("serve/deliver", kind=entry.kind,
                           traces=_traces):
-            if self.blocked is not None:     # the (only) blocking fetch
-                with self.blocked.blocked():
+            # the (only) blocking fetch: deliver's time outside this
+            # span is the host's own work, inside it the device's
+            with tracing.span("serve/wait"):
+                if self.blocked is not None:
+                    with self.blocked.blocked():
+                        toks = np.asarray(entry.tokens)
+                else:
                     toks = np.asarray(entry.tokens)
-            else:
-                toks = np.asarray(entry.tokens)
             now = time.monotonic()
             if entry.kind == "first":
                 self._record(entry.seq, int(toks), now)

@@ -689,7 +689,8 @@ class Trainer:
 
             # f32-accumulated: bf16 grad trees (shadow mode) would
             # otherwise norm-reduce in bf16
-            grad_norm = global_norm_f32(grads)
+            with jax.named_scope("optimizer"):
+                grad_norm = global_norm_f32(grads)
             ok = kind = new_gstate = None
             if guard_on:
                 # anomaly verdict (resilience/guard.py): non-finite loss
@@ -712,9 +713,11 @@ class Trainer:
                 finite = all_finite(grads)
                 safe_grads = jax.tree.map(
                     lambda g: jnp.where(jnp.isfinite(g), g, 0.0), grads)
-                updates, opt_candidate = optimizer.update(
-                    safe_grads, state.opt_state, state.params)
-                params_candidate = optax.apply_updates(state.params, updates)
+                with jax.named_scope("optimizer"):
+                    updates, opt_candidate = optimizer.update(
+                        safe_grads, state.opt_state, state.params)
+                    params_candidate = optax.apply_updates(state.params,
+                                                           updates)
                 # skip the step entirely on overflow — no host sync
                 keep = finite if ok is None else finite & ok
                 new_params = select_tree(keep, params_candidate,
@@ -727,9 +730,11 @@ class Trainer:
                     # the very non-finite values being skipped
                     new_quant = select_tree(keep, new_quant, state.quant)
             else:
-                updates, opt_candidate = optimizer.update(
-                    grads, state.opt_state, state.params)
-                params_candidate = optax.apply_updates(state.params, updates)
+                with jax.named_scope("optimizer"):
+                    updates, opt_candidate = optimizer.update(
+                        grads, state.opt_state, state.params)
+                    params_candidate = optax.apply_updates(state.params,
+                                                           updates)
                 if ok is None:
                     new_params, new_opt = params_candidate, opt_candidate
                 else:
@@ -899,7 +904,7 @@ class Trainer:
         # blocks on the NEWEST dispatched step (save steps are sync
         # points regardless — orbax waits on the arrays); metered so
         # host_blocked_ms attributes the wait honestly
-        with self.blocked.blocked():
+        with self._wait():
             gs = jax.device_get(self._guard_state)
         return {k: np.asarray(v).item() for k, v in gs.items()}
 
@@ -930,6 +935,14 @@ class Trainer:
             out = (fn or self._train_step)(*args)
         return jax.device_get(out[-1]["sdc_digests"])
 
+    @contextlib.contextmanager
+    def _wait(self):
+        """A blocking device fetch: summed by ``self.blocked`` (the
+        ``host_blocked_ms`` meter) and shown as one ``train/wait`` span,
+        so both count the same intervals."""
+        with tracing.span("train/wait"), self.blocked.blocked():
+            yield
+
     def step(self, batch: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
         """One optimizer step; returns (async) metrics.
 
@@ -941,6 +954,10 @@ class Trainer:
         resolved by this call (None while the pipeline is filling).  At
         the default depth 1 every step resolves immediately — exactly
         the pre-pipelining behaviour, fetch-for-fetch."""
+        with tracing.span("train/step"):
+            return self._step_impl(batch)
+
+    def _step_impl(self, batch):
         from torchacc_tpu.resilience.chaos import failpoint
         failpoint("trainer.step")
         if self.state is None:
@@ -951,7 +968,7 @@ class Trainer:
         if self._host_step is None:
             # one-time resync after a restore: the only host<->device
             # step-index round-trip the loop ever pays
-            with self.blocked.blocked():
+            with self._wait():
                 self._host_step = int(self.state.step)
         si = self._host_step
         sdc_check = sdc_spot = False
@@ -1058,10 +1075,10 @@ class Trainer:
                     # resolved step (see ResilienceConfig); raises
                     # AnomalyError with a diagnosis once
                     # max_consecutive_anomalies is reached
-                    with self.blocked.blocked():
+                    with self._wait():
                         self._guard_monitor.observe(e.step, e.metrics)
                 if self._sdc_on and (e.sdc_check or e.sdc_spot):
-                    with self.blocked.blocked():
+                    with self._wait():
                         digests = jax.device_get(e.digests)
                     # verdict from replicated data — identical on every
                     # process, so any raise (and any arbiter
@@ -1631,7 +1648,7 @@ class Trainer:
             if not (do_log or do_eval):
                 return
             now = _time.perf_counter()
-            with self.blocked.blocked():
+            with self._wait():
                 loss = float(entry.metrics["loss"])
             rec = {"step": r, "loss": loss,
                    "time_s": round(now - t0, 2)}
@@ -1655,7 +1672,7 @@ class Trainer:
                 # in one batched fetch — the host never serialises
                 # against the device per eval batch
                 evs = [self.eval_step(eb) for eb in eval_loader]
-                with self.blocked.blocked():
+                with self._wait():
                     vals = jax.device_get(evs)
                 rec["eval_loss"] = (sum(float(v) for v in vals)
                                     / max(len(vals), 1))
@@ -1714,7 +1731,8 @@ class Trainer:
                 if wd is not None:
                     wd.arm("data_fetch", fetch_deadline)
                 try:
-                    step_idx, batch = next(steps_it)
+                    with tracing.span("train/data_wait"):
+                        step_idx, batch = next(steps_it)
                 except StopIteration:
                     if wd is not None:
                         wd.disarm()

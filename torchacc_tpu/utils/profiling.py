@@ -11,19 +11,18 @@ from the jitted executable — no log scraping.
 from __future__ import annotations
 
 import contextlib
-import glob
-import gzip
-import json
-import os
 import time
-from typing import Any, Dict, Iterator, List, Optional, Tuple
+from typing import Any, Dict, Iterator, Optional
 
 import jax
 
 
 @contextlib.contextmanager
 def trace(logdir: str) -> Iterator[None]:
-    """jax.profiler trace context (open the logdir in TensorBoard)."""
+    """jax.profiler trace context (open the logdir in TensorBoard).
+    The program's own spans (obs/tracing.py) appear on the trace's
+    ``/host:CPU`` plane while it is open, and its device ops carry the
+    registered ``jax.named_scope`` names."""
     jax.profiler.start_trace(logdir)
     try:
         yield
@@ -51,98 +50,6 @@ class StepTimer:
     @property
     def mean(self) -> float:
         return sum(self.times) / len(self.times) if self.times else 0.0
-
-
-def _merge_busy(intervals: List[Tuple[float, float]]
-                ) -> Tuple[float, float]:
-    """(busy_us, span_us) of a set of [start, end) event intervals —
-    busy is the measure of their union, span the hull."""
-    if not intervals:
-        return 0.0, 0.0
-    intervals.sort()
-    busy = 0.0
-    cur_s, cur_e = intervals[0]
-    for s, e in intervals[1:]:
-        if s > cur_e:
-            busy += cur_e - cur_s
-            cur_s, cur_e = s, e
-        else:
-            cur_e = max(cur_e, e)
-    busy += cur_e - cur_s
-    return busy, cur_e - intervals[0][0]
-
-
-def device_idle_from_trace(logdir: str) -> Optional[Dict[str, float]]:
-    """Gap-sum between device ops in a ``jax.profiler`` trace.
-
-    Parses the newest ``*.trace.json.gz`` under ``logdir`` (the Chrome
-    trace the profiler writes next to the xplane): complete events of
-    the DEVICE lanes are union-merged and the idle time is the hull
-    minus the union — i.e. the sum of gaps where the device ran
-    nothing while the trace window was live.  This is the overlap
-    measurement the MFU number cannot give: comms/dispatch stalls show
-    up as idle gaps even when every compute op is fast
-    (bench.py ``device_idle_ms`` detail row).
-
-    Lane selection: processes named ``/device:*`` (real TPU/GPU
-    traces).  XLA:CPU has no device plane — there the XLA execution
-    threads (``tf_XLAEigen*`` / ``tf_XLATfrtCpuClient*`` under
-    ``/host:CPU``) stand in, which makes the CPU number a host-compute
-    proxy, good enough for the smoke gate's plumbing check.
-
-    Returns ``{"device_idle_ms", "device_busy_ms", "span_ms",
-    "source"}`` (source 1.0 = device plane, 0.0 = CPU-thread fallback)
-    or None when no trace / no usable lane exists — callers emit null
-    rather than fail."""
-    paths = sorted(glob.glob(os.path.join(
-        logdir, "**", "*.trace.json.gz"), recursive=True),
-        key=os.path.getmtime)
-    if not paths:
-        return None
-    try:
-        with gzip.open(paths[-1], "rt") as f:
-            events = json.load(f).get("traceEvents", [])
-    except (OSError, ValueError, EOFError):
-        # EOFError: a truncated gzip stream (profiler killed mid-write)
-        # raises it directly, not as OSError
-        return None
-    proc_names: Dict[Any, str] = {}
-    thread_names: Dict[Tuple[Any, Any], str] = {}
-    for e in events:
-        if e.get("ph") != "M":
-            continue
-        if e.get("name") == "process_name":
-            proc_names[e.get("pid")] = e.get("args", {}).get("name", "")
-        elif e.get("name") == "thread_name":
-            thread_names[(e.get("pid"), e.get("tid"))] = \
-                e.get("args", {}).get("name", "")
-    device_pids = {p for p, n in proc_names.items()
-                   if n.startswith("/device:")}
-    use_device = bool(device_pids)
-    xla_tids = {k for k, n in thread_names.items()
-                if n.startswith(("tf_XLAEigen", "tf_XLATfrtCpuClient"))}
-    intervals: List[Tuple[float, float]] = []
-    for e in events:
-        if e.get("ph") != "X" or "ts" not in e:
-            continue
-        dur = e.get("dur", 0.0)
-        if dur <= 0:
-            continue
-        if use_device:
-            if e.get("pid") not in device_pids:
-                continue
-        elif (e.get("pid"), e.get("tid")) not in xla_tids:
-            continue
-        intervals.append((float(e["ts"]), float(e["ts"]) + float(dur)))
-    busy, span = _merge_busy(intervals)
-    if span <= 0:
-        return None
-    return {
-        "device_idle_ms": (span - busy) / 1e3,
-        "device_busy_ms": busy / 1e3,
-        "span_ms": span / 1e3,
-        "source": 1.0 if use_device else 0.0,
-    }
 
 
 def compiled_memory_stats(fn, *abstract_args) -> Dict[str, Any]:
