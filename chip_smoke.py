@@ -87,15 +87,15 @@ def train_depth(mc, hbm_bytes: int, batch: int, seq: int):
 
 
 def serve_depth(mc, hbm_bytes: int, pool_tokens: int):
-    """Layers one chip serves: bf16 weights, the KV pool twice (the
-    layer scan writes the updated pool beside the donated one), and
-    ~3 GB for the widest step's temporaries."""
+    """Layers one chip serves: bf16 weights, the KV pool (one buffer
+    for keys and one for values, updated in place), and ~3 GB for the
+    widest step's temporaries."""
     fixed = 2 * mc.vocab_size * mc.hidden_size * 2 + int(3e9)
     layer = (_per_layer_params(mc) * 2
-             + 2 * 2 * pool_tokens * mc.kv_heads * mc.head_size * 2)
+             + 2 * pool_tokens * mc.kv_heads * mc.head_size * 2)
     depth = int((0.7 * hbm_bytes - fixed) // layer)
     why = (f"bf16 weights {_per_layer_params(mc) * 2 / 1e9:.2f} GB a layer "
-           f"+ a {pool_tokens}-token KV pool counted twice, 3 GB of step "
+           f"+ a {pool_tokens}-token KV pool, 3 GB of step "
            f"temporaries, within 70% of {hbm_bytes / 1e9:.1f} GB")
     return max(1, min(depth, mc.num_layers)), why
 
@@ -221,9 +221,10 @@ def _run_engine(engine, prompts, max_new, wave_steps, between=None):
 
 
 def _live_pool_check(engine):
-    """Kernel vs the jnp gather path on the engine's live pool, block
-    tables and context lengths: a decode-shaped query for every active
-    slot and a prefill-chunk-shaped one for those that hold a chunk.
+    """Kernel vs the jnp gather path on the engine's live pool (the
+    whole [L, NB, BS, KH*D] stack, read at its last layer), block tables
+    and context lengths: a decode-shaped query for every active slot
+    and a prefill-chunk-shaped one for those that hold a chunk.
     Returns the largest absolute difference over the largest output."""
     import jax
     import jax.numpy as jnp
@@ -233,7 +234,8 @@ def _live_pool_check(engine):
     sched = engine.scheduler
     cfg = engine.cfg
     chunk = sched.serve_cfg.prefill_chunk
-    kp, vp = sched.k_pools[0], sched.v_pools[0]
+    kp, vp = sched.k_pools, sched.v_pools
+    layer = cfg.num_layers - 1
     active = np.flatnonzero(sched.active)
     worst = 0.0
     for t, slots in ((1, active),
@@ -247,8 +249,8 @@ def _live_pool_check(engine):
             jax.random.PRNGKey(t),
             (slots.size, t, cfg.num_heads, cfg.head_size), kp.dtype)
         args = (q, kp, vp, jnp.array(sched.tables[slots]), ctx, ctx - t)
-        got = paged_attention(*args, impl=sched.decoder.impl)
-        ref = paged_attention(*args, impl="xla")
+        got = paged_attention(*args, layer=layer, impl=sched.decoder.impl)
+        ref = paged_attention(*args, layer=layer, impl="xla")
         got, ref = got.astype(jnp.float32), ref.astype(jnp.float32)
         worst = max(worst, float(jnp.max(jnp.abs(got - ref))
                                  / jnp.max(jnp.abs(ref))))

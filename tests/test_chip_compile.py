@@ -11,9 +11,18 @@ import: only one process may load the TPU library, and every xdist
 worker imports this file) and the compiles happen in the test's own
 process.  All at Mistral-7B widths: 32 q / 8 kv heads, head_dim 128,
 hidden 4096, ffn 14336.
+
+The serving programs (``PagedDecoder._decode`` / ``_prefill``) are
+compiled whole as well, at the benchmark's serve settings: what they
+must NOT hold is a second KV pool.  The pool rides the layer scan's
+carry and is written in place; a compile whose temporaries reach a
+layer's pool, whose pools are not aliased in -> out, or whose text
+copies or slices a pool-shaped array means the mechanism did not
+engage.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +39,7 @@ from torchacc_tpu.ops.attn import attention
 
 H, KH, D, SEQ = 32, 8, 128, 4096
 BF16 = jnp.bfloat16
+LAYERS = 3                   # the paged kernel reads one layer of a stack
 
 
 @pytest.fixture(scope="module")
@@ -87,6 +97,12 @@ def test_flash_fwd_bwd_compiles(one_chip, for_the_chip, segments):
     assert text.count("tpu_custom_call") >= 3      # fwd, dq, dkv
 
 
+def _paged_at_a_traced_layer(q, k_pool, v_pool, tables, lens, q_start,
+                             layer):
+    return paged_mod.paged_attention(q, k_pool, v_pool, tables, lens,
+                                     q_start, layer=layer, impl="pallas")
+
+
 @pytest.mark.parametrize("t", [1, ServeConfig().prefill_chunk],
                          ids=["decode", "prefill_chunk"])
 def test_paged_attention_compiles_at_default_block_size(one_chip,
@@ -94,12 +110,13 @@ def test_paged_attention_compiles_at_default_block_size(one_chip,
     sc = ServeConfig()
     slots = sc.max_slots if t == 1 else 1
     mb = SEQ // sc.block_size
-    pool = _sds((sc.num_blocks, KH, sc.block_size, D), BF16, one_chip)
+    pool = _sds((LAYERS, sc.num_blocks, sc.block_size, KH * D), BF16,
+                one_chip)
     i32 = functools.partial(_sds, dtype=jnp.int32, sharding=one_chip)
     text = _compiled_text(
-        functools.partial(paged_mod.paged_attention, impl="pallas"),
+        _paged_at_a_traced_layer,
         _sds((slots, t, H, D), BF16, one_chip), pool, pool,
-        i32((slots, mb)), i32((slots,)), i32((slots,)))
+        i32((slots, mb)), i32((slots,)), i32((slots,)), i32(()))
     assert "tpu_custom_call" in text
 
 
@@ -132,18 +149,94 @@ def test_flash_under_a_mesh_compiles(topo, for_the_chip, fsdp, tp):
 
 
 def test_paged_attention_under_a_tp_mesh_compiles(topo, for_the_chip):
-    # ServeEngine(mesh=): the pool's kv heads are sharded over 'tp'
+    # ServeEngine(mesh=): the pool's rows are sharded over 'tp' in
+    # whole-head groups
     sc = ServeConfig()
     mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("fsdp", "tp"))
     rep = NamedSharding(mesh, P())
-    pool = _sds((sc.num_blocks, KH, sc.block_size, D), BF16,
-                NamedSharding(mesh, P(None, "tp", None, None)))
+    pool = _sds((LAYERS, sc.num_blocks, sc.block_size, KH * D), BF16,
+                NamedSharding(mesh, P(None, None, None, "tp")))
     i32 = functools.partial(_sds, dtype=jnp.int32, sharding=rep)
     s, mb = sc.max_slots, SEQ // sc.block_size
     with jax.sharding.set_mesh(mesh):
         text = _compiled_text(
-            functools.partial(paged_mod.paged_attention, impl="pallas"),
+            _paged_at_a_traced_layer,
             _sds((s, 1, H, D), BF16,
                  NamedSharding(mesh, P(None, None, "tp", None))),
-            pool, pool, i32((s, mb)), i32((s,)), i32((s,)))
+            pool, pool, i32((s, mb)), i32((s,)), i32((s,)), i32(()))
     assert "tpu_custom_call" in text
+
+
+# the benchmark's serve cell (chipbench/traffic/rollout16.json), depth 2
+SERVE = dict(block_size=128, num_blocks=256, max_slots=16, prefill_chunk=256)
+SERVE_DEPTH = 2
+
+
+def _serve_program(name, one_chip):
+    """``PagedDecoder``'s jitted program ``name`` lowered on abstract
+    arguments, as ``Scheduler._decode_once`` / ``_prefill_one`` call it,
+    and the abstract pool."""
+    from torchacc_tpu.models import TransformerLM, get_preset
+    from torchacc_tpu.serve.kv_cache import blocks_needed, make_pools
+    from torchacc_tpu.serve.scheduler import PagedDecoder
+
+    mc = get_preset(
+        "llama-tiny", num_layers=SERVE_DEPTH, hidden_size=4096,
+        num_heads=H, num_kv_heads=KH, intermediate_size=14336,
+        vocab_size=32768, max_seq_len=SEQ, dtype=BF16, param_dtype=BF16)
+    sc = ServeConfig(**SERVE)
+    decoder = PagedDecoder(mc, sc, "pallas")
+    sds = functools.partial(_sds, sharding=one_chip)
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype), jax.eval_shape(
+            lambda k: TransformerLM(mc).init(
+                k, jnp.zeros((1, 8), jnp.int32))["params"],
+            jax.random.PRNGKey(0)))
+    pool = jax.eval_shape(lambda: make_pools(mc, sc)[0])
+    pool = sds(pool.shape, pool.dtype)
+    s = sc.max_slots
+    mb = min(sc.num_blocks - 1,
+             blocks_needed(SEQ + sc.decode_depth, sc.block_size))
+    i32, f32 = jnp.int32, jnp.float32
+    if name == "decode":
+        carry = {"tok": sds((s,), i32), "key": sds((s, 2), jnp.uint32)}
+        lowered = decoder._decode.lower(
+            params, (pool, pool), carry, sds((s, mb), i32), sds((s,), i32),
+            sds((s,), jnp.bool_), sds((s,), f32), sds((s,), i32),
+            sds((s,), f32), True)
+    else:
+        lowered = decoder._prefill.lower(
+            params, (pool, pool), sds((mb,), i32), sds((), i32),
+            sds((sc.prefill_chunk,), i32), sds((), i32),
+            name == "prefill_final_chunk")
+    return lowered, pool
+
+
+@pytest.mark.parametrize("name",
+                         ["decode", "prefill_chunk", "prefill_final_chunk"])
+def test_serve_program_holds_one_kv_pool(one_chip, for_the_chip, name):
+    lowered, pool = _serve_program(name, one_chip)
+    compiled = lowered.compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    layer_pool = pool.size // SERVE_DEPTH * pool.dtype.itemsize   # 64 MiB
+    assert layer_pool == 64 * 2**20
+    # a relayouted copy of one layer's pool, or a layer sliced out of
+    # the stack, would be 64 MiB of temporaries or more
+    assert mem.temp_size_in_bytes < layer_pool // 2
+    # both pools come back in the buffers they came in
+    assert mem.alias_size_in_bytes >= 2 * SERVE_DEPTH * layer_pool
+    assert text.count("tpu_custom_call") == 1
+    # nothing copies, slices or re-stacks a pool or one layer of it: the
+    # only pool-shaped results are the in-place scatters of kv_write
+    dims = [str(d) for d in pool.shape]
+    shapes = "|".join(re.escape(f"bf16[{','.join(dims[i:])}]")
+                      for i in (0, 1))
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(rf"= ({shapes})\S* "
+                          r"(copy|dynamic-slice|dynamic-update-slice)\(",
+                          line)]
+    assert not moved, moved
+    written = [line for line in text.splitlines()
+               if re.search(rf"= ({shapes})\S* fusion\(", line)]
+    assert len(written) == 2 and all("kv_write" in w for w in written), \
+        written

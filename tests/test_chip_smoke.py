@@ -144,15 +144,16 @@ def test_kernels_under_a_mesh(devices):
     s, t, h, kh, d, bs, mb = 3, 4, 4, 2, 16, 8, 3
     nb = s * mb + 1
     q = jnp.asarray(rng.standard_normal((s, t, h, d)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((nb, kh, bs, d)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((nb, kh, bs, d)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((3, nb, bs, kh * d)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((3, nb, bs, kh * d)), jnp.float32)
     tables = jnp.asarray(1 + np.arange(s * mb).reshape(s, mb), jnp.int32)
     ctx = jnp.asarray([9, 17, 24], jnp.int32)
     args = (q, kp, vp, tables, ctx, ctx - t)
-    ref = paged_attention(*args, impl="xla")
+    ref = paged_attention(*args, layer=2, impl="xla")
     mesh = Mesh(np.asarray(devices[:4]).reshape(2, 2), ("fsdp", "tp"))
     with jax.sharding.set_mesh(mesh):
-        got = jax.jit(lambda *a: paged_attention(*a, impl="pallas"))(*args)
+        got = jax.jit(lambda *a: paged_attention(
+            *a[:-1], layer=a[-1], impl="pallas"))(*args, jnp.int32(2))
         with pytest.raises(ta.ConfigError, match="quant_impl='xla'"):
             quantized_dot(jnp.ones((8, 16)), jnp.ones((16, 8)), impl="pallas")
     np.testing.assert_allclose(got, ref, atol=1e-5, rtol=1e-5)

@@ -127,33 +127,40 @@ def test_block_pool_exhaustion_returns_none_never_partial():
 # ---------------------------------------------------------------------------
 
 def _random_paged_case(rng, *, slots, heads, kv_heads, d, bs, mb, t=1,
-                       ctx_lens=None, dtype=jnp.float32):
-    """Scatter random per-slot contexts into a shuffled block pool;
-    return (paged operands, dense per-slot (q, k, v, q_start))."""
+                       ctx_lens=None, dtype=jnp.float32, layers=3):
+    """Scatter random per-slot contexts into a shuffled block pool of
+    ``layers`` layers (every layer its own random content); return
+    (paged operands, per layer the dense per-slot (q, k, v, q_start))."""
     nb = slots * mb + 1
     ctx = ctx_lens if ctx_lens is not None else [
         int(rng.integers(1, mb * bs + 1)) for _ in range(slots)]
     perm = rng.permutation(np.arange(1, nb)).tolist()
     tables = np.zeros((slots, mb), np.int32)
-    k_pool = rng.standard_normal((nb, bs, kv_heads, d)).astype(np.float32)
-    v_pool = rng.standard_normal((nb, bs, kv_heads, d)).astype(np.float32)
-    dense_k, dense_v = [], []
+    k_pool = rng.standard_normal(
+        (layers, nb, bs, kv_heads, d)).astype(np.float32)
+    v_pool = rng.standard_normal(
+        (layers, nb, bs, kv_heads, d)).astype(np.float32)
+    blocks = []
     for s in range(slots):
         n_blk = blocks_needed(ctx[s], bs)
-        blks = [perm.pop() for _ in range(n_blk)]
-        tables[s, :n_blk] = blks
-        dense_k.append(np.concatenate(
-            [k_pool[b] for b in blks], axis=0)[:ctx[s]])
-        dense_v.append(np.concatenate(
-            [v_pool[b] for b in blks], axis=0)[:ctx[s]])
+        blocks.append([perm.pop() for _ in range(n_blk)])
+        tables[s, :n_blk] = blocks[s]
     q = rng.standard_normal((slots, t, heads, d)).astype(np.float32)
     q_start = np.asarray([max(c - t, 0) for c in ctx], np.int32)
-    # the pool the kernel reads is [NB, KH, BS, D]
-    paged = (jnp.asarray(q, dtype),
-             jnp.asarray(k_pool.swapaxes(1, 2), dtype),
-             jnp.asarray(v_pool.swapaxes(1, 2), dtype), jnp.asarray(tables),
-             jnp.asarray(ctx, np.int32), jnp.asarray(q_start))
-    return paged, (q, dense_k, dense_v, q_start)
+
+    def dense(layer):
+        pages = lambda pool, s: np.concatenate(  # noqa: E731
+            [pool[layer, b] for b in blocks[s]], axis=0)[:ctx[s]]
+        return (q, [pages(k_pool, s) for s in range(slots)],
+                [pages(v_pool, s) for s in range(slots)], q_start)
+
+    # the pool the kernel reads is the stack [L, NB, BS, KH*D]
+    rows = lambda pool: jnp.asarray(  # noqa: E731
+        pool.reshape(layers, nb, bs, kv_heads * d), dtype)
+    paged = (jnp.asarray(q, dtype), rows(k_pool), rows(v_pool),
+             jnp.asarray(tables), jnp.asarray(ctx, np.int32),
+             jnp.asarray(q_start))
+    return paged, dense
 
 
 def _dense_reference(q, dense_k, dense_v, q_start, **kw):
@@ -170,37 +177,42 @@ def _dense_reference(q, dense_k, dense_v, q_start, **kw):
     return np.stack(outs)
 
 
+@pytest.mark.parametrize("layer", [1, 2])
 @pytest.mark.parametrize("seed", [0, 1, 2])
-def test_paged_attention_matches_reference_random_layouts(seed):
+def test_paged_attention_matches_reference_random_layouts(seed, layer):
     rng = np.random.default_rng(seed)
     paged, dense = _random_paged_case(
         rng, slots=4, heads=4, kv_heads=4, d=16, bs=8, mb=4)
-    out = np.asarray(paged_attention(*paged, impl="xla"))
-    ref = _dense_reference(*dense)
+    out = np.asarray(paged_attention(*paged, layer=layer, impl="xla"))
+    ref = _dense_reference(*dense(layer))
     np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
 
 
-def test_paged_attention_gqa_chunk_matches_reference():
+@pytest.mark.parametrize("layer", [1, 3])
+def test_paged_attention_gqa_chunk_matches_reference(layer):
     # T=4 chunk geometry (chunked prefill) + GQA head grouping
     rng = np.random.default_rng(3)
     paged, dense = _random_paged_case(
         rng, slots=3, heads=8, kv_heads=2, d=16, bs=8, mb=3, t=4,
-        ctx_lens=[5, 17, 24])
-    out = np.asarray(paged_attention(*paged, impl="xla"))
-    ref = _dense_reference(*dense)
+        ctx_lens=[5, 17, 24], layers=4)
+    out = np.asarray(paged_attention(*paged, layer=layer, impl="xla"))
+    ref = _dense_reference(*dense(layer))
     np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
 
 
-def test_paged_attention_softcap_matches_reference():
+@pytest.mark.parametrize("layer", [2])
+def test_paged_attention_softcap_matches_reference(layer):
     rng = np.random.default_rng(4)
     paged, dense = _random_paged_case(
         rng, slots=2, heads=4, kv_heads=4, d=16, bs=8, mb=2)
-    out = np.asarray(paged_attention(*paged, impl="xla", logit_softcap=30.0))
-    ref = _dense_reference(*dense, logit_softcap=30.0)
+    out = np.asarray(paged_attention(*paged, layer=layer, impl="xla",
+                                     logit_softcap=30.0))
+    ref = _dense_reference(*dense(layer), logit_softcap=30.0)
     np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
 
 
-def test_paged_attention_inactive_slot_zeros():
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_paged_attention_inactive_slot_zeros(impl):
     rng = np.random.default_rng(5)
     paged, _ = _random_paged_case(
         rng, slots=3, heads=4, kv_heads=4, d=16, bs=8, mb=2,
@@ -209,35 +221,155 @@ def test_paged_attention_inactive_slot_zeros():
     ctx = ctx.at[1].set(0)                   # free slot parked on null block
     tables = tables.at[1, :].set(0)
     out = np.asarray(paged_attention(q, kp, vp, tables, ctx, q_start,
-                                     impl="xla"))
+                                     layer=1, impl=impl))
     assert np.all(out[1] == 0.0)
     assert np.all(np.isfinite(out))
 
 
-def test_paged_attention_pallas_interpret_matches_xla():
-    # tiny grid: the Pallas kernel in interpret mode vs the jnp anchor
+@pytest.mark.parametrize("kv_heads,t", [(2, 1), (1, 4), (4, 8)],
+                         ids=["gqa_decode", "mqa_chunk", "mha_chunk"])
+def test_paged_attention_pallas_interpret_matches_xla(kv_heads, t):
+    # tiny grid: the Pallas kernel in interpret mode vs the jnp anchor,
+    # the layer index traced (as the scheduler's scan hands it over)
     rng = np.random.default_rng(6)
     paged, _ = _random_paged_case(
-        rng, slots=2, heads=2, kv_heads=2, d=16, bs=8, mb=2,
-        ctx_lens=[5, 14])
-    out_x = np.asarray(paged_attention(*paged, impl="xla"))
-    out_p = np.asarray(paged_attention(*paged, impl="pallas"))
-    np.testing.assert_allclose(out_p, out_x, atol=1e-5, rtol=1e-5)
+        rng, slots=2, heads=4, kv_heads=kv_heads, d=16, bs=8, mb=2, t=t,
+        ctx_lens=[9, 14])
+    for layer in (1, 2):
+        out_x = np.asarray(paged_attention(*paged, layer=layer, impl="xla"))
+        out_p = np.asarray(jax.jit(
+            lambda *a: paged_attention(*a[:-1], layer=a[-1], impl="pallas"))(
+                *paged, jnp.int32(layer)))
+        np.testing.assert_allclose(out_p, out_x, atol=1e-5, rtol=1e-5)
 
 
 def test_paged_attention_validation_errors():
     q = jnp.zeros((2, 1, 4, 8))
-    kp = jnp.zeros((4, 2, 8, 8))
+    kp = jnp.zeros((3, 4, 8, 16))            # [L, NB, BS, 2 heads * 8]
     tables = jnp.zeros((2, 2), jnp.int32)
     lens = jnp.zeros((2,), jnp.int32)
     with pytest.raises(ValueError):          # 3 q heads not multiple of 2
-        paged_attention(jnp.zeros((2, 1, 3, 8)), kp, kp, tables, lens, lens)
+        paged_attention(jnp.zeros((2, 1, 3, 8)), kp, kp, tables, lens, lens,
+                        layer=0)
     with pytest.raises(ValueError):          # k/v pool mismatch
-        paged_attention(q, kp, jnp.zeros((4, 4, 8, 8)), tables, lens, lens)
+        paged_attention(q, kp, jnp.zeros((3, 4, 8, 32)), tables, lens, lens,
+                        layer=0)
+    with pytest.raises(ValueError):          # one layer's pool, no stack
+        paged_attention(q, kp[0], kp[0], tables, lens, lens, layer=0)
+    with pytest.raises(ValueError):          # rows are not whole heads
+        paged_attention(q, kp[..., :12], kp[..., :12], tables, lens, lens,
+                        layer=0)
     with pytest.raises(ValueError):          # slot-count mismatch
-        paged_attention(q, kp, kp, tables[:1], lens, lens)
+        paged_attention(q, kp, kp, tables[:1], lens, lens, layer=0)
     with pytest.raises(ValueError):
-        paged_attention(q, kp, kp, tables, lens, lens, impl="nope")
+        paged_attention(q, kp, kp, tables, lens, lens, layer=0, impl="nope")
+
+
+# ---------------------------------------------------------------------------
+# the pool in the layer loop: written in place, one layer at a time
+# ---------------------------------------------------------------------------
+
+def _tiny_decoder(tiny, **serve_kw):
+    from torchacc_tpu.serve.scheduler import PagedDecoder
+    model, params = tiny
+    sc = _serve_cfg(**serve_kw).serve
+    return PagedDecoder(model.cfg, sc), model.cfg, sc, params
+
+
+@pytest.mark.parametrize("layer", [0, 1])
+def test_layer_write_leaves_other_layers_bit_identical(tiny, layer):
+    decoder, cfg, sc, params = _tiny_decoder(tiny)
+    rng = np.random.default_rng(7)
+    shape = (3, sc.num_blocks, sc.block_size, cfg.kv_heads * cfg.head_size)
+    kp = jnp.asarray(rng.standard_normal(shape), cfg.dtype)
+    vp = jnp.asarray(rng.standard_normal(shape), cfg.dtype)
+    s, t = 2, 4
+    x = jnp.asarray(rng.standard_normal((s, t, cfg.hidden_size)), cfg.dtype)
+    positions = jnp.asarray([[8, 9, 10, 11], [3, 4, 5, 6]], jnp.int32)
+    tables = jnp.asarray([[5, 9, 0], [7, 0, 0]], jnp.int32)
+    blk = jnp.asarray([[9, 9, 9, 9], [7, 7, 7, 7]], jnp.int32)
+    off = positions % sc.block_size
+    p_l = jax.tree.map(lambda a: a[layer], params["layers"])["block"]
+    _, (kp2, vp2) = jax.jit(decoder._layer)(
+        p_l, jnp.int32(layer), x, (kp, vp), positions, tables,
+        positions[:, -1] + 1, blk, off)
+    for old, new in ((kp, kp2), (vp, vp2)):
+        old, new = np.asarray(old), np.asarray(new)
+        written = np.zeros(shape[:3], bool)
+        written[layer, np.asarray(blk), np.asarray(off)] = True
+        assert np.array_equal(new[~written], old[~written])
+        assert not np.any(new[written] == old[written])
+        others = [l for l in range(3) if l != layer]
+        assert np.array_equal(new[others], old[others])
+
+
+def test_cow_clones_one_block_across_every_layer(tiny):
+    decoder, cfg, sc, _ = _tiny_decoder(tiny)
+    rng = np.random.default_rng(8)
+    shape = (cfg.num_layers, sc.num_blocks, sc.block_size,
+             cfg.kv_heads * cfg.head_size)
+    kp = rng.standard_normal(shape).astype(np.float32)
+    vp = rng.standard_normal(shape).astype(np.float32)
+    src, dst = 11, 40
+    kp2, vp2 = decoder._cow((jnp.asarray(kp), jnp.asarray(vp)),
+                            jnp.int32(src), jnp.int32(dst))
+    for old, new in ((kp, np.asarray(kp2)), (vp, np.asarray(vp2))):
+        assert np.array_equal(new[:, dst], old[:, src])
+        keep = np.arange(sc.num_blocks) != dst
+        assert np.array_equal(new[:, keep], old[:, keep])
+
+
+@pytest.mark.parametrize("kv_heads", [2, 1], ids=["gqa", "mqa"])
+def test_tp_mesh_pool_rows_split_at_head_boundaries(devices, kv_heads):
+    # ServeEngine(mesh=): the pool's [KH*D] rows are sharded over 'tp'
+    # in whole-head groups — and not at all where tp does not divide the
+    # kv heads (the 16 lanes of one MQA head would divide by 2) — and
+    # the kernel under its shard_map serves the same tokens
+    import dataclasses
+    from jax.sharding import Mesh, PartitionSpec as P
+    cfg = get_preset(
+        "llama-tiny", dtype=jnp.float32, num_layers=2, hidden_size=64,
+        num_heads=4, num_kv_heads=kv_heads, intermediate_size=128,
+        vocab_size=VOCAB, max_seq_len=128)
+    model = TransformerLM(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    prompts = _prompts(np.random.default_rng(9), [5, 19, 11])
+    ref = _ref_generate(model, params, prompts, max_new=6)
+    mesh = Mesh(np.asarray(devices[:4]).reshape(2, 2), ("fsdp", "tp"))
+    kernel = TransformerLM(dataclasses.replace(cfg, attention_impl="pallas"))
+    eng = ServeEngine(kernel, params, _serve_cfg(), mesh=mesh)
+    pool = eng.scheduler.k_pools
+    assert pool.shape == (2, 64, 8, kv_heads * 16)
+    assert pool.sharding.spec == P(None, None, None,
+                                   "tp" if kv_heads == 2 else None)
+    ids = [eng.submit(Request(prompt_ids=p, max_new_tokens=6))
+           for p in prompts]
+    eng.run()
+    assert [eng.result(i).tokens for i in ids] == ref
+    eng.close()
+
+
+def test_unsliceable_head_size_is_a_typed_error_at_construction():
+    # 9 kv heads of 72: no group of them is a multiple of 128 lanes, and
+    # the whole row's blocks for a 1024-token chunk are over the kernel's
+    # VMEM budget — refused when the decoder is built, not at the first
+    # request's lowering; a chunk whose whole row fits is served
+    import torchacc_tpu as ta
+    from torchacc_tpu.serve.scheduler import PagedDecoder
+    cfg = get_preset(
+        "llama-tiny", dtype=jnp.bfloat16, num_layers=1, hidden_size=648,
+        num_heads=9, num_kv_heads=9, intermediate_size=128,
+        vocab_size=VOCAB, max_seq_len=2048)
+    assert cfg.head_size == 72
+    wide = ServeConfig(block_size=128, num_blocks=64, max_slots=4,
+                       prefill_chunk=1024)
+    with pytest.raises(ta.ConfigError, match="head size 72"):
+        PagedDecoder(cfg, wide, "pallas")
+    assert PagedDecoder(cfg, wide, "xla").impl == "xla"
+    narrow = ServeConfig(block_size=128, num_blocks=64, max_slots=4,
+                         prefill_chunk=128)
+    assert PagedDecoder(cfg, narrow, "pallas").impl == "pallas"
 
 
 # ---------------------------------------------------------------------------

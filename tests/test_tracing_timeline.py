@@ -95,7 +95,8 @@ def program_scopes():
     params = jax.eval_shape(
         lambda: TransformerLM(mc).init(
             jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
-    pool = jax.ShapeDtypeStruct((2, 16, 2, 8, 64), mc.dtype)
+    from torchacc_tpu.serve import make_pools
+    pool = jax.eval_shape(lambda: make_pools(mc, sc)[0])
     sds = jax.ShapeDtypeStruct
     carry = {"tok": sds((2,), jnp.int32), "key": sds((2, 2), jnp.uint32)}
     decode = decoder._decode.lower(

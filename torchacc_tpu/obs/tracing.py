@@ -91,14 +91,16 @@ SPAN_NAMES = tuple(SPANS)
 SCOPES: Dict[str, str] = {
     "embed_tokens": "token embedding lookup (training: the Flax module)",
     "embed": "token (+ position) embedding lookup (serving)",
-    "layers": "the layer scan's own ops: per-layer slices of stacked "
-              "weights and pools, carry copies",
+    "layers": "the layer scan's own ops: per-layer slices of the stacked "
+              "weights, the loop counter (serving: the KV pools ride the "
+              "carry untouched)",
     "ln1": "pre-attention norm",
     "ln2": "pre-MLP norm",
     "attn": "attention block outside its kernels: projections, rope, "
             "relayouts (training)",
     "qkv": "q/k/v projections, qk-norm and rope (serving)",
-    "kv_write": "the two scatter updates of the paged KV pool",
+    "kv_write": "the two in-place scatters of the new tokens' rows into "
+                "the paged KV pool at (layer, block, offset)",
     "paged_attn": "the paged-attention kernel and its relayouts",
     "o_proj": "attention output projection + residual (serving)",
     "mlp": "feed-forward block",

@@ -25,10 +25,21 @@ elsewhere; 'pallas' forces the kernel (interpret mode off-TPU);
 
 Geometry: queries are ``[S, T, H, D]`` — S slots, T tokens per slot
 (T=1 for decode, T=chunk for chunked prefill), already rope-rotated.
-The pool is ``[NB, KH, BS, D]`` (blocks, kv heads, block size, head
-dim) per layer — ``(block size, head dim)`` last, so one kv head's page
-is a legal TPU tile whenever ``block_size`` is a multiple of the
-dtype's sublane count (:func:`min_block_size`).  ``context_lens[s]``
+The pool is the WHOLE stack ``[L, NB, BS, KH*D]`` (layers, blocks, block
+size, one token's row of all kv heads) plus a ``layer`` index: the
+kernel's page BlockSpec addresses ``(layer, table[s, b], 0, head
+group)`` in the one preallocated buffer, so the caller's layer loop
+never slices a layer out of the stack (a slice of a 64 MiB layer pool is
+a copy of it, and so is putting it back).  Rows are contiguous because
+the WRITE is XLA's: a scatter of one token's ``[KH, D]`` into a
+``[.., KH, BS, D]`` pool is strided by ``BS*D``, and layout assignment
+then relayouts the whole pool for the scatter and back for the kernel;
+a ``[KH*D]`` row at ``(layer, block, offset)`` is one contiguous
+window, written in place.  A kv head is the lane slice
+``[:, h*D:(h+1)*D]`` of a page's ``[BS, hb*D]`` block — a legal TPU
+tile whenever ``block_size`` is a multiple of the dtype's sublane count
+(:func:`min_block_size`) and some group of heads is a 128-lane slice of
+the row or the whole row (:func:`heads_per_step`).  ``context_lens[s]``
 counts ALL banked tokens of slot s including the T chunk tokens (the
 cache write happens before the attention call), and ``q_start[s]`` is
 the global position of the slot's first query row — causality is
@@ -77,15 +88,15 @@ def min_block_size(dtype) -> int:
     jax.jit,
     static_argnames=("scale", "window", "logit_softcap"))
 def _paged_attention_xla(q, k_pool, v_pool, block_tables, context_lens,
-                         q_start, scale, window, logit_softcap):
+                         q_start, layer, scale, window, logit_softcap):
     s_, t_, h, d = q.shape
-    nb, kh, bs, _ = k_pool.shape
+    bs, kh = k_pool.shape[2], k_pool.shape[3] // d
     mb = block_tables.shape[1]
     # gather each slot's pages into a dense [S, MB*BS, ...] view; the
     # pool read is O(S * MB * BS) — fine for the reference, the kernel
     # never materialises this
-    k = k_pool[block_tables].swapaxes(2, 3).reshape(s_, mb * bs, kh, d)
-    v = v_pool[block_tables].swapaxes(2, 3).reshape(s_, mb * bs, kh, d)
+    k = k_pool[layer, block_tables].reshape(s_, mb * bs, kh, d)
+    v = v_pool[layer, block_tables].reshape(s_, mb * bs, kh, d)
     k = _repeat_kv_heads(k, h)
     v = _repeat_kv_heads(v, h)
     scores = jnp.einsum("sthd,skhd->shtk", q.astype(jnp.float32),
@@ -113,10 +124,10 @@ def _paged_attention_xla(q, k_pool, v_pool, block_tables, context_lens,
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _paged_fwd_kernel(tbl_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                      m_scr, l_scr, acc_scr,
-                      *, scale, block_size, t_len, rows, heads_per_step,
-                      num_kv_blocks, window, logit_softcap):
+def _paged_fwd_kernel(tbl_ref, lens_ref, layer_ref, q_ref, k_ref, v_ref,
+                      o_ref, m_scr, l_scr, acc_scr,
+                      *, scale, block_size, head_dim, t_len, rows,
+                      heads_per_step, num_kv_blocks, window, logit_softcap):
     si = pl.program_id(0)
     bi = pl.program_id(2)
 
@@ -144,9 +155,11 @@ def _paged_fwd_kernel(tbl_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         if right >= 0:
             mask &= kv_pos <= q_pos + right
         for hi in range(heads_per_step):
+            # a kv head is a lane slice of the page's [BS, hb*D] rows
+            lanes = slice(hi * head_dim, (hi + 1) * head_dim)
             q = q_ref[0, hi]                                # [R, D]
-            k = k_ref[0, hi]                                # [BS, D]
-            v = v_ref[0, hi]                                # [BS, D]
+            k = k_ref[:, lanes]                             # [BS, D]
+            v = v_ref[:, lanes]                             # [BS, D]
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale  # [R, BS]
@@ -182,56 +195,71 @@ _LANES = 128
 _VMEM_BUDGET = 10 * 1024 * 1024
 
 
-def _heads_per_step(kh: int, rows: int, bs: int, d: int,
-                    itemsize: int) -> int:
-    """Largest divisor of ``kh`` whose blocks fit the VMEM budget: q and
-    out blocks [rows, d] and the k/v pages [bs, d] double-buffered, the
-    f32 m/l/acc scratch, and the [rows, bs] f32 score temporaries (one
-    head's worth — heads run one after another inside a step)."""
+def heads_per_step(num_heads: int, kv_heads: int, head_dim: int,
+                   block_size: int, t: int, dtype) -> int:
+    """How many kv heads one grid step of the kernel takes for ``t``
+    query tokens a slot, or ``ValueError`` where the kernel cannot tile
+    the geometry (serve/scheduler.PagedDecoder asks at construction).
+
+    It is the largest divisor ``hb`` of ``kv_heads`` whose page block
+    ``[block_size, hb*head_dim]`` is a legal tile of the pool's rows
+    (``hb*head_dim`` a multiple of 128 lanes, or the whole row) and
+    whose blocks fit the VMEM budget: q and out blocks [rows, d] and the
+    k/v pages [bs, d] double-buffered, the f32 m/l/acc scratch, and the
+    [rows, bs] f32 score temporaries (one head's worth — heads run one
+    after another inside a step)."""
+    kh, d, bs = kv_heads, head_dim, block_size
+    itemsize = jnp.dtype(dtype).itemsize
+    if bs % min_block_size(dtype):
+        raise ValueError(
+            f"paged attention kernel: block_size {bs} is not a multiple "
+            f"of {min_block_size(dtype)}, the TPU sublane tile of a "
+            f"{jnp.dtype(dtype).name} pool")
+    rows = (num_heads // kh) * t
     lanes_d = max(d, _LANES)
     per_head = (2 * 2 * rows * lanes_d * itemsize            # q, out
                 + 2 * 2 * bs * lanes_d * itemsize            # k, v
                 + rows * (2 * _LANES + lanes_d) * 4)         # m, l, acc
     temps = 3 * rows * max(bs, _LANES) * 4
     for hb in range(kh, 0, -1):
-        if kh % hb == 0 and hb * per_head + temps <= _VMEM_BUDGET:
+        if (kh % hb == 0 and (hb == kh or (hb * d) % _LANES == 0)
+                and hb * per_head + temps <= _VMEM_BUDGET):
             return hb
     raise ValueError(
-        f"paged attention: one kv head's blocks ({rows} q rows x "
-        f"head_dim {d}, block_size {bs}) need "
-        f"{(per_head + temps) / 2**20:.1f} MiB of VMEM, over the "
-        f"{_VMEM_BUDGET / 2**20:.0f} MiB budget — lower "
-        f"serve.prefill_chunk or serve.block_size")
+        f"paged attention kernel: no group of the {kh} kv heads of "
+        f"head_dim {d} ({rows} q rows a head, block_size {bs}) is both a "
+        f"{_LANES}-lane slice of the pool's rows (or the whole row) and "
+        f"within the {_VMEM_BUDGET / 2**20:.0f} MiB VMEM budget (one "
+        f"head's blocks take {(per_head + temps) / 2**20:.1f} MiB) — "
+        f"lower serve.prefill_chunk or serve.block_size")
 
 
 def _paged_attention_pallas(q, k_pool, v_pool, block_tables, context_lens,
-                            q_start, scale, window, logit_softcap):
+                            q_start, layer, scale, window, logit_softcap):
     s_, t_, h, d = q.shape
-    nb, kh, bs, _ = k_pool.shape
+    bs, kh = k_pool.shape[2], k_pool.shape[3] // d
     mb = block_tables.shape[1]
     group = h // kh
-    if bs % min_block_size(k_pool.dtype):
-        raise ValueError(
-            f"paged attention kernel: block_size {bs} is not a multiple "
-            f"of {min_block_size(k_pool.dtype)}, the TPU sublane tile of a "
-            f"{jnp.dtype(k_pool.dtype).name} pool")
-    # lens = [S, 2] (context_len, q_start) scalar-prefetch operand; the
-    # block table prefetches alongside so every BlockSpec index map can
-    # address the pool block for (slot, kv-block) before the body runs
+    # three scalar-prefetch operands: the block table, lens = [S, 2]
+    # (context_len, q_start) and the layer index, so every BlockSpec
+    # index map can address the page for (layer, slot, kv-block) in the
+    # stacked pool before the body runs
     lens = jnp.stack([context_lens.astype(jnp.int32),
                       q_start.astype(jnp.int32)], axis=1)
+    layer = layer.reshape(1)
     # stack each kv head's q group into the row dim: [S, KH, G*T, D]
     rows = group * t_
     qg = q.reshape(s_, t_, kh, group, d).transpose(0, 2, 3, 1, 4).reshape(
         s_, kh, rows, d)
-    hb = _heads_per_step(kh, rows, bs, d, jnp.dtype(q.dtype).itemsize)
+    hb = heads_per_step(h, kh, d, bs, t_, k_pool.dtype)
 
     q_spec = pl.BlockSpec((1, hb, rows, d),
-                          lambda s, g, b, tbl, lens: (s, g, 0, 0))
-    kv_spec = pl.BlockSpec((1, hb, bs, d),
-                           lambda s, g, b, tbl, lens: (tbl[s, b], g, 0, 0))
+                          lambda s, g, b, tbl, lens, layer: (s, g, 0, 0))
+    kv_spec = pl.BlockSpec(
+        (None, None, bs, hb * d),
+        lambda s, g, b, tbl, lens, layer: (layer[0], tbl[s, b], 0, g))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(s_, kh // hb, mb),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=q_spec,
@@ -242,8 +270,8 @@ def _paged_attention_pallas(q, k_pool, v_pool, block_tables, context_lens,
         ],
     )
     kernel = functools.partial(
-        _paged_fwd_kernel, scale=scale, block_size=bs, t_len=t_, rows=rows,
-        heads_per_step=hb, num_kv_blocks=mb, window=window,
+        _paged_fwd_kernel, scale=scale, block_size=bs, head_dim=d, t_len=t_,
+        rows=rows, heads_per_step=hb, num_kv_blocks=mb, window=window,
         logit_softcap=logit_softcap)
     out = pl.pallas_call(
         kernel,
@@ -253,26 +281,28 @@ def _paged_attention_pallas(q, k_pool, v_pool, block_tables, context_lens,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
         name="paged_attention",
-    )(block_tables.astype(jnp.int32), lens, qg, k_pool, v_pool)
+    )(block_tables.astype(jnp.int32), lens, layer, qg, k_pool, v_pool)
     return out.reshape(s_, kh, group, t_, d).transpose(0, 3, 1, 2, 4).reshape(
         s_, t_, h, d)
 
 
 def _paged_attention_pallas_sharded(mesh, q, k_pool, v_pool, block_tables,
-                                    context_lens, q_start, *static):
+                                    context_lens, q_start, layer, *static):
     """The kernel per shard of ``mesh``: GSPMD cannot partition a Mosaic
-    kernel.  Heads split over 'tp' where it divides the kv heads (the
-    layout serve/kv_cache.make_pools gives the pool); slots, tables and
-    lengths are replicated."""
+    kernel.  Heads split over 'tp' where it divides the kv heads — a
+    shard of the pool's rows is then a group of whole heads (the layout
+    serve/kv_cache.make_pools gives the pool); slots, tables, lengths
+    and the layer index are replicated."""
     tp = (1 if "tp" in mesh.manual_axes else int(mesh.shape.get("tp", 1)))
-    h_axis = "tp" if tp > 1 and k_pool.shape[1] % tp == 0 else None
+    kh = k_pool.shape[3] // q.shape[3]
+    h_axis = "tp" if tp > 1 and kh % tp == 0 else None
     q_spec = P(None, None, h_axis, None)
-    pool_spec = P(None, h_axis, None, None)
+    pool_spec = P(None, None, None, h_axis)
     return jax.shard_map(
         lambda *a: _paged_attention_pallas(*a, *static), mesh=mesh,
-        in_specs=(q_spec, pool_spec, pool_spec, P(), P(), P()),
+        in_specs=(q_spec, pool_spec, pool_spec, P(), P(), P(), P()),
         out_specs=q_spec, check_vma=False,
-    )(q, k_pool, v_pool, block_tables, context_lens, q_start)
+    )(q, k_pool, v_pool, block_tables, context_lens, q_start, layer)
 
 
 # ---------------------------------------------------------------------------
@@ -287,15 +317,18 @@ def paged_attention(
     context_lens: jax.Array,
     q_start: jax.Array,
     *,
+    layer,
     scale: Optional[float] = None,
     window: Tuple[int, int] = (-1, -1),
     logit_softcap: float = 0.0,
     impl: str = "auto",
 ) -> jax.Array:
-    """Causal attention of ``q [S, T, H, D]`` over a paged KV pool.
+    """Causal attention of ``q [S, T, H, D]`` over layer ``layer`` of a
+    paged KV pool.
 
-    ``k_pool``/``v_pool``: [num_blocks, kv_heads, block_size, head_dim]
-    (one layer's pool).  ``block_tables [S, MB]`` maps slot-s logical
+    ``k_pool``/``v_pool``: [layers, num_blocks, block_size,
+    kv_heads * head_dim] (the whole stack; ``layer`` is an int or a
+    traced int32 scalar).  ``block_tables [S, MB]`` maps slot-s logical
     block j to a pool block; ``context_lens [S]`` is the total banked
     length per slot (chunk included); ``q_start [S]`` the global
     position of each slot's first query row.  Returns [S, T, H, D];
@@ -307,10 +340,16 @@ def paged_attention(
     if q.ndim != 4:
         raise ValueError(f"q must be [slots, t, heads, head_dim], got "
                          f"{q.shape}")
-    if k_pool.shape != v_pool.shape:
-        raise ValueError(f"k_pool {k_pool.shape} != v_pool {v_pool.shape}")
+    if k_pool.ndim != 4 or k_pool.shape != v_pool.shape:
+        raise ValueError(
+            f"k_pool {k_pool.shape} / v_pool {v_pool.shape} must be one "
+            f"[layers, blocks, block_size, kv_heads*head_dim] shape")
     s_, t_, h, d = q.shape
-    kh = k_pool.shape[1]
+    if k_pool.shape[3] % d != 0:
+        raise ValueError(
+            f"pool rows of {k_pool.shape[3]} are not whole heads of "
+            f"head_dim {d}")
+    kh = k_pool.shape[3] // d
     if h % kh != 0:
         raise ValueError(
             f"num q heads ({h}) must be a multiple of kv heads ({kh})")
@@ -331,4 +370,5 @@ def paged_attention(
         fn = functools.partial(_paged_attention_pallas_sharded, mesh)
     return fn(q, k_pool, v_pool, block_tables.astype(jnp.int32),
               context_lens.astype(jnp.int32), q_start.astype(jnp.int32),
-              float(scale), tuple(window), float(logit_softcap))
+              jnp.asarray(layer, jnp.int32), float(scale), tuple(window),
+              float(logit_softcap))

@@ -1,16 +1,24 @@
 """Paged KV cache: the preallocated block pool + host-side allocator.
 
 Memory layout (the vLLM PagedAttention idea expressed as JAX arrays):
-ONE pool per layer of shape ``[num_blocks, kv_heads, block_size,
-head_dim]`` for keys and the same for values, stacked over layers into
-``[L, NB, KH, BS, D]`` — ``(block_size, head_dim)`` last, the tile the
-paged-attention kernel reads (ops/paged_attention.py).  A sequence's
-cache is a list of blocks named by its BLOCK TABLE; sequences of wildly
-different lengths share the pool with at most ``block_size - 1`` wasted slots each, and a finished
-sequence's blocks return to the free list as soon as every in-flight
-iteration that could still write through its table has resolved (at
-most ``decode_depth - 1`` iterations — scheduler._release_matured) —
-no ``[batch, max_len]`` padding anywhere.
+ONE preallocated buffer for keys and one for values, each ``[L, NB, BS,
+KH*D]`` — layers, blocks, block size, and one token's ROW of all kv
+heads.  Rows are contiguous because of who writes them: the decode and
+prefill programs bank a token's k/v with an XLA scatter at ``(layer,
+block, offset)``, and a ``[KH*D]`` row there is one contiguous window
+the compiler updates in place.  (With ``(block_size, head_dim)`` last a
+token's ``[KH, D]`` is strided by ``BS*D``; layout assignment then
+relayouts the whole pool for the scatter and copies it back for the
+kernel — every layer, every step.)  The buffer rides the layer scan's
+carry and the paged-attention kernel reads pages straight out of it
+through a layer index (ops/paged_attention.py): no program slices a
+layer out, copies the pool or re-stacks it.  A sequence's cache is a
+list of blocks named by its BLOCK TABLE; sequences of wildly different
+lengths share the pool with at most ``block_size - 1`` wasted slots
+each, and a finished sequence's blocks return to the free list as soon
+as every in-flight iteration that could still write through its table
+has resolved (at most ``decode_depth - 1`` iterations —
+scheduler._release_matured) — no ``[batch, max_len]`` padding anywhere.
 
 Block 0 is the NULL BLOCK: free decode slots (and masked-out prefill
 tail tokens) write their garbage k/v there, so the jitted step needs
@@ -41,6 +49,7 @@ import collections
 import hashlib
 from typing import Dict, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -261,17 +270,22 @@ class BlockPool:
 
 
 def make_pools(model_cfg, serve_cfg, dtype=None):
-    """(k_pools, v_pools) of shape [L, NB, KH, BS, D] in the model's
-    compute dtype, kv heads sharded over 'tp' when a mesh is live (the
-    same activation-constraint seam the model layers use, so the TP
-    head composes — parallel/sharding.py)."""
+    """(k_pools, v_pools) of shape [L, NB, BS, KH*D] in the model's
+    compute dtype.  When a mesh is live and its 'tp' divides the kv
+    heads, the rows are sharded over it in whole-head groups (the same
+    activation-constraint seam the model layers use, so the TP head
+    composes — parallel/sharding.py)."""
     from torchacc_tpu.parallel.sharding import activation_constraint
 
     shape = (model_cfg.num_layers, serve_cfg.num_blocks,
-             model_cfg.kv_heads, serve_cfg.block_size,
-             model_cfg.head_size)
+             serve_cfg.block_size,
+             model_cfg.kv_heads * model_cfg.head_size)
     dt = dtype or model_cfg.dtype
-    axes = (None, None, "heads", None, None)
+    # a row splits at head boundaries only: tp must divide the HEADS
+    # (the constraint alone would split 128 lanes of one MQA head)
+    tp = dict(jax.sharding.get_abstract_mesh().shape).get("tp", 1)
+    axes = (None, None, None,
+            "heads" if model_cfg.kv_heads % tp == 0 else None)
     k = activation_constraint(jnp.zeros(shape, dt), axes)
     v = activation_constraint(jnp.zeros(shape, dt), axes)
     return k, v

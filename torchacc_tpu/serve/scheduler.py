@@ -70,7 +70,7 @@ import numpy as np
 from torchacc_tpu.config import ConfigError
 from torchacc_tpu.obs import tracing
 from torchacc_tpu.ops._common import on_tpu
-from torchacc_tpu.ops.paged_attention import min_block_size, paged_attention
+from torchacc_tpu.ops.paged_attention import heads_per_step, paged_attention
 from torchacc_tpu.resilience.chaos import failpoint
 from torchacc_tpu.serve.kv_cache import (
     BlockPool,
@@ -176,19 +176,24 @@ class PagedDecoder:
         _check_supported(cfg)
         self.cfg = cfg
         self.serve_cfg = serve_cfg
-        # resolve 'auto' once, here, so a block size the kernel cannot
-        # tile is a typed error at construction and not a lowering
-        # failure inside the first request
+        # resolve 'auto' once, here, so a block size or a head size the
+        # kernel cannot tile is a typed error at construction and not a
+        # lowering failure inside the first request
         impl = attention_impl or cfg.attention_impl
         if impl == "auto":
             impl = "pallas" if on_tpu() else "xla"
-        step = min_block_size(cfg.dtype)
-        if impl == "pallas" and serve_cfg.block_size % step:
-            raise ConfigError(
-                f"serve.block_size={serve_cfg.block_size} cannot be tiled "
-                f"by the paged-attention kernel for a "
-                f"{jnp.dtype(cfg.dtype).name} KV pool: it must be a "
-                f"multiple of {step}")
+        if impl == "pallas":
+            for t in (1, serve_cfg.prefill_chunk):
+                try:
+                    heads_per_step(cfg.num_heads, cfg.kv_heads,
+                                   cfg.head_size, serve_cfg.block_size, t,
+                                   cfg.dtype)
+                except ValueError as e:
+                    raise ConfigError(
+                        f"serve.block_size={serve_cfg.block_size}, "
+                        f"serve.prefill_chunk={serve_cfg.prefill_chunk} "
+                        f"with {cfg.kv_heads} kv heads of head size "
+                        f"{cfg.head_size}: {e}") from e
         self.impl = impl
         self.block_size = serve_cfg.block_size
         self.chunk = serve_cfg.prefill_chunk
@@ -230,18 +235,20 @@ class PagedDecoder:
             y = y + bias.astype(cfg.dtype)
         return y
 
-    def _layer(self, p, x, positions, pools_l, tables, ctx_lens, blk, off):
-        """One decoder layer over the paged cache.  ``blk``/``off``
-        [S, T] name the pool slot every token writes its k/v to (the
-        null block for masked tokens); ``ctx_lens`` is the post-write
-        context length per slot."""
+    def _layer(self, p, layer, x, pools, positions, tables, ctx_lens, blk,
+               off):
+        """Decoder layer ``layer`` over the paged cache.  ``pools`` are
+        the whole stacks [L, NB, BS, KH*D]; ``blk``/``off`` [S, T] name
+        the pool slot every token writes its k/v to (the null block for
+        masked tokens); ``ctx_lens`` is the post-write context length
+        per slot."""
         from torchacc_tpu.models.transformer import Norm
 
         # the named scopes are registered device scopes (obs/tracing.py
         # DEVICE_SCOPES): a profiler trace reads each part's device
         # time under the same names the training step's modules carry
         cfg = self.cfg
-        kp, vp = pools_l
+        kp, vp = pools
         s_, t_ = x.shape[:2]
         with jax.named_scope("ln1"):
             h = Norm(cfg).apply({"params": p["ln1"]}, x)
@@ -250,18 +257,18 @@ class PagedDecoder:
             q, k, v = self._qkv(attn, h, positions)
         # bank this chunk's (rotated) k / raw v into the pool, THEN
         # attend over the updated pool — same write-before-read order
-        # as the module's dense-cache decode branch
+        # as the module's dense-cache decode branch.  One scatter per
+        # pool: token n's [KH*D] row lands at (layer, block, offset),
+        # a contiguous window of the carried buffer, updated in place
         flat_b, flat_o = blk.reshape(-1), off.reshape(-1)
-        kh, d = kp.shape[1], kp.shape[3]
-        # pool is [NB, KH, BS, D]: index (block, :, offset) -> [N, KH, D]
         with jax.named_scope("kv_write"):
-            kp = kp.at[flat_b, :, flat_o].set(
-                k.reshape(s_ * t_, kh, d).astype(kp.dtype))
-            vp = vp.at[flat_b, :, flat_o].set(
-                v.reshape(s_ * t_, kh, d).astype(vp.dtype))
+            kp = kp.at[layer, flat_b, flat_o].set(
+                k.reshape(s_ * t_, -1).astype(kp.dtype))
+            vp = vp.at[layer, flat_b, flat_o].set(
+                v.reshape(s_ * t_, -1).astype(vp.dtype))
         with jax.named_scope("paged_attn"):
             out = paged_attention(
-                q, kp, vp, tables, ctx_lens, positions[:, 0],
+                q, kp, vp, tables, ctx_lens, positions[:, 0], layer=layer,
                 scale=cfg.query_scale, window=cfg.window,
                 logit_softcap=cfg.attn_logit_softcap, impl=self.impl)
         with jax.named_scope("o_proj"):
@@ -328,28 +335,32 @@ class PagedDecoder:
 
     def _forward(self, params, pools, ids, positions, tables, ctx_lens,
                  blk, off):
-        """(pools', hidden [S, T, H]): embed -> layer scan over the
-        stacked params + per-layer pools.  The head projection is the
-        caller's: decode projects every slot's single row, prefill
-        projects ONLY the last valid row (the full-chunk head would be
-        a C x hidden x vocab matmul that is discarded for every row
-        but one)."""
+        """(pools', hidden [S, T, H]): embed -> layer scan.  The two
+        stacked pools ride the scan's CARRY with the residual — each
+        layer writes its rows in place and the kernel reads its pages
+        through the layer index, so nothing slices a layer out of the
+        stack or puts it back; ``xs`` are the stacked params and the
+        layer index.  The head projection is the caller's: decode
+        projects every slot's single row, prefill projects ONLY the
+        last valid row (the full-chunk head would be a C x hidden x
+        vocab matmul that is discarded for every row but one)."""
         from torchacc_tpu.models.generate import _zoo_embed
 
         with jax.named_scope("embed"):
             x = _zoo_embed(self.cfg, params, ids, positions)
-        k_pools, v_pools = pools
 
         def body(carry, per):
-            p_l, kp, vp = per
-            y, (kp, vp) = self._layer(p_l["block"], carry, positions,
-                                      (kp, vp), tables, ctx_lens, blk, off)
-            return y, (kp, vp)
+            x, pools = carry
+            p_l, layer = per
+            return self._layer(p_l["block"], layer, x, pools, positions,
+                               tables, ctx_lens, blk, off), None
 
         with jax.named_scope("layers"):
-            x, (k_pools, v_pools) = jax.lax.scan(
-                body, x, (params["layers"], k_pools, v_pools))
-        return (k_pools, v_pools), x
+            (x, pools), _ = jax.lax.scan(
+                body, (x, pools),
+                (params["layers"],
+                 jnp.arange(self.cfg.num_layers, dtype=jnp.int32)))
+        return pools, x
 
     # -- sampling -----------------------------------------------------------
 
@@ -480,10 +491,11 @@ class PagedDecoder:
 
     def _cow_impl(self, pools, src, dst):
         """Copy block ``src``'s k/v into block ``dst`` across every
-        layer — the copy-on-write behind a fully-cached prompt: the
-        final prompt token must re-run (its logits seed the first
-        sampled token) and its k/v write needs a block this sequence
-        owns; everything before it stays shared."""
+        layer (blocks are dim 1 of [L, NB, BS, KH*D]) — the
+        copy-on-write behind a fully-cached prompt: the final prompt
+        token must re-run (its logits seed the first sampled token) and
+        its k/v write needs a block this sequence owns; everything
+        before it stays shared."""
         kp, vp = pools
         kp = kp.at[:, dst].set(kp[:, src])
         vp = vp.at[:, dst].set(vp[:, src])
