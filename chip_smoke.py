@@ -234,7 +234,7 @@ def _live_pool_check(engine):
     sched = engine.scheduler
     cfg = engine.cfg
     chunk = sched.serve_cfg.prefill_chunk
-    kp, vp = sched.k_pools, sched.v_pools
+    kp, vp = sched.pools
     layer = cfg.num_layers - 1
     active = np.flatnonzero(sched.active)
     worst = 0.0
@@ -263,7 +263,7 @@ def _program_kernels(engine) -> dict:
     import jax.numpy as jnp
     sched = engine.scheduler
     dec = sched.decoder
-    pools = (sched.k_pools, sched.v_pools)
+    pools = sched.pools
     tables, active, temp, top_k, top_p = sched._dev_stable_arrays()
     decode = dec._decode.lower(
         sched.params, pools, sched.carry, tables, jnp.array(sched.seq_lens),

@@ -32,6 +32,7 @@ from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
 import torchacc_tpu.ops.flash_attention as flash_mod
+import torchacc_tpu.ops.grouped_matmul as grouped_mod
 import torchacc_tpu.ops.paged_attention as paged_mod
 import torchacc_tpu.ops.quantized_matmul as quant_mod
 from torchacc_tpu.config import ServeConfig
@@ -70,7 +71,7 @@ def one_chip(topo):
 def for_the_chip(monkeypatch):
     """The kernels ask ``interpret_mode()``, which sees the CPU backend
     here; steer them to the Mosaic lowering for the described chip."""
-    for mod in (flash_mod, paged_mod, quant_mod):
+    for mod in (flash_mod, grouped_mod, paged_mod, quant_mod):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -240,3 +241,129 @@ def test_serve_program_holds_one_kv_pool(one_chip, for_the_chip, name):
                if re.search(rf"= ({shapes})\S* fusion\(", line)]
     assert len(written) == 2 and all("kv_write" in w for w in written), \
         written
+
+
+# -- the latent-attention / held-expert family at A.X-K1's published
+# geometry (chipbench/configs/a.x-k1.json, traffic/rollout32.json) --------
+
+AXK1 = dict(
+    hidden_size=7168, num_heads=64, num_kv_heads=64,
+    intermediate_size=18432, vocab_size=163840, max_seq_len=SEQ,
+    rope_interleaved=True, kv_lora_rank=512, q_lora_rank=1536,
+    qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+    first_dense_layers=1, num_experts=12, num_experts_per_tok=8,
+    moe_intermediate_size=2048, moe_scoring="sigmoid", moe_n_group=8,
+    moe_topk_group=4, moe_route_scale=2.5, moe_shared_experts=1,
+    moe_router_width=192, moe_first_expert=96, moe_dispatch="grouped",
+    norm_eps=1e-6, rope_theta=10000.0,
+    rope_yarn=(32.0, 4096.0, 32.0, 1.0, 1.0, True), query_scale=0.1309)
+AXK1_SERVE = dict(block_size=128, num_blocks=640, max_slots=32,
+                  prefill_chunk=512)
+AXK1_DEPTH = 2               # one dense + one expert layer
+
+
+@pytest.mark.parametrize("slots,t", [(32, 1), (1, 512)],
+                         ids=["decode", "prefill_chunk"])
+def test_latent_paged_kernel_compiles_at_published_geometry(
+        one_chip, for_the_chip, slots, t):
+    """64 heads on one shared row of 512 + 64 values in a 640-lane pool
+    row, block 128: t = 1 and the chunk path (query rows tiled)."""
+    sds = functools.partial(_sds, sharding=one_chip)
+    i32 = functools.partial(sds, dtype=jnp.int32)
+    tile = paged_mod.latent_query_tile(64, 512, 64, 128, t, BF16)
+    assert t % tile == 0 and tile * 64 >= min(t, 8) * 64
+
+    def call(q_lat, q_pe, pool, tables, ctx, q0, layer):
+        return paged_mod.latent_paged_attention(
+            q_lat, q_pe, pool, tables, ctx, q0, layer=layer, scale=0.13,
+            impl="pallas")
+
+    compiled = jax.jit(call).lower(
+        sds((slots, t, 64, 512), BF16), sds((slots, t, 64, 64), BF16),
+        sds((LAYERS, 640, 128, 640), BF16), i32((slots, 33)), i32((slots,)),
+        i32((slots,)), i32(())).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the pool is read where it lies: no relayouted copy of it
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+@pytest.mark.parametrize("rows,k,n", [(256, 7168, 2048), (4096, 7168, 2048),
+                                      (256, 2048, 7168)],
+                         ids=["decode_up", "chunk_up", "decode_down"])
+def test_grouped_expert_matmul_compiles_for_the_chip(one_chip, for_the_chip,
+                                                     rows, k, n):
+    """12 held experts of 7168 x 2048 (and the way back): the Pallas
+    grouped matmul over the sorted (token, expert) pairs of a decode
+    step (32 x 8) and of a prefill chunk (512 x 8), weights read in
+    place (no temporary of an expert's size)."""
+    sds = functools.partial(_sds, sharding=one_chip)
+    compiled = jax.jit(grouped_mod.grouped_matmul).lower(
+        sds((rows, k), BF16), sds((12, k, n), BF16),
+        sds((12,), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+def _axk1_program(name, one_chip):
+    from torchacc_tpu.models import TransformerLM, get_preset
+    from torchacc_tpu.serve.kv_cache import blocks_needed, make_pools
+    from torchacc_tpu.serve.scheduler import PagedDecoder
+
+    mc = get_preset("llama-tiny", num_layers=AXK1_DEPTH, dtype=BF16,
+                    param_dtype=BF16, **AXK1)
+    sc = ServeConfig(**AXK1_SERVE)
+    decoder = PagedDecoder(mc, sc, "pallas")
+    sds = functools.partial(_sds, sharding=one_chip)
+    abstract = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: sds(x.shape, x.dtype), tree)
+    params = abstract(jax.eval_shape(
+        lambda k: TransformerLM(mc).init(
+            k, jnp.zeros((1, 8), jnp.int32))["params"],
+        jax.random.PRNGKey(0)))
+    pools = abstract(jax.eval_shape(lambda: make_pools(mc, sc)))
+    s = sc.max_slots
+    mb = min(sc.num_blocks - 1,
+             blocks_needed(SEQ + sc.decode_depth, sc.block_size))
+    i32, f32 = jnp.int32, jnp.float32
+    if name == "decode":
+        carry = {"tok": sds((s,), i32), "key": sds((s, 2), jnp.uint32)}
+        return decoder._decode.lower(
+            params, pools, carry, sds((s, mb), i32), sds((s,), i32),
+            sds((s,), jnp.bool_), sds((s,), f32), sds((s,), i32),
+            sds((s,), f32), True), pools
+    return decoder._prefill.lower(
+        params, pools, sds((mb,), i32), sds((), i32),
+        sds((sc.prefill_chunk,), i32), sds((), i32),
+        name == "prefill_final_chunk"), pools
+
+
+@pytest.mark.parametrize("name",
+                         ["decode", "prefill_chunk", "prefill_final_chunk"])
+def test_latent_serve_program_holds_one_latent_pool(one_chip, for_the_chip,
+                                                    name):
+    """The three serve programs of the A.X-K1 cell: ONE latent pool
+    [L, 640, 128, 640], aliased in -> out, no pool-shaped copy; the
+    latent kernel once a layer stack and the three grouped matmuls.  The temporaries hold one layer's expert stack
+    (336 MiB: the scan slices a layer's [12, 7168, 2048] out of the
+    stacked weights for the grouped matmul — PERF.md section 5; a
+    perf_opt PR's to remove) and must stay under two of them."""
+    lowered, (pool,) = _axk1_program(name, one_chip)
+    compiled = lowered.compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    pool_bytes = pool.size * pool.dtype.itemsize
+    assert pool.shape == (AXK1_DEPTH, 640, 128, 640)
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 2 * 336 * 2**20
+    # a non-final chunk returns no logits, so at this depth (ONE expert
+    # layer, the last) the compiler drops that layer's expert matmuls:
+    # only the two latent kernels are left
+    assert text.count("tpu_custom_call") == (
+        2 if name == "prefill_chunk" else 5)
+    dims = [str(d) for d in pool.shape]
+    shapes = "|".join(re.escape(f"bf16[{','.join(dims[i:])}]")
+                      for i in (0, 1))
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(rf"= ({shapes})\S* "
+                          r"(copy|dynamic-slice|dynamic-update-slice)\(",
+                          line)]
+    assert not moved, moved

@@ -290,7 +290,7 @@ def test_layer_write_leaves_other_layers_bit_identical(tiny, layer):
     blk = jnp.asarray([[9, 9, 9, 9], [7, 7, 7, 7]], jnp.int32)
     off = positions % sc.block_size
     p_l = jax.tree.map(lambda a: a[layer], params["layers"])["block"]
-    _, (kp2, vp2) = jax.jit(decoder._layer)(
+    _, (kp2, vp2), _ = jax.jit(decoder._layer)(
         p_l, jnp.int32(layer), x, (kp, vp), positions, tables,
         positions[:, -1] + 1, blk, off)
     for old, new in ((kp, kp2), (vp, vp2)):
@@ -339,7 +339,7 @@ def test_tp_mesh_pool_rows_split_at_head_boundaries(devices, kv_heads):
     mesh = Mesh(np.asarray(devices[:4]).reshape(2, 2), ("fsdp", "tp"))
     kernel = TransformerLM(dataclasses.replace(cfg, attention_impl="pallas"))
     eng = ServeEngine(kernel, params, _serve_cfg(), mesh=mesh)
-    pool = eng.scheduler.k_pools
+    pool = eng.scheduler.pools[0]
     assert pool.shape == (2, 64, 8, kv_heads * 16)
     assert pool.sharding.spec == P(None, None, None,
                                    "tp" if kv_heads == 2 else None)

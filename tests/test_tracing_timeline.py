@@ -41,6 +41,14 @@ DECODE_SCOPES = ("embed", "layers", "ln1", "ln2", "qkv", "kv_write",
 PREFILL_SCOPES = ("embed", "layers", "qkv", "kv_write", "paged_attn",
                   "mlp", "head")
 
+# a latent-attention / held-expert model's decode program (PR 26)
+LATENT_DECODE_SCOPES = ("mla_q", "mla_kv", "latent_attn", "kv_write",
+                        "router", "moe_dispatch", "experts",
+                        "shared_expert", "moe_combine")
+# what its serve/deliver spans carry (chipbench/readers/expert_load.py)
+MOE_SPAN_ATTRS = ("moe_pairs", "moe_max", "moe_hit", "moe_slots",
+                  "moe_layer_steps")
+
 # the spans a traced serve loop / fit has to leave on the host plane
 SERVE_SPANS = ("serve/step", "serve/sweep", "serve/admit", "serve/prefill",
                "serve/decode", "serve/deliver", "serve/wait")
@@ -53,6 +61,20 @@ def _model_cfg(**kw):
         "llama-tiny", dtype=jnp.float32, num_layers=2, hidden_size=128,
         num_heads=2, num_kv_heads=2, intermediate_size=256, vocab_size=256,
         max_seq_len=256, **kw)
+
+
+def _latent_model_cfg():
+    """A toy of the latent-attention / held-expert family."""
+    return get_preset(
+        "llama-tiny", dtype=jnp.float32, num_layers=2, hidden_size=64,
+        num_heads=2, num_kv_heads=2, intermediate_size=128, vocab_size=256,
+        max_seq_len=128, rope_interleaved=True, kv_lora_rank=32,
+        q_lora_rank=48, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, first_dense_layers=1, num_experts=4,
+        num_experts_per_tok=2, moe_intermediate_size=32,
+        moe_scoring="sigmoid", moe_n_group=2, moe_topk_group=1,
+        moe_route_scale=2.5, moe_shared_experts=1, moe_router_width=8,
+        moe_first_expert=2, moe_dispatch="grouped")
 
 
 def _scopes_in(hlo_text):
@@ -108,21 +130,34 @@ def program_scopes():
     prefill = decoder._prefill.lower(
         params, (pool, pool), sds((15,), jnp.int32), i32,
         sds((8,), jnp.int32), i32, True).compile().as_text()
+    lmc = _latent_model_cfg()
+    ldecoder = PagedDecoder(lmc, sc, "xla")
+    lparams = jax.eval_shape(
+        lambda: TransformerLM(lmc).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    latent = ldecoder._decode.lower(
+        lparams, jax.eval_shape(lambda: make_pools(lmc, sc)), carry,
+        sds((2, 15), jnp.int32), sds((2,), jnp.int32), sds((2,), jnp.bool_),
+        sds((2,), jnp.float32), sds((2,), jnp.int32),
+        sds((2,), jnp.float32), True).compile().as_text()
     return {"train": _scopes_in(train), "decode": _scopes_in(decode),
-            "prefill": _scopes_in(prefill)}
+            "prefill": _scopes_in(prefill),
+            "latent_decode": _scopes_in(latent)}
 
 
 @pytest.mark.parametrize("program,scope", [
     *(("train", s) for s in TRAIN_SCOPES),
     *(("decode", s) for s in DECODE_SCOPES),
-    *(("prefill", s) for s in PREFILL_SCOPES)])
+    *(("prefill", s) for s in PREFILL_SCOPES),
+    *(("latent_decode", s) for s in LATENT_DECODE_SCOPES)])
 def test_device_scope_in_compiled_program(program_scopes, program, scope):
     assert scope in tracing.DEVICE_SCOPES
     assert scope in program_scopes[program]
 
 
 def test_every_registered_scope_is_placed():
-    placed = set(TRAIN_SCOPES) | set(DECODE_SCOPES) | set(PREFILL_SCOPES)
+    placed = (set(TRAIN_SCOPES) | set(DECODE_SCOPES) | set(PREFILL_SCOPES)
+              | set(LATENT_DECODE_SCOPES))
     assert placed == set(tracing.DEVICE_SCOPES)
 
 
@@ -177,6 +212,16 @@ def traced(tmp_path_factory):
                 .astype(np.int32)} for _ in range(3)]
     trainer.fit(batches[:1], log_every=1)  # compile outside the trace
 
+    lmodel = TransformerLM(_latent_model_cfg())
+    lengine = ServeEngine(
+        lmodel, lmodel.init(jax.random.PRNGKey(1),
+                            jnp.zeros((1, 8), jnp.int32))["params"],
+        ta.Config(serve=ta.config.ServeConfig(
+            block_size=8, num_blocks=64, max_slots=2, prefill_chunk=8)))
+    lreqs = [Request(prompt_ids=rng.integers(1, 256, size=n).tolist(),
+                     max_new_tokens=3) for n in (5, 19)]
+    lengine.generate(lreqs[:1])            # compile outside the trace
+
     assert not tracing.enabled()
     tracing.clear()
     trace_dir = str(tmp_path_factory.mktemp("timeline"))
@@ -187,9 +232,11 @@ def traced(tmp_path_factory):
     try:
         engine.generate(reqs)
         trainer.fit(batches, log_every=1)
+        lengine.generate(lreqs)
     finally:
         jax.profiler.stop_trace()
     engine.close()
+    lengine.close()
     return {"events": _host_events(trace_dir),
             "ring": tracing.snapshot()}
 
@@ -222,6 +269,27 @@ def test_profiler_sink_carries_scalars_and_leaves_the_ring_off(traced):
     decodes = [st for n, _, _, st in traced["events"] if n == "serve/decode"]
     assert decodes and all("slots" in st and "traces" not in st
                            for st in decodes)
+
+
+@pytest.mark.parametrize("attr", MOE_SPAN_ATTRS)
+def test_deliver_spans_carry_the_expert_layers_counts(traced, attr):
+    """An expert model's serve/deliver spans reach the profiler with
+    the counts of the steps behind them (set after the token fetch, on
+    the open annotation); a dense model's carry none."""
+    with_counts = [st for n, _, _, st in traced["events"]
+                   if n == "serve/deliver" and "moe_pairs" in st]
+    without = [st for n, _, _, st in traced["events"]
+               if n == "serve/deliver" and "moe_pairs" not in st]
+    assert with_counts and without
+    assert all(attr in st for st in with_counts)
+    # 2 held experts of 8, one expert layer: a decode step of <= 2 slots
+    # x 2 picks puts at most 4 pairs here; a 'first' entry brings every
+    # prefill chunk of its prompt (19 tokens: 3 chunks)
+    assert all(0 <= int(st["moe_hit"]) <= int(st["moe_slots"])
+               and int(st["moe_max"]) <= int(st["moe_pairs"])
+               for st in with_counts)
+    assert max(int(st["moe_layer_steps"]) for st in with_counts) == 3
+    assert sum(int(st["moe_pairs"]) for st in with_counts) > 0
 
 
 # -- the idle path and the ring ------------------------------------------------

@@ -240,6 +240,59 @@ def config_from_hf(hf_config: Any, **overrides) -> ModelConfig:
             router_aux_weight=float(get("router_aux_loss_coef", 0.001)),
             intermediate_size=int(get("moe_intermediate_size")),
             moe_renorm_topk=bool(get("norm_topk_prob", False)))
+    if mt == "axk1":
+        # A.X-K1 (skt): multi-head latent attention, `first_k_dense_replace`
+        # leading dense layers, then expert layers (sigmoid scores,
+        # group-limited top-k, normalised weights times
+        # routed_scaling_factor, shared experts).  `n_routed_experts` is
+        # the number of experts THIS program holds; an expert-parallel
+        # share states the router's published width and its first held
+        # expert beside it (`router_n_experts`, `first_held_expert`;
+        # absent = every expert is held).  `topk_method` "none" is read
+        # as: no selection-bias term ("noaux_tc" adds one).
+        if int(get("moe_layer_freq", 1) or 1) != 1:
+            raise NotImplementedError(
+                "axk1 with moe_layer_freq != 1 (dense layers between the "
+                "expert layers) is not implemented")
+        if get("hidden_act", "silu") != "silu":
+            raise NotImplementedError("axk1 hidden_act must be silu")
+        if get("topk_method", "none") not in ("none", "noaux_tc"):
+            raise NotImplementedError(
+                f"axk1 topk_method {get('topk_method')!r} is not "
+                f"implemented ('none' and 'noaux_tc' are)")
+        held = int(get("n_routed_experts"))
+        kw.update(
+            head_dim=None, qkv_bias=False, o_bias=False,
+            rope_interleaved=True,
+            kv_lora_rank=int(get("kv_lora_rank")),
+            q_lora_rank=int(get("q_lora_rank") or 0),
+            qk_nope_head_dim=int(get("qk_nope_head_dim")),
+            qk_rope_head_dim=int(get("qk_rope_head_dim")),
+            v_head_dim=int(get("v_head_dim")),
+            first_dense_layers=int(get("first_k_dense_replace", 0) or 0),
+            num_experts=held,
+            num_experts_per_tok=int(get("num_experts_per_tok")),
+            moe_intermediate_size=int(get("moe_intermediate_size")),
+            moe_scoring=get("scoring_func", "softmax"),
+            moe_n_group=int(get("n_group", 1) or 1),
+            moe_topk_group=int(get("topk_group", 1) or 1),
+            moe_route_scale=float(get("routed_scaling_factor", 1.0)),
+            moe_renorm_topk=bool(get("norm_topk_prob", True)),
+            moe_router_bias=get("topk_method", "none") == "noaux_tc",
+            moe_shared_experts=int(get("n_shared_experts", 0) or 0),
+            moe_router_width=int(get("router_n_experts", held)),
+            moe_first_expert=int(get("first_held_expert", 0)),
+            moe_dispatch="grouped")
+        # softmax scale: head dim ** -0.5, times yarn's mscale**2 over
+        # ALL dims (the family's attention folds it into the scale, the
+        # generic yarn branch below keeps the cos/sin factor)
+        import math as _m
+        scale = (kw["qk_nope_head_dim"] + kw["qk_rope_head_dim"]) ** -0.5
+        rs = get("rope_scaling") or {}
+        if rs.get("mscale_all_dim") and float(rs.get("factor", 1.0)) > 1.0:
+            scale *= (0.1 * float(rs["mscale_all_dim"])
+                      * _m.log(float(rs["factor"])) + 1.0) ** 2
+        kw["query_scale"] = scale
     if mt == "mixtral":
         # Mixtral 8x7B/8x22B: llama attention + top-k sparse MoE MLP.
         # HF routes softmax-then-topk-then-renormalise, which equals the
@@ -299,10 +352,15 @@ def config_from_hf(hf_config: Any, **overrides) -> ModelConfig:
                 orig = float(rs.get("original_max_position_embeddings")
                              or kw["max_seq_len"])
                 af = rs.get("attention_factor")
-                if rs.get("mscale") or rs.get("mscale_all_dim"):
-                    raise NotImplementedError(
-                        "yarn mscale variants (deepseek) are not "
-                        "implemented")
+                if af is None and rs.get("mscale") \
+                        and rs.get("mscale_all_dim"):
+                    # the mscale variant: the cos/sin factor is the ratio
+                    # of the two (HF _compute_yarn_parameters)
+                    import math as _m
+                    f = float(rs["factor"])
+                    ms = lambda m: (1.0 if f <= 1.0  # noqa: E731
+                                    else 0.1 * float(m) * _m.log(f) + 1.0)
+                    af = ms(rs["mscale"]) / ms(rs["mscale_all_dim"])
                 kw["rope_yarn"] = (
                     float(rs["factor"]), orig,
                     float(rs.get("beta_fast") or 32.0),
@@ -526,6 +584,12 @@ def params_from_hf_state_dict(
     names) take their own mapping.
     """
     dtype = dtype or cfg.param_dtype
+    if cfg.kv_lora_rank:
+        raise NotImplementedError(
+            "converting a latent-attention (axk1) checkpoint is not "
+            "implemented: config_from_hf builds the model, the weight "
+            "mapping (kv_b_proj split into kv_b_k / kv_b_v, the two "
+            "layer stacks, a held share of the experts) is not written")
     # the Conv1D-packed c_attn is specific to the gpt2 layout (GPT-J /
     # GPT-Neo also have wte but different attention naming — those are
     # unsupported and will fail on their attention tensors loudly)

@@ -1,0 +1,166 @@
+"""Multi-head latent attention (MLA).
+
+Keys and values come from one low-rank latent a token: ``[c_kv | k_pe] =
+x W_kva`` (``kv_lora_rank`` + ``qk_rope_head_dim`` values), ``c_kv``
+RMS-normed, ``k_pe`` rotated and shared by every head; a head's key is
+``[c_kv W_kvb,h^K | k_pe]`` and its value ``c_kv W_kvb,h^V``.  Queries go
+through their own low-rank pair (``q_lora_rank``; 0 = one plain
+projection).  Two forms of the same arithmetic live here:
+
+- expanded (:class:`MlaAttention`, the module's plain forward): build
+  every head's k and v and run ordinary causal attention;
+- absorbed (:func:`absorb_q` / :func:`expand_out`, the serving decoder):
+  cache only the row ``[c_kv | rope(k_pe)]``, fold ``W_kvb^K`` into the
+  query (``q~_h = q_nope,h W_kvb,h^K``) and ``W_kvb^V`` into the output
+  (``o_h = (P c_kv) W_kvb,h^V``), so attention reads the latent rows as
+  one shared key/value head of ``kv_lora_rank + qk_rope_head_dim`` lanes
+  (ops/paged_attention.latent_paged_attention).
+
+The projections are pure functions of the raw parameter tree, shared by
+both forms.  The softmax scale is ``cfg.query_scale`` (the ingest folds
+yarn's ``mscale**2`` into it).
+"""
+
+from __future__ import annotations
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+
+def _dense(cfg, x, kernel):
+    return jnp.einsum("bth,h...->bt...", x.astype(cfg.dtype),
+                      kernel.astype(cfg.dtype))
+
+
+def _norm(cfg, scale_tree, x):
+    from torchacc_tpu.models.transformer import Norm
+    return Norm(cfg).apply({"params": scale_tree}, x)
+
+
+def _rope_one(cfg, x, positions):
+    """Rotary embedding of ``x`` [b, s, heads, rope_dim]."""
+    from torchacc_tpu.models.transformer import _rope
+    return _rope(x, x, positions, cfg)[0]
+
+
+def project_q(cfg, attn, h, positions):
+    """``(q_nope [b, s, H, nope], q_pe [b, s, H, rope])``, q_pe rotated."""
+    if cfg.q_lora_rank:
+        c_q = _norm(cfg, attn["q_a_norm"],
+                    _dense(cfg, h, attn["q_a_proj"]["kernel"]))
+        q = _dense(cfg, c_q, attn["q_b_proj"]["kernel"])
+    else:
+        q = _dense(cfg, h, attn["q_proj"]["kernel"])
+    q_nope, q_pe = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
+    return q_nope, _rope_one(cfg, q_pe, positions)
+
+
+def project_latent(cfg, attn, h, positions):
+    """``(c_kv [b, s, R] normed, k_pe [b, s, rope] rotated)``: the row a
+    latent cache banks is their concatenation."""
+    ckv = _dense(cfg, h, attn["kv_a_proj"]["kernel"])
+    c_kv, k_pe = jnp.split(ckv, [cfg.kv_lora_rank], axis=-1)
+    c_kv = _norm(cfg, attn["kv_a_norm"], c_kv)
+    return c_kv, _rope_one(cfg, k_pe[:, :, None, :], positions)[:, :, 0]
+
+
+def absorb_q(cfg, attn, q_nope):
+    """``q~_h = q_nope,h W_kvb,h^K``: [b, s, H, nope] -> [b, s, H, R]."""
+    return jnp.einsum("bshn,rhn->bshr", q_nope.astype(cfg.dtype),
+                      attn["kv_b_k"]["kernel"].astype(cfg.dtype))
+
+
+def expand_out(cfg, attn, o_lat):
+    """``o_h = o~_h W_kvb,h^V``: [b, s, H, R] -> [b, s, H, v]."""
+    return jnp.einsum("bshr,rhv->bshv", o_lat.astype(cfg.dtype),
+                      attn["kv_b_v"]["kernel"].astype(cfg.dtype))
+
+
+def project_out(cfg, attn, out):
+    """``concat_h(o_h) W_o``: [b, s, H, v] -> [b, s, hidden]."""
+    return jnp.einsum("bshv,hvd->bsd", out.astype(cfg.dtype),
+                      attn["o_proj"]["kernel"].astype(cfg.dtype))
+
+
+def query_scale(cfg) -> float:
+    return (cfg.query_scale if cfg.query_scale is not None else
+            (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5)
+
+
+def expanded_attention(cfg, attn, h, positions, segment_ids=None):
+    """The expanded form on the raw tree ``attn``: [b, s, hidden] ->
+    [b, s, hidden] (the attention block's output before the residual)."""
+    from torchacc_tpu.ops.attention import attention_reference
+
+    with jax.named_scope("mla_q"):
+        q_nope, q_pe = project_q(cfg, attn, h, positions)
+    with jax.named_scope("mla_kv"):
+        c_kv, k_pe = project_latent(cfg, attn, h, positions)
+        k_nope = jnp.einsum("bsr,rhn->bshn", c_kv.astype(cfg.dtype),
+                            attn["kv_b_k"]["kernel"].astype(cfg.dtype))
+        v = jnp.einsum("bsr,rhv->bshv", c_kv.astype(cfg.dtype),
+                       attn["kv_b_v"]["kernel"].astype(cfg.dtype))
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_pe[:, :, None, :],
+                                      k_nope.shape[:3] + k_pe.shape[-1:])],
+            axis=-1)
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    out = attention_reference(q, k, v, causal=True, scale=query_scale(cfg),
+                              q_segment_ids=segment_ids,
+                              kv_segment_ids=segment_ids)
+    with jax.named_scope("o_proj"):
+        return project_out(cfg, attn, out)
+
+
+class _Scale(nn.Module):
+    """Holds one norm ``scale`` (the tree a :class:`Norm` would hold)."""
+    size: int
+    param_dtype: object
+
+    @nn.compact
+    def __call__(self):
+        return {"scale": self.param("scale", nn.initializers.ones,
+                                    (self.size,), self.param_dtype)}
+
+
+class MlaAttention(nn.Module):
+    """The attention block of an MLA model, expanded form.  Holds the
+    parameters (kernels ``[in, heads, dim]`` like :class:`Attention`'s)
+    and computes on the raw tree, so the serving decoder's absorbed
+    form reads the same leaves."""
+    cfg: object  # ModelConfig
+
+    @nn.compact
+    def __call__(self, x, positions, segment_ids=None, dropout_seed=None):
+        cfg = self.cfg
+        if self.has_variable("cache", "k") or (
+                self.is_mutable_collection("cache")
+                and not self.is_initializing()) or cfg.decode:
+            raise NotImplementedError(
+                "latent-attention models decode through "
+                "torchacc_tpu.serve.ServeEngine (a latent paged cache); "
+                "the module's dense-cache decode path is not implemented")
+        from torchacc_tpu.models.moe import _Kernel
+
+        h, nh, r = cfg.hidden_size, cfg.num_heads, cfg.kv_lora_rank
+        nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                          cfg.v_head_dim)
+        kernel = lambda name, *shape: _Kernel(  # noqa: E731
+            shape, cfg.param_dtype, name=name)()
+        scale = lambda name, size: _Scale(  # noqa: E731
+            size, cfg.param_dtype, name=name)()
+        attn = {}
+        if cfg.q_lora_rank:
+            attn["q_a_proj"] = kernel("q_a_proj", h, cfg.q_lora_rank)
+            attn["q_a_norm"] = scale("q_a_norm", cfg.q_lora_rank)
+            attn["q_b_proj"] = kernel("q_b_proj", cfg.q_lora_rank, nh,
+                                      nope + rope)
+        else:
+            attn["q_proj"] = kernel("q_proj", h, nh, nope + rope)
+        attn["kv_a_proj"] = kernel("kv_a_proj", h, r + rope)
+        attn["kv_a_norm"] = scale("kv_a_norm", r)
+        attn["kv_b_k"] = kernel("kv_b_k", r, nh, nope)
+        attn["kv_b_v"] = kernel("kv_b_v", r, nh, vd)
+        attn["o_proj"] = kernel("o_proj", nh, vd, h)
+        return expanded_attention(cfg, attn, x, positions, segment_ids)

@@ -15,6 +15,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from torchacc_tpu.ops.grouped_matmul import grouped_matmul
+
 
 def _sort_dispatch(xf, sel_f, w_f, e, cap):
     """Scale-proof capacity dispatch: argsort by expert instead of
@@ -57,6 +59,177 @@ def _sort_dispatch(xf, sel_f, w_f, e, cap):
     return ex_in.reshape(e, cap, h), dest, tok_sorted, w_keep
 
 
+def route(cfg, logits, bias=None):
+    """Token-choice routing over ``cfg.router_width`` experts.
+
+    ``logits`` [n, E] float32.  Returns ``(sel [n, k] int32, weights
+    [n, k] float32, scores [n, E])``.
+
+    - ``moe_scoring='softmax'``: top-k of the logits, weights the softmax
+      over the selected logits (``moe_renorm_topk``, mixtral) or the
+      plain full-softmax probabilities (qwen3-moe).
+    - ``moe_scoring='sigmoid'``: scores ``s = sigmoid(logits)``;
+      selection runs on ``s + bias`` (``bias`` optional, selection only),
+      limited to the ``moe_topk_group`` best of ``moe_n_group`` groups of
+      adjacent experts (a group's score: the sum of its two largest
+      selection scores); weights are the UNbiased ``s`` of the selected,
+      divided by their sum (+1e-20) under ``moe_renorm_topk``, times
+      ``moe_route_scale``.  ``moe_n_group=1`` is plain top-k.
+    """
+    k = cfg.num_experts_per_tok
+    n, e = logits.shape
+    if cfg.moe_scoring == "softmax":
+        if cfg.moe_renorm_topk:
+            weights, sel = jax.lax.top_k(logits, k)
+            return sel, jax.nn.softmax(weights, axis=-1), logits
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, sel = jax.lax.top_k(probs, k)
+        return sel, weights, probs
+    if cfg.moe_scoring != "sigmoid":
+        raise ValueError(f"moe_scoring must be 'softmax' | 'sigmoid', got "
+                         f"{cfg.moe_scoring!r}")
+    scores = jax.nn.sigmoid(logits)
+    choice = scores if bias is None else scores + bias.astype(jnp.float32)
+    g = cfg.moe_n_group
+    if g > 1:
+        if e % g or e // g < 2:
+            raise ValueError(f"moe_n_group={g} does not split {e} experts "
+                             f"into groups of at least two")
+        grouped = choice.reshape(n, g, e // g)
+        group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, keep = jax.lax.top_k(group_score, cfg.moe_topk_group)  # [n, tg]
+        kept = jnp.any(keep[:, :, None] == jnp.arange(g)[None, None, :],
+                       axis=1)                                    # [n, g]
+        choice = jnp.where(jnp.repeat(kept, e // g, axis=1), choice,
+                           -jnp.inf)
+    _, sel = jax.lax.top_k(choice, k)
+    weights = jnp.take_along_axis(scores, sel, axis=-1)
+    if cfg.moe_renorm_topk:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                             + 1e-20)
+    return sel, weights * cfg.moe_route_scale, scores
+
+
+def swiglu(x, w_gate, w_up, w_down, dtype):
+    """One SwiGLU FFN on raw kernels ([in, f], [in, f], [f, out])."""
+    xd = x.astype(dtype)
+    ff = nn.silu(xd @ w_gate.astype(dtype)) * (xd @ w_up.astype(dtype))
+    return ff @ w_down.astype(dtype)
+
+
+def held_experts_ffn(cfg, x, sel, weights, w_gate, w_up, w_down,
+                     valid=None):
+    """The held experts' part of ``sum_i w_i E_i(x)``, dropless.
+
+    ``x`` [n, h]; ``sel``/``weights`` [n, k] name experts of the router's
+    whole width; this program holds the ``e = w_gate.shape[0]`` experts
+    ``[cfg.moe_first_expert, +e)``.  The (token, expert) pairs that chose
+    a held expert are sorted by expert and pass through three grouped
+    matmuls (``ops/grouped_matmul.py``: a Pallas kernel that visits only
+    the row tiles that hold pairs and the weights of the groups they
+    belong to), so FLOPs follow the routed pairs and an expert that drew
+    no token is not read.  Nothing is dropped under any
+    imbalance: the sorted buffer holds all ``n * k`` pairs.  Pairs on
+    experts held elsewhere (and tokens with ``valid`` False: padding,
+    free serving slots) sort behind the held groups, are computed by no
+    group and add nothing.  What the absent experts would add is left
+    out: the exchange that gathers it is not this program's.
+
+    Returns ``(y [n, h] float32, load int32[3])``: pairs on held experts,
+    the largest held expert's count, held experts that drew a pair.
+    """
+    n, k = sel.shape
+    e = w_gate.shape[0]
+    nk = n * k
+    with jax.named_scope("moe_dispatch"):
+        local = sel - cfg.moe_first_expert
+        held = (local >= 0) & (local < e)
+        if valid is not None:
+            held &= valid[:, None]
+        key = jnp.where(held, local, e).reshape(nk).astype(jnp.int32)
+        order = jnp.argsort(key, stable=True)
+        counts = jnp.bincount(key, length=e + 1)[:e].astype(jnp.int32)
+        tok_sorted = (order // k).astype(jnp.int32)
+        xs = x.astype(cfg.dtype)[tok_sorted]                    # [nk, h]
+    with jax.named_scope("experts"):
+        dt = cfg.dtype
+        gate = grouped_matmul(xs, w_gate, counts)
+        up = grouped_matmul(xs, w_up, counts)
+        out = grouped_matmul((nn.silu(gate) * up).astype(dt), w_down,
+                             counts)                            # [nk, h]
+    with jax.named_scope("moe_combine"):
+        # rows past the held groups belong to no group: whatever the
+        # kernel left there is masked, not multiplied by a zero weight
+        in_group = jnp.arange(nk) < jnp.sum(counts)
+        w_sorted = weights.reshape(nk)[order]
+        contrib = jnp.where(in_group[:, None],
+                            out.astype(jnp.float32) * w_sorted[:, None], 0.0)
+        unsort = jnp.zeros((nk,), jnp.int32).at[order].set(
+            jnp.arange(nk, dtype=jnp.int32))
+        y = jnp.sum(contrib[unsort].reshape(n, k, -1), axis=1)
+    load = jnp.stack([jnp.sum(counts), jnp.max(counts),
+                      jnp.sum(counts > 0)]).astype(jnp.int32)
+    return y, load
+
+
+def moe_ffn(cfg, p, x, valid=None):
+    """``shared(x) + sum_i w_i E_i(x)`` over the held experts, on the raw
+    parameter tree ``p`` of :class:`MoEMlp` (``router``, ``experts/*``,
+    ``shared``) — the one definition behind the module's 'grouped' path
+    and the serving decoder's expert layer.  ``x`` [n, h] ->
+    ``(y [n, h] in cfg.dtype, scores, sel, load)``."""
+    with jax.named_scope("router"):
+        # float32 at full precision: a TPU's default float32 product
+        # rounds its operands to bfloat16, and a rounded router input
+        # flips near-tied experts
+        logits = jnp.dot(x.astype(jnp.float32),
+                         p["router"]["kernel"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        sel, weights, scores = route(cfg, logits, p.get("router_bias"))
+    y, load = held_experts_ffn(cfg, x, sel, weights, p["experts/gate"],
+                               p["experts/up"], p["experts/down"], valid)
+    if cfg.moe_shared_experts:
+        with jax.named_scope("shared_expert"):
+            sh = p["shared"]
+            shared = swiglu(x, sh["gate_proj"]["kernel"],
+                            sh["up_proj"]["kernel"],
+                            sh["down_proj"]["kernel"], cfg.dtype)
+        with jax.named_scope("moe_combine"):
+            y = y + shared.astype(jnp.float32)
+    return y.astype(cfg.dtype), scores, sel, load
+
+
+class _Kernel(nn.Module):
+    """Holds one ``kernel`` parameter (the tree a bias-free ``nn.Dense``
+    of that name would hold) and returns ``{"kernel": value}``: the
+    grouped path computes on raw kernels, shared with the serving
+    decoder."""
+    shape: tuple
+    param_dtype: object
+
+    @nn.compact
+    def __call__(self):
+        return {"kernel": self.param(
+            "kernel", nn.initializers.normal(0.02), self.shape,
+            self.param_dtype)}
+
+
+class _SwigluKernels(nn.Module):
+    """``gate_proj`` / ``up_proj`` / ``down_proj`` kernels of one SwiGLU
+    FFN, named as :class:`Mlp` names them."""
+    hidden: int
+    ffn: int
+    param_dtype: object
+
+    @nn.compact
+    def __call__(self):
+        h, f = self.hidden, self.ffn
+        return {name: _Kernel(shape, self.param_dtype, name=name)()
+                for name, shape in (("gate_proj", (h, f)),
+                                    ("up_proj", (h, f)),
+                                    ("down_proj", (f, h)))}
+
+
 class MoEMlp(nn.Module):
     """Top-k token-choice MoE: capacity-free dense dispatch, or
     switch-transformer capacity dispatch (``cfg.moe_capacity_factor``).
@@ -80,12 +253,21 @@ class MoEMlp(nn.Module):
         f = cfg.ffn_size
         b, s, _ = x.shape
 
-        if cfg.moe_dispatch not in ("auto", "einsum", "sort"):
+        if cfg.moe_dispatch not in ("auto", "einsum", "sort", "grouped"):
             # validate regardless of capacity mode so a typo surfaces at
             # the config that introduced it
             raise ValueError(
-                f"moe_dispatch must be 'auto' | 'einsum' | 'sort', "
-                f"got {cfg.moe_dispatch!r}")
+                f"moe_dispatch must be 'auto' | 'einsum' | 'sort' | "
+                f"'grouped', got {cfg.moe_dispatch!r}")
+        if cfg.moe_dispatch == "grouped":
+            return self._grouped(x)
+        if (cfg.moe_scoring != "softmax" or cfg.moe_shared_experts
+                or cfg.router_width != e or cfg.moe_first_expert):
+            raise ValueError(
+                "sigmoid/grouped routing, shared experts and a held share "
+                "of the router's experts run on moe_dispatch='grouped' "
+                "only (the dense and capacity paths score and hold every "
+                "expert)")
         router = nn.Dense(e, use_bias=False, name="router",
                           dtype=jnp.float32, param_dtype=cfg.param_dtype,
                           kernel_init=nn.initializers.normal(0.02))
@@ -190,3 +372,45 @@ class MoEMlp(nn.Module):
         self.sow("intermediates", "moe_aux_loss",
                  e * jnp.sum(frac_tokens * frac_probs))
         return y.astype(cfg.dtype)
+
+    def _grouped(self, x):
+        """moe_dispatch='grouped': the dropless held-expert layer
+        (:func:`moe_ffn`) on this module's parameters."""
+        cfg = self.cfg
+        if cfg.moe_capacity_factor is not None:
+            raise ValueError("moe_dispatch='grouped' is dropless: it takes "
+                             "no moe_capacity_factor")
+        e, h, f = cfg.num_experts, cfg.hidden_size, cfg.expert_ffn_size
+        width = cfg.router_width
+        if not 0 <= cfg.moe_first_expert <= width - e:
+            raise ValueError(
+                f"held experts [{cfg.moe_first_expert}, +{e}) are not "
+                f"inside the router's {width}")
+        init = nn.initializers.normal(0.02)
+        p = {"router": _Kernel((h, width), cfg.param_dtype, name="router")()}
+        if cfg.moe_router_bias:
+            p["router_bias"] = self.param(
+                "router_bias", nn.initializers.zeros, (width,), jnp.float32)
+        p["experts/gate"] = self.param("experts/gate", init, (e, h, f),
+                                       cfg.param_dtype)
+        p["experts/up"] = self.param("experts/up", init, (e, h, f),
+                                     cfg.param_dtype)
+        p["experts/down"] = self.param("experts/down", init, (e, f, h),
+                                       cfg.param_dtype)
+        if cfg.moe_shared_experts:
+            fs = cfg.moe_shared_experts * f
+            p["shared"] = _SwigluKernels(h, fs, cfg.param_dtype,
+                                         name="shared")()
+        b, s, _ = x.shape
+        y, scores, sel, _ = moe_ffn(cfg, p, x.reshape(b * s, h))
+        # load-balance signal over the router's whole width (the same
+        # switch-style product as the dense paths, on the router's
+        # scores normalised to a distribution)
+        k = cfg.num_experts_per_tok
+        frac_tokens = jnp.mean(jnp.sum(jax.nn.one_hot(
+            sel, width, dtype=jnp.float32), axis=-2), axis=0) / k
+        probs = scores / (jnp.sum(scores, axis=-1, keepdims=True) + 1e-20) \
+            if cfg.moe_scoring == "sigmoid" else jax.nn.softmax(scores, -1)
+        self.sow("intermediates", "moe_aux_loss",
+                 width * jnp.sum(frac_tokens * jnp.mean(probs, axis=0)))
+        return y.reshape(b, s, h)

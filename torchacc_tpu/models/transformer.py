@@ -195,7 +195,10 @@ class ModelConfig:
     # capacity-dispatch mechanism (models/moe.py): 'einsum' = one-hot
     # dispatch/combine einsums (MXU-friendly at small n*e*cap), 'sort' =
     # argsort/scatter (no [n, e, cap] materialisation — the Mixtral-scale
-    # answer), 'auto' = sort above ~2^24 dispatch elements
+    # answer), 'auto' = sort above ~2^24 dispatch elements.  'grouped'
+    # (capacity-free only) is the dropless sparse path: the (token,
+    # expert) pairs on held experts sorted by expert through a grouped
+    # matmul, FLOPs by the routed pairs
     moe_dispatch: str = "auto"
     # True (mixtral): softmax over the selected top-k logits (equals
     # HF's softmax-then-topk-then-renormalise).  False (qwen3-moe with
@@ -208,6 +211,45 @@ class ModelConfig:
     # ceil(cf * k * tokens / e) slots, FLOPs independent of e; tokens
     # over capacity are dropped (combine weight 0).
     moe_capacity_factor: Optional[float] = None
+    # -- latent attention + held-expert MoE (models/mla.py, models/moe.py;
+    # the 'axk1' family of models/hf.py) -----------------------------------
+    # Multi-head latent attention: kv_lora_rank > 0 replaces the q/k/v
+    # projections by low-rank ones (q through q_lora_rank, k/v through one
+    # shared kv_lora_rank latent) with a head split of qk_nope_head_dim
+    # un-rotated + qk_rope_head_dim rotated dims (the rotated key is ONE
+    # head shared by all) and values of v_head_dim; query_scale carries
+    # the softmax scale (yarn's mscale**2 folded in by the ingest)
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # the first N layers are dense MLPs of intermediate_size, the rest
+    # expert layers: two stacked trees ('dense_layers', 'layers'), each
+    # scanned; expert FFNs are moe_intermediate_size wide (None =
+    # intermediate_size)
+    first_dense_layers: int = 0
+    moe_intermediate_size: Optional[int] = None
+    # router: 'softmax' (mixtral/qwen3, above) | 'sigmoid' scores with
+    # group-limited top-k (groups of width/moe_n_group, a group's score =
+    # its two largest, the moe_topk_group best groups stay), weights
+    # renormalised (moe_renorm_topk) and times moe_route_scale;
+    # moe_router_bias adds a selection-only bias to the scores
+    moe_scoring: str = "softmax"
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    moe_route_scale: float = 1.0
+    moe_router_bias: bool = False
+    # SwiGLU experts every token passes through, fused into one FFN of
+    # moe_shared_experts * moe_intermediate_size
+    moe_shared_experts: int = 0
+    # expert-parallel share: the router scores moe_router_width experts
+    # (None = num_experts) and this program holds num_experts of them
+    # from moe_first_expert on, adding only their terms (moe_dispatch
+    # 'grouped': dropless sort + grouped matmul; the other chips' terms
+    # and the exchange are not this program's)
+    moe_router_width: Optional[int] = None
+    moe_first_expert: int = 0
 
     @property
     def kv_heads(self) -> int:
@@ -216,6 +258,14 @@ class ModelConfig:
     @property
     def head_size(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def router_width(self) -> int:
+        return self.moe_router_width or self.num_experts
+
+    @property
+    def expert_ffn_size(self) -> int:
+        return self.moe_intermediate_size or self.ffn_size
 
     @property
     def ffn_size(self) -> int:
@@ -252,8 +302,23 @@ class ModelConfig:
             mlp = 2 * h * self.ffn_size
             if self.mlp_bias:
                 mlp += self.ffn_size + h
+        if self.kv_lora_rank:
+            qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+            ql, r = self.q_lora_rank, self.kv_lora_rank
+            attn = ((h * ql + ql + ql * self.num_heads * qk) if ql
+                    else h * self.num_heads * qk)
+            attn += (h * (r + self.qk_rope_head_dim) + r
+                     + r * self.num_heads
+                     * (self.qk_nope_head_dim + self.v_head_dim)
+                     + self.num_heads * self.v_head_dim * h)
+        dense_mlp = mlp
         if self.num_experts > 0:
-            mlp = mlp * self.num_experts + h * self.num_experts
+            # the held experts, the router's whole width, shared experts
+            per = (mlp if self.moe_intermediate_size is None
+                   else mlp // self.ffn_size * self.expert_ffn_size)
+            mlp = (per * (self.num_experts + self.moe_shared_experts)
+                   + h * self.router_width
+                   + (self.router_width if self.moe_router_bias else 0))
         norm_size = (2 * h
                      if self.norm in ("layernorm", "layernorm1p")
                      and self.norm_bias else h)
@@ -264,7 +329,9 @@ class ModelConfig:
         out = 0 if self.tie_embeddings else v * h
         if self.head_bias:
             out += v
-        return emb + self.num_layers * (attn + mlp) + norms + out
+        nd = self.first_dense_layers
+        return (emb + self.num_layers * attn + nd * dense_mlp
+                + (self.num_layers - nd) * mlp + norms + out)
 
 
 def softcap(logits: jax.Array, cap: float) -> jax.Array:
@@ -727,6 +794,9 @@ class Block(nn.Module):
         from jax.ad_checkpoint import checkpoint_name
         cfg = self.cfg
         attn_cls, mlp_cls = Attention, Mlp
+        if cfg.kv_lora_rank:
+            from torchacc_tpu.models.mla import MlaAttention
+            attn_cls = MlaAttention
         if cfg.num_experts > 0:
             from torchacc_tpu.models.moe import MoEMlp
             mlp_cls = MoEMlp
@@ -771,8 +841,13 @@ class Block(nn.Module):
             attn_out = Norm(cfg, name="ln1")(attn_out)
         # names referenced by the 'offload_dots' remat policy (utils/remat.py)
         h = x + checkpoint_name(attn_out, "attn_out")
+        # the grouped expert layer routes in float32: its norm hands it
+        # float32 (a bf16-rounded router input flips near-tied experts)
+        ln2_cfg = (dataclasses.replace(cfg, dtype=jnp.float32)
+                   if cfg.num_experts > 0 and cfg.moe_dispatch == "grouped"
+                   else cfg)
         mlp_out = mlp_cls(cfg, name="moe" if cfg.num_experts > 0 else "mlp")(
-            h if post else Norm(cfg, name="ln2")(h))
+            h if post else Norm(ln2_cfg, name="ln2")(h))
         if cfg.sandwich_norms:
             mlp_out = Norm(cfg, name="ln2_post")(mlp_out)
         if post:
@@ -958,20 +1033,49 @@ class TransformerLM(nn.Module):
                 "the overlap path) — disable one of the two")
         overlap_active = (cfg.overlap_fsdp and not cache_live
                           and cfg.pp_size <= 1 and not cfg.layer_pattern)
-        scan_mod = nn.scan(
-            block_cls,
-            variable_axes={"params": 0, "intermediates": 0, "cache": 0,
-                           "quant": 0},
-            split_rngs={"params": True},
-            length=cfg.num_layers,
-            metadata_params={nn.PARTITION_NAME: "layers"},
-        )(cfg, name="layers")
+        def stack(stack_cfg, length, name):
+            return nn.scan(
+                block_cls,
+                variable_axes={"params": 0, "intermediates": 0, "cache": 0,
+                               "quant": 0},
+                split_rngs={"params": True},
+                length=length,
+                metadata_params={nn.PARTITION_NAME: "layers"},
+            )(stack_cfg, name=name)
+
+        n_dense = cfg.first_dense_layers
+        scan_mod = (None if n_dense
+                    else stack(cfg, cfg.num_layers, "layers"))
         # every application path below runs under the registered device
         # scope "layers" (obs/tracing.py DEVICE_SCOPES): the loop's own
         # ops — per-layer slices of the stack, the scan's saved-residual
         # stacking — read under it in a profiler trace, the blocks'
         # parts under their module names inside it
-        if self.is_initializing():
+        if n_dense:
+            # two kinds of layer, two stacked trees: 'dense_layers'
+            # (plain MLPs) then 'layers' (expert layers), each one scan —
+            # no per-layer branch inside a scan, and each tree's leaves
+            # keep one shape
+            if (cfg.pp_size > 1 or cfg.layer_pattern or quant_on
+                    or cfg.overlap_fsdp or seeds_xs is not None
+                    or not 0 < n_dense < cfg.num_layers):
+                raise NotImplementedError(
+                    "first_dense_layers (leading dense layers before the "
+                    "expert layers) runs as two plain layer scans: it "
+                    "needs 0 < first_dense_layers < num_layers and does "
+                    "not compose with pp, layer_pattern, quant, "
+                    "overlap_fsdp or attention dropout")
+            dense_cfg = dataclasses.replace(
+                cfg, num_experts=0, first_dense_layers=0,
+                num_layers=n_dense)
+            carry = (x, positions, segment_ids)
+            with jax.named_scope("layers"):
+                carry, _ = stack(dense_cfg, n_dense, "dense_layers")(
+                    carry, None)
+                carry, _ = stack(cfg, cfg.num_layers - n_dense, "layers")(
+                    carry, None)
+            x = carry[0]
+        elif self.is_initializing():
             with jax.named_scope("layers"):
                 (x, _, _), _ = scan_mod((x, positions, segment_ids),
                                         seeds_xs)

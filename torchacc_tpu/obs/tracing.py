@@ -77,7 +77,9 @@ SPANS: Dict[str, str] = {
     "serve/decode": "Scheduler._decode_once — one batched decode "
                     "dispatch",
     "serve/deliver": "Scheduler._resolve_one — token readback + stream "
-                     "callbacks for one ring entry",
+                     "callbacks for one ring entry (expert models: "
+                     "attrs moe_pairs, moe_max, moe_hit, moe_slots, "
+                     "moe_layer_steps of the step(s) behind it)",
     "serve/wait": "inside deliver — the one blocking token fetch",
 }
 
@@ -114,6 +116,22 @@ SCOPES: Dict[str, str] = {
                 "backward and rematerialised chunks",
     "optimizer": "gradient norm, clipping, optimizer update and "
                  "parameter apply",
+    "mla_q": "latent attention: query projections, rope and (serving) "
+             "the key up-projection folded into the query",
+    "mla_kv": "latent attention: the latent projection, its norm and "
+              "rope (serving: the row a token banks; plain forward: "
+              "every head's k and v expanded from it)",
+    "latent_attn": "the latent paged-attention kernel (one shared row a "
+                   "token, values = the row's latent part)",
+    "router": "expert layer: router matmul in f32, scores, group-limited "
+              "top-k, weights",
+    "moe_dispatch": "expert layer: sort of the (token, expert) pairs on "
+                    "held experts and the gather of their rows",
+    "experts": "expert layer: the three grouped matmuls over the held "
+               "experts",
+    "shared_expert": "expert layer: the shared experts' FFN",
+    "moe_combine": "expert layer: unsort, weight and sum the pairs' "
+                   "outputs, add the shared expert",
 }
 
 DEVICE_SCOPES = tuple(SCOPES)
@@ -168,6 +186,7 @@ class _NullSpan:
     """The disabled-path singleton: enter/exit do nothing."""
 
     __slots__ = ()
+    live = False        # no sink: attributes set on it go nowhere
 
     def __enter__(self):
         return self
@@ -195,6 +214,7 @@ class _Span:
 
     __slots__ = ("name", "attrs", "id", "parent", "_t0", "_ring",
                  "_to_profiler", "_prof")
+    live = True
 
     def __init__(self, name: str, attrs: Dict[str, Any],
                  parent: Optional[int], ring: bool, prof: bool):

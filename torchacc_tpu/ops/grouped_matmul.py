@@ -1,0 +1,178 @@
+"""Grouped matmul (Pallas TPU): ``out[rows of group g] = x[rows of g] @ w[g]``.
+
+The dropless expert layer (``models/moe.held_experts_ffn``) sorts its
+(token, expert) pairs by expert; the rows of one expert are then one
+contiguous group, and the three expert matmuls are one call each over
+all groups.  Design as the public "megablox" grouped matmul: the groups
+cut the row tiles, and a small schedule computed outside the kernel says
+which (group, row tile) each grid step works on, so
+
+- a group with no rows takes no grid step and none of its weights is
+  read (an expert that drew no token costs nothing);
+- row tiles behind the last group are never visited (in a prefill chunk
+  most of the ``tokens * k`` sorted rows belong to experts held
+  elsewhere), and what the output holds there is undefined — the caller
+  masks it;
+- a row tile that two groups share is visited once for each, and each
+  visit stores only its own rows.
+
+``jax.lax.ragged_dot`` computes the same; on the TPU XLA runs it as
+instructions that carry no ``op_name`` (my chip run, PR 26: a quarter of
+the serving window's device time under no scope), so the program's
+``experts`` scope could not be read from a trace.  A Pallas call keeps
+the scope it was traced under.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from torchacc_tpu.ops._common import (
+    ambient_mesh,
+    interpret_mode as _interpret,
+    needs_shard_map,
+    round_up,
+)
+
+ROW_TILE = 128           # rows of x a grid step multiplies
+WEIGHT_BLOCK = 2 * 1024 * 1024   # elements of the [tk, tn] weight block
+
+
+def _divisor_tile(dim: int, target: int) -> int:
+    """Largest multiple of 128 that divides ``dim`` and is at most
+    ``target``; ``dim`` itself where there is none (toy widths)."""
+    for t in range(min(target, dim) // 128 * 128, 0, -128):
+        if dim % t == 0:
+            return t
+    return dim
+
+
+def weight_tiles(k: int, n: int) -> tuple[int, int]:
+    """``(tk, tn)``: the narrower of the two dimensions whole (up to
+    2048), the other as wide as ``WEIGHT_BLOCK`` allows — 4 MiB of bf16
+    a block, two of them in flight.  My chip run, PR 26, 12 experts of
+    7168 x 2048, 16 pairs on 9 of them (the weight-read floor is 0.32 ms
+    a matmul): (1024, 2048) reads the way up in 0.57 ms and (2048, 1024)
+    the way down in 0.41 ms; square 1024 blocks 0.62 / 0.60 ms."""
+    if k <= n:
+        tk = _divisor_tile(k, 2048)
+        return tk, _divisor_tile(n, max(128, WEIGHT_BLOCK // tk))
+    tn = _divisor_tile(n, 2048)
+    return _divisor_tile(k, max(128, WEIGHT_BLOCK // tn)), tn
+
+
+def tile_schedule(group_sizes, m: int, tm: int):
+    """Which (group, row tile) each grid step works on.
+
+    ``group_sizes`` int32 [g] (its sum may be less than ``m``).  Returns
+    int32 ``(group_of [steps], tile_of [steps], starts [g], ends [g],
+    num_steps [1])`` with ``steps = m // tm + g - 1`` the static upper bound
+    (each group boundary inside a tile adds one visit) and ``num_steps``
+    the steps that do work.  Steps past ``num_steps`` repeat the last
+    working step's indices (and the index maps hold them to its last k
+    block), so the pipeline fetches nothing for them."""
+    g = group_sizes.shape[0]
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    first = starts // tm
+    tiles = jnp.where(group_sizes > 0, (ends - 1) // tm - first + 1, 0)
+    step_ends = jnp.cumsum(tiles)
+    step_starts = step_ends - tiles
+    num_steps = step_ends[-1]
+    step = jnp.minimum(jnp.arange(m // tm + g - 1, dtype=jnp.int32),
+                       jnp.maximum(num_steps - 1, 0))
+    group_of = jnp.minimum(
+        jnp.searchsorted(step_ends, step, side="right"), g - 1)
+    tile_of = first[group_of] + step - step_starts[group_of]
+    return group_of, tile_of, starts, ends, num_steps[None]
+
+
+def _kernel(group_of, tile_of, starts, ends, num_steps, x_ref, w_ref, o_ref,
+            acc_ref, *, tm: int, k_steps: int):
+    s, ki = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(s < num_steps[0])
+    def _work():
+        @pl.when(ki == 0)
+        def _zero():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
+                                preferred_element_type=jnp.float32)
+
+        @pl.when(ki == k_steps - 1)
+        def _store():
+            g = group_of[s]
+            rows = tile_of[s] * tm + jax.lax.broadcasted_iota(
+                jnp.int32, acc_ref.shape, 0)
+            mine = (rows >= starts[g]) & (rows < ends[g])
+            o_ref[...] = jnp.where(mine, acc_ref[...].astype(o_ref.dtype),
+                                   o_ref[...])
+
+
+def _grouped_matmul_pallas(x, w, group_sizes, *, tk, tn):
+    m, k = x.shape
+    g, _, n = w.shape
+    tm = min(ROW_TILE, round_up(m, 16))
+    m_pad = round_up(m, tm)
+    if m_pad != m:
+        x = jnp.pad(x, ((0, m_pad - m), (0, 0)))
+    auto = weight_tiles(k, n)
+    tk = _divisor_tile(k, tk) if tk else auto[0]
+    tn = _divisor_tile(n, tn) if tn else auto[1]
+    k_steps = k // tk
+    schedule = tile_schedule(group_sizes.astype(jnp.int32), m_pad, tm)
+
+    def k_block(s, ki, num_steps):
+        # an idle step stays on the last working step's last k block: an
+        # index that moved would fetch a weight block for nothing (my chip
+        # run, PR 26: 3.5 us an idle step, 1.5 of a chunk's 2.0 ms)
+        return jnp.where(s < num_steps[0], ki, k_steps - 1)
+
+    out = pl.pallas_call(
+        functools.partial(_kernel, tm=tm, k_steps=k_steps),
+        out_shape=jax.ShapeDtypeStruct((m_pad, n), x.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(n // tn, m_pad // tm + g - 1, k_steps),
+            in_specs=[
+                pl.BlockSpec((tm, tk), lambda ni, s, ki, go, to, st, en, num:
+                             (to[s], k_block(s, ki, num))),
+                pl.BlockSpec((None, tk, tn),
+                             lambda ni, s, ki, go, to, st, en, num:
+                             (go[s], k_block(s, ki, num), ni)),
+            ],
+            out_specs=pl.BlockSpec(
+                (tm, tn), lambda ni, s, ki, go, to, *_: (to[s], ni)),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        interpret=_interpret(),
+        name="grouped_matmul",
+    )(*schedule, x, w)
+    return out[:m]
+
+
+def grouped_matmul(x, w, group_sizes, *, tk: int | None = None,
+                   tn: int | None = None):
+    """``x`` [m, k] with its rows sorted by group, ``w`` [g, k, n],
+    ``group_sizes`` int [g]: rows ``[sum(sizes[:i]), sum(sizes[:i+1]))``
+    are multiplied by ``w[i]`` (float32 accumulation, result in
+    ``x.dtype``).  Rows past ``sum(group_sizes)`` belong to no group:
+    the result there is UNDEFINED (possibly not finite) — mask it, do
+    not multiply it by zero.  ``tk``/``tn`` override the weight block
+    of :func:`weight_tiles` (tests)."""
+    fn = functools.partial(_grouped_matmul_pallas, tk=tk, tn=tn)
+    mesh = ambient_mesh()
+    if needs_shard_map(mesh):
+        # the held-expert layer is one chip's share: every shard holds
+        # the same rows and weights and runs the whole call
+        from jax.sharding import PartitionSpec as P
+        fn = jax.shard_map(fn, mesh=mesh, in_specs=(P(),) * 3,
+                           out_specs=P(), check_vma=False)
+    return fn(x, w.astype(x.dtype), group_sizes)

@@ -61,6 +61,7 @@ from jax.sharding import PartitionSpec as P
 from torchacc_tpu.ops._common import NEG_INF, interpret_mode as _interpret
 from torchacc_tpu.ops._common import ambient_mesh, needs_shard_map
 from torchacc_tpu.ops._common import on_tpu as _on_tpu
+from torchacc_tpu.ops._common import round_up
 
 
 def _repeat_kv_heads(x: jax.Array, num_q_heads: int) -> jax.Array:
@@ -303,6 +304,234 @@ def _paged_attention_pallas_sharded(mesh, q, k_pool, v_pool, block_tables,
         in_specs=(q_spec, pool_spec, pool_spec, P(), P(), P(), P()),
         out_specs=q_spec, check_vma=False,
     )(q, k_pool, v_pool, block_tables, context_lens, q_start, layer)
+
+
+# ---------------------------------------------------------------------------
+# latent (MLA, absorbed form) paged attention: one shared row a token
+# ---------------------------------------------------------------------------
+#
+# The pool is ONE stack [L, NB, BS, W]: a token's row is [c_kv | k_pe]
+# (the latent of ``R`` lanes that is key AND value, then the rotated
+# shared key of ``P`` lanes; W = R + P).  Every query head reads the same
+# row: scores are ``q_lat . row[:R] + q_pe . row[R:]`` and the output is
+# ``P row[:R]`` — multi-query attention whose value is the first R lanes
+# of its key.  Query rows of a slot are tiled (``tq`` tokens x all heads
+# a grid step, token-major, so the tiling is a reshape), which is what
+# lets a prefill chunk of 512 tokens x 64 heads through the same kernel
+# as a decode step; a page's index map stops at the last block the tile
+# can see (its causal reach, the slot's length), so blocks past it are
+# not fetched again.
+
+@functools.partial(jax.jit, static_argnames=("scale",))
+def _latent_paged_attention_xla(q_lat, q_pe, pool, block_tables,
+                                context_lens, q_start, layer, scale):
+    s_, t_, h, r = q_lat.shape
+    bs = pool.shape[2]
+    mb = block_tables.shape[1]
+    rows = pool[layer, block_tables].reshape(s_, mb * bs, -1).astype(
+        jnp.float32)
+    scores = (jnp.einsum("sthr,skr->shtk", q_lat.astype(jnp.float32),
+                         rows[..., :r])
+              + jnp.einsum("sthp,skp->shtk", q_pe.astype(jnp.float32),
+                           rows[..., r:r + q_pe.shape[-1]])) * scale
+    kv_pos = jnp.arange(mb * bs, dtype=jnp.int32)
+    q_pos = q_start[:, None] + jnp.arange(t_, dtype=jnp.int32)
+    mask = kv_pos[None, None, :] < context_lens[:, None, None]
+    mask &= kv_pos[None, None, :] <= q_pos[:, :, None]
+    mask = mask[:, None, :, :]
+    scores = jnp.where(mask, scores, NEG_INF)
+    lse = jax.nn.logsumexp(scores, axis=-1)
+    probs = jnp.where(mask, jnp.exp(scores - lse[..., None]), 0.0)
+    out = jnp.einsum("shtk,skr->sthr", probs, rows[..., :r])
+    return out.astype(q_lat.dtype)
+
+
+def _latent_fwd_kernel(tbl_ref, lens_ref, layer_ref, ql_ref, qp_ref, kv_ref,
+                       o_ref, m_scr, l_scr, acc_scr,
+                       *, scale, block_size, latent, rope, heads, tq, rows,
+                       num_kv_blocks):
+    si = pl.program_id(0)
+    ti = pl.program_id(1)
+    bi = pl.program_id(2)
+
+    @pl.when(bi == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    ctx = lens_ref[si, 0]
+    q0 = lens_ref[si, 1] + ti * tq          # this tile's first position
+    k_start = bi * block_size
+
+    @pl.when((k_start < ctx) & (k_start <= q0 + tq - 1))
+    def _compute():
+        kv_pos = k_start + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, block_size), 1)
+        # row r holds head r % heads of tile token r // heads
+        q_pos = q0 + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, block_size), 0) // heads
+        mask = (kv_pos < ctx) & (kv_pos <= q_pos)
+        c_kv = kv_ref[:, :latent]                             # [BS, R]
+        k_pe = kv_ref[:, latent:latent + rope]                # [BS, P]
+        s = (jax.lax.dot_general(
+            ql_ref[0, 0], c_kv, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+             + jax.lax.dot_general(
+            qp_ref[0, 0], k_pe, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)) * scale      # [rows, BS]
+        s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_scr[:, 0]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
+        alpha = jnp.where(m_prev == NEG_INF, 0.0, jnp.exp(m_prev - m_new))
+        l_scr[...] = jnp.broadcast_to(
+            (alpha * l_scr[:, 0] + jnp.sum(p, axis=1))[:, None], l_scr.shape)
+        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
+            p.astype(c_kv.dtype), c_kv, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[...] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
+
+    @pl.when(bi == num_kv_blocks - 1)
+    def _finalize():
+        l = l_scr[:, 0]
+        o_ref[0, 0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)[:, None]
+                       ).astype(o_ref.dtype)
+
+
+def latent_query_tile(num_heads: int, latent: int, rope: int,
+                      block_size: int, t: int, dtype) -> int:
+    """Query tokens one grid step of the latent kernel takes of ``t`` a
+    slot (all heads of each), or ``ValueError`` where no tile fits: the
+    largest divisor of ``t`` whose blocks — q and out [rows, R] and
+    q_pe [rows, P] double-buffered, the page, the f32 m/l/acc scratch
+    and the [rows, bs] score temporaries — stay inside the VMEM budget
+    (rows = tile tokens x heads)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    if block_size % min_block_size(dtype):
+        raise ValueError(
+            f"latent paged attention kernel: block_size {block_size} is "
+            f"not a multiple of {min_block_size(dtype)}, the TPU sublane "
+            f"tile of a {jnp.dtype(dtype).name} pool")
+    lat, pe = round_up(latent, _LANES), round_up(rope, _LANES)
+    page = 2 * block_size * (lat + pe) * itemsize
+
+    def need(rows):
+        return (2 * rows * (2 * lat + pe) * itemsize
+                + rows * (2 * _LANES + lat) * 4
+                + 3 * rows * max(block_size, _LANES) * 4 + page)
+    for tq in range(t, 0, -1):
+        rows = tq * num_heads
+        if t % tq == 0 and (rows % 8 == 0 or tq == t) \
+                and need(rows) <= _VMEM_BUDGET:
+            return tq
+    raise ValueError(
+        f"latent paged attention kernel: {num_heads} heads of a "
+        f"{latent}+{rope} row do not fit the "
+        f"{_VMEM_BUDGET / 2**20:.0f} MiB VMEM budget even one token a "
+        f"step ({need(num_heads) / 2**20:.1f} MiB)")
+
+
+def _latent_paged_attention_pallas(q_lat, q_pe, pool, block_tables,
+                                   context_lens, q_start, layer, *, scale):
+    s_, t_, h, r = q_lat.shape
+    pe = q_pe.shape[-1]
+    bs, w = pool.shape[2], pool.shape[3]
+    mb = block_tables.shape[1]
+    tq = latent_query_tile(h, r, pe, bs, t_, pool.dtype)
+    nt, rows = t_ // tq, tq * h
+    lens = jnp.stack([context_lens.astype(jnp.int32),
+                      q_start.astype(jnp.int32)], axis=1)
+    layer = layer.reshape(1)
+    # token-major rows: tiling the slot's [T, H, .] queries is a reshape
+    ql = q_lat.reshape(s_, nt, rows, r)
+    qp = q_pe.reshape(s_, nt, rows, pe)
+
+    def page(s, t, b, tbl, lens, layer):
+        # the last block this tile reads: its causal reach within the
+        # slot's length (the same index again = no new fetch)
+        reach = jnp.minimum(lens[s, 0], lens[s, 1] + (t + 1) * tq)
+        last = jnp.maximum(reach - 1, 0) // bs
+        return (layer[0], tbl[s, jnp.minimum(b, last)], 0, 0)
+
+    q_map = lambda s, t, b, tbl, lens, layer: (s, t, 0, 0)  # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(s_, nt, mb),
+        in_specs=[pl.BlockSpec((1, 1, rows, r), q_map),
+                  pl.BlockSpec((1, 1, rows, pe), q_map),
+                  pl.BlockSpec((None, None, bs, w), page)],
+        out_specs=pl.BlockSpec((1, 1, rows, r), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((rows, _LANES), jnp.float32),
+            pltpu.VMEM((rows, _LANES), jnp.float32),
+            pltpu.VMEM((rows, r), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _latent_fwd_kernel, scale=scale, block_size=bs, latent=r, rope=pe,
+        heads=h, tq=tq, rows=rows, num_kv_blocks=mb)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(ql.shape, q_lat.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=_interpret(),
+        name="latent_paged_attention",
+    )(block_tables.astype(jnp.int32), lens, layer, ql, qp, pool)
+    return out.reshape(s_, t_, h, r)
+
+
+def latent_paged_attention(
+    q_lat: jax.Array,
+    q_pe: jax.Array,
+    pool: jax.Array,
+    block_tables: jax.Array,
+    context_lens: jax.Array,
+    q_start: jax.Array,
+    *,
+    layer,
+    scale: float,
+    impl: str = "auto",
+) -> jax.Array:
+    """Causal absorbed-form MLA attention over layer ``layer`` of a
+    latent paged pool.
+
+    ``q_lat`` [S, T, H, R] (queries with ``W_kvb^K`` folded in), ``q_pe``
+    [S, T, H, P] (rotated); ``pool`` [layers, num_blocks, block_size, W]
+    with a token's row ``[c_kv (R) | rope(k_pe) (P) | padding]``.
+    Tables, lengths, ``q_start`` and ``impl`` as
+    :func:`paged_attention`.  Returns the latent outputs ``P c_kv``
+    [S, T, H, R]; the caller applies ``W_kvb^V``."""
+    if q_lat.ndim != 4 or q_pe.shape[:3] != q_lat.shape[:3]:
+        raise ValueError(f"q_lat {q_lat.shape} / q_pe {q_pe.shape} must be "
+                         f"[slots, t, heads, R] and [slots, t, heads, P]")
+    s_ = q_lat.shape[0]
+    if pool.ndim != 4 or pool.shape[3] < q_lat.shape[3] + q_pe.shape[3]:
+        raise ValueError(
+            f"pool {pool.shape} must be [layers, blocks, block_size, W] "
+            f"with W >= {q_lat.shape[3]} + {q_pe.shape[3]}")
+    if block_tables.shape[0] != s_ or context_lens.shape != (s_,):
+        raise ValueError(
+            f"block_tables {block_tables.shape} / context_lens "
+            f"{context_lens.shape} do not match {s_} slots")
+    if impl == "auto":
+        impl = "pallas" if _on_tpu() else "xla"
+    if impl not in ("pallas", "xla"):
+        raise ValueError(f"impl must be auto|pallas|xla, got {impl!r}")
+    fn = functools.partial(
+        _latent_paged_attention_pallas if impl == "pallas"
+        else _latent_paged_attention_xla, scale=float(scale))
+    mesh = ambient_mesh()
+    if impl == "pallas" and needs_shard_map(mesh):
+        # one shared row for every head: nothing to split, each shard
+        # runs the whole call
+        fn = jax.shard_map(fn, mesh=mesh, in_specs=(P(),) * 7,
+                           out_specs=P(), check_vma=False)
+    return fn(q_lat, q_pe, pool, block_tables.astype(jnp.int32),
+              context_lens.astype(jnp.int32), q_start.astype(jnp.int32),
+              jnp.asarray(layer, jnp.int32))
 
 
 # ---------------------------------------------------------------------------

@@ -269,14 +269,32 @@ class BlockPool:
         return n
 
 
+def latent_row_width(model_cfg) -> int:
+    """Lanes of one token's row in a latent pool: ``[c_kv | rope(k_pe)]``
+    (kv_lora_rank + qk_rope_head_dim values) padded to whole 128-lane
+    tiles — a 576-lane row makes the compiler relayout the whole pool
+    around the kernel (sandbox compile for v5e: a pool-sized temporary);
+    a 640-lane one is read where it lies."""
+    from torchacc_tpu.ops._common import round_up
+    return round_up(model_cfg.kv_lora_rank + model_cfg.qk_rope_head_dim, 128)
+
+
 def make_pools(model_cfg, serve_cfg, dtype=None):
-    """(k_pools, v_pools) of shape [L, NB, BS, KH*D] in the model's
-    compute dtype.  When a mesh is live and its 'tp' divides the kv
-    heads, the rows are sharded over it in whole-head groups (the same
-    activation-constraint seam the model layers use, so the TP head
-    composes — parallel/sharding.py)."""
+    """The paged pools of a model, a tuple, in the model's compute
+    dtype: ``(k_pools, v_pools)`` of shape [L, NB, BS, KH*D], or for a
+    latent-attention model ONE pool ``(latent,)`` of shape
+    [L, NB, BS, latent_row_width] with no head dimension (every head
+    reads the same row).  When a mesh is live and its 'tp' divides the
+    kv heads, the k/v rows are sharded over it in whole-head groups (the
+    same activation-constraint seam the model layers use, so the TP head
+    composes — parallel/sharding.py); a latent pool is replicated."""
     from torchacc_tpu.parallel.sharding import activation_constraint
 
+    if model_cfg.kv_lora_rank:
+        return (jnp.zeros((model_cfg.num_layers, serve_cfg.num_blocks,
+                           serve_cfg.block_size,
+                           latent_row_width(model_cfg)),
+                          dtype or model_cfg.dtype),)
     shape = (model_cfg.num_layers, serve_cfg.num_blocks,
              serve_cfg.block_size,
              model_cfg.kv_heads * model_cfg.head_size)

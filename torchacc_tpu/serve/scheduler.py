@@ -70,7 +70,12 @@ import numpy as np
 from torchacc_tpu.config import ConfigError
 from torchacc_tpu.obs import tracing
 from torchacc_tpu.ops._common import on_tpu
-from torchacc_tpu.ops.paged_attention import heads_per_step, paged_attention
+from torchacc_tpu.ops.paged_attention import (
+    heads_per_step,
+    latent_paged_attention,
+    latent_query_tile,
+    paged_attention,
+)
 from torchacc_tpu.resilience.chaos import failpoint
 from torchacc_tpu.serve.kv_cache import (
     BlockPool,
@@ -115,13 +120,24 @@ _AUDITED_MODEL_FIELDS = frozenset({
     # own loop and never consults it.
     "quant", "quant_sites", "quant_amax_history_len", "quant_impl",
     "overlap_fsdp",
+    # PR-26 audit: latent attention (_attend_latent), the two layer
+    # stacks (_forward) and the sigmoid/grouped router, shared experts
+    # and held-expert share of moe_dispatch='grouped' (_moe ->
+    # models/moe.moe_ffn, the module's own definition)
+    "kv_lora_rank", "q_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+    "v_head_dim", "first_dense_layers", "moe_intermediate_size",
+    "moe_scoring", "moe_n_group", "moe_topk_group", "moe_route_scale",
+    "moe_router_bias", "moe_shared_experts", "moe_router_width",
+    "moe_first_expert",
 })
 
 
 def _check_supported(cfg) -> None:
-    """The v1 serving surface: standard dense pre-norm decoders (the
-    llama/qwen/gpt2/gemma-dense families).  Everything else raises a
-    typed error here instead of decoding garbage."""
+    """The serving surface: standard dense pre-norm decoders (the
+    llama/qwen/gpt2/gemma-dense families), and pre-norm latent-attention
+    decoders whose expert layers are the dropless held-expert layer
+    (moe_dispatch='grouped').  Everything else raises a typed error here
+    instead of decoding garbage."""
     import dataclasses
     unknown = ({f.name for f in dataclasses.fields(cfg)}
                - _AUDITED_MODEL_FIELDS)
@@ -132,8 +148,18 @@ def _check_supported(cfg) -> None:
             f"on PagedDecoder._layer/_forward (scheduler.py) and add "
             f"them to _AUDITED_MODEL_FIELDS.")
     bad = []
-    if cfg.num_experts > 0:
-        bad.append("MoE (num_experts > 0)")
+    if cfg.num_experts > 0 and cfg.moe_dispatch != "grouped":
+        bad.append("MoE outside moe_dispatch='grouped' (the dense and "
+                   "capacity dispatch paths)")
+    if cfg.first_dense_layers and not cfg.num_experts:
+        bad.append("first_dense_layers without expert layers")
+    if cfg.kv_lora_rank and (
+            cfg.pos_emb != "rope" or cfg.qk_norm or cfg.qkv_bias
+            or cfg.o_bias or cfg.attn_logit_softcap or cfg.rope_scale != 1.0
+            or cfg.partial_rotary != 1.0 or cfg.mlp_bias
+            or cfg.activation != "swiglu"):
+        bad.append("latent attention with anything but plain rope, "
+                   "bias-free projections and SwiGLU")
     if cfg.pp_size > 1:
         bad.append("pipeline parallelism (pp_size > 1)")
     if cfg.context_parallel:
@@ -185,9 +211,15 @@ class PagedDecoder:
         if impl == "pallas":
             for t in (1, serve_cfg.prefill_chunk):
                 try:
-                    heads_per_step(cfg.num_heads, cfg.kv_heads,
-                                   cfg.head_size, serve_cfg.block_size, t,
-                                   cfg.dtype)
+                    if cfg.kv_lora_rank:
+                        latent_query_tile(
+                            cfg.num_heads, cfg.kv_lora_rank,
+                            cfg.qk_rope_head_dim, serve_cfg.block_size, t,
+                            cfg.dtype)
+                    else:
+                        heads_per_step(cfg.num_heads, cfg.kv_heads,
+                                       cfg.head_size, serve_cfg.block_size,
+                                       t, cfg.dtype)
                 except ValueError as e:
                     raise ConfigError(
                         f"serve.block_size={serve_cfg.block_size}, "
@@ -236,23 +268,52 @@ class PagedDecoder:
         return y
 
     def _layer(self, p, layer, x, pools, positions, tables, ctx_lens, blk,
-               off):
-        """Decoder layer ``layer`` over the paged cache.  ``pools`` are
-        the whole stacks [L, NB, BS, KH*D]; ``blk``/``off`` [S, T] name
-        the pool slot every token writes its k/v to (the null block for
-        masked tokens); ``ctx_lens`` is the post-write context length
-        per slot."""
+               off, valid=None):
+        """Decoder layer ``layer`` over the paged cache, by the kinds of
+        its two halves: the attention is grouped-query over a k and a v
+        pool or latent over one pool (``cfg.kv_lora_rank``), the
+        feed-forward a dense MLP or the held-expert layer (the layer's
+        tree holds ``mlp`` or ``moe``).  ``pools`` are the whole stacks;
+        ``blk``/``off`` [S, T] name the pool slot every token writes its
+        row to (the null block for masked tokens); ``ctx_lens`` is the
+        post-write context length per slot; ``valid`` [S, T] marks the
+        real tokens (the expert layer routes no others).  Returns
+        ``(x, pools, load)``, ``load`` the expert layer's counts or
+        None."""
         from torchacc_tpu.models.transformer import Norm
 
         # the named scopes are registered device scopes (obs/tracing.py
         # DEVICE_SCOPES): a profiler trace reads each part's device
         # time under the same names the training step's modules carry
         cfg = self.cfg
-        kp, vp = pools
-        s_, t_ = x.shape[:2]
         with jax.named_scope("ln1"):
             h = Norm(cfg).apply({"params": p["ln1"]}, x)
-        attn = p["attn"]
+        attend = self._attend_latent if cfg.kv_lora_rank else self._attend
+        x, pools = attend(p["attn"], layer, x, h, pools, positions, tables,
+                          ctx_lens, blk, off)
+        # an expert layer's router reads the norm's float32 (Block's too)
+        ln2_cfg = (dataclasses.replace(cfg, dtype=jnp.float32)
+                   if "moe" in p else cfg)
+        with jax.named_scope("ln2"):
+            h2 = Norm(ln2_cfg).apply({"params": p["ln2"]}, x)
+        if "moe" in p:
+            from torchacc_tpu.models.moe import moe_ffn
+            s_, t_, hd = h2.shape
+            y, _, _, load = moe_ffn(
+                cfg, p["moe"], h2.reshape(s_ * t_, hd),
+                None if valid is None else valid.reshape(-1))
+            return x + y.reshape(s_, t_, hd), pools, load
+        with jax.named_scope("mlp"):
+            x = x + self._mlp(p["mlp"], h2)
+        return x, pools, None
+
+    def _attend(self, attn, layer, x, h, pools, positions, tables, ctx_lens,
+                blk, off):
+        """Grouped-query attention over the k and v pools
+        [L, NB, BS, KH*D], residual added."""
+        cfg = self.cfg
+        kp, vp = pools
+        s_, t_ = x.shape[:2]
         with jax.named_scope("qkv"):
             q, k, v = self._qkv(attn, h, positions)
         # bank this chunk's (rotated) k / raw v into the pool, THEN
@@ -276,11 +337,40 @@ class PagedDecoder:
                 out.reshape(s_, t_, -1),
                 attn["o_proj"]["kernel"].reshape(-1, cfg.hidden_size),
                 attn["o_proj"].get("bias"))
-        with jax.named_scope("ln2"):
-            h2 = Norm(cfg).apply({"params": p["ln2"]}, x)
-        with jax.named_scope("mlp"):
-            x = x + self._mlp(p["mlp"], h2)
         return x, (kp, vp)
+
+    def _attend_latent(self, attn, layer, x, h, pools, positions, tables,
+                       ctx_lens, blk, off):
+        """Latent attention in the absorbed form (models/mla.py) over
+        the one pool [L, NB, BS, W]: a token banks the row
+        ``[c_kv | rope(k_pe)]`` (padded to W lanes), written in place
+        like a k row; the kernel reads it as key and value of every
+        head; ``W_kvb`` is folded into the query and the output."""
+        from torchacc_tpu.models import mla
+
+        cfg = self.cfg
+        (pool,) = pools
+        s_, t_ = x.shape[:2]
+        with jax.named_scope("mla_q"):
+            q_nope, q_pe = mla.project_q(cfg, attn, h, positions)
+            q_lat = mla.absorb_q(cfg, attn, q_nope)
+        with jax.named_scope("mla_kv"):
+            c_kv, k_pe = mla.project_latent(cfg, attn, h, positions)
+            row = jnp.concatenate([c_kv, k_pe], axis=-1)
+            row = jnp.pad(row, ((0, 0), (0, 0),
+                                (0, pool.shape[-1] - row.shape[-1])))
+        with jax.named_scope("kv_write"):
+            pool = pool.at[layer, blk.reshape(-1), off.reshape(-1)].set(
+                row.reshape(s_ * t_, -1).astype(pool.dtype))
+        with jax.named_scope("latent_attn"):
+            o_lat = latent_paged_attention(
+                q_lat, q_pe.astype(q_lat.dtype), pool, tables, ctx_lens,
+                positions[:, 0], layer=layer, scale=mla.query_scale(cfg),
+                impl=self.impl)
+        with jax.named_scope("o_proj"):
+            x = x + mla.project_out(cfg, attn,
+                                    mla.expand_out(cfg, attn, o_lat))
+        return x, (pool,)
 
     def _qkv(self, attn, h, positions):
         """q/k/v projections, qk-norm and rope of one layer."""
@@ -334,8 +424,8 @@ class PagedDecoder:
                            mlp["down_proj"].get("bias"))
 
     def _forward(self, params, pools, ids, positions, tables, ctx_lens,
-                 blk, off):
-        """(pools', hidden [S, T, H]): embed -> layer scan.  The two
+                 blk, off, valid):
+        """(pools', hidden [S, T, H], load): embed -> layer scan(s).  The
         stacked pools ride the scan's CARRY with the residual — each
         layer writes its rows in place and the kernel reads its pages
         through the layer index, so nothing slices a layer out of the
@@ -343,7 +433,12 @@ class PagedDecoder:
         layer index.  The head projection is the caller's: decode
         projects every slot's single row, prefill projects ONLY the
         last valid row (the full-chunk head would be a C x hidden x
-        vocab matmul that is discarded for every row but one)."""
+        vocab matmul that is discarded for every row but one).  A model
+        with leading dense layers runs two scans over its two stacked
+        trees ('dense_layers', then 'layers'), the pool on both carries,
+        the layer index counting on; ``load`` is the expert layers'
+        counts summed (int32[3], models/moe.held_experts_ffn) or None
+        for a model without them."""
         from torchacc_tpu.models.generate import _zoo_embed
 
         with jax.named_scope("embed"):
@@ -352,15 +447,22 @@ class PagedDecoder:
         def body(carry, per):
             x, pools = carry
             p_l, layer = per
-            return self._layer(p_l["block"], layer, x, pools, positions,
-                               tables, ctx_lens, blk, off), None
+            x, pools, load = self._layer(
+                p_l["block"], layer, x, pools, positions, tables, ctx_lens,
+                blk, off, valid)
+            return (x, pools), load
 
+        n_dense, n = self.cfg.first_dense_layers, self.cfg.num_layers
         with jax.named_scope("layers"):
-            (x, pools), _ = jax.lax.scan(
+            if n_dense:
+                (x, pools), _ = jax.lax.scan(
+                    body, (x, pools),
+                    (params["dense_layers"],
+                     jnp.arange(n_dense, dtype=jnp.int32)))
+            (x, pools), load = jax.lax.scan(
                 body, (x, pools),
-                (params["layers"],
-                 jnp.arange(self.cfg.num_layers, dtype=jnp.int32)))
-        return pools, x
+                (params["layers"], jnp.arange(n_dense, n, dtype=jnp.int32)))
+        return pools, x, (None if load is None else jnp.sum(load, axis=0))
 
     # -- sampling -----------------------------------------------------------
 
@@ -412,9 +514,10 @@ class PagedDecoder:
             0)
         off = jnp.where(active, seq_lens % bs, 0)
         ctx = jnp.where(active, seq_lens + 1, 0)
-        pools, x = self._forward(params, pools, tok[:, None],
-                                 positions, tables, ctx,
-                                 blk[:, None], off[:, None])
+        pools, x, load = self._forward(params, pools, tok[:, None],
+                                       positions, tables, ctx,
+                                       blk[:, None], off[:, None],
+                                       active[:, None])
         from torchacc_tpu.models.transformer import head_logits
         with jax.named_scope("head"):
             logits = head_logits(self.cfg, params, x)
@@ -425,7 +528,7 @@ class PagedDecoder:
             else:
                 toks = self._sample_slots(logits[:, 0], split[:, 1], temp,
                                           top_k, top_p)
-        return pools, {"tok": toks, "key": split[:, 0]}, toks
+        return pools, {"tok": toks, "key": split[:, 0]}, toks, load
 
     def _prefill_impl(self, params, pools, table_row, t0, tokens, n_valid,
                       is_final):
@@ -446,18 +549,18 @@ class PagedDecoder:
         blk = jnp.where(valid, table_row[pos // bs], 0)
         off = jnp.where(valid, pos % bs, 0)
         ctx = (t0 + n_valid)[None]
-        pools, x = self._forward(params, pools, tokens[None],
-                                 positions, table_row[None], ctx,
-                                 blk[None], off[None])
+        pools, x, load = self._forward(params, pools, tokens[None],
+                                       positions, table_row[None], ctx,
+                                       blk[None], off[None], valid[None])
         if not is_final:
-            return pools, None
+            return pools, None, load
         from torchacc_tpu.models.transformer import head_logits
         with jax.named_scope("head"):
             logits = head_logits(self.cfg, params, x)
             last = jnp.take_along_axis(
                 logits[0], jnp.maximum(n_valid - 1, 0)[None, None],
                 axis=0)[0]                                         # [V]
-        return pools, last
+        return pools, last, load
 
     def _prefill_batch_impl(self, params, pools, table_rows, t0s, tokens,
                             n_valids):
@@ -480,26 +583,23 @@ class PagedDecoder:
             valid, jnp.take_along_axis(table_rows, pos // bs, axis=1), 0)
         off = jnp.where(valid, pos % bs, 0)
         ctx = t0s + n_valids                                     # [PB]
-        pools, x = self._forward(params, pools, tokens, positions,
-                                 table_rows, ctx, blk, off)
+        pools, x, load = self._forward(params, pools, tokens, positions,
+                                       table_rows, ctx, blk, off, valid)
         from torchacc_tpu.models.transformer import head_logits
         with jax.named_scope("head"):
             last = jnp.take_along_axis(
                 x, jnp.maximum(n_valids - 1, 0)[:, None, None], axis=1)
             logits = head_logits(self.cfg, params, last)         # [PB, 1, V]
-        return pools, logits[:, 0]
+        return pools, logits[:, 0], load
 
     def _cow_impl(self, pools, src, dst):
-        """Copy block ``src``'s k/v into block ``dst`` across every
-        layer (blocks are dim 1 of [L, NB, BS, KH*D]) — the
+        """Copy block ``src``'s rows into block ``dst`` across every
+        layer of every pool (blocks are dim 1 of [L, NB, BS, row]) — the
         copy-on-write behind a fully-cached prompt: the final prompt
         token must re-run (its logits seed the first sampled token) and
         its k/v write needs a block this sequence owns; everything
         before it stays shared."""
-        kp, vp = pools
-        kp = kp.at[:, dst].set(kp[:, src])
-        vp = vp.at[:, dst].set(vp[:, src])
-        return kp, vp
+        return tuple(p.at[:, dst].set(p[:, src]) for p in pools)
 
     def _sample_first_impl(self, logits, key, temp, top_k, top_p):
         with jax.named_scope("sample"):
@@ -558,6 +658,9 @@ class Sequence:
     t_first_token: float = 0.0
     t_finish: float = 0.0
     token_times: List[float] = dataclasses.field(default_factory=list)
+    # expert-layer counts of this request's prefill programs, handed to
+    # the ring with its first token (device arrays; empty without experts)
+    loads: List[Any] = dataclasses.field(default_factory=list)
 
     @property
     def prompt_len(self) -> int:
@@ -587,6 +690,10 @@ class _InFlight:
     seq: Optional[Sequence] = None           # 'first' entries
     iter_idx: int = -1                       # decode iteration index
     t_dispatch: float = 0.0
+    # expert-layer counts of the step(s) behind this entry: int32[3]
+    # device arrays (models/moe.held_experts_ffn), one a program — a
+    # decode step's own, or every prefill chunk's of a 'first' entry
+    loads: List[Any] = dataclasses.field(default_factory=list)
 
 
 class Scheduler:
@@ -609,7 +716,9 @@ class Scheduler:
         self.prefix = (PrefixIndex(serve_cfg.block_size)
                        if serve_cfg.prefix_cache else None)
         self.pool = BlockPool(serve_cfg.num_blocks, index=self.prefix)
-        self.k_pools, self.v_pools = make_pools(model_cfg, serve_cfg)
+        # (k, v) stacks, or the one latent stack: a tuple either way,
+        # donated to and returned by every step
+        self.pools = make_pools(model_cfg, serve_cfg)
         s = serve_cfg.max_slots
         # table width bounds the LONGEST admissible sequence, not the
         # pool: the attention cost per decode token scales with table
@@ -755,9 +864,8 @@ class Scheduler:
             # exactly where the popped match sat.  Device program order
             # makes the copy read src before any later program could
             # recycle it, so the pin can drop right after dispatch.
-            pools = (self.k_pools, self.v_pools)
-            self.k_pools, self.v_pools = self.decoder._cow(
-                pools, jnp.asarray(cow_src, jnp.int32),
+            self.pools = self.decoder._cow(
+                self.pools, jnp.asarray(cow_src, jnp.int32),
                 jnp.asarray(fresh[0], jnp.int32))
             self.pool.free([cow_src])
             cached = seq.prompt_len - 1
@@ -854,16 +962,16 @@ class Scheduler:
         n_valid = int(chunk.shape[0])
         if n_valid < c:
             chunk = np.pad(chunk, (0, c - n_valid))
-        pools = (self.k_pools, self.v_pools)
         final = (t0 + n_valid) >= seq.prompt_len
         with tracing.span("serve/prefill", sid=seq.sid, t0=t0,
                           tokens=n_valid, batched=False,
                           trace=seq.trace_id):
-            pools, last_logits = self.decoder._prefill(
-                self.params, pools, _upload(self.tables[seq.slot]),
+            self.pools, last_logits, load = self.decoder._prefill(
+                self.params, self.pools, _upload(self.tables[seq.slot]),
                 jnp.asarray(t0, jnp.int32), jnp.asarray(chunk, jnp.int32),
                 jnp.asarray(n_valid, jnp.int32), final)
-        self.k_pools, self.v_pools = pools
+        if load is not None:
+            seq.loads.append(load)
         seq.prefilled += n_valid
         self.seq_lens[seq.slot] = seq.prefilled
         self._register_prefix(seq)
@@ -891,15 +999,15 @@ class Scheduler:
             toks[r, :n] = chunk
             n_valids[r] = n
             taken.append(n)
-        pools = (self.k_pools, self.v_pools)
         with tracing.span("serve/prefill", batched=True,
                           sids=[s.sid for s in seqs],
                           traces=[s.trace_id for s in seqs],
                           tokens=int(sum(taken))):
-            pools, logits = self.decoder._prefill_batch(
-                self.params, pools, jnp.asarray(tables), jnp.asarray(t0s),
-                jnp.asarray(toks), jnp.asarray(n_valids))
-        self.k_pools, self.v_pools = pools
+            self.pools, logits, load = self.decoder._prefill_batch(
+                self.params, self.pools, jnp.asarray(tables),
+                jnp.asarray(t0s), jnp.asarray(toks), jnp.asarray(n_valids))
+        if load is not None:
+            seqs[0].loads.append(load)       # one program, counted once
         for r, seq in enumerate(seqs):
             seq.prefilled += taken[r]
             self.seq_lens[seq.slot] = seq.prefilled
@@ -938,8 +1046,9 @@ class Scheduler:
         self.active[seq.slot] = True
         self._dev_stable = None
         self._ring.append(_InFlight(
-            kind="first", tokens=tok, seq=seq,
+            kind="first", tokens=tok, seq=seq, loads=seq.loads,
             t_dispatch=time.monotonic()))
+        seq.loads = []
 
     def _dev_stable_arrays(self):
         if self._dev_stable is None:
@@ -959,7 +1068,6 @@ class Scheduler:
                     if self.active[i] and s is not None]
         tables, active, temp, top_k, top_p = self._dev_stable_arrays()
         all_greedy = bool((self.temp[self.active] <= 0.0).all())
-        pools = (self.k_pools, self.v_pools)
         # per-request trace ids on the batched span: built only while
         # tracing records (the list comprehension must cost nothing on
         # the disabled hot path)
@@ -967,15 +1075,15 @@ class Scheduler:
                    if tracing.enabled() else None)
         with tracing.span("serve/decode", iter=self._iter,
                           slots=len(snapshot), traces=_traces):
-            pools, self.carry, toks = self.decoder._decode(
-                self.params, pools, self.carry,
+            self.pools, self.carry, toks, load = self.decoder._decode(
+                self.params, self.pools, self.carry,
                 tables, _upload(self.seq_lens),
                 active, temp, top_k, top_p, all_greedy)
-        self.k_pools, self.v_pools = pools
         # host mirror: every active slot banked one more token
         self.seq_lens[self.active] += 1
         self._ring.append(_InFlight(
             kind="decode", tokens=toks, slots=snapshot,
+            loads=[] if load is None else [load],
             iter_idx=self._iter, t_dispatch=time.monotonic()))
         self._iter += 1
 
@@ -1062,7 +1170,7 @@ class Scheduler:
             _traces = ([entry.seq.trace_id] if entry.kind == "first"
                        else [s.trace_id for _, s in entry.slots])
         with tracing.span("serve/deliver", kind=entry.kind,
-                          traces=_traces):
+                          traces=_traces) as deliver:
             # the (only) blocking fetch: deliver's time outside this
             # span is the host's own work, inside it the device's
             with tracing.span("serve/wait"):
@@ -1071,6 +1179,17 @@ class Scheduler:
                         toks = np.asarray(entry.tokens)
                 else:
                     toks = np.asarray(entry.tokens)
+            if entry.loads and deliver.live:
+                # the steps' expert-layer counts: computed with the
+                # tokens just fetched, so reading them waits on nothing
+                pairs, largest, hit = np.sum(
+                    [np.asarray(x) for x in entry.loads], axis=0)
+                layer_steps = len(entry.loads) * (
+                    self.cfg.num_layers - self.cfg.first_dense_layers)
+                deliver.set(
+                    moe_pairs=int(pairs), moe_max=int(largest),
+                    moe_hit=int(hit), moe_layer_steps=layer_steps,
+                    moe_slots=layer_steps * self.cfg.num_experts)
             now = time.monotonic()
             if entry.kind == "first":
                 self._record(entry.seq, int(toks), now)

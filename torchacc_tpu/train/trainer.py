@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from torchacc_tpu.config import Config
+from torchacc_tpu.config import Config, ConfigError
 from torchacc_tpu.errors import TorchAccTPUError, TrainerStateError
 from torchacc_tpu.obs import tracing
 from torchacc_tpu.models.axes import param_axes as resolve_param_axes
@@ -104,6 +104,16 @@ class Trainer:
         loss: Optional[Callable] = None,
         mesh: Optional[Mesh] = None,
     ):
+        mcfg = getattr(model, "cfg", None)
+        if getattr(mcfg, "kv_lora_rank", 0) or getattr(
+                mcfg, "moe_dispatch", "") == "grouped":
+            raise ConfigError(
+                "training a latent-attention / held-expert model is not "
+                "supported: the grouped expert layer computes one chip's "
+                "share without the exchange across 'ep', its auxiliary "
+                "loss and sharding rules are untested under a train step, "
+                "and the attention has no flash path.  ServeEngine serves "
+                "it; TransformerLM.apply runs its forward")
         self.model = model
         self.config = config
         self.optimizer = optimizer or optax.adamw(1e-4)
