@@ -259,7 +259,9 @@ AXK1 = dict(
     rope_yarn=(32.0, 4096.0, 32.0, 1.0, 1.0, True), query_scale=0.1309)
 AXK1_SERVE = dict(block_size=128, num_blocks=640, max_slots=32,
                   prefill_chunk=512)
-AXK1_DEPTH = 2               # one dense + one expert layer
+# one dense + TWO expert layers: at one, the scan's slice of a stack of
+# one is a bitcast and no compile could show a copied expert stack
+AXK1_DEPTH = 3
 
 
 @pytest.mark.parametrize("slots,t", [(32, 1), (1, 512)],
@@ -287,19 +289,23 @@ def test_latent_paged_kernel_compiles_at_published_geometry(
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
-@pytest.mark.parametrize("rows,k,n", [(256, 7168, 2048), (4096, 7168, 2048),
-                                      (256, 2048, 7168)],
-                         ids=["decode_up", "chunk_up", "decode_down"])
+@pytest.mark.parametrize("rows,k,n,stack", [
+    (256, 7168, 2048, ()), (4096, 7168, 2048, ()), (256, 2048, 7168, ()),
+    (256, 7168, 2048, (5,))],
+    ids=["decode_up", "chunk_up", "decode_down", "stack"])
 def test_grouped_expert_matmul_compiles_for_the_chip(one_chip, for_the_chip,
-                                                     rows, k, n):
+                                                     rows, k, n, stack):
     """12 held experts of 7168 x 2048 (and the way back): the Pallas
     grouped matmul over the sorted (token, expert) pairs of a decode
     step (32 x 8) and of a prefill chunk (512 x 8), weights read in
-    place (no temporary of an expert's size)."""
+    place (no temporary of an expert's size) — also out of the cell's
+    stack of five expert layers [5, 12, 7168, 2048] through a traced
+    layer index."""
     sds = functools.partial(_sds, sharding=one_chip)
+    layer = {"layer": sds((), jnp.int32)} if stack else {}
     compiled = jax.jit(grouped_mod.grouped_matmul).lower(
-        sds((rows, k), BF16), sds((12, k, n), BF16),
-        sds((12,), jnp.int32)).compile()
+        sds((rows, k), BF16), sds(stack + (12, k, n), BF16),
+        sds((12,), jnp.int32), **layer).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
@@ -341,24 +347,33 @@ def _axk1_program(name, one_chip):
                          ["decode", "prefill_chunk", "prefill_final_chunk"])
 def test_latent_serve_program_holds_one_latent_pool(one_chip, for_the_chip,
                                                     name):
-    """The three serve programs of the A.X-K1 cell: ONE latent pool
-    [L, 640, 128, 640], aliased in -> out, no pool-shaped copy; the
-    latent kernel once a layer stack and the three grouped matmuls.  The temporaries hold one layer's expert stack
-    (336 MiB: the scan slices a layer's [12, 7168, 2048] out of the
-    stacked weights for the grouped matmul — PERF.md section 5; a
-    perf_opt PR's to remove) and must stay under two of them."""
+    """The three serve programs of the A.X-K1 cell, one dense and two
+    expert layers deep: ONE latent pool [L, 640, 128, 640], aliased
+    in -> out, no pool-shaped copy; the latent kernel once a layer stack
+    and the three grouped matmuls.  The expert kernels stay where they
+    lie: the layer scan does not slice them (``PagedDecoder._forward``
+    closes over the stacks) and the grouped matmul reads its layer
+    through an index, so no instruction but a parameter yields an array
+    of one layer's expert stack ([12, 7168, 2048] or its transpose,
+    336 MiB), decode holds under 32 MiB of temporaries and a prefill
+    program under one stack (what it keeps is the chunk's own sorted
+    activations: 4,096 pairs x 7168 bf16 = 56 MiB and the like).  With
+    the stacks on the scan's ``xs`` the same compile read 338 / 401 /
+    401 MiB (sandbox compile, PR 27)."""
     lowered, (pool,) = _axk1_program(name, one_chip)
     compiled = lowered.compile()
     mem, text = compiled.memory_analysis(), compiled.as_text()
     pool_bytes = pool.size * pool.dtype.itemsize
     assert pool.shape == (AXK1_DEPTH, 640, 128, 640)
     assert mem.alias_size_in_bytes >= pool_bytes
-    assert mem.temp_size_in_bytes < 2 * 336 * 2**20
-    # a non-final chunk returns no logits, so at this depth (ONE expert
-    # layer, the last) the compiler drops that layer's expert matmuls:
-    # only the two latent kernels are left
-    assert text.count("tpu_custom_call") == (
-        2 if name == "prefill_chunk" else 5)
+    assert mem.temp_size_in_bytes < (32 if name == "decode" else 256) * 2**20
+    # the latent kernel in each of the two layer scans, the three
+    # grouped matmuls in the second
+    assert text.count("tpu_custom_call") == 5
+    stack = r"bf16\[12,(7168,2048|2048,7168)\]"
+    copied = [line.strip()[:160] for line in text.splitlines()
+              if re.search(rf"= {stack}\S* (?!parameter\()", line)]
+    assert not copied, copied
     dims = [str(d) for d in pool.shape]
     shapes = "|".join(re.escape(f"bf16[{','.join(dims[i:])}]")
                       for i in (0, 1))

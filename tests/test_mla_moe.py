@@ -14,6 +14,7 @@ and above.
 """
 
 import dataclasses
+import functools
 import types
 
 import jax
@@ -169,10 +170,16 @@ def test_serving_through_the_latent_pool_matches_the_reference(
     chunks interleave with decode steps; absorbed form, kernel in
     interpret mode.  Every served token must be the reference's best up
     to float32 noise: the reference runs the EXPANDED form over the
-    whole row at once."""
+    whole row at once.  And the module's own apply, which runs the
+    expert layers under ``nn.scan`` on each layer's [E, in, out] kernels
+    where the decoder's scan leaves the [L, E, in, out] stacks whole and
+    hands the grouped matmul a layer index (one dense + two expert
+    layers here): every served token — the prefill's first and the
+    decode steps' — is the module's best too."""
     pub, w, params, mc = whole
     if first is not None:
         pub, w, params, mc = held_share(pub, w, first, 4)
+    assert params["layers"]["block"]["moe"]["experts/gate"].shape[0] == 2
     cfg = ta.Config()
     cfg.serve.block_size, cfg.serve.num_blocks = 8, 64
     cfg.serve.max_slots, cfg.serve.prefill_chunk = 3, 12
@@ -189,6 +196,12 @@ def test_serving_through_the_latent_pool_matches_the_reference(
     eng.close()
     assert all(len(t) == 6 for t in results)
     assert _served_gap(pub, w, prompts, results) < 1e-5
+    module = jax.jit(lambda ids: TransformerLM(mc).apply(
+        {"params": params}, ids[None])[0])
+    for prompt, tokens in zip(prompts, results):
+        z = module(jnp.asarray(prompt + tokens[:-1]))[len(prompt) - 1:]
+        picked = z[jnp.arange(len(tokens)), jnp.asarray(tokens)]
+        assert float(jnp.max(jnp.max(z, axis=-1) - picked)) < 1e-5
 
 
 def test_absorbed_form_equals_expanded_form(whole):
@@ -295,21 +308,52 @@ def _moe_tree(w, layer=0):
     (300, [1, 127, 1, 128, 43]),    # rows padded to the tile, full
     (28, [3, 0, 20, 1]),            # fewer rows than a tile
 ], ids=["empty_groups", "no_pairs", "one_expert", "padded_rows", "few_rows"])
-def test_grouped_matmul_against_a_loop_over_groups(rows, sizes):
+@pytest.mark.parametrize("layer", [None, 0, 1, 2],
+                         ids=["w3d", "layer0", "layer1", "layer2"])
+def test_grouped_matmul_against_a_loop_over_groups(rows, sizes, layer):
     """The Pallas grouped matmul (interpreted here) against one plain
     product a group: float32 at ``highest``, summation-order noise only.
     Rows behind the last group are undefined by contract (the
-    interpreter leaves NaN there) and are not compared."""
+    interpreter leaves NaN there) and are not compared.  With ``layer``
+    the weights are a stack of three layers and the index is traced:
+    the same kernel reads ``w[layer]`` where it lies, so the rows of the
+    groups equal the call on the layer's own [g, k, n] bit for bit."""
     g = len(sizes)
     x = jax.random.normal(jax.random.PRNGKey(0), (rows, 256))
-    w = jax.random.normal(jax.random.PRNGKey(1), (g, 256, 384))
-    got = grouped_matmul(x, w, jnp.asarray(sizes, jnp.int32), tk=128, tn=128)
+    stack = jax.random.normal(jax.random.PRNGKey(1), (3, g, 256, 384))
+    w = stack[layer or 0]
+    sizes_ = jnp.asarray(sizes, jnp.int32)
+    got = grouped_matmul(x, w, sizes_, tk=128, tn=128)
+    if layer is not None:
+        flat, got = got, jax.jit(functools.partial(
+            grouped_matmul, tk=128, tn=128))(x, stack, sizes_,
+                                             layer=jnp.int32(layer))
+        assert np.array_equal(got[:sum(sizes)], flat[:sum(sizes)])
     assert got.shape == (rows, 384)
     start = 0
     for i, n in enumerate(sizes):
         np.testing.assert_allclose(got[start:start + n],
                                    x[start:start + n] @ w[i], atol=2e-4)
         start += n
+
+
+def test_grouped_matmul_takes_a_stack_in_the_rows_dtype_only():
+    """A [g, k, n] ``w`` of another dtype is converted on the way in; a
+    layer stack is not (that would convert every layer of it inside the
+    caller's scan) and is a typed error, as are a stack without an index
+    and an index without a stack."""
+    x = jnp.ones((16, 128), jnp.bfloat16)
+    stack = jnp.ones((2, 2, 128, 128), jnp.float32)
+    sizes = jnp.asarray([8, 8], jnp.int32)
+    assert grouped_matmul(x, stack[1], sizes).dtype == jnp.bfloat16
+    with pytest.raises(TypeError, match="outside the layer scan"):
+        grouped_matmul(x, stack, sizes, layer=jnp.int32(1))
+    with pytest.raises(ValueError, match="takes a layer index"):
+        grouped_matmul(x, stack.astype(x.dtype), sizes)
+    with pytest.raises(ValueError, match="takes a layer index"):
+        grouped_matmul(x, stack[1], sizes, layer=jnp.int32(1))
+    got = grouped_matmul(x, stack.astype(x.dtype), sizes, layer=jnp.int32(1))
+    assert np.array_equal(got, jnp.full((16, 128), 128, jnp.bfloat16))
 
 
 def test_grouped_matmul_schedule_visits_only_tiles_that_hold_rows():

@@ -118,17 +118,20 @@ def swiglu(x, w_gate, w_up, w_down, dtype):
 
 
 def held_experts_ffn(cfg, x, sel, weights, w_gate, w_up, w_down,
-                     valid=None):
+                     valid=None, layer=None):
     """The held experts' part of ``sum_i w_i E_i(x)``, dropless.
 
     ``x`` [n, h]; ``sel``/``weights`` [n, k] name experts of the router's
-    whole width; this program holds the ``e = w_gate.shape[0]`` experts
+    whole width; this program holds the ``e = w_gate.shape[-3]`` experts
     ``[cfg.moe_first_expert, +e)``.  The (token, expert) pairs that chose
     a held expert are sorted by expert and pass through three grouped
     matmuls (``ops/grouped_matmul.py``: a Pallas kernel that visits only
     the row tiles that hold pairs and the weights of the groups they
     belong to), so FLOPs follow the routed pairs and an expert that drew
-    no token is not read.  Nothing is dropped under any
+    no token is not read.  The kernels are one layer's [e, in, out] or,
+    with ``layer`` (an int32 scalar, traced in a layer scan), every
+    expert layer's [L, e, in, out] in ``cfg.dtype``, which the grouped
+    matmul reads at ``layer`` where they lie.  Nothing is dropped under any
     imbalance: the sorted buffer holds all ``n * k`` pairs.  Pairs on
     experts held elsewhere (and tokens with ``valid`` False: padding,
     free serving slots) sort behind the held groups, are computed by no
@@ -139,7 +142,7 @@ def held_experts_ffn(cfg, x, sel, weights, w_gate, w_up, w_down,
     the largest held expert's count, held experts that drew a pair.
     """
     n, k = sel.shape
-    e = w_gate.shape[0]
+    e = w_gate.shape[-3]
     nk = n * k
     with jax.named_scope("moe_dispatch"):
         local = sel - cfg.moe_first_expert
@@ -153,10 +156,10 @@ def held_experts_ffn(cfg, x, sel, weights, w_gate, w_up, w_down,
         xs = x.astype(cfg.dtype)[tok_sorted]                    # [nk, h]
     with jax.named_scope("experts"):
         dt = cfg.dtype
-        gate = grouped_matmul(xs, w_gate, counts)
-        up = grouped_matmul(xs, w_up, counts)
+        gate = grouped_matmul(xs, w_gate, counts, layer=layer)
+        up = grouped_matmul(xs, w_up, counts, layer=layer)
         out = grouped_matmul((nn.silu(gate) * up).astype(dt), w_down,
-                             counts)                            # [nk, h]
+                             counts, layer=layer)               # [nk, h]
     with jax.named_scope("moe_combine"):
         # rows past the held groups belong to no group: whatever the
         # kernel left there is masked, not multiplied by a zero weight
@@ -172,11 +175,14 @@ def held_experts_ffn(cfg, x, sel, weights, w_gate, w_up, w_down,
     return y, load
 
 
-def moe_ffn(cfg, p, x, valid=None):
+def moe_ffn(cfg, p, x, valid=None, layer=None):
     """``shared(x) + sum_i w_i E_i(x)`` over the held experts, on the raw
     parameter tree ``p`` of :class:`MoEMlp` (``router``, ``experts/*``,
     ``shared``) — the one definition behind the module's 'grouped' path
-    and the serving decoder's expert layer.  ``x`` [n, h] ->
+    and the serving decoder's expert layer.  With ``layer`` the three
+    ``experts/*`` leaves are the stacks of every expert layer and
+    ``layer`` the index into them (:func:`held_experts_ffn`); the other
+    leaves are always one layer's.  ``x`` [n, h] ->
     ``(y [n, h] in cfg.dtype, scores, sel, load)``."""
     with jax.named_scope("router"):
         # float32 at full precision: a TPU's default float32 product
@@ -187,7 +193,8 @@ def moe_ffn(cfg, p, x, valid=None):
                          precision=jax.lax.Precision.HIGHEST)
         sel, weights, scores = route(cfg, logits, p.get("router_bias"))
     y, load = held_experts_ffn(cfg, x, sel, weights, p["experts/gate"],
-                               p["experts/up"], p["experts/down"], valid)
+                               p["experts/up"], p["experts/down"], valid,
+                               layer)
     if cfg.moe_shared_experts:
         with jax.named_scope("shared_expert"):
             sh = p["shared"]

@@ -16,6 +16,20 @@ which (group, row tile) each grid step works on, so
 - a row tile that two groups share is visited once for each, and each
   visit stores only its own rows.
 
+The weights may be one layer's ``[g, k, n]`` or the stack of every
+layer's, ``[L, g, k, n]``, with a layer index.  A layer scan hands its
+body a slice of each stacked leaf; an XLA dot absorbs that slice, a
+custom call cannot — its operand has to be a whole buffer, so XLA copies
+the layer's stack out of the stacked weights before every call (A.X-K1's
+serving cell: 336 MiB a stack, three stacks a layer, 15.9 ms of a 30.7 ms
+decode step at the HBM's pace — ledger and PERF.md, PR 26).  So the
+caller keeps the stacks off its scan (``serve/scheduler.py::_forward``)
+and the layer index rides as one more scalar-prefetch operand: the
+weight block's index map addresses ``(layer, group, k block, n block)``
+in the stack where it lies, as ``ops/paged_attention.py`` addresses a
+page of the stacked pool.  One kernel: ``[g, k, n]`` enters as a stack
+of one.
+
 ``jax.lax.ragged_dot`` computes the same; on the TPU XLA runs it as
 instructions that carry no ``op_name`` (my chip run, PR 26: a quarter of
 the serving window's device time under no scope), so the program's
@@ -92,8 +106,8 @@ def tile_schedule(group_sizes, m: int, tm: int):
     return group_of, tile_of, starts, ends, num_steps[None]
 
 
-def _kernel(group_of, tile_of, starts, ends, num_steps, x_ref, w_ref, o_ref,
-            acc_ref, *, tm: int, k_steps: int):
+def _kernel(group_of, tile_of, starts, ends, num_steps, layer, x_ref, w_ref,
+            o_ref, acc_ref, *, tm: int, k_steps: int):
     s, ki = pl.program_id(1), pl.program_id(2)
 
     @pl.when(s < num_steps[0])
@@ -115,9 +129,9 @@ def _kernel(group_of, tile_of, starts, ends, num_steps, x_ref, w_ref, o_ref,
                                    o_ref[...])
 
 
-def _grouped_matmul_pallas(x, w, group_sizes, *, tk, tn):
+def _grouped_matmul_pallas(x, w, group_sizes, layer, *, tk, tn):
     m, k = x.shape
-    g, _, n = w.shape
+    _, g, _, n = w.shape
     tm = min(ROW_TILE, round_up(m, 16))
     m_pad = round_up(m, tm)
     if m_pad != m:
@@ -138,14 +152,19 @@ def _grouped_matmul_pallas(x, w, group_sizes, *, tk, tn):
         functools.partial(_kernel, tm=tm, k_steps=k_steps),
         out_shape=jax.ShapeDtypeStruct((m_pad, n), x.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
+            # six scalar-prefetch operands: the schedule's five and the
+            # layer index, so the weight block's index map can address
+            # (layer, group, k block, n block) in the stack before the
+            # body runs (the pattern of ops/paged_attention.py's pools)
+            num_scalar_prefetch=6,
             grid=(n // tn, m_pad // tm + g - 1, k_steps),
             in_specs=[
-                pl.BlockSpec((tm, tk), lambda ni, s, ki, go, to, st, en, num:
+                pl.BlockSpec((tm, tk),
+                             lambda ni, s, ki, go, to, st, en, num, layer:
                              (to[s], k_block(s, ki, num))),
-                pl.BlockSpec((None, tk, tn),
-                             lambda ni, s, ki, go, to, st, en, num:
-                             (go[s], k_block(s, ki, num), ni)),
+                pl.BlockSpec((None, None, tk, tn),
+                             lambda ni, s, ki, go, to, st, en, num, layer:
+                             (layer[0], go[s], k_block(s, ki, num), ni)),
             ],
             out_specs=pl.BlockSpec(
                 (tm, tn), lambda ni, s, ki, go, to, *_: (to[s], ni)),
@@ -154,25 +173,47 @@ def _grouped_matmul_pallas(x, w, group_sizes, *, tk, tn):
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=_interpret(),
         name="grouped_matmul",
-    )(*schedule, x, w)
+    )(*schedule, layer.reshape(1), x, w)
     return out[:m]
 
 
-def grouped_matmul(x, w, group_sizes, *, tk: int | None = None,
+def grouped_matmul(x, w, group_sizes, *, layer=None, tk: int | None = None,
                    tn: int | None = None):
     """``x`` [m, k] with its rows sorted by group, ``w`` [g, k, n],
     ``group_sizes`` int [g]: rows ``[sum(sizes[:i]), sum(sizes[:i+1]))``
     are multiplied by ``w[i]`` (float32 accumulation, result in
     ``x.dtype``).  Rows past ``sum(group_sizes)`` belong to no group:
     the result there is UNDEFINED (possibly not finite) — mask it, do
-    not multiply it by zero.  ``tk``/``tn`` override the weight block
-    of :func:`weight_tiles` (tests)."""
+    not multiply it by zero.
+
+    ``w`` may also be a layer stack [L, g, k, n] with ``layer`` an int32
+    scalar (traced inside a layer scan): the call computes with
+    ``w[layer]`` and reads only that layer's hit groups out of the stack
+    where it lies.  A stack has to be in ``x.dtype`` already — converting
+    it here would convert every layer of it on every call — and one of
+    another dtype is a ``TypeError``; the caller converts once, outside
+    its scan.  (A [g, k, n] ``w`` of another dtype is converted, as
+    before.)  ``tk``/``tn`` override the weight block of
+    :func:`weight_tiles` (tests)."""
+    if (w.ndim == 4) != (layer is not None):
+        raise ValueError(
+            f"grouped_matmul: weights of shape {w.shape} with layer="
+            f"{layer}; a stack [L, g, k, n] takes a layer index, one "
+            f"layer's [g, k, n] takes none")
+    if layer is None:
+        # a stack of one (a bitcast), so there is one kernel
+        w, layer = w.astype(x.dtype)[None], 0
+    elif w.dtype != x.dtype:
+        raise TypeError(
+            f"grouped_matmul: a layer stack in {w.dtype} with rows in "
+            f"{x.dtype}; convert the stack once, outside the layer scan")
+    layer = jnp.asarray(layer, jnp.int32)
     fn = functools.partial(_grouped_matmul_pallas, tk=tk, tn=tn)
     mesh = ambient_mesh()
     if needs_shard_map(mesh):
         # the held-expert layer is one chip's share: every shard holds
         # the same rows and weights and runs the whole call
         from jax.sharding import PartitionSpec as P
-        fn = jax.shard_map(fn, mesh=mesh, in_specs=(P(),) * 3,
+        fn = jax.shard_map(fn, mesh=mesh, in_specs=(P(),) * 4,
                            out_specs=P(), check_vma=False)
-    return fn(x, w.astype(x.dtype), group_sizes)
+    return fn(x, w, group_sizes, layer)

@@ -192,6 +192,11 @@ def _upload(host_mirror: np.ndarray) -> jax.Array:
     return jnp.asarray(host_mirror.copy())
 
 
+#: leaves of an expert layer's ``moe`` tree that the layer scan does not
+#: slice (PagedDecoder._forward)
+_EXPERT_STACKS = ("experts/gate", "experts/up", "experts/down")
+
+
 class PagedDecoder:
     """The jitted device steps: a raw-params transformer forward over
     the paged pool (the established raw-params idiom of
@@ -268,7 +273,7 @@ class PagedDecoder:
         return y
 
     def _layer(self, p, layer, x, pools, positions, tables, ctx_lens, blk,
-               off, valid=None):
+               off, valid=None, expert_stacks=None):
         """Decoder layer ``layer`` over the paged cache, by the kinds of
         its two halves: the attention is grouped-query over a k and a v
         pool or latent over one pool (``cfg.kv_lora_rank``), the
@@ -277,7 +282,10 @@ class PagedDecoder:
         ``blk``/``off`` [S, T] name the pool slot every token writes its
         row to (the null block for masked tokens); ``ctx_lens`` is the
         post-write context length per slot; ``valid`` [S, T] marks the
-        real tokens (the expert layer routes no others).  Returns
+        real tokens (the expert layer routes no others);
+        ``expert_stacks`` the expert kernels of ALL expert layers
+        (:meth:`_forward` keeps them off the scan), read by the grouped
+        matmul at this layer's index among them.  Returns
         ``(x, pools, load)``, ``load`` the expert layer's counts or
         None."""
         from torchacc_tpu.models.transformer import Norm
@@ -300,8 +308,10 @@ class PagedDecoder:
             from torchacc_tpu.models.moe import moe_ffn
             s_, t_, hd = h2.shape
             y, _, _, load = moe_ffn(
-                cfg, p["moe"], h2.reshape(s_ * t_, hd),
-                None if valid is None else valid.reshape(-1))
+                cfg, {**p["moe"], **expert_stacks},
+                h2.reshape(s_ * t_, hd),
+                None if valid is None else valid.reshape(-1),
+                layer=layer - cfg.first_dense_layers)
             return x + y.reshape(s_, t_, hd), pools, load
         with jax.named_scope("mlp"):
             x = x + self._mlp(p["mlp"], h2)
@@ -430,7 +440,16 @@ class PagedDecoder:
         layer writes its rows in place and the kernel reads its pages
         through the layer index, so nothing slices a layer out of the
         stack or puts it back; ``xs`` are the stacked params and the
-        layer index.  The head projection is the caller's: decode
+        layer index.  An expert model's three expert kernel stacks
+        [L, E, in, out] are NOT on ``xs``: a scan hands its body a slice
+        of every ``xs`` leaf, and a custom call's operand cannot absorb
+        that slice, so XLA would copy each layer's 336 MiB stacks into a
+        second buffer before every grouped matmul (PERF.md PR 27).  The
+        body closes over them whole — loop invariants of the ``while`` —
+        and the kernel reads its layer through an index, like the pools.
+        The split is made here, at trace time, on the jitted function's
+        own argument: ``params`` stays the pytree it is and no weight is
+        copied.  The head projection is the caller's: decode
         projects every slot's single row, prefill projects ONLY the
         last valid row (the full-chunk head would be a C x hidden x
         vocab matmul that is discarded for every row but one).  A model
@@ -444,12 +463,23 @@ class PagedDecoder:
         with jax.named_scope("embed"):
             x = _zoo_embed(self.cfg, params, ids, positions)
 
+        layers, expert_stacks = params["layers"], None
+        moe = layers["block"].get("moe")
+        if moe is not None:
+            # in cfg.dtype the kernel wants them: a no-op on weights cast
+            # to serving precision, one conversion outside the scan
+            # otherwise
+            expert_stacks = {k: moe[k].astype(self.cfg.dtype)
+                             for k in _EXPERT_STACKS}
+            layers = {**layers, "block": {**layers["block"], "moe": {
+                k: v for k, v in moe.items() if k not in _EXPERT_STACKS}}}
+
         def body(carry, per):
             x, pools = carry
             p_l, layer = per
             x, pools, load = self._layer(
                 p_l["block"], layer, x, pools, positions, tables, ctx_lens,
-                blk, off, valid)
+                blk, off, valid, expert_stacks)
             return (x, pools), load
 
         n_dense, n = self.cfg.first_dense_layers, self.cfg.num_layers
@@ -461,7 +491,7 @@ class PagedDecoder:
                      jnp.arange(n_dense, dtype=jnp.int32)))
             (x, pools), load = jax.lax.scan(
                 body, (x, pools),
-                (params["layers"], jnp.arange(n_dense, n, dtype=jnp.int32)))
+                (layers, jnp.arange(n_dense, n, dtype=jnp.int32)))
         return pools, x, (None if load is None else jnp.sum(load, axis=0))
 
     # -- sampling -----------------------------------------------------------
