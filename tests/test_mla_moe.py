@@ -116,7 +116,7 @@ def test_yarn_mscale_is_a_cos_sin_factor_and_a_softmax_scale():
     assert mc2.rope_yarn[4] == pytest.approx((0.2 * np.log(32.0) + 1) / m)
     # the program's rotary embedding against the reference's, position
     # by position (1e-5: float32 cos/sin of angles up to ~200)
-    from torchacc_tpu.models.transformer import _rope
+    from torchacc_tpu.models.block import _rope
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 200, 2, 8))
     pos = jnp.arange(200)
     got = _rope(x, x, pos[None], mc2)[0][0]

@@ -390,11 +390,59 @@ def test_serve_config_validation():
 # continuous batching vs generate()
 # ---------------------------------------------------------------------------
 
-def test_greedy_continuous_batching_token_identical_mixed_lengths(tiny):
+# decoder-block shapes at toy width, float32: the serving decoder runs
+# the model's own block (models/block.py), so every shape TransformerLM
+# trains serves token-identically to generate().  Each is the named
+# family's BLOCK only (norm placement, qk-norm, biases, activation),
+# never its window, pattern or softcaps.
+_BIASES = dict(qkv_bias=True, o_bias=True, mlp_bias=True)
+BLOCK_SHAPES = {
+    "llama_pre_norm": {},
+    "olmo2_post_norm_flat_qk_norm": dict(
+        norm_placement="post", qk_norm=True, qk_norm_proj=True),
+    "gemma2_sandwich_norms": dict(
+        sandwich_norms=True, norm="rmsnorm1p", activation="geglu",
+        embed_scale=True),
+    "phi_parallel_shared_norm": dict(
+        parallel_block=True, norm="layernorm", activation="gelu",
+        partial_rotary=0.5, **_BIASES),
+    "gpt_neox_parallel_own_ln2": dict(
+        parallel_block=True, parallel_block_shared_norm=False,
+        norm="layernorm", activation="gelu_exact", **_BIASES),
+    "qwen3_per_head_qk_norm": dict(qk_norm=True),
+    "nemotron_relu2": dict(activation="relu2", norm="layernorm1p"),
+}
+
+
+def _block_model(**shape):
+    """The ``tiny`` geometry with another block shape.  Every leaf is
+    moved off its initial value: norm scales start at one and biases at
+    zero, where a norm applied in the wrong place or a dropped bias
+    would not show."""
+    cfg = get_preset(
+        "llama-tiny", dtype=jnp.float32, num_layers=2, hidden_size=64,
+        num_heads=4, num_kv_heads=2, intermediate_size=128,
+        vocab_size=VOCAB, max_seq_len=128, **shape)
+    model = TransformerLM(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    leaves, treedef = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
+    # stacked [L, ...]: vectors (scales, biases) by 0.3, matrices by 0.05
+    leaves = [x + (0.3 if x.ndim == 2 else 0.05)
+              * jax.random.normal(k, x.shape, x.dtype)
+              for x, k in zip(leaves, keys)]
+    return model, jax.tree.unflatten(treedef, leaves)
+
+
+@pytest.mark.parametrize("shape", list(BLOCK_SHAPES))
+def test_greedy_continuous_batching_token_identical_mixed_lengths(
+        tiny, shape):
     # prompt lengths span 25/3 > 8x; 6 requests > max_slots=4 so the
     # queue + admission path runs; prefill_chunk=8 < 25 so long prompts
     # take multiple interleaved chunks
-    model, params = tiny
+    model, params = (_block_model(**BLOCK_SHAPES[shape])
+                     if BLOCK_SHAPES[shape] else tiny)
     rng = np.random.default_rng(0)
     prompts = _prompts(rng, [3, 25, 7, 16, 4, 11])
     eng = ServeEngine(model, params, _serve_cfg())

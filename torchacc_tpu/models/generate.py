@@ -351,14 +351,6 @@ def generate(
 # pipeline-parallel KV-cache decode (VERDICT r3 next-7)
 # ---------------------------------------------------------------------------
 
-def _zoo_embed(cfg, params, ids, positions):
-    from torchacc_tpu.models.transformer import _embed_extras
-
-    emb = params["embed_tokens"]["embedding"]
-    return _embed_extras(cfg, emb[ids].astype(cfg.dtype), positions,
-                         params.get("pos_embed"))
-
-
 @functools.partial(jax.jit, static_argnames=(
     "cfg", "temperature", "max_new", "eos_id", "top_k", "top_p"))
 def _generate_cached_pp(cfg, params, prompt_ids, prompt_mask, rng,
@@ -367,7 +359,7 @@ def _generate_cached_pp(cfg, params, prompt_ids, prompt_mask, rng,
     stays STAGE-LOCAL (sharded over 'pp' on the layer-chunk dim); each
     token costs one pass over the stage ring (pp.py
     pp_forward_with_cache) — no full-prefix recompute."""
-    from torchacc_tpu.models.transformer import head_logits
+    from torchacc_tpu.models.transformer import embed_ids, head_logits
     from torchacc_tpu.parallel.pp import pp_forward_with_cache
 
     b, p = prompt_ids.shape
@@ -381,13 +373,13 @@ def _generate_cached_pp(cfg, params, prompt_ids, prompt_mask, rng,
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(p), (b, p))
 
-    x = _zoo_embed(cfg, params, prompt_ids, positions)
+    x = embed_ids(cfg, params, prompt_ids, positions)
     y, cache = pp_forward_with_cache(
         blk_pre, params["layers"], None, x, positions, seg, cfg.pp_size)
     logits = head_logits(cfg, params, y)
 
     def step_fn(cache, tok, positions1):
-        x1 = _zoo_embed(cfg, params, tok[:, None], positions1)
+        x1 = embed_ids(cfg, params, tok[:, None], positions1)
         y1, cache = pp_forward_with_cache(
             blk_dec, params["layers"], cache, x1, positions1, None,
             cfg.pp_size)
@@ -432,7 +424,7 @@ def _generate_cached_pattern(cfg, params, prompt_ids, prompt_mask, rng,
                              temperature, max_new, eos_id, top_k, top_p):
     """KV-cache decode for layer_pattern models: same scaffold as the
     other cached paths, with the per-layer pattern loop as forward."""
-    from torchacc_tpu.models.transformer import head_logits
+    from torchacc_tpu.models.transformer import embed_ids, head_logits
 
     b, p = prompt_ids.shape
     total = p + max_new
@@ -443,13 +435,13 @@ def _generate_cached_pattern(cfg, params, prompt_ids, prompt_mask, rng,
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(p), (b, p))
 
-    x = _zoo_embed(cfg, params, prompt_ids, positions)
+    x = embed_ids(cfg, params, prompt_ids, positions)
     y, cache = _pattern_layers_with_cache(
         blk_pre, params["layers"], None, x, positions, seg)
     logits = head_logits(cfg, params, y)
 
     def step_fn(cache, tok, positions1):
-        x1 = _zoo_embed(cfg, params, tok[:, None], positions1)
+        x1 = embed_ids(cfg, params, tok[:, None], positions1)
         y1, cache = _pattern_layers_with_cache(
             blk_dec, params["layers"], cache, x1, positions1, None)
         return head_logits(cfg, params, y1)[:, 0], cache

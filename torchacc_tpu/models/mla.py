@@ -27,42 +27,29 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-
-def _dense(cfg, x, kernel):
-    return jnp.einsum("bth,h...->bt...", x.astype(cfg.dtype),
-                      kernel.astype(cfg.dtype))
-
-
-def _norm(cfg, scale_tree, x):
-    from torchacc_tpu.models.transformer import Norm
-    return Norm(cfg).apply({"params": scale_tree}, x)
-
-
-def _rope_one(cfg, x, positions):
-    """Rotary embedding of ``x`` [b, s, heads, rope_dim]."""
-    from torchacc_tpu.models.transformer import _rope
-    return _rope(x, x, positions, cfg)[0]
+from torchacc_tpu.models.block import _rope, tree_norm, tree_proj
 
 
 def project_q(cfg, attn, h, positions):
     """``(q_nope [b, s, H, nope], q_pe [b, s, H, rope])``, q_pe rotated."""
+    proj = tree_proj(cfg, attn)
     if cfg.q_lora_rank:
-        c_q = _norm(cfg, attn["q_a_norm"],
-                    _dense(cfg, h, attn["q_a_proj"]["kernel"]))
-        q = _dense(cfg, c_q, attn["q_b_proj"]["kernel"])
+        c_q = tree_norm(cfg, attn)("q_a_norm", proj("q_a_proj", h))
+        q = proj("q_b_proj", c_q)
     else:
-        q = _dense(cfg, h, attn["q_proj"]["kernel"])
+        q = proj("q_proj", h)
     q_nope, q_pe = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
-    return q_nope, _rope_one(cfg, q_pe, positions)
+    return q_nope, _rope(q_pe, q_pe, positions, cfg)[0]
 
 
 def project_latent(cfg, attn, h, positions):
     """``(c_kv [b, s, R] normed, k_pe [b, s, rope] rotated)``: the row a
     latent cache banks is their concatenation."""
-    ckv = _dense(cfg, h, attn["kv_a_proj"]["kernel"])
+    ckv = tree_proj(cfg, attn)("kv_a_proj", h)
     c_kv, k_pe = jnp.split(ckv, [cfg.kv_lora_rank], axis=-1)
-    c_kv = _norm(cfg, attn["kv_a_norm"], c_kv)
-    return c_kv, _rope_one(cfg, k_pe[:, :, None, :], positions)[:, :, 0]
+    c_kv = tree_norm(cfg, attn)("kv_a_norm", c_kv)
+    k_pe = k_pe[:, :, None, :]
+    return c_kv, _rope(k_pe, k_pe, positions, cfg)[0][:, :, 0]
 
 
 def absorb_q(cfg, attn, q_nope):

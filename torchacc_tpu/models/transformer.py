@@ -23,6 +23,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from torchacc_tpu.models import block
+from torchacc_tpu.models.block import Norm
 from torchacc_tpu.ops.attn import attention
 
 
@@ -354,149 +356,6 @@ def scale_hidden(cfg: "ModelConfig", xn: jax.Array) -> jax.Array:
     return xn * jnp.asarray(cfg.logit_scale, xn.dtype)
 
 
-def _rope(q: jax.Array, k: jax.Array, positions: jax.Array,
-          cfg: "ModelConfig") -> Tuple[jax.Array, jax.Array]:
-    """Rotary embeddings, llama convention (half-split, not interleaved —
-    matches HF transformers so converted weights agree).
-
-    Scaling variants (all from the per-layer cfg, so gemma3's dual-base
-    pattern composes):
-
-    - ``rope_llama3`` — Llama-3.1 frequency banding: long wavelengths
-      divide by ``factor``, short ones stay, the band between
-      interpolates smoothly.  Every 3.1+ release ships this.
-    - ``rope_longrope`` — Phi-3.5/4: per-dim inv_freq divisors with the
-      LONG set activating once any position exceeds the original
-      context (a traced switch: both static sets are built, jnp.where
-      selects), and cos/sin scaled by the attention factor.  The
-      ``jnp.max(positions)`` is a reduction that can lower to a small
-      collective when positions are sharded (cp) — measured harmless
-      (compiles+runs under pp×dp, 1f1b and cp-ring;
-      test_longrope_composes_with_parallelism) and CSE dedupes it in
-      the unrolled-layer path; revisit only if a partitioner change
-      breaks that test.
-    - ``partial_rotary`` < 1 — only the first ``d * partial`` head dims
-      rotate (phi-4-mini: 0.75); the rest pass through.
-    """
-    import math as _math
-
-    d = q.shape[-1]
-    rot_d = int(d * cfg.partial_rotary)
-    theta = cfg.rope_theta
-    freqs = 1.0 / (theta ** (jnp.arange(0, rot_d, 2, dtype=jnp.float32)
-                             / rot_d))
-    scale = jnp.float32(1.0)
-    if cfg.rope_llama3 is not None:
-        factor, lo, hi, old_len = cfg.rope_llama3
-        wavelen = 2.0 * _math.pi / freqs
-        low_wl, high_wl = old_len / lo, old_len / hi
-        smooth = (old_len / wavelen - lo) / (hi - lo)
-        scaled = jnp.where(wavelen > low_wl, freqs / factor, freqs)
-        smoothed = ((1.0 - smooth) / factor + smooth) * freqs
-        freqs = jnp.where((wavelen >= high_wl) & (wavelen <= low_wl),
-                          smoothed, scaled)
-    if cfg.rope_yarn is not None:
-        # YaRN NTK-by-parts (HF _compute_yarn_parameters): interpolate
-        # per-dim between the original freqs (short wavelengths) and
-        # position-interpolated freqs (long), with a linear ramp
-        # between the beta_fast/beta_slow correction dims
-        factor, old_len, bfast, bslow, attn_f, truncate = cfg.rope_yarn
-
-        def corr_dim(beta):
-            return (rot_d * _math.log(old_len / (beta * 2 * _math.pi))
-                    / (2 * _math.log(theta)))
-
-        low, high = corr_dim(bfast), corr_dim(bslow)
-        if truncate:
-            low, high = _math.floor(low), _math.ceil(high)
-        low, high = max(low, 0), min(high, rot_d - 1)
-        if low == high:
-            high += 0.001  # HF's singularity guard
-        ramp = jnp.clip(
-            (jnp.arange(rot_d // 2, dtype=jnp.float32) - low)
-            / (high - low), 0.0, 1.0)
-        mask = 1.0 - ramp                       # 1 = keep original
-        freqs = (freqs / factor) * (1.0 - mask) + freqs * mask
-        if attn_f is None:
-            attn_f = (1.0 if factor <= 1.0
-                      else 0.1 * _math.log(factor) + 1.0)
-        scale = jnp.float32(attn_f)
-    if cfg.rope_longrope is not None:
-        short_f, long_f, old_len, attn_f = cfg.rope_longrope
-        short = freqs / jnp.asarray(short_f, jnp.float32)
-        long = freqs / jnp.asarray(long_f, jnp.float32)
-        # HF switches factor sets when the sequence grows past the
-        # original context; positions are traced, so build both static
-        # sets and select (one jnp.where, no retrace)
-        is_long = jnp.max(positions) + 1 > old_len
-        freqs = jnp.where(is_long, long, short)
-        if attn_f is None:
-            s = cfg.max_seq_len / old_len
-            attn_f = (1.0 if s <= 1.0
-                      else _math.sqrt(1.0 + _math.log(s)
-                                      / _math.log(old_len)))
-        scale = jnp.float32(attn_f)
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [b,s,rd/2]
-    cos = (jnp.cos(angles) * scale)[:, :, None, :]
-    sin = (jnp.sin(angles) * scale)[:, :, None, :]
-
-    def rot(x):
-        xf = x.astype(jnp.float32)
-        xr, xp = xf[..., :rot_d], xf[..., rot_d:]
-        if cfg.rope_interleaved:
-            # cohere: dims pair as (even, odd) instead of llama's half
-            # split; rotate each pair and restore the interleaving
-            x1, x2 = xr[..., 0::2], xr[..., 1::2]
-            out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                            axis=-1).reshape(xr.shape)
-        else:
-            x1, x2 = jnp.split(xr, 2, axis=-1)
-            out = jnp.concatenate(
-                [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-        if rot_d < d:
-            out = jnp.concatenate([out, xp], axis=-1)
-        return out.astype(x.dtype)
-
-    return rot(q), rot(k)
-
-
-class Norm(nn.Module):
-    cfg: ModelConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.cfg
-        xf = x.astype(jnp.float32)
-        if cfg.norm in ("rmsnorm", "rmsnorm1p"):
-            one_p = cfg.norm == "rmsnorm1p"
-            # Gemma convention: weight stored as w, effective scale 1 + w,
-            # zero-initialised (HF GemmaRMSNorm)
-            scale = self.param(
-                "scale",
-                nn.initializers.zeros if one_p else nn.initializers.ones,
-                (x.shape[-1],), cfg.param_dtype)
-            y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
-                                   + cfg.norm_eps)
-            sf = scale.astype(jnp.float32)
-            if one_p:
-                sf = 1.0 + sf
-            return (y * sf).astype(cfg.dtype)
-        one_p = cfg.norm == "layernorm1p"   # nemotron: stored w, scale 1+w
-        scale = self.param(
-            "scale", nn.initializers.zeros if one_p else nn.initializers.ones,
-            (x.shape[-1],), cfg.param_dtype)
-        mean = jnp.mean(xf, axis=-1, keepdims=True)
-        var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
-        y = (xf - mean) * jax.lax.rsqrt(var + cfg.norm_eps)
-        sf = scale.astype(jnp.float32)
-        y = y * (1.0 + sf if one_p else sf)
-        if cfg.norm_bias:   # cohere's LayerNorm carries no bias
-            bias = self.param("bias", nn.initializers.zeros,
-                              (x.shape[-1],), cfg.param_dtype)
-            y = y + bias.astype(jnp.float32)
-        return y.astype(cfg.dtype)
-
-
 def alibi_slopes(num_heads: int) -> Tuple[float, ...]:
     """Standard ALiBi per-head slopes (geometric 2^(-8i/n) with the
     paper's interpolation for non-power-of-two head counts) — the same
@@ -544,6 +403,33 @@ def _quant_dense(cfg: "ModelConfig", name, features, axis, use_bias):
         amax_history_len=cfg.quant_amax_history_len)
 
 
+def _site_proj(cfg: "ModelConfig", site: str, sites):
+    """The block's ``proj`` verb (models/block.py) for a Flax module:
+    ``sites`` maps a parameter's name to ``(features, axis, use_bias)``;
+    the verb creates the dense of that name and applies it — the
+    quantized one where dense ``site`` runs quantized."""
+    def proj(name, t):
+        features, axis, use_bias = sites[name]
+        if quant_site_on(cfg, site):
+            return _quant_dense(cfg, name, features, axis, use_bias)(t)
+        return nn.DenseGeneral(
+            features=features, axis=axis, use_bias=use_bias, name=name,
+            dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            kernel_init=nn.initializers.normal(0.02))(t)
+    return proj
+
+
+def _layout_hint(cfg: "ModelConfig"):
+    """The block's ``hint`` verb for training: the megatron TP
+    activation layout under the config's logical-axis rules."""
+    from torchacc_tpu.parallel.sharding import (
+        DEFAULT_RULES,
+        activation_constraint,
+    )
+    rules = cfg.logical_axis_rules or DEFAULT_RULES
+    return lambda t, axes: activation_constraint(t, axes, rules)
+
+
 class Attention(nn.Module):
     cfg: ModelConfig
 
@@ -551,52 +437,15 @@ class Attention(nn.Module):
     def __call__(self, x, positions, segment_ids=None, dropout_seed=None):
         cfg = self.cfg
         d = cfg.head_size
-        if quant_site_on(cfg, "attn"):
-            dense = lambda name, heads: _quant_dense(
-                cfg, name, (heads, d), -1, cfg.qkv_bias)
-        else:
-            dense = lambda name, heads: nn.DenseGeneral(
-                features=(heads, d), use_bias=cfg.qkv_bias, name=name,
-                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                kernel_init=nn.initializers.normal(0.02))
-        from torchacc_tpu.parallel.sharding import (
-            DEFAULT_RULES,
-            activation_constraint,
-        )
-        rules = cfg.logical_axis_rules or DEFAULT_RULES
-        q = dense("q_proj", cfg.num_heads)(x)
-        k = dense("k_proj", cfg.kv_heads)(x)
-        v = dense("v_proj", cfg.kv_heads)(x)
-        # megatron TP activation layout: heads sharded on 'tp'
-        q = activation_constraint(q, ("batch", "seq", "heads", None), rules)
-        k = activation_constraint(k, ("batch", "seq", "heads", None), rules)
-        v = activation_constraint(v, ("batch", "seq", "heads", None), rules)
-        if cfg.qk_norm:
-            if cfg.qk_norm_proj:
-                # OLMo2: RMSNorm over the FLAT projection (heads*d
-                # jointly, scale of nh*d / nk*d) before the head split's
-                # rope — HF Olmo2Attention norms the projection output
-                bq, sq_ = q.shape[:2]
-                q = Norm(cfg, name="q_norm")(
-                    q.reshape(bq, sq_, -1)).reshape(q.shape)
-                k = Norm(cfg, name="k_norm")(
-                    k.reshape(bq, sq_, -1)).reshape(k.shape)
-            else:
-                # Gemma3/Qwen3: per-head-dim RMSNorm on q and k after
-                # projection, BEFORE rope (HF q_norm/k_norm)
-                q = Norm(cfg, name="q_norm")(q)
-                k = Norm(cfg, name="k_norm")(k)
-        if cfg.pos_emb == "rope":
-            rp = (positions.astype(jnp.float32) / cfg.rope_scale
-                  if cfg.rope_scale != 1.0 else positions)
-            q, k = _rope(q, k, rp, cfg)
-        # names for the selective-remat policies (utils/remat.py): saving
-        # post-rope q/k/v means the backward recomputes only the cheap
-        # norms/elementwise ops, never the projections or the rope
-        from jax.ad_checkpoint import checkpoint_name
-        q = checkpoint_name(q, "qkv_proj")
-        k = checkpoint_name(k, "qkv_proj")
-        v = checkpoint_name(v, "qkv_proj")
+        proj = _site_proj(cfg, "attn", {
+            "q_proj": ((cfg.num_heads, d), -1, cfg.qkv_bias),
+            "k_proj": ((cfg.kv_heads, d), -1, cfg.qkv_bias),
+            "v_proj": ((cfg.kv_heads, d), -1, cfg.qkv_bias),
+            "o_proj": (cfg.hidden_size, (-2, -1), cfg.o_bias)})
+        hint = _layout_hint(cfg)
+        q, k, v = block.qkv(
+            cfg, x, positions, proj,
+            norm=lambda name, t: Norm(cfg, name=name)(t), hint=hint)
         slopes = (jnp.asarray(alibi_slopes(cfg.num_heads), jnp.float32)
                   if cfg.pos_emb == "alibi" else None)
 
@@ -623,10 +472,8 @@ class Attention(nn.Module):
                 if cfg.context_parallel:
                     # keep the slot dim sp-sharded through the decode
                     # scan (see the prefill-side constraint below)
-                    ck.value = activation_constraint(
-                        ck.value, ("batch", "seq", None, None), rules)
-                    cv.value = activation_constraint(
-                        cv.value, ("batch", "seq", None, None), rules)
+                    ck.value = hint(ck.value, ("batch", "seq", None, None))
+                    cv.value = hint(cv.value, ("batch", "seq", None, None))
                 cidx.value = pos + s
                 # ragged (left-padded) prompts: prefill banked per-slot
                 # validity in the 'seg' cache; decode-appended tokens are
@@ -656,12 +503,7 @@ class Attention(nn.Module):
                     q_segment_ids=qseg, kv_segment_ids=kvseg,
                     q_offset=pos - (kv_len - s),
                     logit_softcap=cfg.attn_logit_softcap)
-                return nn.DenseGeneral(
-                    features=cfg.hidden_size, axis=(-2, -1),
-                    use_bias=cfg.o_bias,
-                    name="o_proj", dtype=cfg.dtype,
-                    param_dtype=cfg.param_dtype,
-                    kernel_init=nn.initializers.normal(0.02))(out)
+                return proj("o_proj", out)   # cfg.decode: never quantized
             # prefill: bank the prompt's (rotated) k / v, then fall
             # through to the normal attention below
             ck.value = jax.lax.dynamic_update_slice(
@@ -674,10 +516,8 @@ class Attention(nn.Module):
                 # cache_len/sp — the point of cp decode.  Decode's
                 # single-token DUS and the partial-softmax attention
                 # over the sharded slots are GSPMD-handled.
-                ck.value = activation_constraint(
-                    ck.value, ("batch", "seq", None, None), rules)
-                cv.value = activation_constraint(
-                    cv.value, ("batch", "seq", None, None), rules)
+                ck.value = hint(ck.value, ("batch", "seq", None, None))
+                cv.value = hint(cv.value, ("batch", "seq", None, None))
             cidx.value = jnp.asarray(s, jnp.int32)
             if segment_ids is not None:
                 # ragged (left-padded) prompts: bank per-slot validity so
@@ -716,17 +556,7 @@ class Attention(nn.Module):
                             dropout_seed=seed,
                             impl=cfg.attention_impl,
                             logit_softcap=cfg.attn_logit_softcap)
-        if quant_site_on(cfg, "attn"):
-            out = _quant_dense(cfg, "o_proj", cfg.hidden_size, (-2, -1),
-                               cfg.o_bias)(out)
-        else:
-            out = nn.DenseGeneral(
-                features=cfg.hidden_size, axis=(-2, -1),
-                use_bias=cfg.o_bias,
-                name="o_proj", dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype,
-                kernel_init=nn.initializers.normal(0.02))(out)
-        return out
+        return proj("o_proj", out)
 
 
 class Mlp(nn.Module):
@@ -735,44 +565,11 @@ class Mlp(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        if quant_site_on(cfg, "mlp"):
-            dense = lambda name, feat: _quant_dense(
-                cfg, name, feat, -1, cfg.mlp_bias)
-        else:
-            dense = lambda name, feat: nn.Dense(
-                feat, use_bias=cfg.mlp_bias, name=name, dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype,
-                kernel_init=nn.initializers.normal(0.02))
-        from torchacc_tpu.parallel.sharding import (
-            DEFAULT_RULES,
-            activation_constraint,
-        )
-        from jax.ad_checkpoint import checkpoint_name
-        if cfg.activation in ("swiglu", "geglu"):
-            # named so 'save_attn_mlp' can save the ffn-width projections
-            # (recompute becomes elementwise-only) while 'save_attn' leaves
-            # them unsaved — they are the dominant activation cost
-            gate = checkpoint_name(dense("gate_proj", cfg.ffn_size)(x),
-                                   "mlp_gate_up")
-            up = checkpoint_name(dense("up_proj", cfg.ffn_size)(x),
-                                 "mlp_gate_up")
-            # geglu = Gemma's gelu_pytorch_tanh gate (nn.gelu default is
-            # the tanh approximation)
-            act = nn.silu if cfg.activation == "swiglu" else nn.gelu
-            h = act(gate) * up
-        else:
-            up = checkpoint_name(dense("up_proj", cfg.ffn_size)(x),
-                                 "mlp_gate_up")
-            if cfg.activation == "relu2":   # nemotron: square(relu(x))
-                h = jnp.square(nn.relu(up))
-            elif cfg.activation == "gelu_exact":   # gpt-neox erf gelu
-                h = nn.gelu(up, approximate=False)
-            else:
-                h = nn.gelu(up)
-        # megatron TP: ffn hidden sharded on 'tp' (column-parallel out)
-        h = activation_constraint(h, ("batch", "seq", "mlp"),
-                                  cfg.logical_axis_rules or DEFAULT_RULES)
-        return dense("down_proj", cfg.hidden_size)(h)
+        proj = _site_proj(cfg, "mlp", {
+            name: (features, -1, cfg.mlp_bias) for name, features in (
+                ("gate_proj", cfg.ffn_size), ("up_proj", cfg.ffn_size),
+                ("down_proj", cfg.hidden_size))})
+        return block.mlp(cfg, x, proj, hint=_layout_hint(cfg))
 
 
 def _sub_remat(cfg: ModelConfig) -> bool:
@@ -791,7 +588,6 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, segment_ids=None, dropout_seed=None):
-        from jax.ad_checkpoint import checkpoint_name
         cfg = self.cfg
         attn_cls, mlp_cls = Attention, Mlp
         if cfg.kv_lora_rank:
@@ -807,52 +603,13 @@ class Block(nn.Module):
                 attn_cls = nn.remat(attn_cls, policy=pol, prevent_cse=False)
             if mlp_cls.__name__ in cfg.remat_cls or "Mlp" in cfg.remat_cls:
                 mlp_cls = nn.remat(mlp_cls, policy=pol, prevent_cse=False)
-        post = cfg.norm_placement == "post"
-        if post and cfg.sandwich_norms:
-            raise ValueError("norm_placement='post' (OLMo2) does not "
-                             "compose with sandwich_norms (gemma2)")
-        if cfg.norm_placement not in ("pre", "post"):
-            raise ValueError(f"norm_placement must be 'pre' | 'post', "
-                             f"got {cfg.norm_placement!r}")
-        if cfg.parallel_block:
-            # phi-2: both sublayers read ONE shared pre-norm and the
-            # residual adds them together; no ln2 exists
-            if post or cfg.sandwich_norms:
-                raise ValueError("parallel_block (phi) does not compose "
-                                 "with norm_placement='post' or "
-                                 "sandwich_norms")
-            n = Norm(cfg, name="ln1")(x)
-            attn_out = attn_cls(cfg, name="attn")(
-                n, positions, segment_ids, dropout_seed)
-            n_mlp = (n if cfg.parallel_block_shared_norm
-                     else Norm(cfg, name="ln2")(x))   # gpt-neox
-            mlp_out = mlp_cls(
-                cfg, name="moe" if cfg.num_experts > 0 else "mlp")(n_mlp)
-            return (x + checkpoint_name(attn_out, "attn_out")
-                    + checkpoint_name(mlp_out, "mlp_out"))
-        attn_out = attn_cls(cfg, name="attn")(
-            x if post else Norm(cfg, name="ln1")(x),
-            positions, segment_ids, dropout_seed)
-        if cfg.sandwich_norms:
-            # Gemma2: post-attention norm before the residual add
-            attn_out = Norm(cfg, name="ln1_post")(attn_out)
-        if post:
-            # OLMo2: the sublayer OUTPUT is normed (no pre-norm at all)
-            attn_out = Norm(cfg, name="ln1")(attn_out)
-        # names referenced by the 'offload_dots' remat policy (utils/remat.py)
-        h = x + checkpoint_name(attn_out, "attn_out")
-        # the grouped expert layer routes in float32: its norm hands it
-        # float32 (a bf16-rounded router input flips near-tied experts)
-        ln2_cfg = (dataclasses.replace(cfg, dtype=jnp.float32)
-                   if cfg.num_experts > 0 and cfg.moe_dispatch == "grouped"
-                   else cfg)
-        mlp_out = mlp_cls(cfg, name="moe" if cfg.num_experts > 0 else "mlp")(
-            h if post else Norm(ln2_cfg, name="ln2")(h))
-        if cfg.sandwich_norms:
-            mlp_out = Norm(cfg, name="ln2_post")(mlp_out)
-        if post:
-            mlp_out = Norm(cfg, name="ln2")(mlp_out)
-        return h + checkpoint_name(mlp_out, "mlp_out")
+        return block.block(
+            cfg, x,
+            norm=lambda name, t, cfg=cfg: Norm(cfg, name=name)(t),
+            attention=lambda h: attn_cls(cfg, name="attn")(
+                h, positions, segment_ids, dropout_seed),
+            ffn=lambda h: mlp_cls(
+                cfg, name="moe" if cfg.num_experts > 0 else "mlp")(h))
 
 
 class ScanBlock(nn.Module):
@@ -1420,6 +1177,16 @@ def _embed_extras(cfg: ModelConfig, x: jax.Array, positions: jax.Array,
     if cfg.pos_emb == "learned":
         x = x + pos_table.astype(cfg.dtype)[positions]
     return x
+
+
+def embed_ids(cfg: ModelConfig, params, ids: jax.Array,
+              positions: jax.Array) -> jax.Array:
+    """Shared raw-params embedding front end, :func:`head_logits`'
+    counterpart: table lookup in the compute dtype, then
+    :func:`_embed_extras`."""
+    emb = params["embed_tokens"]["embedding"]
+    return _embed_extras(cfg, emb[ids].astype(cfg.dtype), positions,
+                         params.get("pos_embed"))
 
 
 def pattern_cfg(cfg: ModelConfig, i: int) -> ModelConfig:

@@ -68,6 +68,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from torchacc_tpu.config import ConfigError
+from torchacc_tpu.models import block
+from torchacc_tpu.models.transformer import embed_ids, head_logits
 from torchacc_tpu.obs import tracing
 from torchacc_tpu.ops._common import on_tpu
 from torchacc_tpu.ops.paged_attention import (
@@ -87,14 +89,17 @@ from torchacc_tpu.utils.logger import logger
 from torchacc_tpu.utils.metrics import counters
 
 
-# every ModelConfig field the paged forward (_layer/_forward) has been
-# audited against — the rejection below is effectively an ALLOWLIST: a
-# field added to ModelConfig after this audit raises at engine
-# construction instead of being silently ignored by the re-implemented
-# layer forward (which would decode tokens that diverge from
-# generate() with no error).  When auditing a new field, either handle
-# it in _layer/_forward, add it to the denylist checks, or confirm it
-# cannot affect decode numerics — then add it here.
+# every ModelConfig field serving has been audited against — the
+# rejection below is effectively an ALLOWLIST.  The block's arithmetic is
+# the model's own (models/block.py): a field it reads serves as it
+# trains.  What stays serving's own, and what this list guards, is the
+# attention core over the paged cache (_attend/_attend_latent: scale,
+# window, softcap, alibi), the cache row (serve/kv_cache.py) and the
+# layer loop (_forward: stacks, patterns, pipeline): a field added to
+# ModelConfig after this audit raises at engine construction instead of
+# being silently ignored there (decoding tokens that diverge from
+# generate() with no error).  If those three would have to read a new
+# field, handle it there or in the denylist checks; then add it here.
 _AUDITED_MODEL_FIELDS = frozenset({
     "activation", "attention_impl", "attn_dropout", "attn_logit_softcap",
     "cache_len", "context_parallel", "decode", "dtype", "embed_scale",
@@ -113,9 +118,9 @@ _AUDITED_MODEL_FIELDS = frozenset({
     "tie_embeddings", "tp_vocab_head", "vocab_size", "window",
     # PR-7 audit: quant* select TRAIN-forward matmul execution only —
     # the param layout is unchanged and inference runs in the compute
-    # dtype (generate() strips quant; PagedDecoder's hand-written
-    # layer never quantizes), so a quant-trained model serves exactly
-    # like its unquantized twin.  overlap_fsdp only reshapes the train
+    # dtype (generate() strips quant; block.tree_proj never
+    # quantizes), so a quant-trained model serves exactly like its
+    # unquantized twin.  overlap_fsdp only reshapes the train
     # layer loop (scan vs unrolled prefetch); PagedDecoder owns its
     # own loop and never consults it.
     "quant", "quant_sites", "quant_amax_history_len", "quant_impl",
@@ -133,20 +138,20 @@ _AUDITED_MODEL_FIELDS = frozenset({
 
 
 def _check_supported(cfg) -> None:
-    """The serving surface: standard dense pre-norm decoders (the
-    llama/qwen/gpt2/gemma-dense families), and pre-norm latent-attention
-    decoders whose expert layers are the dropless held-expert layer
-    (moe_dispatch='grouped').  Everything else raises a typed error here
-    instead of decoding garbage."""
-    import dataclasses
+    """The serving surface: any dense decoder block TransformerLM
+    trains (models/block.py is the one definition of both), and
+    latent-attention decoders whose expert layers are the dropless
+    held-expert layer (moe_dispatch='grouped') — minus what the paged
+    cache, its kernel or the layer loop cannot hold, which raises a
+    typed error here instead of decoding garbage."""
     unknown = ({f.name for f in dataclasses.fields(cfg)}
                - _AUDITED_MODEL_FIELDS)
     if unknown:
         raise NotImplementedError(
             f"ModelConfig grew fields the serving forward has not been "
             f"audited against: {sorted(unknown)}.  Audit their effect "
-            f"on PagedDecoder._layer/_forward (scheduler.py) and add "
-            f"them to _AUDITED_MODEL_FIELDS.")
+            f"on PagedDecoder's attention core, cache and layer loop "
+            f"(scheduler.py) and add them to _AUDITED_MODEL_FIELDS.")
     bad = []
     if cfg.num_experts > 0 and cfg.moe_dispatch != "grouped":
         bad.append("MoE outside moe_dispatch='grouped' (the dense and "
@@ -166,12 +171,6 @@ def _check_supported(cfg) -> None:
         bad.append("context parallelism")
     if cfg.layer_pattern:
         bad.append("layer_pattern (per-layer sliding windows)")
-    if cfg.parallel_block:
-        bad.append("parallel_block")
-    if cfg.sandwich_norms:
-        bad.append("sandwich_norms")
-    if cfg.norm_placement != "pre":
-        bad.append(f"norm_placement={cfg.norm_placement!r}")
     if cfg.pos_emb == "alibi":
         bad.append("pos_emb='alibi'")
     if tuple(cfg.window) != (-1, -1):
@@ -198,10 +197,10 @@ _EXPERT_STACKS = ("experts/gate", "experts/up", "experts/down")
 
 
 class PagedDecoder:
-    """The jitted device steps: a raw-params transformer forward over
-    the paged pool (the established raw-params idiom of
-    models/generate.py `_zoo_embed` / `head_logits`, numerically
-    matched to the module's own apply)."""
+    """The jitted device steps: the model's forward on raw params over
+    the paged pool — ``embed_ids`` / ``head_logits`` and the block of
+    models/block.py, the definitions the module's own apply runs, with
+    the attention core and the layer loop that are serving's own."""
 
     def __init__(self, cfg, serve_cfg, attention_impl: Optional[str] = None):
         _check_supported(cfg)
@@ -264,68 +263,73 @@ class PagedDecoder:
 
     # -- model forward ------------------------------------------------------
 
-    def _dense(self, x, kernel, bias=None):
-        cfg = self.cfg
-        y = jnp.einsum("bth,h...->bt...", x.astype(cfg.dtype),
-                       kernel.astype(cfg.dtype))
-        if bias is not None:
-            y = y + bias.astype(cfg.dtype)
-        return y
-
     def _layer(self, p, layer, x, pools, positions, tables, ctx_lens, blk,
                off, valid=None, expert_stacks=None):
-        """Decoder layer ``layer`` over the paged cache, by the kinds of
-        its two halves: the attention is grouped-query over a k and a v
-        pool or latent over one pool (``cfg.kv_lora_rank``), the
-        feed-forward a dense MLP or the held-expert layer (the layer's
-        tree holds ``mlp`` or ``moe``).  ``pools`` are the whole stacks;
-        ``blk``/``off`` [S, T] name the pool slot every token writes its
-        row to (the null block for masked tokens); ``ctx_lens`` is the
-        post-write context length per slot; ``valid`` [S, T] marks the
-        real tokens (the expert layer routes no others);
-        ``expert_stacks`` the expert kernels of ALL expert layers
-        (:meth:`_forward` keeps them off the scan), read by the grouped
-        matmul at this layer's index among them.  Returns
-        ``(x, pools, load)``, ``load`` the expert layer's counts or
-        None."""
-        from torchacc_tpu.models.transformer import Norm
+        """Decoder layer ``layer`` over the paged cache: the model's own
+        block (models/block.py) on this layer's raw tree ``p``, with the
+        two halves that are serving's own.  The attention is
+        grouped-query over a k and a v pool or latent over one pool
+        (``cfg.kv_lora_rank``), the feed-forward the model's MLP or the
+        held-expert layer (the layer's tree holds ``mlp`` or ``moe``).
+        ``pools`` are the whole stacks; ``blk``/``off`` [S, T] name the
+        pool slot every token writes its row to (the null block for
+        masked tokens); ``ctx_lens`` is the post-write context length
+        per slot; ``valid`` [S, T] marks the real tokens (the expert
+        layer routes no others); ``expert_stacks`` the expert kernels of
+        ALL expert layers (:meth:`_forward` keeps them off the scan),
+        read by the grouped matmul at this layer's index among them.
+        Returns ``(x, pools, load)``, ``load`` the expert layer's counts
+        or None."""
+        cfg = self.cfg
+        if cfg.num_experts and "moe" not in p:
+            # a leading dense layer of an expert model: TransformerLM
+            # gives that stack's blocks this config too
+            cfg = dataclasses.replace(cfg, num_experts=0)
+        attend = self._attend_latent if cfg.kv_lora_rank else self._attend
+        # what the halves leave besides their output: the updated pools,
+        # the expert layer's counts (all traced in this layer's own trace)
+        left = {"load": None}
 
         # the named scopes are registered device scopes (obs/tracing.py
         # DEVICE_SCOPES): a profiler trace reads each part's device
         # time under the same names the training step's modules carry
-        cfg = self.cfg
-        with jax.named_scope("ln1"):
-            h = Norm(cfg).apply({"params": p["ln1"]}, x)
-        attend = self._attend_latent if cfg.kv_lora_rank else self._attend
-        x, pools = attend(p["attn"], layer, x, h, pools, positions, tables,
-                          ctx_lens, blk, off)
-        # an expert layer's router reads the norm's float32 (Block's too)
-        ln2_cfg = (dataclasses.replace(cfg, dtype=jnp.float32)
-                   if "moe" in p else cfg)
-        with jax.named_scope("ln2"):
-            h2 = Norm(ln2_cfg).apply({"params": p["ln2"]}, x)
-        if "moe" in p:
+        def norm(name, t, cfg=cfg):
+            with jax.named_scope(name):
+                return block.tree_norm(cfg, p)(name, t)
+
+        def attention(h):
+            out, left["pools"] = attend(p["attn"], layer, h, pools,
+                                        positions, tables, ctx_lens, blk, off)
+            return out
+
+        def ffn(h2):
+            if not cfg.num_experts:
+                with jax.named_scope("mlp"):
+                    return block.mlp(cfg, h2, block.tree_proj(cfg, p["mlp"]))
             from torchacc_tpu.models.moe import moe_ffn
             s_, t_, hd = h2.shape
-            y, _, _, load = moe_ffn(
+            y, _, _, left["load"] = moe_ffn(
                 cfg, {**p["moe"], **expert_stacks},
                 h2.reshape(s_ * t_, hd),
                 None if valid is None else valid.reshape(-1),
                 layer=layer - cfg.first_dense_layers)
-            return x + y.reshape(s_, t_, hd), pools, load
-        with jax.named_scope("mlp"):
-            x = x + self._mlp(p["mlp"], h2)
-        return x, pools, None
+            return y.reshape(s_, t_, hd)
 
-    def _attend(self, attn, layer, x, h, pools, positions, tables, ctx_lens,
+        x = block.block(cfg, x, norm, attention, ffn)
+        return x, left["pools"], left["load"]
+
+    def _attend(self, attn, layer, h, pools, positions, tables, ctx_lens,
                 blk, off):
-        """Grouped-query attention over the k and v pools
-        [L, NB, BS, KH*D], residual added."""
+        """Grouped-query attention of the normed ``h`` over the k and v
+        pools [L, NB, BS, KH*D]: ``(output before the residual,
+        pools)``."""
         cfg = self.cfg
         kp, vp = pools
-        s_, t_ = x.shape[:2]
+        s_, t_ = h.shape[:2]
+        proj = block.tree_proj(cfg, attn)
         with jax.named_scope("qkv"):
-            q, k, v = self._qkv(attn, h, positions)
+            q, k, v = block.qkv(cfg, h, positions, proj,
+                                block.tree_norm(cfg, attn))
         # bank this chunk's (rotated) k / raw v into the pool, THEN
         # attend over the updated pool — same write-before-read order
         # as the module's dense-cache decode branch.  One scatter per
@@ -343,13 +347,9 @@ class PagedDecoder:
                 scale=cfg.query_scale, window=cfg.window,
                 logit_softcap=cfg.attn_logit_softcap, impl=self.impl)
         with jax.named_scope("o_proj"):
-            x = x + self._dense(
-                out.reshape(s_, t_, -1),
-                attn["o_proj"]["kernel"].reshape(-1, cfg.hidden_size),
-                attn["o_proj"].get("bias"))
-        return x, (kp, vp)
+            return proj("o_proj", out), (kp, vp)
 
-    def _attend_latent(self, attn, layer, x, h, pools, positions, tables,
+    def _attend_latent(self, attn, layer, h, pools, positions, tables,
                        ctx_lens, blk, off):
         """Latent attention in the absorbed form (models/mla.py) over
         the one pool [L, NB, BS, W]: a token banks the row
@@ -360,7 +360,7 @@ class PagedDecoder:
 
         cfg = self.cfg
         (pool,) = pools
-        s_, t_ = x.shape[:2]
+        s_, t_ = h.shape[:2]
         with jax.named_scope("mla_q"):
             q_nope, q_pe = mla.project_q(cfg, attn, h, positions)
             q_lat = mla.absorb_q(cfg, attn, q_nope)
@@ -378,60 +378,8 @@ class PagedDecoder:
                 positions[:, 0], layer=layer, scale=mla.query_scale(cfg),
                 impl=self.impl)
         with jax.named_scope("o_proj"):
-            x = x + mla.project_out(cfg, attn,
-                                    mla.expand_out(cfg, attn, o_lat))
-        return x, (pool,)
-
-    def _qkv(self, attn, h, positions):
-        """q/k/v projections, qk-norm and rope of one layer."""
-        from torchacc_tpu.models.transformer import Norm, _rope
-
-        cfg = self.cfg
-        s_, t_ = h.shape[:2]
-        q = self._dense(h, attn["q_proj"]["kernel"],
-                        attn["q_proj"].get("bias"))
-        k = self._dense(h, attn["k_proj"]["kernel"],
-                        attn["k_proj"].get("bias"))
-        v = self._dense(h, attn["v_proj"]["kernel"],
-                        attn["v_proj"].get("bias"))
-        if cfg.qk_norm:
-            if cfg.qk_norm_proj:
-                q = Norm(cfg).apply({"params": attn["q_norm"]},
-                                    q.reshape(s_, t_, -1)).reshape(q.shape)
-                k = Norm(cfg).apply({"params": attn["k_norm"]},
-                                    k.reshape(s_, t_, -1)).reshape(k.shape)
-            else:
-                q = Norm(cfg).apply({"params": attn["q_norm"]}, q)
-                k = Norm(cfg).apply({"params": attn["k_norm"]}, k)
-        if cfg.pos_emb == "rope":
-            rp = (positions.astype(jnp.float32) / cfg.rope_scale
-                  if cfg.rope_scale != 1.0 else positions)
-            q, k = _rope(q, k, rp, cfg)
-        return q, k, v
-
-    def _mlp(self, mlp, h2):
-        """The feed-forward block's output (before the residual add)."""
-        import flax.linen as nn
-
-        cfg = self.cfg
-        if cfg.activation in ("swiglu", "geglu"):
-            gate = self._dense(h2, mlp["gate_proj"]["kernel"],
-                               mlp["gate_proj"].get("bias"))
-            up = self._dense(h2, mlp["up_proj"]["kernel"],
-                             mlp["up_proj"].get("bias"))
-            act = nn.silu if cfg.activation == "swiglu" else nn.gelu
-            ff = act(gate) * up
-        else:
-            up = self._dense(h2, mlp["up_proj"]["kernel"],
-                             mlp["up_proj"].get("bias"))
-            if cfg.activation == "relu2":
-                ff = jnp.square(nn.relu(up))
-            elif cfg.activation == "gelu_exact":
-                ff = nn.gelu(up, approximate=False)
-            else:
-                ff = nn.gelu(up)
-        return self._dense(ff, mlp["down_proj"]["kernel"],
-                           mlp["down_proj"].get("bias"))
+            return mla.project_out(
+                cfg, attn, mla.expand_out(cfg, attn, o_lat)), (pool,)
 
     def _forward(self, params, pools, ids, positions, tables, ctx_lens,
                  blk, off, valid):
@@ -458,10 +406,8 @@ class PagedDecoder:
         the layer index counting on; ``load`` is the expert layers'
         counts summed (int32[3], models/moe.held_experts_ffn) or None
         for a model without them."""
-        from torchacc_tpu.models.generate import _zoo_embed
-
         with jax.named_scope("embed"):
-            x = _zoo_embed(self.cfg, params, ids, positions)
+            x = embed_ids(self.cfg, params, ids, positions)
 
         layers, expert_stacks = params["layers"], None
         moe = layers["block"].get("moe")
@@ -548,7 +494,6 @@ class PagedDecoder:
                                        positions, tables, ctx,
                                        blk[:, None], off[:, None],
                                        active[:, None])
-        from torchacc_tpu.models.transformer import head_logits
         with jax.named_scope("head"):
             logits = head_logits(self.cfg, params, x)
         with jax.named_scope("sample"):
@@ -584,7 +529,6 @@ class PagedDecoder:
                                        blk[None], off[None], valid[None])
         if not is_final:
             return pools, None, load
-        from torchacc_tpu.models.transformer import head_logits
         with jax.named_scope("head"):
             logits = head_logits(self.cfg, params, x)
             last = jnp.take_along_axis(
@@ -615,7 +559,6 @@ class PagedDecoder:
         ctx = t0s + n_valids                                     # [PB]
         pools, x, load = self._forward(params, pools, tokens, positions,
                                        table_rows, ctx, blk, off, valid)
-        from torchacc_tpu.models.transformer import head_logits
         with jax.named_scope("head"):
             last = jnp.take_along_axis(
                 x, jnp.maximum(n_valids - 1, 0)[:, None, None], axis=1)
