@@ -382,3 +382,52 @@ def test_latent_serve_program_holds_one_latent_pool(one_chip, for_the_chip,
                           r"(copy|dynamic-slice|dynamic-update-slice)\(",
                           line)]
     assert not moved, moved
+
+
+def test_fused_head_under_fsdp_reduces_its_logits_once(topo, for_the_chip,
+                                                       monkeypatch):
+    """A small fsdp=4 train step, compiled whole: the partitioner shards
+    the head matmul's contraction (hidden) dimension inside the chunk
+    loop, so every pass over a chunk's logits costs an all-reduce of the
+    ``[chunk_rows, vocab]`` float32 block.  The head forms its gradient
+    in the forward loop (ops/fused.py), so there is ONE such pass — the
+    recompute and its all-reduce are gone.  ROADMAP S2 takes this to
+    zero (rows kept data-sharded, dW reduced once after the loop)."""
+    import torchacc_tpu as ta
+    import torchacc_tpu.ops.attn as attn_mod
+    from torchacc_tpu.models import get_preset
+    from torchacc_tpu.models.transformer import TransformerLM
+    from torchacc_tpu.train.accelerate import apply_config_to_model
+    from torchacc_tpu.train.trainer import Trainer
+
+    monkeypatch.setattr(attn_mod, "_on_tpu", lambda: True)
+    vocab, hidden, batch, seq, chunk_rows = 4096, 512, 8, 1024, 2048
+    mc = get_preset("llama-tiny", vocab_size=vocab, hidden_size=hidden,
+                    num_layers=2, num_heads=4, num_kv_heads=4,
+                    intermediate_size=1024, max_seq_len=seq, dtype=BF16)
+    cfg = ta.Config()
+    cfg.dist.fsdp.size = 4
+    cfg.compute.bf16_compute_params = True
+    cfg.validate()
+    names = tuple(cfg.dist.topology)
+    sizes = cfg.dist.axis_sizes(len(topo.devices))
+    mesh = Mesh(np.asarray(topo.devices).reshape(
+        [sizes[a] for a in names]), names)
+    trainer = Trainer(TransformerLM(apply_config_to_model(mc, cfg)), cfg,
+                      mesh=mesh)
+    state = trainer.abstract_state()
+    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
+    b = {"input_ids": _sds(ids.shape, ids.dtype,
+                           trainer._batch_shardings({"input_ids": ids})
+                           ["input_ids"])}
+    with jax.sharding.set_mesh(mesh):
+        text = trainer._build_train_step(b).lower(state, b).compile(
+            ).as_text()
+    head = [ln for ln in text.splitlines()
+            if re.search(r'op_name="[^"]*fused_ce', ln)]
+    assert head and not any("rematted_computation" in ln for ln in head)
+    logits_reduces = [
+        ln for ln in head
+        if re.search(rf"= f32\[{chunk_rows},{vocab}\]\S* all-reduce"
+                     r"(-start)?\(", ln)]
+    assert len(logits_reduces) == 1, logits_reduces

@@ -2,6 +2,8 @@
 naive logits path, plus trainer integration (reference analogue: Liger
 fused-linear-cross-entropy parity, ops/liger.py)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -94,3 +96,108 @@ def test_scan_free_chunk_never_unrolls_tiny_divisors():
     for n in (4099, 2 * 4099, 3 * 1361, 8192, 4106, 13, 6 * 4099):
         d = _scan_free_chunk(n, 2048)
         assert n % d == 0 and n // d <= 64, (n, d)
+
+
+def _rule_case(name):
+    """(hidden, w_head, labels, kwargs) for one case of the custom_vjp."""
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    hidden = jax.random.normal(ks[0], (2, 24, 32))
+    w = jax.random.normal(ks[1], (32, 101)) * 0.1
+    labels = jax.random.randint(ks[2], (2, 24), 0, 101)
+    kw = dict(chunk_rows=16)
+    if name == "some_rows_ignored":
+        labels = labels.at[0, 3:9].set(-100).at[1, -5:].set(-100)
+    elif name == "every_row_ignored":
+        labels = jnp.full_like(labels, -100)
+    elif name == "softcap":
+        w, kw = w * 20.0, dict(chunk_rows=16, logit_softcap=5.0)
+    elif name == "tied_head":
+        w = w.T                 # the embedding [V, H]; the head is its .T
+    elif name == "pad_path":
+        kw = dict(chunk_rows=20)            # 48 rows: 3 chunks, 12 padded
+    elif name == "scan_free":
+        kw = dict(chunk_rows=16, scan_free=True)
+    elif name == "bf16_hidden_f32_weight":
+        hidden = hidden.astype(jnp.bfloat16)
+    elif name == "bf16_hidden_bf16_weight":   # dW summed in bf16
+        hidden, w = hidden.astype(jnp.bfloat16), w.astype(jnp.bfloat16)
+    return hidden, w, labels, kw
+
+
+@pytest.mark.parametrize("case", [
+    "plain", "some_rows_ignored", "every_row_ignored", "softcap",
+    "tied_head", "pad_path", "scan_free", "bf16_hidden_f32_weight",
+    "bf16_hidden_bf16_weight", "upstream_cotangent"])
+def test_fused_ce_rule_matches_autodiff_of_naive(case):
+    """The hand-written rule (dlogits, d(hidden), dW formed in the
+    forward's chunk loop, scaled in the backward) against ``jax.grad``
+    of the materialised-logits head."""
+    from torchacc_tpu.models.transformer import softcap
+    hidden, w, labels, kw = _rule_case(case)
+    tied = case == "tied_head"
+    scale = 3.0 if case == "upstream_cotangent" else 1.0
+
+    def mean(l, c):
+        return scale * l / jnp.maximum(c, 1.0)
+
+    def f_fused(h, w):
+        return mean(*fused_linear_cross_entropy(
+            h, w.T if tied else w, labels, **kw))
+
+    def f_naive(h, w):
+        # the fused head rounds the weight to the hidden's dtype
+        wh = (w.T if tied else w).astype(h.dtype).astype(jnp.float32)
+        logits = softcap(h.astype(jnp.float32) @ wh,
+                         kw.get("logit_softcap", 0.0))
+        return mean(*loss_sum_count(logits, labels))
+
+    lf, gf = jax.value_and_grad(f_fused, argnums=(0, 1))(hidden, w)
+    ln, gn = jax.value_and_grad(f_naive, argnums=(0, 1))(hidden, w)
+    bf16 = hidden.dtype == jnp.bfloat16
+    np.testing.assert_allclose(float(lf), float(ln),
+                               rtol=2e-3 if bf16 else 1e-6)
+    for a, b, name in zip(gf, gn, ("d_hidden", "d_w")):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        # bf16: d(hidden) is rounded to the hidden's dtype, and a bf16
+        # head's dW is summed over the chunks in bf16
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32),
+            rtol=3e-2 if bf16 else 1e-5, atol=2e-3 if bf16 else 1e-6,
+            err_msg=name)
+    if case == "every_row_ignored":
+        assert float(lf) == 0.0
+        assert not np.asarray(gf[0]).any() and not np.asarray(gf[1]).any()
+
+
+def test_fused_ce_forms_its_gradient_in_the_forward_loop():
+    """The mechanism, not the numbers: under ``jax.grad`` the head holds
+    ONE chunk loop with three head-sized matmuls in its body (logits,
+    d(hidden), dW), nothing rematerialised, and no second loop in the
+    backward — which only scales the two saved gradients."""
+    rows, h, v, chunks = 16, 32, 128, 4
+    hidden = jnp.zeros((1, rows * chunks, h))
+    w = jnp.zeros((h, v))
+    labels = jnp.zeros((1, rows * chunks), jnp.int32)
+
+    def loss(hid, w):
+        l, c = fused_linear_cross_entropy(hid, w, labels, chunk_rows=rows)
+        return l / c
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1)))
+    text = grad.lower(hidden, w).compile().as_text()
+    head_ops = [ln for ln in text.splitlines()
+                if re.search(r'op_name="[^"]*fused_ce', ln)]
+    assert head_ops and not any(
+        "rematted_computation" in ln or "checkpoint" in ln
+        for ln in head_ops)
+    dots = [ln for ln in head_ops if " dot(" in ln]
+    assert all("jvp(fused_ce)/while/body" in ln for ln in dots), dots
+    assert sorted(re.search(r"= (f32\[[\d,]+\])", ln).group(1)
+                  for ln in dots) == sorted([
+        f"f32[{rows},{v}]",         # logits
+        f"f32[{rows},{h}]",         # d(hidden)
+        f"f32[{h},{v}]"]), dots     # dW
+    assert sum(" while(" in ln for ln in text.splitlines()) == 1
+    # loss-only (eval): the loop alone, one matmul a chunk
+    fwd = jax.jit(loss).lower(hidden, w).compile().as_text()
+    assert sum(" dot(" in ln for ln in fwd.splitlines()) == 1

@@ -915,8 +915,12 @@ def test_micro_batch_view_get_raises_like_getitem():
 def test_pp_1f1b_tp_head_sharded_and_smaller(devices):
     """VERDICT r3 #3: the 1F1B head must be vocab-parallel under tp —
     head weight tp-sharded at state level AND in-region (peak temp
-    memory strictly below the replicated-pin fallback at a vocab-heavy
-    geometry), with identical losses."""
+    memory no higher than the pinned-weight fallback at a vocab-heavy
+    geometry), with identical losses.  Since PR 29 the fallback's chunk
+    arithmetic (ops/fused.py: three matmuls and reductions, no gather)
+    is partitioned over tp by GSPMD too — 5.43 MB here against 9.00 MB
+    when it ran replicated — so the two paths now tie to within 1%; a
+    head that fell back to replicated logits would stand ~60% above."""
     import dataclasses
     import optax
 
@@ -940,6 +944,7 @@ def test_pp_1f1b_tp_head_sharded_and_smaller(devices):
             stats[mode] = compiled.memory_analysis().temp_size_in_bytes
         loss = float(tr.step(batch)["loss"])
         stats[mode + "_loss"] = loss
-    assert stats["tp_head"] < stats["pinned"], stats
+    assert stats["tp_head"] <= 1.01 * stats["pinned"], stats
+    assert stats["pinned"] < 7e6, stats     # neither runs replicated
     np.testing.assert_allclose(stats["tp_head_loss"], stats["pinned_loss"],
                                rtol=2e-4)

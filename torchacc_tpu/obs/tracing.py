@@ -112,8 +112,9 @@ SCOPES: Dict[str, str] = {
     "flash_fwd": "flash-attention forward kernel",
     "flash_dq": "flash-attention backward kernel, dq",
     "flash_dkv": "flash-attention backward kernel, dk and dv",
-    "fused_ce": "chunked head matmul + cross-entropy, forward, "
-                "backward and rematerialised chunks",
+    "fused_ce": "chunked head matmul + cross-entropy: the chunk loop "
+                "that forms the loss and its gradients, and the "
+                "backward's scaling of them",
     "optimizer": "gradient norm, clipping, optimizer update and "
                  "parameter apply",
     "mla_q": "latent attention: query projections, rope and (serving) "

@@ -5,10 +5,14 @@ On TPU, XLA already fuses RMSNorm/SwiGLU/RoPE elementwise chains into
 their neighbouring matmuls, so those need no kernels (the reference
 itself notes Liger is an eager-backend fallback).  The one that matters
 is **fused linear + cross entropy**: computing ``hidden @ W_head`` and
-the CE loss per sequence chunk — with the backward recomputing each
-chunk's logits — keeps peak memory at O(chunk x vocab) instead of
-materialising the full [batch*seq, vocab] float32 logits (+ its
-softmax) that otherwise dominates HBM at large vocab.
+the CE loss per sequence chunk keeps peak memory at O(chunk x vocab)
+instead of materialising the full [batch*seq, vocab] float32 logits
+(+ its softmax) that otherwise dominates HBM at large vocab.  The
+gradient is formed where the logits already are, as Liger does: the
+forward's chunk loop computes dlogits, d(hidden) and dW from each
+chunk's block and saves d(hidden) and one [hidden, vocab] dW; the
+backward scales them by the loss's cotangent and recomputes nothing
+(peak memory: O(chunk x vocab) logits plus that one accumulator).
 """
 
 from __future__ import annotations
@@ -39,50 +43,72 @@ def _scan_free_chunk(n: int, chunk_rows: int) -> int:
     return min([d for d in divisors if d >= chunk_rows] or [n])
 
 
-@scoped("fused_ce")
-def fused_linear_cross_entropy(
-    hidden: jax.Array,
-    w_head: jax.Array,
-    labels: jax.Array,
-    *,
-    chunk_rows: int = 2048,
-    logit_softcap: float = 0.0,
-    scan_free: bool = False,
-) -> Tuple[jax.Array, jax.Array]:
-    """(loss_sum, valid_count) of next-token CE without full logits.
+def _head_chunk(xi, yi, w, logit_softcap: float, with_grads: bool):
+    """One chunk of rows through the head.  Returns ``(sums, dx)``:
+    sums = (loss_sum, valid_count) and dx = None, or with ``with_grads``
+    sums = (loss_sum, valid_count, the chunk's float32 share of
+    d(loss_sum)/d(w)) and dx = d(loss_sum)/d(xi) in xi's dtype, formed
+    from the logits block while it is there.  ``w`` is the head already
+    in xi's dtype.  Knows nothing of where its rows live or how the
+    chunks are looped."""
+    from torchacc_tpu.models.transformer import softcap
+    # operands stay in the model dtype (bf16 MXU throughput); the
+    # accumulation and all loss arithmetic are f32
+    logits = softcap(jnp.dot(xi, w, preferred_element_type=jnp.float32),
+                     logit_softcap)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    valid = yi != -100
+    safe = jnp.where(valid, yi, 0)
+    # the label's logit by a masked row sum, not a gather: a reduction
+    # partitions like the max and the sum beside it wherever the vocab
+    # dim ends up sharded (a gather there trips the SPMD partitioner
+    # inside the 1F1B region)
+    onehot = jax.lax.broadcasted_iota(
+        jnp.int32, logits.shape, 1) == safe[:, None]
+    ll = jnp.sum(jnp.where(onehot, logits, 0.0), axis=-1)
+    loss = jnp.sum(jnp.where(valid, lse - ll, 0.0))
+    count = jnp.sum(valid).astype(jnp.float32)
+    if not with_grads:
+        return (loss, count), None
+    dlogits = jnp.where(valid[:, None],
+                        jnp.exp(logits - lse[:, None]) - onehot, 0.0)
+    if logit_softcap > 0.0:
+        # d/dz of c * tanh(z / c), from the capped logits themselves
+        dlogits = dlogits * (1.0 - jnp.square(logits / logit_softcap))
+    # the float32 dlogits meets the model-dtype operands under default
+    # precision, as autodiff's transpose of the forward dot would have it
+    dx = jax.lax.dot_general(dlogits, w, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    dw = jax.lax.dot_general(xi, dlogits, (((0,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    return (loss, count, dw), dx.astype(xi.dtype)
 
-    hidden: [batch, seq, H]; w_head: [H, V]; labels: [batch, seq] with
-    -100 ignored.  Equivalent to ``loss_sum_count(hidden @ w_head,
-    labels)`` but chunked over rows with rematerialised logits, so the
-    [rows, V] buffer exists only one chunk at a time in fwd AND bwd.
-    chunk_rows=2048 measured best on v5e (1024 costs ~1.5 MFU points on
-    the 32k-vocab bench; 4096 is equal but doubles the chunk buffer).
-    ``logit_softcap`` > 0 applies Gemma2's c * tanh(logits / c) before
-    the loss.
 
-    ``scan_free=True`` unrolls the chunk loop (python loop over
-    ``jax.checkpoint``-ed chunks instead of ``lax.scan``).  Required
-    when this runs inside a branch only SOME devices take — the 1F1B
-    last-stage ``lax.cond`` — because the scan's WhileThunk
-    desynchronizes XLA:CPU's in-process collective rendezvous.  Same
-    math, same per-chunk memory profile; only the loop is unrolled.
-    """
+def _head_chunks(hidden, w_head, labels, chunk_rows: int,
+                 logit_softcap: float, scan_free: bool, with_grads: bool):
+    """Drive ``_head_chunk`` over the rows, ``chunk_rows`` at a time, by
+    a ``lax.scan`` or (``scan_free``) a Python loop.  Returns (loss_sum,
+    count) and, with ``with_grads``, d(hidden) in hidden's dtype and dW
+    in w_head's dtype, both for a unit cotangent of loss_sum.  dW is
+    summed over the chunks in w_head's dtype, as autodiff's transpose
+    of a scan sums a closed-over weight's cotangent: a bf16 head
+    (``bf16_compute_params``) gets a bf16 sum, float32 weights a
+    float32 one.  A float32 sum for a bf16 head fits, but the dW matmul
+    then reads and writes twice the bytes every chunk: 6.4 -> 7.8 ms a
+    chunk at a 100k vocabulary on a v5e (PERF.md section 6, PR 29)."""
     b, s, h = hidden.shape
-    v = w_head.shape[1]
     n = b * s
     x = hidden.reshape(n, h)
     y = labels.reshape(n)
 
     if scan_free:
         # no pad either: the pad+concat of a data-sharded array inside
-        # the cond is another resharding-collective source.  Pick the
-        # largest chunk size <= chunk_rows that divides n exactly (n =
-        # micro_batch * seq is essentially always highly composite).
-        # Any divisor of n works; pick the chunk size nearest the tuned
-        # chunk_rows.  Awkward token counts (n = 2 * prime, or prime)
-        # degrade smoothly — worst case one chunk of n rows, which IS the
-        # plain materialized-logits head — instead of failing at trace
-        # time (the old bounded search raised for e.g. n=4106).
+        # the cond is another resharding-collective source.  Any divisor
+        # of n works; pick the chunk size nearest the tuned chunk_rows.
+        # Awkward token counts (n = 2 * prime, or prime) degrade
+        # smoothly — worst case one chunk of n rows, which IS the plain
+        # materialized-logits head — instead of failing at trace time
+        # (the old bounded search raised for e.g. n=4106).
         best = _scan_free_chunk(n, chunk_rows)
         if best > 4 * chunk_rows:
             from torchacc_tpu.utils.logger import logger
@@ -100,44 +126,95 @@ def fused_linear_cross_entropy(
     chunks = (n + pad) // chunk_rows
     xc = x.reshape(chunks, chunk_rows, h)
     yc = y.reshape(chunks, chunk_rows)
+    w = w_head.astype(x.dtype)
 
-    def one_chunk(xi, yi):
-        # operands stay in the model dtype (bf16 MXU throughput); the
-        # accumulation and all loss arithmetic are f32
-        logits = jnp.dot(xi, w_head.astype(xi.dtype),
-                         preferred_element_type=jnp.float32)
-        if logit_softcap > 0.0:
-            from torchacc_tpu.models.transformer import softcap
-            logits = softcap(logits, logit_softcap)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        valid = yi != -100
-        safe = jnp.where(valid, yi, 0)
-        ll = jnp.take_along_axis(logits, safe[:, None], axis=1)[:, 0]
-        loss = jnp.sum(jnp.where(valid, lse - ll, 0.0))
-        count = jnp.sum(valid).astype(jnp.float32)
-        return loss, count
+    # the sums ride the loop's carry; the chunks' dx are stacked
+    zero = jnp.zeros((), jnp.float32)
+    acc = (zero, zero)
+    if with_grads:
+        acc += (jnp.zeros(w.shape, w_head.dtype),)
 
-    # remat: backward recomputes each chunk's logits instead of saving them
-    one_chunk = jax.checkpoint(one_chunk,
-                               policy=jax.checkpoint_policies.nothing_saveable)
+    def body(acc, xy):
+        sums, dx = _head_chunk(*xy, w, logit_softcap, with_grads)
+        return tuple(a + s.astype(a.dtype) for a, s in zip(acc, sums)), dx
 
     if scan_free:
-        loss_sum = jnp.zeros((), jnp.float32)
-        count = jnp.zeros((), jnp.float32)
+        dxs = []
         for i in range(chunks):
-            l, c = one_chunk(xc[i], yc[i])
-            loss_sum, count = loss_sum + l, count + c
-        return loss_sum, count
+            acc, dx = body(acc, (xc[i], yc[i]))
+            dxs.append(dx)
+        dxs = jnp.stack(dxs) if with_grads else None
+    else:
+        acc, dxs = jax.lax.scan(body, acc, (xc, yc))
+    if not with_grads:
+        return acc
+    loss_sum, count, dw = acc
+    dx = dxs.reshape(chunks * chunk_rows, h)[:n].reshape(hidden.shape)
+    return loss_sum, count, dx, dw
 
-    def body(carry, xy):
-        l_acc, c_acc = carry
-        l, c = one_chunk(*xy)
-        return (l_acc + l, c_acc + c), None
 
-    (loss_sum, count), _ = jax.lax.scan(
-        body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-        (xc, yc))
-    return loss_sum, count
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _fused_ce(hidden, w_head, labels, chunk_rows, logit_softcap, scan_free):
+    return _head_chunks(hidden, w_head, labels, chunk_rows, logit_softcap,
+                        scan_free, with_grads=False)
+
+
+def _fused_ce_fwd(hidden, w_head, labels, chunk_rows, logit_softcap,
+                  scan_free):
+    loss_sum, count, dx, dw = _head_chunks(
+        hidden, w_head, labels, chunk_rows, logit_softcap, scan_free,
+        with_grads=True)
+    return (loss_sum, count), (dx, dw)
+
+
+def _fused_ce_bwd(chunk_rows, logit_softcap, scan_free, res, cts):
+    dx, dw = res
+    g = cts[0]  # the count has no gradient, nor have the labels
+    return (dx * g).astype(dx.dtype), (dw * g).astype(dw.dtype), None
+
+
+_fused_ce.defvjp(_fused_ce_fwd, _fused_ce_bwd)
+
+
+@scoped("fused_ce")
+def fused_linear_cross_entropy(
+    hidden: jax.Array,
+    w_head: jax.Array,
+    labels: jax.Array,
+    *,
+    chunk_rows: int = 2048,
+    logit_softcap: float = 0.0,
+    scan_free: bool = False,
+) -> Tuple[jax.Array, jax.Array]:
+    """(loss_sum, valid_count) of next-token CE without full logits.
+
+    hidden: [batch, seq, H]; w_head: [H, V]; labels: [batch, seq] with
+    -100 ignored.  Equivalent to ``loss_sum_count(hidden @ w_head,
+    labels)`` but chunked over rows, so the [rows, V] float32 logits
+    exist only one chunk at a time.  A ``jax.custom_vjp``: under
+    differentiation the forward's one pass over the chunks also forms
+    each chunk's dlogits (softmax - onehot, zero on ignored rows), its
+    d(hidden) rows and its share of dW while the logits are there —
+    three head-sized matmuls a chunk, nothing recomputed — and saves
+    d(hidden) (hidden's dtype) and dW (summed and saved in w_head's
+    dtype) for a backward that only scales them by the cotangent of
+    loss_sum.  Without differentiation (eval) it is the chunk loop
+    alone: one matmul a chunk.  Peak memory is O(chunk x vocab) for the
+    logits plus one [H, V] accumulator.
+    chunk_rows=2048 measured best on v5e (1024 costs ~1.5 MFU points on
+    the 32k-vocab bench; 4096 is equal but doubles the chunk buffer).
+    ``logit_softcap`` > 0 applies Gemma2's c * tanh(logits / c) before
+    the loss.
+
+    ``scan_free=True`` unrolls the chunk loop (a Python loop over the
+    same chunk function instead of ``lax.scan``).  Required when this
+    runs inside a branch only SOME devices take — the 1F1B last-stage
+    ``lax.cond`` — because the scan's WhileThunk desynchronizes
+    XLA:CPU's in-process collective rendezvous.  Same math, same
+    per-chunk memory profile; only the loop is unrolled.
+    """
+    return _fused_ce(hidden, w_head, labels, chunk_rows, logit_softcap,
+                     scan_free)
 
 
 @scoped("fused_ce")
