@@ -431,3 +431,152 @@ def test_fused_head_under_fsdp_reduces_its_logits_once(topo, for_the_chip,
         if re.search(rf"= f32\[{chunk_rows},{vocab}\]\S* all-reduce"
                      r"(-start)?\(", ln)]
     assert len(logits_reduces) == 1, logits_reduces
+
+
+# -- two kinds of latent layer: dots3-note-prev's published geometry ---------
+
+DOTS3_SERVE = dict(block_size=128, num_blocks=2081, max_slots=8,
+                   prefill_chunk=512)
+DOTS3_SEQ = 33280
+# the dense layer + TWO periods (full, sliding x 3): at one period each
+# position's expert stack is a stack of one (see AXK1_DEPTH)
+DOTS3_DEPTH = 9
+
+
+def _dots3_program(name, one_chip):
+    import json
+    import types
+
+    from chipbench.layouts import mla_sparse_window_moe_decoder as layout
+    from chipbench.weights import mla_sparse_window_moe_decoder as weights
+    from torchacc_tpu.models.hf import config_from_hf
+    from torchacc_tpu.serve.kv_cache import blocks_needed, make_pools
+    from torchacc_tpu.serve.scheduler import PagedDecoder
+
+    (row,) = [r for r in map(json.loads, open(
+        "/opt/skills/guides/model-configs/architectures.jsonl"))
+        if r["name"] == "dots3-note-prev"]
+    pub = dict(row["config"], n_routed_experts=16, router_n_experts=256,
+               first_held_expert=128)
+    mc = config_from_hf(types.SimpleNamespace(**pub), num_layers=DOTS3_DEPTH,
+                        max_seq_len=DOTS3_SEQ, param_dtype=BF16)
+    sc = ServeConfig(**DOTS3_SERVE)
+    decoder = PagedDecoder(mc, sc, "pallas")
+    sds = functools.partial(_sds, sharding=one_chip)
+    abstract = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: sds(x.shape, x.dtype), tree)
+    params = abstract(jax.eval_shape(
+        lambda k: layout.to_program_params(
+            weights.make(k, pub, DOTS3_DEPTH, BF16), mc),
+        jax.random.PRNGKey(0)))
+    pools = abstract(jax.eval_shape(lambda: make_pools(mc, sc)))
+    s = sc.max_slots
+    mb = blocks_needed(DOTS3_SEQ + sc.decode_depth, sc.block_size)
+    i32, f32 = jnp.int32, jnp.float32
+    if name == "decode":
+        carry = {"tok": sds((s,), i32), "key": sds((s, 2), jnp.uint32)}
+        return decoder._decode.lower(
+            params, pools, carry, sds((s, mb), i32), sds((s,), i32),
+            sds((s,), jnp.bool_), sds((s,), f32), sds((s,), i32),
+            sds((s,), f32), True, sds((s, mb), i32)), pools, mb
+    return decoder._prefill.lower(
+        params, pools, sds((mb,), i32), sds((), i32),
+        sds((sc.prefill_chunk,), i32), sds((), i32),
+        name == "prefill_final_chunk", sds((mb,), i32)), pools, mb
+
+
+@pytest.mark.parametrize("name",
+                         ["decode", "prefill_chunk", "prefill_final_chunk"])
+def test_sparse_window_serve_program_holds_three_pools_in_place(
+        one_chip, for_the_chip, name):
+    """The three serve programs of the dots3-note-prev cell at its own
+    settings and depth (a dense layer and two periods of full, sliding,
+    sliding, sliding; 16 held experts of 5120 x 1536): THREE pools —
+    full layers' latent rows [3, 2081, 128, 640], their index keys
+    [3, 2081, 128, 128], window layers' rows [6, 81, 128, 1152] (1,088
+    values padded to whole lane tiles) — all aliased in -> out, none
+    copied, sliced or relayouted; 19 Mosaic kernels (a full layer:
+    indexer scores + the latent kernel under the selection, a sliding
+    layer: the windowed latent kernel, an expert layer: three grouped
+    matmuls; the dense scan 2, the period scan 2 + 3 + 4 x 3); no
+    instruction but a parameter yields an expert-stack-shaped array
+    ([16, 5120, 1536], 236 MiB); and the indexer's products of every
+    head with every cached position ([chunk, 64 heads, 33,408]: 4.4 GB
+    in float32) exist nowhere — what reaches HBM is one float32 a
+    (query, position), 65 MiB a chunk, and the exact top-k's passes over
+    it (sandbox compile, PR 30: 7.7 MiB of temporaries in decode, 453 /
+    434 MiB in the prefill programs)."""
+    lowered, pools, mb = _dots3_program(name, one_chip)
+    compiled = lowered.compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    assert [p.shape for p in pools] == [
+        (3, 2081, 128, 640), (3, 2081, 128, 128), (6, 81, 128, 1152)]
+    assert mem.alias_size_in_bytes >= sum(
+        p.size * p.dtype.itemsize for p in pools)
+    assert mem.temp_size_in_bytes < (32 if name == "decode" else 640) * 2**20
+    assert text.count("tpu_custom_call") == 19
+    stack = r"bf16\[16,(5120,1536|1536,5120)\]"
+    copied = [line.strip()[:160] for line in text.splitlines()
+              if re.search(rf"= {stack}\S* (?!parameter\()", line)]
+    assert not copied, copied
+    shapes = "|".join(
+        re.escape(f"bf16[{','.join(str(d) for d in p.shape[i:])}]")
+        for p in pools for i in (0, 1))
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(rf"= ({shapes})\S* "
+                          r"(copy|dynamic-slice|dynamic-update-slice)\(",
+                          line)]
+    assert not moved, moved
+    # per-head scores against every cached position: [.., 64, .., T]
+    t = mb * 128
+    per_head = [line.strip()[:160] for line in text.splitlines()
+                if re.search(rf"= \w+\[[\d,]*\b64,[\d,]*{t}\]", line)]
+    assert not per_head, per_head
+
+
+@pytest.mark.parametrize("kernel,slots,t", [
+    ("indexer", 8, 1), ("indexer", 1, 512), ("sparse", 8, 1),
+    ("sparse", 1, 512), ("window", 8, 1), ("window", 1, 512)])
+def test_sparse_window_kernels_compile_at_published_geometry(
+        one_chip, for_the_chip, kernel, slots, t):
+    """Each new kernel alone for the chip, decode step and 512-token
+    chunk: the indexer (64 heads x 128 over a [L, NB, 128, 128] key
+    pool), the latent kernel under a selection (128 heads on a 640-lane
+    row) and with a window of 512 back (64 heads on a 1,152-lane row,
+    the grid's block axis ceil((512 + tile) / 128) + 1 long instead of
+    261); pools read where they lie (under 1 MiB of temporaries beside
+    the selection's own reshape)."""
+    sds = functools.partial(_sds, sharding=one_chip)
+    i32 = functools.partial(sds, dtype=jnp.int32)
+    mb, n = 261, 261 * 128
+    common = (i32((slots, mb)), i32((slots,)), i32((slots,)), i32(()))
+    if kernel == "indexer":
+        def call(q, w, pool, tables, ctx, q0, layer):
+            return paged_mod.indexer_scores(q, w, pool, tables, ctx, q0,
+                                            layer=layer, impl="pallas")
+        args = (sds((slots, t, 64, 128), BF16),
+                sds((slots, t, 64), jnp.float32),
+                sds((LAYERS, 2081, 128, 128), BF16))
+    elif kernel == "sparse":
+        def call(ql, qp, pool, sc, thr, tie, tables, ctx, q0, layer):
+            return paged_mod.latent_paged_attention(
+                ql, qp, pool, tables, ctx, q0, layer=layer, scale=0.07,
+                impl="pallas", selection=(sc, thr, tie),
+                name="sparse_latent_attention")
+        args = (sds((slots, t, 128, 512), BF16),
+                sds((slots, t, 128, 64), BF16),
+                sds((LAYERS, 2081, 128, 640), BF16),
+                sds((slots, t, n), jnp.float32),
+                sds((slots, t), jnp.float32), i32((slots, t)))
+    else:
+        def call(ql, qp, pool, tables, ctx, q0, layer):
+            return paged_mod.latent_paged_attention(
+                ql, qp, pool, tables, ctx, q0, layer=layer, scale=0.06,
+                impl="pallas", window=512, name="window_latent_attention")
+        args = (sds((slots, t, 64, 1024), BF16),
+                sds((slots, t, 64, 64), BF16),
+                sds((LAYERS, 81, 128, 1152), BF16))
+    compiled = jax.jit(call).lower(*args, *common).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20 + (
+        slots * t * n * 4 if kernel == "sparse" else 0)

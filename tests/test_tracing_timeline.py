@@ -49,6 +49,18 @@ LATENT_DECODE_SCOPES = ("mla_q", "mla_kv", "latent_attn", "kv_write",
 MOE_SPAN_ATTRS = ("moe_pairs", "moe_max", "moe_hit", "moe_slots",
                   "moe_layer_steps")
 
+# the decode program of a model of two latent layer kinds (PR 30): the
+# indexed selection of its full layers, the window layers' kernel, the
+# headwise gate
+SPARSE_DECODE_SCOPES = ("index_write", "indexer", "index_topk",
+                        "sparse_latent_attn", "window_latent_attn",
+                        "attn_gate")
+# what its serve/deliver spans carry (chipbench/readers/
+# selected_context.py, rooflines/sparse_select_common.py) and its
+# serve/admit spans (blocks in use by kind of layer)
+SELECT_SPAN_ATTRS = ("sel_attended", "sel_cached", "win_attended")
+ADMIT_BLOCK_ATTRS = ("blocks_full", "blocks_window", "window_blocks_freed")
+
 # the spans a traced serve loop / fit has to leave on the host plane
 SERVE_SPANS = ("serve/step", "serve/sweep", "serve/admit", "serve/prefill",
                "serve/decode", "serve/deliver", "serve/wait")
@@ -75,6 +87,42 @@ def _latent_model_cfg():
         moe_scoring="sigmoid", moe_n_group=2, moe_topk_group=1,
         moe_route_scale=2.5, moe_shared_experts=1, moe_router_width=8,
         moe_first_expert=2, moe_dispatch="grouped")
+
+
+def _sparse_model():
+    """``(ModelConfig, params)`` of a toy of the family of two latent
+    layer kinds: one dense full layer, then one period (full, sliding);
+    indexer of 2 heads choosing 6 positions, a window of 5.  Its weights
+    and their layout are the benchmark's (the module's own init refuses
+    the family: it runs through ServeEngine alone)."""
+    import types
+
+    from chipbench.layouts import mla_sparse_window_moe_decoder as layout
+    from chipbench.weights import mla_sparse_window_moe_decoder as weights
+    from torchacc_tpu.models.hf import config_from_hf
+    pub = dict(
+        model_type="dots3_note", hidden_size=64, intermediate_size=128,
+        num_attention_heads=2, num_key_value_heads=2, vocab_size=256,
+        kv_lora_rank=32, q_lora_rank=48, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, swa_num_attention_heads=2,
+        swa_num_key_value_heads=2, swa_kv_lora_rank=40, swa_q_lora_rank=48,
+        swa_qk_nope_head_dim=24, swa_qk_rope_head_dim=8, swa_v_head_dim=16,
+        swa_rope_theta=500, swa_attention_gate_type="headwise",
+        attention_gate_type="headwise", apply_mla_qkv_lora_rescale=True,
+        index_head_dim=16, index_n_heads=2, index_topk=6,
+        sliding_window_size=5, first_k_dense_replace=1,
+        moe_intermediate_size=32, moe_layer_freq=1, n_routed_experts=4,
+        n_shared_experts=1, num_experts_per_tok=2, norm_topk_prob=True,
+        routed_scaling_factor=1, scoring_func="sigmoid",
+        topk_method="noaux_tc", hidden_act="silu", rms_norm_eps=1e-5,
+        rope_theta=10000, rope_scaling=None, max_position_embeddings=128,
+        num_hidden_layers=3, attention_bias=False,
+        layer_types=["full_attention", "full_attention",
+                     "sliding_attention"], tie_word_embeddings=False)
+    mc = config_from_hf(types.SimpleNamespace(**pub), max_seq_len=128,
+                        dtype=jnp.float32, param_dtype=jnp.float32)
+    return mc, layout.to_program_params(
+        weights.make(weights.base_key(7), pub, 3, jnp.float32), mc)
 
 
 def _scopes_in(hlo_text):
@@ -140,24 +188,50 @@ def program_scopes():
         sds((2, 15), jnp.int32), sds((2,), jnp.int32), sds((2,), jnp.bool_),
         sds((2,), jnp.float32), sds((2,), jnp.int32),
         sds((2,), jnp.float32), True).compile().as_text()
+    smc, sparams = _sparse_model()
+    sparse_lowered = PagedDecoder(smc, sc, "xla")._decode.lower(
+        jax.eval_shape(lambda: sparams),
+        jax.eval_shape(lambda: make_pools(smc, sc)), carry,
+        sds((2, 15), jnp.int32), sds((2,), jnp.int32), sds((2,), jnp.bool_),
+        sds((2,), jnp.float32), sds((2,), jnp.int32),
+        sds((2,), jnp.float32), True, sds((2, 15), jnp.int32))
+    sparse = sparse_lowered.compile().as_text()
     return {"train": _scopes_in(train), "decode": _scopes_in(decode),
             "prefill": _scopes_in(prefill),
-            "latent_decode": _scopes_in(latent)}
+            "latent_decode": _scopes_in(latent),
+            "sparse_decode": _scopes_in(sparse),
+            # read before the compile: the persistent compile cache's
+            # key leaves op names out, so a scope moved with no change
+            # to the computation comes back under its old names
+            "sparse_decode_scatters": set(re.findall(
+                r'loc\("([^"]+/scatter)"',
+                sparse_lowered.as_text(debug_info=True)))}
 
 
 @pytest.mark.parametrize("program,scope", [
     *(("train", s) for s in TRAIN_SCOPES),
     *(("decode", s) for s in DECODE_SCOPES),
     *(("prefill", s) for s in PREFILL_SCOPES),
-    *(("latent_decode", s) for s in LATENT_DECODE_SCOPES)])
+    *(("latent_decode", s) for s in LATENT_DECODE_SCOPES),
+    *(("sparse_decode", s) for s in SPARSE_DECODE_SCOPES)])
 def test_device_scope_in_compiled_program(program_scopes, program, scope):
     assert scope in tracing.DEVICE_SCOPES
     assert scope in program_scopes[program]
 
 
+def test_every_pool_write_of_the_sparse_program_is_a_kv_write(
+        program_scopes):
+    """``kv_pool_time_pct.serve`` reads the scope ``kv_write``: the
+    index key's scatter into its pool lies under it like the latent
+    rows' (``index_write`` keeps the key's projection, norm and rope)."""
+    writes = {n for n in program_scopes["sparse_decode_scatters"]
+              if "kv_write" in n or "index_write" in n}
+    assert writes and all("kv_write" in n for n in writes), writes
+
+
 def test_every_registered_scope_is_placed():
     placed = (set(TRAIN_SCOPES) | set(DECODE_SCOPES) | set(PREFILL_SCOPES)
-              | set(LATENT_DECODE_SCOPES))
+              | set(LATENT_DECODE_SCOPES) | set(SPARSE_DECODE_SCOPES))
     assert placed == set(tracing.DEVICE_SCOPES)
 
 
@@ -222,6 +296,14 @@ def traced(tmp_path_factory):
                      max_new_tokens=3) for n in (5, 19)]
     lengine.generate(lreqs[:1])            # compile outside the trace
 
+    smc, sparams = _sparse_model()
+    sengine = ServeEngine(TransformerLM(smc), sparams, ta.Config(
+        serve=ta.config.ServeConfig(block_size=4, num_blocks=64,
+                                    max_slots=2, prefill_chunk=8)))
+    sreqs = [Request(prompt_ids=rng.integers(1, 256, size=n).tolist(),
+                     max_new_tokens=3) for n in (5, 19)]
+    sengine.generate(sreqs[:1])            # compile outside the trace
+
     assert not tracing.enabled()
     tracing.clear()
     trace_dir = str(tmp_path_factory.mktemp("timeline"))
@@ -233,10 +315,12 @@ def traced(tmp_path_factory):
         engine.generate(reqs)
         trainer.fit(batches, log_every=1)
         lengine.generate(lreqs)
+        sengine.generate(sreqs)
     finally:
         jax.profiler.stop_trace()
     engine.close()
     lengine.close()
+    sengine.close()
     return {"events": _host_events(trace_dir),
             "ring": tracing.snapshot()}
 
@@ -276,8 +360,11 @@ def test_deliver_spans_carry_the_expert_layers_counts(traced, attr):
     """An expert model's serve/deliver spans reach the profiler with
     the counts of the steps behind them (set after the token fetch, on
     the open annotation); a dense model's carry none."""
+    # (the toy of two latent layer kinds has expert layers too: its
+    # spans are read by the next test)
     with_counts = [st for n, _, _, st in traced["events"]
-                   if n == "serve/deliver" and "moe_pairs" in st]
+                   if n == "serve/deliver" and "moe_pairs" in st
+                   and "sel_cached" not in st]
     without = [st for n, _, _, st in traced["events"]
                if n == "serve/deliver" and "moe_pairs" not in st]
     assert with_counts and without
@@ -290,6 +377,34 @@ def test_deliver_spans_carry_the_expert_layers_counts(traced, attr):
                for st in with_counts)
     assert max(int(st["moe_layer_steps"]) for st in with_counts) == 3
     assert sum(int(st["moe_pairs"]) for st in with_counts) > 0
+
+
+@pytest.mark.parametrize("attr", SELECT_SPAN_ATTRS + ADMIT_BLOCK_ATTRS)
+def test_spans_carry_the_selection_and_the_blocks_by_kind(traced, attr):
+    """A model of two latent layer kinds: its serve/deliver spans carry
+    the positions a full layer attended and had cached and the positions
+    a window layer attended, for the queries behind the tokens (a 'first'
+    entry: its whole prompt's; a decode step: one query a slot); its
+    admitting serve/admit spans the blocks in use by kind and the window
+    blocks given back.  Other models' spans carry none of them."""
+    spans = "serve/admit" if attr in ADMIT_BLOCK_ATTRS else "serve/deliver"
+    key = "blocks_window" if attr in ADMIT_BLOCK_ATTRS else "sel_cached"
+    have = [st for n, _, _, st in traced["events"]
+            if n == spans and key in st]
+    rest = [st for n, _, _, st in traced["events"]
+            if n == spans and key not in st]
+    assert have and rest and all(attr in st for st in have)
+    if attr in ADMIT_BLOCK_ATTRS:
+        return
+    # index_topk 6, window 5: a prompt of 19 tokens attends
+    # 1 + 2 + .. + 6 + 13 x 6 = 99 of 190 cached positions on a full
+    # layer and 1 + .. + 5 + 14 x 5 = 85 on a window layer
+    firsts = [st for st in have if str(st.get("kind")) == "first"]
+    assert ["99", "190", "85"] in [[str(st[a]) for a in SELECT_SPAN_ATTRS]
+                                    for st in firsts]
+    assert all(int(st["sel_attended"]) <= int(st["sel_cached"])
+               and int(st["win_attended"]) <= int(st["sel_cached"])
+               for st in have)
 
 
 # -- the idle path and the ring ------------------------------------------------

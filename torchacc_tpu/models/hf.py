@@ -240,7 +240,45 @@ def config_from_hf(hf_config: Any, **overrides) -> ModelConfig:
             router_aux_weight=float(get("router_aux_loss_coef", 0.001)),
             intermediate_size=int(get("moe_intermediate_size")),
             moe_renorm_topk=bool(get("norm_topk_prob", False)))
-    if mt == "axk1":
+    if mt == "dots3_note":
+        # dots3-note: the axk1 family's latent attention and held-expert
+        # layers (same keys, read below), with two kinds of attention
+        # layer named by `layer_types`: 'full_attention' layers select
+        # the `index_topk` best cached positions with a learned indexer,
+        # 'sliding_attention' layers are latent attention of their own
+        # sizes (`swa_*`) over `sliding_window_size` positions, the token
+        # itself counted.  Every layer's pattern entry is written out
+        # (no shorter period: the leading dense layer is a full one).
+        types_ = list(get("layer_types") or [])
+        n = int(overrides.get("num_layers", kw["num_layers"]))
+        if len(types_) < n:
+            raise ValueError(
+                f"dots3_note layer_types names {len(types_)} layers, "
+                f"num_layers is {n}")
+        if get("swa_attention_gate_type",
+               get("attention_gate_type")) != get("attention_gate_type"):
+            raise NotImplementedError(
+                "dots3_note with different gates on the two layer kinds")
+        if get("rope_scaling"):
+            raise NotImplementedError("dots3_note with rope_scaling")
+        kw.update(
+            layer_pattern=tuple(
+                "sliding" if t == "sliding_attention" else "global"
+                for t in types_[:n]),
+            window=(int(get("sliding_window_size")) - 1, -1),
+            rope_local_theta=float(get("swa_rope_theta")),
+            index_topk=int(get("index_topk")),
+            index_n_heads=int(get("index_n_heads")),
+            index_head_dim=int(get("index_head_dim")),
+            swa_num_heads=int(get("swa_num_attention_heads")),
+            swa_kv_lora_rank=int(get("swa_kv_lora_rank")),
+            swa_q_lora_rank=int(get("swa_q_lora_rank")),
+            swa_qk_nope_head_dim=int(get("swa_qk_nope_head_dim")),
+            swa_qk_rope_head_dim=int(get("swa_qk_rope_head_dim")),
+            swa_v_head_dim=int(get("swa_v_head_dim")),
+            mla_lora_rescale=bool(get("apply_mla_qkv_lora_rescale", False)),
+            attn_gate=get("attention_gate_type") or "none")
+    if mt in ("axk1", "dots3_note"):
         # A.X-K1 (skt): multi-head latent attention, `first_k_dense_replace`
         # leading dense layers, then expert layers (sigmoid scores,
         # group-limited top-k, normalised weights times
@@ -292,7 +330,8 @@ def config_from_hf(hf_config: Any, **overrides) -> ModelConfig:
         if rs.get("mscale_all_dim") and float(rs.get("factor", 1.0)) > 1.0:
             scale *= (0.1 * float(rs["mscale_all_dim"])
                       * _m.log(float(rs["factor"])) + 1.0) ** 2
-        kw["query_scale"] = scale
+        # two kinds of layer: each kind's head size gives its own scale
+        kw["query_scale"] = None if mt == "dots3_note" else scale
     if mt == "mixtral":
         # Mixtral 8x7B/8x22B: llama attention + top-k sparse MoE MLP.
         # HF routes softmax-then-topk-then-renormalise, which equals the

@@ -252,6 +252,30 @@ class ModelConfig:
     # and the exchange are not this program's)
     moe_router_width: Optional[int] = None
     moe_first_expert: int = 0
+    # -- two kinds of latent layer under one layer_pattern (the
+    # 'dots3_note' family of models/hf.py; models/mla.kind_config) -------
+    # 'global' layers are the latent attention above plus a learned
+    # selection: an indexer (index_n_heads heads of index_head_dim over
+    # one cached key a token) scores every cached position and attention
+    # runs over the index_topk best (all of them up to index_topk).
+    # 'sliding' layers are latent attention of their OWN sizes (swa_*)
+    # over cfg.window, with rope base rope_local_theta.  layer_pattern
+    # names every layer ('global' | 'sliding', num_layers entries).
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    swa_num_heads: int = 0
+    swa_kv_lora_rank: int = 0
+    swa_q_lora_rank: int = 0
+    swa_qk_nope_head_dim: int = 0
+    swa_qk_rope_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    # normalised latents times sqrt(hidden / rank) (the scale correction
+    # of low-rank projections); False = 1
+    mla_lora_rescale: bool = False
+    # 'headwise': the attention output of head h times sigmoid(x W_g)[h],
+    # the gate read from the layer's normed input, before o_proj
+    attn_gate: str = "none"
 
     @property
     def kv_heads(self) -> int:
@@ -313,6 +337,11 @@ class ModelConfig:
                      + r * self.num_heads
                      * (self.qk_nope_head_dim + self.v_head_dim)
                      + self.num_heads * self.v_head_dim * h)
+        if self.swa_kv_lora_rank:
+            # per-kind latent attention: return early with the sum over
+            # the pattern (gate and indexer projections counted)
+            from torchacc_tpu.models.mla import attn_param_count
+            attn = None
         dense_mlp = mlp
         if self.num_experts > 0:
             # the held experts, the router's whole width, shared experts
@@ -332,7 +361,10 @@ class ModelConfig:
         if self.head_bias:
             out += v
         nd = self.first_dense_layers
-        return (emb + self.num_layers * attn + nd * dense_mlp
+        attn_all = (self.num_layers * attn if attn is not None else
+                    sum(attn_param_count(self, i)
+                        for i in range(self.num_layers)))
+        return (emb + attn_all + nd * dense_mlp
                 + (self.num_layers - nd) * mlp + norms + out)
 
 

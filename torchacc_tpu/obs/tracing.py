@@ -101,8 +101,9 @@ SCOPES: Dict[str, str] = {
     "attn": "attention block outside its kernels: projections, rope, "
             "relayouts (training)",
     "qkv": "q/k/v projections, qk-norm and rope (serving)",
-    "kv_write": "the two in-place scatters of the new tokens' rows into "
-                "the paged KV pool at (layer, block, offset)",
+    "kv_write": "the in-place scatters of the new tokens' rows into the "
+                "paged pools at (layer, block, offset): k and v, or a "
+                "latent row (and a full layer's index key)",
     "paged_attn": "the paged-attention kernel and its relayouts",
     "o_proj": "attention output projection + residual (serving)",
     "mlp": "feed-forward block",
@@ -124,6 +125,22 @@ SCOPES: Dict[str, str] = {
               "every head's k and v expanded from it)",
     "latent_attn": "the latent paged-attention kernel (one shared row a "
                    "token, values = the row's latent part)",
+    "index_write": "indexed selection: the index key a token banks "
+                   "(projection, LayerNorm, rope); its scatter into "
+                   "the index-key pool is a kv_write",
+    "indexer": "indexed selection: the indexer's query heads and "
+               "weights, and the kernel that scores every visible "
+               "cached position from the paged index keys",
+    "index_topk": "indexed selection: the exact search for the "
+                  "index_topk best scores a query (skipped while no "
+                  "slot holds more positions than that)",
+    "sparse_latent_attn": "the latent kernel of a full layer under the "
+                          "indexer's selection",
+    "window_latent_attn": "the latent kernel of a sliding layer, bounded "
+                          "to the blocks its window reaches",
+    "attn_gate": "the headwise gate on the attention output: its "
+                 "projection, sigmoid and product, and the value "
+                 "up-projection it is applied to",
     "router": "expert layer: router matmul in f32, scores, group-limited "
               "top-k, weights",
     "moe_dispatch": "expert layer: sort of the (token, expert) pairs on "

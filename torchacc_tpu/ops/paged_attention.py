@@ -322,9 +322,28 @@ def _paged_attention_pallas_sharded(mesh, q, k_pool, v_pool, block_tables,
 # can see (its causal reach, the slot's length), so blocks past it are
 # not fetched again.
 
-@functools.partial(jax.jit, static_argnames=("scale",))
+#
+# Two more shapes of the same attention (a model that mixes a learned
+# sparse selection with windowed latent layers, models/mla.py):
+#
+# - ``window`` >= 0: a query at position t sees ``[t - window, t]``; the
+#   grid's block axis covers only the blocks a query tile's window
+#   reaches, counted from the tile's first live block, so the table's
+#   entries before the window are never read (serve/kv_cache.py frees
+#   them);
+# - ``selection = (scores, thr, tie_hi)``: query (s, t) attends position
+#   p iff ``scores[s, t, p] > thr[s, t]``, or ``== thr`` and ``p <=
+#   tie_hi[s, t]`` (:func:`select_topk`: exactly the k best, ties to the
+#   lower position).  The kernel walks the slot's live pages as before
+#   and masks what is not selected: it reads whole pages, not the
+#   selected rows alone (a gather of 1,280-byte rows costs more DMA
+#   issues than the pages cost bandwidth at the contexts served today;
+#   ROADMAP R3).
+
+@functools.partial(jax.jit, static_argnames=("scale", "window"))
 def _latent_paged_attention_xla(q_lat, q_pe, pool, block_tables,
-                                context_lens, q_start, layer, scale):
+                                context_lens, q_start, layer, selection=None,
+                                *, scale, window=-1):
     s_, t_, h, r = q_lat.shape
     bs = pool.shape[2]
     mb = block_tables.shape[1]
@@ -338,6 +357,13 @@ def _latent_paged_attention_xla(q_lat, q_pe, pool, block_tables,
     q_pos = q_start[:, None] + jnp.arange(t_, dtype=jnp.int32)
     mask = kv_pos[None, None, :] < context_lens[:, None, None]
     mask &= kv_pos[None, None, :] <= q_pos[:, :, None]
+    if window >= 0:
+        mask &= kv_pos[None, None, :] >= q_pos[:, :, None] - window
+    if selection is not None:
+        sel_scores, thr, tie_hi = selection
+        mask &= (sel_scores > thr[..., None]) | (
+            (sel_scores == thr[..., None])
+            & (kv_pos[None, None, :] <= tie_hi[..., None]))
     mask = mask[:, None, :, :]
     scores = jnp.where(mask, scores, NEG_INF)
     lse = jax.nn.logsumexp(scores, axis=-1)
@@ -346,10 +372,13 @@ def _latent_paged_attention_xla(q_lat, q_pe, pool, block_tables,
     return out.astype(q_lat.dtype)
 
 
-def _latent_fwd_kernel(tbl_ref, lens_ref, layer_ref, ql_ref, qp_ref, kv_ref,
-                       o_ref, m_scr, l_scr, acc_scr,
-                       *, scale, block_size, latent, rope, heads, tq, rows,
-                       num_kv_blocks):
+def _latent_fwd_kernel(tbl_ref, lens_ref, layer_ref, ql_ref, qp_ref, *rest,
+                       scale, block_size, latent, rope, heads, tq, rows,
+                       num_kv_blocks, window=-1, select=False):
+    if select:
+        sc_ref, thr_ref, tie_ref, kv_ref, o_ref, m_scr, l_scr, acc_scr = rest
+    else:
+        kv_ref, o_ref, m_scr, l_scr, acc_scr = rest
     si = pl.program_id(0)
     ti = pl.program_id(1)
     bi = pl.program_id(2)
@@ -363,6 +392,10 @@ def _latent_fwd_kernel(tbl_ref, lens_ref, layer_ref, ql_ref, qp_ref, kv_ref,
     ctx = lens_ref[si, 0]
     q0 = lens_ref[si, 1] + ti * tq          # this tile's first position
     k_start = bi * block_size
+    if window >= 0:
+        # the block axis counts from the first block the tile's window
+        # reaches
+        k_start += jnp.maximum(q0 - window, 0) // block_size * block_size
 
     @pl.when((k_start < ctx) & (k_start <= q0 + tq - 1))
     def _compute():
@@ -372,6 +405,20 @@ def _latent_fwd_kernel(tbl_ref, lens_ref, layer_ref, ql_ref, qp_ref, kv_ref,
         q_pos = q0 + jax.lax.broadcasted_iota(
             jnp.int32, (rows, block_size), 0) // heads
         mask = (kv_pos < ctx) & (kv_pos <= q_pos)
+        if window >= 0:
+            mask &= kv_pos >= q_pos - window
+        if select:
+            # one token's selection for all its heads: [tq, BS] ->
+            # [tq * heads, BS], token-major like the rows
+            sc = sc_ref[0, 0]
+            pos = k_start + jax.lax.broadcasted_iota(
+                jnp.int32, (tq, block_size), 1)
+            chosen = ((sc > thr_ref[0, 0]) | (
+                (sc == thr_ref[0, 0]) & (pos <= tie_ref[0, 0]))
+            ).astype(jnp.float32)
+            mask &= jnp.concatenate(
+                [jnp.broadcast_to(chosen[i:i + 1], (heads, block_size))
+                 for i in range(tq)], axis=0) > 0.0
         c_kv = kv_ref[:, :latent]                             # [BS, R]
         k_pe = kv_ref[:, latent:latent + rope]                # [BS, P]
         s = (jax.lax.dot_general(
@@ -400,7 +447,8 @@ def _latent_fwd_kernel(tbl_ref, lens_ref, layer_ref, ql_ref, qp_ref, kv_ref,
 
 
 def latent_query_tile(num_heads: int, latent: int, rope: int,
-                      block_size: int, t: int, dtype) -> int:
+                      block_size: int, t: int, dtype,
+                      select: bool = False) -> int:
     """Query tokens one grid step of the latent kernel takes of ``t`` a
     slot (all heads of each), or ``ValueError`` where no tile fits: the
     largest divisor of ``t`` whose blocks — q and out [rows, R] and
@@ -419,11 +467,14 @@ def latent_query_tile(num_heads: int, latent: int, rope: int,
     def need(rows):
         return (2 * rows * (2 * lat + pe) * itemsize
                 + rows * (2 * _LANES + lat) * 4
-                + 3 * rows * max(block_size, _LANES) * 4 + page)
+                + (5 if select else 3) * rows * max(block_size, _LANES) * 4
+                + page)
     for tq in range(t, 0, -1):
         rows = tq * num_heads
+        # a selection's tile unrolls over its tokens: at most 8 of them
         if t % tq == 0 and (rows % 8 == 0 or tq == t) \
-                and need(rows) <= _VMEM_BUDGET:
+                and need(rows) <= _VMEM_BUDGET \
+                and (not select or tq <= 8):
             return tq
     raise ValueError(
         f"latent paged attention kernel: {num_heads} heads of a "
@@ -433,13 +484,18 @@ def latent_query_tile(num_heads: int, latent: int, rope: int,
 
 
 def _latent_paged_attention_pallas(q_lat, q_pe, pool, block_tables,
-                                   context_lens, q_start, layer, *, scale):
+                                   context_lens, q_start, layer,
+                                   selection=None, *, scale, window=-1,
+                                   name="latent_paged_attention"):
     s_, t_, h, r = q_lat.shape
     pe = q_pe.shape[-1]
     bs, w = pool.shape[2], pool.shape[3]
     mb = block_tables.shape[1]
-    tq = latent_query_tile(h, r, pe, bs, t_, pool.dtype)
+    select = selection is not None
+    tq = latent_query_tile(h, r, pe, bs, t_, pool.dtype, select)
     nt, rows = t_ // tq, tq * h
+    # blocks one tile's window can reach: its positions span window + tq
+    nb = mb if window < 0 else min(mb, -(-(window + tq) // bs) + 1)
     lens = jnp.stack([context_lens.astype(jnp.int32),
                       q_start.astype(jnp.int32)], axis=1)
     layer = layer.reshape(1)
@@ -452,15 +508,29 @@ def _latent_paged_attention_pallas(q_lat, q_pe, pool, block_tables,
         # slot's length (the same index again = no new fetch)
         reach = jnp.minimum(lens[s, 0], lens[s, 1] + (t + 1) * tq)
         last = jnp.maximum(reach - 1, 0) // bs
+        if window >= 0:
+            b = b + jnp.maximum(lens[s, 1] + t * tq - window, 0) // bs
         return (layer[0], tbl[s, jnp.minimum(b, last)], 0, 0)
 
     q_map = lambda s, t, b, tbl, lens, layer: (s, t, 0, 0)  # noqa: E731
+    in_specs = [pl.BlockSpec((1, 1, rows, r), q_map),
+                pl.BlockSpec((1, 1, rows, pe), q_map)]
+    operands = [ql, qp]
+    if select:
+        sel_scores, thr, tie_hi = selection
+        # the score tile of the page in hand, and the tile's thresholds
+        in_specs += [
+            pl.BlockSpec((1, 1, tq, bs),
+                         lambda s, t, b, tbl, lens, layer: (s, t, 0, b)),
+            pl.BlockSpec((1, 1, tq, 1), q_map),
+            pl.BlockSpec((1, 1, tq, 1), q_map)]
+        operands += [sel_scores.reshape(s_, nt, tq, mb * bs),
+                     thr.reshape(s_, nt, tq, 1).astype(jnp.float32),
+                     tie_hi.reshape(s_, nt, tq, 1).astype(jnp.int32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(s_, nt, mb),
-        in_specs=[pl.BlockSpec((1, 1, rows, r), q_map),
-                  pl.BlockSpec((1, 1, rows, pe), q_map),
-                  pl.BlockSpec((None, None, bs, w), page)],
+        grid=(s_, nt, nb),
+        in_specs=in_specs + [pl.BlockSpec((None, None, bs, w), page)],
         out_specs=pl.BlockSpec((1, 1, rows, r), q_map),
         scratch_shapes=[
             pltpu.VMEM((rows, _LANES), jnp.float32),
@@ -470,7 +540,8 @@ def _latent_paged_attention_pallas(q_lat, q_pe, pool, block_tables,
     )
     kernel = functools.partial(
         _latent_fwd_kernel, scale=scale, block_size=bs, latent=r, rope=pe,
-        heads=h, tq=tq, rows=rows, num_kv_blocks=mb)
+        heads=h, tq=tq, rows=rows, num_kv_blocks=nb, window=window,
+        select=select)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -478,8 +549,8 @@ def _latent_paged_attention_pallas(q_lat, q_pe, pool, block_tables,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
-        name="latent_paged_attention",
-    )(block_tables.astype(jnp.int32), lens, layer, ql, qp, pool)
+        name=name,
+    )(block_tables.astype(jnp.int32), lens, layer, *operands, pool)
     return out.reshape(s_, t_, h, r)
 
 
@@ -494,6 +565,9 @@ def latent_paged_attention(
     layer,
     scale: float,
     impl: str = "auto",
+    window: int = -1,
+    selection=None,
+    name: str = "latent_paged_attention",
 ) -> jax.Array:
     """Causal absorbed-form MLA attention over layer ``layer`` of a
     latent paged pool.
@@ -502,7 +576,11 @@ def latent_paged_attention(
     [S, T, H, P] (rotated); ``pool`` [layers, num_blocks, block_size, W]
     with a token's row ``[c_kv (R) | rope(k_pe) (P) | padding]``.
     Tables, lengths, ``q_start`` and ``impl`` as
-    :func:`paged_attention`.  Returns the latent outputs ``P c_kv``
+    :func:`paged_attention`.  ``window`` >= 0 bounds a query at t to
+    ``[t - window, t]``; ``selection`` ``(scores [S, T, MB * BS], thr
+    [S, T], tie_hi [S, T])`` to the positions :func:`select_topk` chose;
+    ``name`` is the kernel's instruction name (a profile reads the
+    variants apart by it).  Returns the latent outputs ``P c_kv``
     [S, T, H, R]; the caller applies ``W_kvb^V``."""
     if q_lat.ndim != 4 or q_pe.shape[:3] != q_lat.shape[:3]:
         raise ValueError(f"q_lat {q_lat.shape} / q_pe {q_pe.shape} must be "
@@ -520,18 +598,214 @@ def latent_paged_attention(
         impl = "pallas" if _on_tpu() else "xla"
     if impl not in ("pallas", "xla"):
         raise ValueError(f"impl must be auto|pallas|xla, got {impl!r}")
-    fn = functools.partial(
-        _latent_paged_attention_pallas if impl == "pallas"
-        else _latent_paged_attention_xla, scale=float(scale))
+    fn = (functools.partial(_latent_paged_attention_pallas, name=name)
+          if impl == "pallas" else _latent_paged_attention_xla)
+    fn = functools.partial(fn, scale=float(scale), window=int(window))
+    args = (q_lat, q_pe, pool, block_tables.astype(jnp.int32),
+            context_lens.astype(jnp.int32), q_start.astype(jnp.int32),
+            jnp.asarray(layer, jnp.int32))
+    if selection is not None:
+        args += (tuple(selection),)
     mesh = ambient_mesh()
     if impl == "pallas" and needs_shard_map(mesh):
         # one shared row for every head: nothing to split, each shard
         # runs the whole call
+        fn = jax.shard_map(fn, mesh=mesh, in_specs=(P(),) * len(args),
+                           out_specs=P(), check_vma=False)
+    return fn(*args)
+
+
+# ---------------------------------------------------------------------------
+# the learned selection in front of latent attention: indexer scores over
+# a paged pool of index keys, and the exact k best of them
+# ---------------------------------------------------------------------------
+#
+# A token banks ONE index key of ``dI`` lanes in the pool [L, NB, BS, dI]
+# beside its latent row (same block, same offset).  A query's score of a
+# cached position is ``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])``
+# over the indexer's ``nI`` heads; the [T, nI, ctx] products exist only
+# in VMEM, a page at a time — what reaches HBM is I, one float32 a
+# (query, position), NEG_INF where the position is not visible.
+
+@jax.jit
+def _indexer_scores_xla(q_idx, w, pool, block_tables, context_lens,
+                        q_start, layer):
+    s_, t_ = q_idx.shape[:2]
+    bs, mb = pool.shape[2], block_tables.shape[1]
+    keys = pool[layer, block_tables].reshape(s_, mb * bs, -1)
+    prod = jnp.einsum("stjd,skd->stjk", q_idx.astype(jnp.float32),
+                      keys.astype(jnp.float32))
+    scores = jnp.sum(jnp.maximum(prod, 0.0) * w[..., None], axis=2)
+    kv_pos = jnp.arange(mb * bs, dtype=jnp.int32)
+    q_pos = q_start[:, None] + jnp.arange(t_, dtype=jnp.int32)
+    mask = ((kv_pos[None, None, :] < context_lens[:, None, None])
+            & (kv_pos[None, None, :] <= q_pos[:, :, None]))
+    return jnp.where(mask, scores, NEG_INF)
+
+
+def _indexer_kernel(tbl_ref, lens_ref, layer_ref, q_ref, w_ref, k_ref, o_ref,
+                    *, block_size, heads, tq):
+    si, ti, bi = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    ctx = lens_ref[si, 0]
+    q0 = lens_ref[si, 1] + ti * tq
+    k_start = bi * block_size
+    live = (k_start < ctx) & (k_start <= q0 + tq - 1)
+
+    @pl.when(live)
+    def _compute():
+        s = jax.lax.dot_general(
+            q_ref[0, 0], k_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [tq * nI, BS]
+        s = jnp.maximum(s, 0.0) * w_ref[0, 0]              # [rows, 1] weights
+        # rows are token-major: a token's heads are `heads` adjacent rows
+        tok = jnp.concatenate(
+            [jnp.sum(s[i * heads:(i + 1) * heads], axis=0, keepdims=True)
+             for i in range(tq)], axis=0)                  # [tq, BS]
+        kv_pos = k_start + jax.lax.broadcasted_iota(
+            jnp.int32, (tq, block_size), 1)
+        q_pos = q0 + jax.lax.broadcasted_iota(
+            jnp.int32, (tq, block_size), 0)
+        o_ref[0, 0] = jnp.where((kv_pos < ctx) & (kv_pos <= q_pos), tok,
+                                NEG_INF)
+
+    @pl.when(jnp.logical_not(live))
+    def _fill():
+        o_ref[0, 0] = jnp.full((tq, block_size), NEG_INF, jnp.float32)
+
+
+def index_query_tile(heads: int, dim: int, block_size: int, t: int,
+                     dtype) -> int:
+    """Query tokens one grid step of the indexer kernel takes of ``t`` a
+    slot, or ``ValueError``: the largest divisor of ``t``, at most 32
+    (the head sum unrolls over the tile's tokens), whose blocks — q
+    [rows, dI] and the weights [rows, 1] (a lane-padded column)
+    double-buffered, the key page, the [rows, bs] float32 products and
+    the [tq, bs] output — fit the VMEM budget (rows = tokens x heads)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    if block_size % min_block_size(dtype):
+        raise ValueError(
+            f"indexer kernel: block_size {block_size} is not a multiple "
+            f"of {min_block_size(dtype)}, the TPU sublane tile of a "
+            f"{jnp.dtype(dtype).name} pool")
+    lanes = round_up(dim, _LANES)
+    bsl = max(block_size, _LANES)
+
+    def need(tq):
+        rows = tq * heads
+        return (2 * rows * lanes * itemsize + 2 * rows * _LANES * 4
+                + 3 * rows * bsl * 4 + 2 * block_size * lanes * itemsize
+                + 2 * max(tq, 8) * bsl * 4)
+    for tq in range(min(t, 32), 0, -1):
+        if t % tq == 0 and (tq % 8 == 0 or tq == t) \
+                and need(tq) <= _VMEM_BUDGET:
+            return tq
+    raise ValueError(
+        f"indexer kernel: {heads} heads of {dim} do not fit the "
+        f"{_VMEM_BUDGET / 2**20:.0f} MiB VMEM budget in any tile of {t} "
+        f"query tokens")
+
+
+def _indexer_scores_pallas(q_idx, w, pool, block_tables, context_lens,
+                           q_start, layer):
+    s_, t_, nh, d = q_idx.shape
+    bs, mb = pool.shape[2], block_tables.shape[1]
+    tq = index_query_tile(nh, d, bs, t_, pool.dtype)
+    nt, rows = t_ // tq, tq * nh
+    lens = jnp.stack([context_lens, q_start], axis=1)
+
+    def page(s, t, b, tbl, lens, layer):
+        reach = jnp.minimum(lens[s, 0], lens[s, 1] + (t + 1) * tq)
+        last = jnp.maximum(reach - 1, 0) // bs
+        return (layer[0], tbl[s, jnp.minimum(b, last)], 0, 0)
+
+    q_map = lambda s, t, b, tbl, lens, layer: (s, t, 0, 0)  # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(s_, nt, mb),
+        in_specs=[pl.BlockSpec((1, 1, rows, d), q_map),
+                  pl.BlockSpec((1, 1, rows, 1), q_map),
+                  pl.BlockSpec((None, None, bs, d), page)],
+        out_specs=pl.BlockSpec(
+            (1, 1, tq, bs), lambda s, t, b, tbl, lens, layer: (s, t, 0, b)),
+    )
+    out = pl.pallas_call(
+        functools.partial(_indexer_kernel, block_size=bs, heads=nh, tq=tq),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((s_, nt, tq, mb * bs), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=_interpret(),
+        name="indexer_scores",
+    )(block_tables, lens, layer.reshape(1),
+      q_idx.reshape(s_, nt, rows, d),
+      w.astype(jnp.float32).reshape(s_, nt, rows, 1), pool)
+    return out.reshape(s_, t_, mb * bs)
+
+
+def indexer_scores(q_idx: jax.Array, w: jax.Array, pool: jax.Array,
+                   block_tables: jax.Array, context_lens: jax.Array,
+                   q_start: jax.Array, *, layer, impl: str = "auto"
+                   ) -> jax.Array:
+    """``I[s, t, p] = sum_j w[s, t, j] relu(q_idx[s, t, j] . key(s, p))``
+    over layer ``layer`` of the paged index-key pool [layers, blocks,
+    block_size, dI], float32 [S, T, MB * BS]; NEG_INF where position p
+    is not banked or lies after query t.  ``q_idx`` [S, T, nI, dI]
+    (rotated), ``w`` [S, T, nI]; tables, lengths, ``q_start`` and
+    ``impl`` as :func:`paged_attention`."""
+    if q_idx.ndim != 4 or w.shape != q_idx.shape[:3]:
+        raise ValueError(f"q_idx {q_idx.shape} / w {w.shape} must be "
+                         f"[slots, t, heads, dI] and [slots, t, heads]")
+    if pool.ndim != 4 or pool.shape[3] != q_idx.shape[3]:
+        raise ValueError(f"pool {pool.shape} must be [layers, blocks, "
+                         f"block_size, {q_idx.shape[3]}]")
+    if impl == "auto":
+        impl = "pallas" if _on_tpu() else "xla"
+    if impl not in ("pallas", "xla"):
+        raise ValueError(f"impl must be auto|pallas|xla, got {impl!r}")
+    fn = _indexer_scores_pallas if impl == "pallas" else _indexer_scores_xla
+    args = (q_idx, w, pool, block_tables.astype(jnp.int32),
+            context_lens.astype(jnp.int32), q_start.astype(jnp.int32),
+            jnp.asarray(layer, jnp.int32))
+    mesh = ambient_mesh()
+    if impl == "pallas" and needs_shard_map(mesh):
         fn = jax.shard_map(fn, mesh=mesh, in_specs=(P(),) * 7,
                            out_specs=P(), check_vma=False)
-    return fn(q_lat, q_pe, pool, block_tables.astype(jnp.int32),
-              context_lens.astype(jnp.int32), q_start.astype(jnp.int32),
-              jnp.asarray(layer, jnp.int32))
+    return fn(*args)
+
+
+def select_topk(scores: jax.Array, k: int):
+    """The exact ``k`` best of ``scores`` [..., N] along the last axis,
+    ties to the lower position, as a rule a kernel can apply to a tile:
+    ``(thr [...], tie_hi [...])`` such that position p is chosen iff
+    ``scores[p] > thr`` or (``scores[p] == thr`` and ``p <= tie_hi``).
+    Exactly ``k`` positions satisfy it (rows of fewer than ``k``
+    positions above NEG_INF choose them all, and some masked ones the
+    caller's own mask drops).
+
+    No sort: the k-th largest value is found bit by bit on the floats'
+    order-preserving integer keys — 32 counting passes over the row —
+    and the ties at it by one running count."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.uint32)
+    sign = jnp.uint32(1 << 31)
+    # unsigned keys in the floats' order: negatives flip whole, the rest
+    # gain the top bit
+    key = jnp.where(bits >= sign, ~bits, bits | sign)
+
+    def refine(i, prefix):
+        cand = prefix | (sign >> i.astype(jnp.uint32))
+        enough = jnp.sum(key >= cand[..., None], axis=-1,
+                         dtype=jnp.int32) >= k
+        return jnp.where(enough, cand, prefix)
+
+    kth = jax.lax.fori_loop(0, 32, refine,
+                            jnp.zeros(scores.shape[:-1], jnp.uint32))
+    thr = jax.lax.bitcast_convert_type(
+        jnp.where(kth >= sign, kth ^ sign, ~kth), jnp.float32)
+    above = jnp.sum(scores > thr[..., None], axis=-1, dtype=jnp.int32)
+    ties = scores == thr[..., None]
+    seen = jnp.cumsum(ties.astype(jnp.int32), axis=-1)
+    tie_hi = jnp.argmax(ties & (seen >= (k - above)[..., None]), axis=-1)
+    return thr, tie_hi.astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------

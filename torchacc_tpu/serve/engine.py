@@ -1023,6 +1023,10 @@ class ServeEngine:
             # deadline preemption (serve.preempt_deadlines): admitted
             # sequences evicted mid-decode with a typed partial result
             "preempted": a.get("preempted", 0),
+            # blocks in use now by kind of layer (a model that mixes
+            # windowed and full latent layers also says how many window
+            # blocks went back as windows passed; serve/kv_cache.py)
+            **self.scheduler.blocks_by_kind(),
         }
 
     def admission_snapshot(self) -> Dict[str, Any]:

@@ -37,6 +37,17 @@ pressure.  Eviction only ever takes refcount-0 cached blocks, so the
 whole-reservation admission guarantee survives: blocks owned by an
 admitted sequence are untouchable until that sequence frees them.
 
+A model that mixes windowed and full latent layers (models/mla.py, the
+'dots3_note' family) has THREE pools and two kinds of block: the full
+layers' latent pool and their index-key pool share one block table
+(a token's latent row and its index key lie at the same block and
+offset), reserved whole at admission like any other; the window layers'
+latent pool has its own :class:`WindowBlocks` — a sequence holds only
+the blocks its window still reaches, takes them as it grows and gives
+them back as the window passes, so a 32k-token sequence costs those
+layers ``window_blocks_bound`` blocks (10 at a window of 513, chunks of
+512 and blocks of 128) where a full table would hold 260.
+
 The allocator is deliberately host-side and synchronous: allocation
 decisions happen at admission time (serve/engine.py), outside the
 jitted hot path, exactly like the trainer's host/device split
@@ -269,6 +280,75 @@ class BlockPool:
         return n
 
 
+def window_blocks_bound(window: int, chunk: int, block_size: int) -> int:
+    """The most window-layer blocks one sequence holds: a program's
+    queries span at most ``chunk`` positions and each sees ``window``
+    positions back and itself, so the live rows span ``window + 1 +
+    chunk`` positions, which touch one block more than they fill."""
+    return blocks_needed(window + 1 + chunk, block_size) + 1
+
+
+class WindowBlocks:
+    """The window layers' blocks: a :class:`BlockPool` of their own kind
+    in which a sequence holds a block only while some query still to
+    come can see a row of it.
+
+    ``reserve`` at admission sets ``bound`` blocks aside for the
+    sequence (never more are live, :func:`window_blocks_bound`), so that
+    ``advance`` cannot fail; ``advance`` runs before every program of
+    the sequence: it frees the blocks that lie wholly before the first
+    query's window and takes the blocks the program's rows land in,
+    writing both into the sequence's table row (0 = not held).  A freed
+    block goes back to the pool at once: programs run in dispatch order
+    on the device, so whoever is handed it next writes it after every
+    program that could still read it has read it."""
+
+    def __init__(self, num_blocks: int, block_size: int, window: int,
+                 bound: int):
+        self.pool = BlockPool(num_blocks)
+        self.block_size, self.window, self.bound = block_size, window, bound
+        self.reserved = 0
+        self.freed = 0                      # blocks returned as windows passed
+
+    def can_reserve(self) -> bool:
+        return self.pool.num_blocks - 1 - self.reserved >= self.bound
+
+    def reserve(self) -> None:
+        if not self.can_reserve():
+            raise ValueError("window blocks: reservation beyond the pool")
+        self.reserved += self.bound
+
+    def advance(self, held: Dict[int, int], row: np.ndarray,
+                first_query: int, upto: int) -> bool:
+        """Make ``held`` (logical block -> pool block) and ``row`` right
+        for a program whose queries are positions [first_query, upto).
+        Returns whether the row changed."""
+        bs = self.block_size
+        lo = max(first_query - self.window, 0) // bs
+        hi = (upto - 1) // bs
+        dead = [j for j in held if j < lo]
+        for j in dead:
+            self.pool.free([held.pop(j)])
+            row[j] = 0
+        self.freed += len(dead)
+        new = [j for j in range(max(lo, max(held, default=-1) + 1), hi + 1)]
+        if new:
+            got = self.pool.alloc(len(new))
+            if got is None or len(held) + len(new) > self.bound:
+                raise RuntimeError(
+                    f"window blocks: a sequence needs {len(held) + len(new)} "
+                    f"blocks, its reservation is {self.bound}")
+            for j, b in zip(new, got):
+                held[j] = b
+                row[j] = b
+        return bool(dead or new)
+
+    def release(self, blocks: List[int]) -> None:
+        """A sequence is gone: its blocks and its reservation return."""
+        self.pool.free(blocks)
+        self.reserved -= self.bound
+
+
 def latent_row_width(model_cfg) -> int:
     """Lanes of one token's row in a latent pool: ``[c_kv | rope(k_pe)]``
     (kv_lora_rank + qk_rope_head_dim values) padded to whole 128-lane
@@ -279,17 +359,48 @@ def latent_row_width(model_cfg) -> int:
     return round_up(model_cfg.kv_lora_rank + model_cfg.qk_rope_head_dim, 128)
 
 
+def num_window_blocks(model_cfg, serve_cfg) -> int:
+    """Blocks of the window layers' pool: every slot's bound and the
+    null block.  No setting sizes it: a sequence's reservation is fixed
+    at admission, so fewer blocks would only admit fewer sequences
+    (``serve.max_slots`` says that) and more would never be touched."""
+    return serve_cfg.max_slots * window_blocks_bound(
+        model_cfg.window[0], serve_cfg.prefill_chunk,
+        serve_cfg.block_size) + 1
+
+
 def make_pools(model_cfg, serve_cfg, dtype=None):
     """The paged pools of a model, a tuple, in the model's compute
     dtype: ``(k_pools, v_pools)`` of shape [L, NB, BS, KH*D], or for a
     latent-attention model ONE pool ``(latent,)`` of shape
     [L, NB, BS, latent_row_width] with no head dimension (every head
-    reads the same row).  When a mesh is live and its 'tp' divides the
+    reads the same row), or for a model of two latent kinds ``(full
+    latent [L_full, NB, BS, W], index keys [L_full, NB, BS, dI], window
+    latent [L_win, NB_win, BS, W_win])``.  When a mesh is live and its 'tp' divides the
     kv heads, the k/v rows are sharded over it in whole-head groups (the
     same activation-constraint seam the model layers use, so the TP head
     composes — parallel/sharding.py); a latent pool is replicated."""
     from torchacc_tpu.parallel.sharding import activation_constraint
 
+    if model_cfg.swa_kv_lora_rank:
+        # two kinds of latent layer: (full layers' latent rows, their
+        # index keys, window layers' latent rows), each stacked over the
+        # layers of its kind; the first two share the block table
+        from torchacc_tpu.models.mla import kind_config, layer_kind
+        kinds = [layer_kind(model_cfg, i)
+                 for i in range(model_cfg.num_layers)]
+        n_win = kinds.count("sliding")
+        n_full = len(kinds) - n_win
+        dt = dtype or model_cfg.dtype
+        bs = serve_cfg.block_size
+        return (
+            jnp.zeros((n_full, serve_cfg.num_blocks, bs, latent_row_width(
+                kind_config(model_cfg, "global"))), dt),
+            jnp.zeros((n_full, serve_cfg.num_blocks, bs,
+                       model_cfg.index_head_dim), dt),
+            jnp.zeros((n_win, num_window_blocks(model_cfg, serve_cfg), bs,
+                       latent_row_width(kind_config(model_cfg, "sliding"))),
+                      dt))
     if model_cfg.kv_lora_rank:
         return (jnp.zeros((model_cfg.num_layers, serve_cfg.num_blocks,
                            serve_cfg.block_size,

@@ -74,16 +74,22 @@ from torchacc_tpu.obs import tracing
 from torchacc_tpu.ops._common import on_tpu
 from torchacc_tpu.ops.paged_attention import (
     heads_per_step,
+    index_query_tile,
+    indexer_scores,
     latent_paged_attention,
     latent_query_tile,
     paged_attention,
+    select_topk,
 )
 from torchacc_tpu.resilience.chaos import failpoint
 from torchacc_tpu.serve.kv_cache import (
     BlockPool,
     PrefixIndex,
+    WindowBlocks,
     blocks_needed,
     make_pools,
+    num_window_blocks,
+    window_blocks_bound,
 )
 from torchacc_tpu.utils.logger import logger
 from torchacc_tpu.utils.metrics import counters
@@ -134,7 +140,29 @@ _AUDITED_MODEL_FIELDS = frozenset({
     "moe_scoring", "moe_n_group", "moe_topk_group", "moe_route_scale",
     "moe_router_bias", "moe_shared_experts", "moe_router_width",
     "moe_first_expert",
+    # PR-30 audit: two kinds of latent layer under one layer_pattern
+    # (_attend_sparse / _attend_window, the three pools of
+    # serve/kv_cache.py, _forward's scan over periods), the latents'
+    # rescale and the headwise gate (models/mla.py)
+    "index_topk", "index_n_heads", "index_head_dim", "swa_num_heads",
+    "swa_kv_lora_rank", "swa_q_lora_rank", "swa_qk_nope_head_dim",
+    "swa_qk_rope_head_dim", "swa_v_head_dim", "mla_lora_rescale",
+    "attn_gate",
 })
+
+
+def _period(cfg):
+    """``(kinds of the leading dense layers, kinds of one period)`` of a
+    model of two latent kinds: the layers after the dense ones repeat
+    the shortest period that divides them."""
+    from torchacc_tpu.models.mla import layer_kind
+    kinds = [layer_kind(cfg, i) for i in range(cfg.num_layers)]
+    dense, rest = kinds[:cfg.first_dense_layers], \
+        kinds[cfg.first_dense_layers:]
+    for n in range(1, len(rest) + 1):
+        if len(rest) % n == 0 and rest == rest[:n] * (len(rest) // n):
+            return dense, rest[:n]
+    return dense, rest
 
 
 def _check_supported(cfg) -> None:
@@ -169,12 +197,35 @@ def _check_supported(cfg) -> None:
         bad.append("pipeline parallelism (pp_size > 1)")
     if cfg.context_parallel:
         bad.append("context parallelism")
-    if cfg.layer_pattern:
-        bad.append("layer_pattern (per-layer sliding windows)")
+    two_kinds = bool(cfg.kv_lora_rank and cfg.swa_kv_lora_rank)
+    if two_kinds:
+        # windowed and full latent layers under one pattern: admitted
+        # where the scan over periods and the three pools can hold it
+        dense, period = _period(cfg)
+        if (not cfg.layer_pattern or set(cfg.layer_pattern)
+                - {"global", "sliding"} or cfg.window[0] < 0
+                or cfg.window[1] >= 0 or not cfg.index_topk
+                or not cfg.num_experts or not cfg.first_dense_layers
+                or "sliding" in dense or not cfg.q_lora_rank):
+            bad.append("two kinds of latent layer in any arrangement but: "
+                       "a layer_pattern of 'global' (indexed) and "
+                       "'sliding' (left window) layers, the leading dense "
+                       "layers all 'global', expert layers after them")
+        if cfg.attn_gate not in ("none", "headwise"):
+            bad.append(f"attn_gate {cfg.attn_gate!r}")
+    else:
+        if cfg.layer_pattern:
+            bad.append("layer_pattern (per-layer sliding windows) outside "
+                       "the latent family of two kinds (windows on "
+                       "grouped-query pools)")
+        if tuple(cfg.window) != (-1, -1):
+            bad.append(f"sliding window {cfg.window}")
+        if (cfg.index_topk or cfg.swa_kv_lora_rank
+                or cfg.attn_gate != "none" or cfg.mla_lora_rescale):
+            bad.append("indexed selection, a headwise gate or rescaled "
+                       "latents outside the latent family of two kinds")
     if cfg.pos_emb == "alibi":
         bad.append("pos_emb='alibi'")
-    if tuple(cfg.window) != (-1, -1):
-        bad.append(f"sliding window {cfg.window}")
     if bad:
         raise NotImplementedError(
             "the serving engine (torchacc_tpu/serve) does not yet "
@@ -212,10 +263,33 @@ class PagedDecoder:
         impl = attention_impl or cfg.attention_impl
         if impl == "auto":
             impl = "pallas" if on_tpu() else "xla"
+        self.two_kinds = bool(cfg.swa_kv_lora_rank)
+        if self.two_kinds:
+            from torchacc_tpu.models.mla import kind_config
+            if serve_cfg.prefix_cache:
+                raise NotImplementedError(
+                    "the serving engine does not yet support prefix "
+                    "sharing across window layers (serve.prefix_cache "
+                    "with windowed latent layers: a window layer's "
+                    "blocks are freed as the window passes, so a cached "
+                    "prefix has no rows left to share there)")
+            self._full_cfg = kind_config(cfg, "global")
+            self._win_cfg = kind_config(cfg, "sliding")
         if impl == "pallas":
             for t in (1, serve_cfg.prefill_chunk):
                 try:
-                    if cfg.kv_lora_rank:
+                    if self.two_kinds:
+                        f, w = self._full_cfg, self._win_cfg
+                        latent_query_tile(
+                            f.num_heads, f.kv_lora_rank, f.qk_rope_head_dim,
+                            serve_cfg.block_size, t, cfg.dtype, True)
+                        latent_query_tile(
+                            w.num_heads, w.kv_lora_rank, w.qk_rope_head_dim,
+                            serve_cfg.block_size, t, cfg.dtype)
+                        index_query_tile(
+                            cfg.index_n_heads, cfg.index_head_dim,
+                            serve_cfg.block_size, t, cfg.dtype)
+                    elif cfg.kv_lora_rank:
                         latent_query_tile(
                             cfg.num_heads, cfg.kv_lora_rank,
                             cfg.qk_rope_head_dim, serve_cfg.block_size, t,
@@ -241,6 +315,8 @@ class PagedDecoder:
         # while the mixed trace keeps the one-program-per-request-mix
         # property; both advance the slot PRNG keys identically, so
         # flipping between variants cannot drift a sampled stream
+        # (win_tables, an optional last argument, is None but for a model
+        # of two latent kinds: the window layers' block table)
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1, 2),
                                static_argnums=(9,))
         # is_final is static: the non-final trace skips the vocab head
@@ -264,7 +340,8 @@ class PagedDecoder:
     # -- model forward ------------------------------------------------------
 
     def _layer(self, p, layer, x, pools, positions, tables, ctx_lens, blk,
-               off, valid=None, expert_stacks=None):
+               off, valid=None, expert_stacks=None, kind="",
+               expert_layer=None):
         """Decoder layer ``layer`` over the paged cache: the model's own
         block (models/block.py) on this layer's raw tree ``p``, with the
         two halves that are serving's own.  The attention is
@@ -277,15 +354,26 @@ class PagedDecoder:
         per slot; ``valid`` [S, T] marks the real tokens (the expert
         layer routes no others); ``expert_stacks`` the expert kernels of
         ALL expert layers (:meth:`_forward` keeps them off the scan),
-        read by the grouped matmul at this layer's index among them.
-        Returns ``(x, pools, load)``, ``load`` the expert layer's counts
-        or None."""
+        read by the grouped matmul at this layer's index among them
+        (``expert_layer``; None = ``layer`` less the dense ones).  In a
+        model of two latent kinds ``kind`` names this layer's ('global':
+        indexed selection over the full layers' pools, 'sliding': the
+        window layers' pool); ``layer`` is then its index among the
+        layers of its kind, ``tables`` and ``blk`` pairs ``(full,
+        window)``.  Returns ``(x, pools, load)``, ``load`` the expert
+        layer's counts or None."""
         cfg = self.cfg
+        if kind:
+            cfg = self._full_cfg if kind == "global" else self._win_cfg
+            which = int(kind == "sliding")
+            tables, blk = tables[which], blk[which]
         if cfg.num_experts and "moe" not in p:
             # a leading dense layer of an expert model: TransformerLM
             # gives that stack's blocks this config too
             cfg = dataclasses.replace(cfg, num_experts=0)
-        attend = self._attend_latent if cfg.kv_lora_rank else self._attend
+        attend = (self._attend if not cfg.kv_lora_rank else
+                  {"": self._attend_latent, "global": self._attend_sparse,
+                   "sliding": self._attend_window}[kind])
         # what the halves leave besides their output: the updated pools,
         # the expert layer's counts (all traced in this layer's own trace)
         left = {"load": None}
@@ -312,7 +400,8 @@ class PagedDecoder:
                 cfg, {**p["moe"], **expert_stacks},
                 h2.reshape(s_ * t_, hd),
                 None if valid is None else valid.reshape(-1),
-                layer=layer - cfg.first_dense_layers)
+                layer=(layer - cfg.first_dense_layers
+                       if expert_layer is None else expert_layer))
             return y.reshape(s_, t_, hd)
 
         x = block.block(cfg, x, norm, attention, ffn)
@@ -360,18 +449,11 @@ class PagedDecoder:
 
         cfg = self.cfg
         (pool,) = pools
-        s_, t_ = h.shape[:2]
         with jax.named_scope("mla_q"):
             q_nope, q_pe = mla.project_q(cfg, attn, h, positions)
             q_lat = mla.absorb_q(cfg, attn, q_nope)
-        with jax.named_scope("mla_kv"):
-            c_kv, k_pe = mla.project_latent(cfg, attn, h, positions)
-            row = jnp.concatenate([c_kv, k_pe], axis=-1)
-            row = jnp.pad(row, ((0, 0), (0, 0),
-                                (0, pool.shape[-1] - row.shape[-1])))
-        with jax.named_scope("kv_write"):
-            pool = pool.at[layer, blk.reshape(-1), off.reshape(-1)].set(
-                row.reshape(s_ * t_, -1).astype(pool.dtype))
+        pool = self._bank_latent(cfg, attn, pool, layer, h, positions, blk,
+                                 off)
         with jax.named_scope("latent_attn"):
             o_lat = latent_paged_attention(
                 q_lat, q_pe.astype(q_lat.dtype), pool, tables, ctx_lens,
@@ -380,6 +462,158 @@ class PagedDecoder:
         with jax.named_scope("o_proj"):
             return mla.project_out(
                 cfg, attn, mla.expand_out(cfg, attn, o_lat)), (pool,)
+
+    def _bank_latent(self, cfg, attn, pool, layer, h, positions, blk, off):
+        """Project this chunk's latent rows and write them in place:
+        ``pool`` with ``[c_kv | rope(k_pe) | padding]`` at (layer, blk,
+        off)."""
+        from torchacc_tpu.models import mla
+        s_, t_ = h.shape[:2]
+        with jax.named_scope("mla_kv"):
+            c_kv, k_pe = mla.project_latent(cfg, attn, h, positions)
+            row = jnp.concatenate([c_kv, k_pe], axis=-1)
+            row = jnp.pad(row, ((0, 0), (0, 0),
+                                (0, pool.shape[-1] - row.shape[-1])))
+        with jax.named_scope("kv_write"):
+            return pool.at[layer, blk.reshape(-1), off.reshape(-1)].set(
+                row.reshape(s_ * t_, -1).astype(pool.dtype))
+
+    def _gated_out(self, cfg, attn, h, o_lat):
+        """Latent outputs -> the block's attention output: ``W_kvb^V``,
+        the headwise gate, ``W_o``."""
+        from torchacc_tpu.models import mla
+        with jax.named_scope("attn_gate"):
+            out = mla.head_gate(cfg, attn, h,
+                                mla.expand_out(cfg, attn, o_lat))
+        with jax.named_scope("o_proj"):
+            return mla.project_out(cfg, attn, out)
+
+    def _attend_sparse(self, attn, layer, h, pools, positions, tables,
+                       ctx_lens, blk, off):
+        """A 'global' layer of a model of two latent kinds: latent
+        attention over the ``index_topk`` cached positions its indexer
+        scores highest.  The token banks its latent row in the full
+        layers' pool and ONE index key in the index-key pool, same block
+        and offset; the indexer kernel scores every visible position of
+        the slot, :func:`select_topk` finds the exact k best, and the
+        latent kernel attends them.  While no slot holds more than k
+        positions the selection is every position and the search for the
+        k-th best is skipped (its cache writes are not)."""
+        from torchacc_tpu.models import mla
+
+        cfg = self._full_cfg
+        pool, keys, win = pools
+        s_, t_ = h.shape[:2]
+        with jax.named_scope("mla_q"):
+            c_q = mla.latent_q(cfg, attn, h)
+            q_nope, q_pe = mla.project_q(cfg, attn, h, positions, c_q)
+            q_lat = mla.absorb_q(cfg, attn, q_nope)
+        pool = self._bank_latent(cfg, attn, pool, layer, h, positions, blk,
+                                 off)
+        with jax.named_scope("index_write"):
+            k_idx = mla.index_key(cfg, attn, h, positions)
+        with jax.named_scope("kv_write"):
+            keys = keys.at[layer, blk.reshape(-1), off.reshape(-1)].set(
+                k_idx.reshape(s_ * t_, -1).astype(keys.dtype))
+        q_start = positions[:, 0]
+        with jax.named_scope("indexer"):
+            scores = indexer_scores(
+                mla.index_query(cfg, attn, c_q, positions).astype(keys.dtype),
+                mla.index_weights(cfg, attn, h), keys, tables, ctx_lens,
+                q_start, layer=layer, impl=self.impl)
+        with jax.named_scope("index_topk"):
+            thr, tie_hi = jax.lax.cond(
+                jnp.max(ctx_lens) <= cfg.index_topk,
+                lambda sc: (jnp.full(sc.shape[:2], -jnp.inf, jnp.float32),
+                            jnp.zeros(sc.shape[:2], jnp.int32)),
+                lambda sc: select_topk(sc, cfg.index_topk), scores)
+        with jax.named_scope("sparse_latent_attn"):
+            o_lat = latent_paged_attention(
+                q_lat, q_pe.astype(q_lat.dtype), pool, tables, ctx_lens,
+                q_start, layer=layer, scale=mla.query_scale(cfg),
+                impl=self.impl, selection=(scores, thr, tie_hi),
+                name="sparse_latent_attention")
+        return self._gated_out(cfg, attn, h, o_lat), (pool, keys, win)
+
+    def _attend_window(self, attn, layer, h, pools, positions, tables,
+                       ctx_lens, blk, off):
+        """A 'sliding' layer of a model of two latent kinds: latent
+        attention of its own sizes over ``cfg.window`` positions back,
+        in the window layers' pool through their own table (entries
+        before the window are 0: freed, never read)."""
+        from torchacc_tpu.models import mla
+
+        cfg = self._win_cfg
+        pool, keys, win = pools
+        with jax.named_scope("mla_q"):
+            q_nope, q_pe = mla.project_q(cfg, attn, h, positions)
+            q_lat = mla.absorb_q(cfg, attn, q_nope)
+        win = self._bank_latent(cfg, attn, win, layer, h, positions, blk,
+                                off)
+        with jax.named_scope("window_latent_attn"):
+            o_lat = latent_paged_attention(
+                q_lat, q_pe.astype(q_lat.dtype), win, tables, ctx_lens,
+                positions[:, 0], layer=layer, scale=mla.query_scale(cfg),
+                impl=self.impl, window=cfg.window[0],
+                name="window_latent_attention")
+        return self._gated_out(cfg, attn, h, o_lat), (pool, keys, win)
+
+    def _forward_periods(self, params, pools, x, positions, tables, ctx_lens,
+                         blk, off, valid):
+        """The layer loop of a model of two latent kinds: one scan over
+        the leading dense layers (all 'global'), then one over the
+        PERIODS of the pattern — the body runs a period's layers one
+        after another, each position of the period its own stacked tree
+        ``params['layers']['p<k>']`` [periods, ...] with its expert
+        stacks kept off ``xs`` (see :meth:`_forward`), all three pools
+        on the carry.  A layer's index in its kind's pools counts the
+        layers of that kind before it."""
+        cfg = self.cfg
+        dense, period = _period(cfg)
+        n_periods = (cfg.num_layers - len(dense)) // len(period)
+        per_kind = {k: period.count(k) for k in ("global", "sliding")}
+        before = [{k: period[:i].count(k) for k in per_kind}
+                  for i in range(len(period))]
+        stacks, layers = [], {}
+        for i in range(len(period)):
+            tree = params["layers"][f"p{i}"]
+            moe = tree["block"]["moe"]
+            stacks.append({k: moe[k].astype(cfg.dtype)
+                           for k in _EXPERT_STACKS})
+            layers[f"p{i}"] = {**tree, "block": {**tree["block"], "moe": {
+                k: v for k, v in moe.items() if k not in _EXPERT_STACKS}}}
+
+        def dense_body(carry, per):
+            x, pools = carry
+            p_l, i = per
+            x, pools, _ = self._layer(
+                p_l["block"], i, x, pools, positions, tables, ctx_lens, blk,
+                off, valid, kind="global")
+            return (x, pools), None
+
+        def body(carry, per):
+            x, pools = carry
+            p_l, n = per
+            load = 0
+            for i, kind in enumerate(period):
+                first = len(dense) if kind == "global" else 0
+                x, pools, one = self._layer(
+                    p_l[f"p{i}"]["block"],
+                    first + n * per_kind[kind] + before[i][kind], x, pools,
+                    positions, tables, ctx_lens, blk, off, valid,
+                    expert_stacks=stacks[i], kind=kind, expert_layer=n)
+                load = load + one
+            return (x, pools), load
+
+        with jax.named_scope("layers"):
+            (x, pools), _ = jax.lax.scan(
+                dense_body, (x, pools),
+                (params["dense_layers"],
+                 jnp.arange(len(dense), dtype=jnp.int32)))
+            (x, pools), load = jax.lax.scan(
+                body, (x, pools),
+                (layers, jnp.arange(n_periods, dtype=jnp.int32)))
+        return pools, x, jnp.sum(load, axis=0)
 
     def _forward(self, params, pools, ids, positions, tables, ctx_lens,
                  blk, off, valid):
@@ -408,6 +642,9 @@ class PagedDecoder:
         for a model without them."""
         with jax.named_scope("embed"):
             x = embed_ids(self.cfg, params, ids, positions)
+        if self.two_kinds:
+            return self._forward_periods(params, pools, x, positions, tables,
+                                         ctx_lens, blk, off, valid)
 
         layers, expert_stacks = params["layers"], None
         moe = layers["block"].get("moe")
@@ -476,24 +713,33 @@ class PagedDecoder:
     # -- jitted steps -------------------------------------------------------
 
     def _decode_impl(self, params, pools, carry, tables, seq_lens, active,
-                     temp, top_k, top_p, all_greedy):
+                     temp, top_k, top_p, all_greedy, win_tables=None):
         """One decode token for every slot.  ``seq_lens`` is the banked
         length BEFORE this token; free slots (active=False) run on the
         null block and their sampled tokens are ignored by the host."""
         bs = self.block_size
         tok = carry["tok"]
         positions = seq_lens[:, None]
-        blk = jnp.where(
-            active,
-            jnp.take_along_axis(tables, (seq_lens // bs)[:, None],
-                                axis=1)[:, 0],
-            0)
+
+        def block_of(table):
+            return jnp.where(
+                active,
+                jnp.take_along_axis(table, (seq_lens // bs)[:, None],
+                                    axis=1)[:, 0],
+                0)
+        blk = block_of(tables)
         off = jnp.where(active, seq_lens % bs, 0)
         ctx = jnp.where(active, seq_lens + 1, 0)
-        pools, x, load = self._forward(params, pools, tok[:, None],
-                                       positions, tables, ctx,
-                                       blk[:, None], off[:, None],
-                                       active[:, None])
+        if win_tables is None:
+            pools, x, load = self._forward(params, pools, tok[:, None],
+                                           positions, tables, ctx,
+                                           blk[:, None], off[:, None],
+                                           active[:, None])
+        else:
+            pools, x, load = self._forward(
+                params, pools, tok[:, None], positions, (tables, win_tables),
+                ctx, (blk[:, None], block_of(win_tables)[:, None]),
+                off[:, None], active[:, None])
         with jax.named_scope("head"):
             logits = head_logits(self.cfg, params, x)
         with jax.named_scope("sample"):
@@ -506,7 +752,7 @@ class PagedDecoder:
         return pools, {"tok": toks, "key": split[:, 0]}, toks, load
 
     def _prefill_impl(self, params, pools, table_row, t0, tokens, n_valid,
-                      is_final):
+                      is_final, win_row=None):
         """One chunk of ONE sequence: bank k/v for tokens
         [t0, t0 + n_valid) and return the last valid row's logits (the
         first-token sampling input when this is the final chunk;
@@ -524,9 +770,16 @@ class PagedDecoder:
         blk = jnp.where(valid, table_row[pos // bs], 0)
         off = jnp.where(valid, pos % bs, 0)
         ctx = (t0 + n_valid)[None]
-        pools, x, load = self._forward(params, pools, tokens[None],
-                                       positions, table_row[None], ctx,
-                                       blk[None], off[None], valid[None])
+        if win_row is None:
+            pools, x, load = self._forward(params, pools, tokens[None],
+                                           positions, table_row[None], ctx,
+                                           blk[None], off[None], valid[None])
+        else:
+            win_blk = jnp.where(valid, win_row[pos // bs], 0)
+            pools, x, load = self._forward(
+                params, pools, tokens[None], positions,
+                (table_row[None], win_row[None]), ctx,
+                (blk[None], win_blk[None]), off[None], valid[None])
         if not is_final:
             return pools, None, load
         with jax.named_scope("head"):
@@ -537,7 +790,7 @@ class PagedDecoder:
         return pools, last, load
 
     def _prefill_batch_impl(self, params, pools, table_rows, t0s, tokens,
-                            n_valids):
+                            n_valids, win_rows=None):
         """One chunk each of up to ``prefill_batch`` DISTINCT sequences
         in one program: ``table_rows`` [PB, MB], ``t0s``/``n_valids``
         [PB] (0 valid = padded row: runs on the null block, output
@@ -557,6 +810,10 @@ class PagedDecoder:
             valid, jnp.take_along_axis(table_rows, pos // bs, axis=1), 0)
         off = jnp.where(valid, pos % bs, 0)
         ctx = t0s + n_valids                                     # [PB]
+        if win_rows is not None:
+            blk = (blk, jnp.where(valid, jnp.take_along_axis(
+                win_rows, pos // bs, axis=1), 0))
+            table_rows = (table_rows, win_rows)
         pools, x, load = self._forward(params, pools, tokens, positions,
                                        table_rows, ctx, blk, off, valid)
         with jax.named_scope("head"):
@@ -634,6 +891,13 @@ class Sequence:
     # expert-layer counts of this request's prefill programs, handed to
     # the ring with its first token (device arrays; empty without experts)
     loads: List[Any] = dataclasses.field(default_factory=list)
+    # window layers' blocks held now: logical block -> pool block
+    # (kv_cache.WindowBlocks; empty for a model without window layers)
+    win_blocks: Dict[int, int] = dataclasses.field(default_factory=dict)
+    # positions a full layer attended, positions cached for it and
+    # positions a window layer attended in this request's prefill
+    # programs (Scheduler._note_selected; None without such layers)
+    selected: Any = None
 
     @property
     def prompt_len(self) -> int:
@@ -667,6 +931,9 @@ class _InFlight:
     # device arrays (models/moe.held_experts_ffn), one a program — a
     # decode step's own, or every prefill chunk's of a 'first' entry
     loads: List[Any] = dataclasses.field(default_factory=list)
+    # Scheduler._note_selected's counts of the step(s) behind this entry
+    # (None for a model without an indexed selection)
+    selected: Any = None
 
 
 class Scheduler:
@@ -706,6 +973,18 @@ class Scheduler:
             blocks_needed(model_cfg.max_seq_len + serve_cfg.decode_depth,
                           serve_cfg.block_size))
         self.tables = np.zeros((s, self.max_blocks_per_seq), np.int32)
+        # a model of two latent kinds: the window layers' blocks, held
+        # only while the window reaches them, and their table
+        self.window = None
+        if self.decoder.two_kinds:
+            self.window = WindowBlocks(
+                num_window_blocks(model_cfg, serve_cfg),
+                serve_cfg.block_size, model_cfg.window[0],
+                window_blocks_bound(model_cfg.window[0],
+                                    serve_cfg.prefill_chunk,
+                                    serve_cfg.block_size))
+            self.win_tables = np.zeros_like(self.tables)
+            self._dev_win = None
         self.seq_lens = np.zeros((s,), np.int32)
         self.active = np.zeros((s,), bool)
         self.temp = np.zeros((s,), np.float32)
@@ -722,6 +1001,8 @@ class Scheduler:
         self._iter = 0            # decode iterations dispatched
         self._resolved = 0        # decode iterations resolved
         self._deferred: List[Tuple[int, List[int]]] = []
+        # the same for an evicted sequence's window-layer blocks
+        self._deferred_window: List[Tuple[int, List[int]]] = []
         # newly finished sequences, drained by the engine each step —
         # completion accounting stays O(finished this step), never a
         # scan over every request the process has served
@@ -760,7 +1041,8 @@ class Scheduler:
 
     def can_admit(self, seq: Sequence) -> bool:
         return (self.free_slot() is not None
-                and self.pool.can_alloc(self.blocks_for(seq)))
+                and self.pool.can_alloc(self.blocks_for(seq))
+                and (self.window is None or self.window.can_reserve()))
 
     def admit(self, seq: Sequence) -> bool:
         """Give ``seq`` a decode slot + its whole block reservation, or
@@ -786,16 +1068,54 @@ class Scheduler:
             queue_s = (max(seq.t_admit - seq.t_submit, 0.0)
                        if seq.t_submit else 0.0)
             sp.set(admitted=1, cached_tokens=seq.cached_tokens,
-                   queue_ms=queue_s * 1e3)
+                   queue_ms=queue_s * 1e3, **self.blocks_by_kind())
         if seq.t_submit and tracing.enabled():
             now = time.perf_counter()
             tracing.record_span("serve/queue", now - queue_s, now,
                                 sid=seq.sid, trace=seq.trace_id)
         return True
 
+    def blocks_by_kind(self) -> Dict[str, int]:
+        """Blocks in use by kind of layer (and, where window layers free
+        theirs as the window passes, how many went back that way)."""
+        out = {"blocks_full": self.pool.in_use}
+        if self.window is not None:
+            out.update(blocks_window=self.window.pool.in_use,
+                       window_blocks_freed=self.window.freed)
+        return out
+
+    def _advance_window(self, seq: Sequence, first_query: int,
+                        upto: int) -> None:
+        """Before a program whose queries of ``seq`` are positions
+        [first_query, upto): the window layers' table row holds the
+        blocks those queries see and write, and no others."""
+        if self.window.advance(seq.win_blocks, self.win_tables[seq.slot],
+                               first_query, upto):
+            self._dev_win = None
+
+    def _before_prefill(self, seq: Sequence, t0: int, n: int) -> None:
+        """A model with window layers, before a prefill program of
+        ``seq`` over positions [t0, t0 + n): its window table row, and
+        the positions the request's prefill has worked through so far."""
+        self._advance_window(seq, t0, t0 + n)
+        seq.selected = self._note_selected(t0, n) + (
+            0 if seq.selected is None else seq.selected)
+
+    def _note_selected(self, t0: int, n: int) -> np.ndarray:
+        """(positions a full layer attends, positions cached for it,
+        positions a window layer attends) for queries at positions
+        [t0, t0 + n): query t sees t + 1 cached positions, a full layer
+        attends ``index_topk`` of them at most, a window layer those in
+        its window."""
+        t = np.arange(t0 + 1, t0 + n + 1, dtype=np.int64)
+        return np.array([np.minimum(t, self.cfg.index_topk).sum(), t.sum(),
+                         np.minimum(t, self.cfg.window[0] + 1).sum()])
+
     def _admit_impl(self, seq: Sequence) -> bool:
         slot = self.free_slot()
         if slot is None:
+            return False
+        if self.window is not None and not self.window.can_reserve():
             return False
         total = self.blocks_for(seq)
         shared: List[int] = []
@@ -827,6 +1147,10 @@ class Scheduler:
                 self.pool.free([cow_src])
             return False
         blocks = shared + fresh
+        if self.window is not None:
+            self.window.reserve()
+            self.win_tables[slot, :] = 0
+            self._dev_win = None
         seq.slot = slot
         seq.blocks = blocks
         seq.key = jax.random.PRNGKey(seq.seed)
@@ -936,13 +1260,17 @@ class Scheduler:
         if n_valid < c:
             chunk = np.pad(chunk, (0, c - n_valid))
         final = (t0 + n_valid) >= seq.prompt_len
+        win = ()
+        if self.window is not None:
+            self._before_prefill(seq, t0, n_valid)
+            win = (_upload(self.win_tables[seq.slot]),)
         with tracing.span("serve/prefill", sid=seq.sid, t0=t0,
                           tokens=n_valid, batched=False,
                           trace=seq.trace_id):
             self.pools, last_logits, load = self.decoder._prefill(
                 self.params, self.pools, _upload(self.tables[seq.slot]),
                 jnp.asarray(t0, jnp.int32), jnp.asarray(chunk, jnp.int32),
-                jnp.asarray(n_valid, jnp.int32), final)
+                jnp.asarray(n_valid, jnp.int32), final, *win)
         if load is not None:
             seq.loads.append(load)
         seq.prefilled += n_valid
@@ -972,13 +1300,22 @@ class Scheduler:
             toks[r, :n] = chunk
             n_valids[r] = n
             taken.append(n)
+            if self.window is not None:
+                self._before_prefill(seq, t0, n)
+        win = ()
+        if self.window is not None:
+            win_rows = np.zeros_like(tables)
+            for r, seq in enumerate(seqs):
+                win_rows[r] = self.win_tables[seq.slot]
+            win = (jnp.asarray(win_rows),)
         with tracing.span("serve/prefill", batched=True,
                           sids=[s.sid for s in seqs],
                           traces=[s.trace_id for s in seqs],
                           tokens=int(sum(taken))):
             self.pools, logits, load = self.decoder._prefill_batch(
                 self.params, self.pools, jnp.asarray(tables),
-                jnp.asarray(t0s), jnp.asarray(toks), jnp.asarray(n_valids))
+                jnp.asarray(t0s), jnp.asarray(toks), jnp.asarray(n_valids),
+                *win)
         if load is not None:
             seqs[0].loads.append(load)       # one program, counted once
         for r, seq in enumerate(seqs):
@@ -1020,8 +1357,8 @@ class Scheduler:
         self._dev_stable = None
         self._ring.append(_InFlight(
             kind="first", tokens=tok, seq=seq, loads=seq.loads,
-            t_dispatch=time.monotonic()))
-        seq.loads = []
+            selected=seq.selected, t_dispatch=time.monotonic()))
+        seq.loads, seq.selected = [], None
 
     def _dev_stable_arrays(self):
         if self._dev_stable is None:
@@ -1039,6 +1376,19 @@ class Scheduler:
         failpoint("serve.decode", iter=self._iter)
         snapshot = [(i, s) for i, s in enumerate(self.slot_seq)
                     if self.active[i] and s is not None]
+        win, selected = (), None
+        if self.window is not None:
+            for slot, seq in snapshot:
+                n = int(self.seq_lens[slot])
+                self._advance_window(seq, n, n + 1)
+            seen = self.seq_lens[[slot for slot, _ in snapshot]].astype(
+                np.int64) + 1                # cached positions a query
+            selected = np.array([
+                np.minimum(seen, self.cfg.index_topk).sum(), seen.sum(),
+                np.minimum(seen, self.cfg.window[0] + 1).sum()])
+            if self._dev_win is None:
+                self._dev_win = _upload(self.win_tables)
+            win = (self._dev_win,)
         tables, active, temp, top_k, top_p = self._dev_stable_arrays()
         all_greedy = bool((self.temp[self.active] <= 0.0).all())
         # per-request trace ids on the batched span: built only while
@@ -1051,12 +1401,12 @@ class Scheduler:
             self.pools, self.carry, toks, load = self.decoder._decode(
                 self.params, self.pools, self.carry,
                 tables, _upload(self.seq_lens),
-                active, temp, top_k, top_p, all_greedy)
+                active, temp, top_k, top_p, all_greedy, *win)
         # host mirror: every active slot banked one more token
         self.seq_lens[self.active] += 1
         self._ring.append(_InFlight(
             kind="decode", tokens=toks, slots=snapshot,
-            loads=[] if load is None else [load],
+            loads=[] if load is None else [load], selected=selected,
             iter_idx=self._iter, t_dispatch=time.monotonic()))
         self._iter += 1
 
@@ -1120,8 +1470,13 @@ class Scheduler:
         # DEFERRED free: iterations dispatched before this point may
         # still write through the old table — release only once every
         # decode iteration < self._iter has resolved
+        if self.window is not None:
+            self.win_tables[slot, :] = 0
+            self._dev_win = None
+            self._deferred_window.append(
+                (self._iter, list(seq.win_blocks.values())))
         self._deferred.append((self._iter, seq.blocks))
-        seq.blocks = []
+        seq.blocks, seq.win_blocks = [], {}
         self._release_matured()
 
     def _release_matured(self) -> None:
@@ -1133,6 +1488,14 @@ class Scheduler:
             else:
                 keep.append((after, blocks))
         self._deferred = keep
+        if self.window is not None:
+            keep = []
+            for after, blocks in self._deferred_window:
+                if self._resolved >= after or ring_empty:
+                    self.window.release(blocks)
+                else:
+                    keep.append((after, blocks))
+            self._deferred_window = keep
 
     def _resolve_one(self) -> None:
         entry = self._ring.popleft()
@@ -1163,6 +1526,13 @@ class Scheduler:
                     moe_pairs=int(pairs), moe_max=int(largest),
                     moe_hit=int(hit), moe_layer_steps=layer_steps,
                     moe_slots=layer_steps * self.cfg.num_experts)
+            if entry.selected is not None and deliver.live:
+                # the queries behind these tokens, one layer of each
+                # kind: positions a full layer attended and had cached,
+                # positions a window layer attended
+                att, cached, win_att = (int(x) for x in entry.selected)
+                deliver.set(sel_attended=att, sel_cached=cached,
+                            win_attended=win_att)
             now = time.monotonic()
             if entry.kind == "first":
                 self._record(entry.seq, int(toks), now)
