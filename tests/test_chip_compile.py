@@ -264,6 +264,16 @@ AXK1_SERVE = dict(block_size=128, num_blocks=640, max_slots=32,
 AXK1_DEPTH = 3
 
 
+def _fits_the_vmem_the_call_asks_for(rows, pages, latent, select):
+    """What one step of the latent kernel holds — its query tile, two
+    groups of ``pages`` pool pages, the float32 score tiles of a group —
+    against the ``vmem_limit_bytes`` its call hands the compiler."""
+    need = paged_mod.latent_vmem_bytes(rows, pages, latent, 64, 128, 2,
+                                       select)
+    assert need <= paged_mod._LATENT_VMEM_BUDGET \
+        < paged_mod._LATENT_VMEM_LIMIT, (rows, pages, need)
+
+
 @pytest.mark.parametrize("slots,t", [(32, 1), (1, 512)],
                          ids=["decode", "prefill_chunk"])
 def test_latent_paged_kernel_compiles_at_published_geometry(
@@ -272,8 +282,10 @@ def test_latent_paged_kernel_compiles_at_published_geometry(
     row, block 128: t = 1 and the chunk path (query rows tiled)."""
     sds = functools.partial(_sds, sharding=one_chip)
     i32 = functools.partial(sds, dtype=jnp.int32)
-    tile = paged_mod.latent_query_tile(64, 512, 64, 128, t, BF16)
-    assert t % tile == 0 and tile * 64 >= min(t, 8) * 64
+    tq, pages = paged_mod.latent_query_tile(64, 512, 64, 128, t, BF16,
+                                            False, 33)
+    assert t % tq == 0 and tq >= min(t, 8) and pages >= 2
+    _fits_the_vmem_the_call_asks_for(tq * 64, pages, 512, False)
 
     def call(q_lat, q_pe, pool, tables, ctx, q0, layer):
         return paged_mod.latent_paged_attention(
@@ -528,9 +540,12 @@ def test_sparse_window_serve_program_holds_three_pools_in_place(
                           line)]
     assert not moved, moved
     # per-head scores against every cached position: [.., 64, .., T]
+    # (the latent kernel's view of the chunk's scores as 64 query tiles
+    # of 8 tokens, [1, 64, 8, T], is a bitcast of the same 65 MiB)
     t = mb * 128
     per_head = [line.strip()[:160] for line in text.splitlines()
-                if re.search(rf"= \w+\[[\d,]*\b64,[\d,]*{t}\]", line)]
+                if re.search(rf"= \w+\[[\d,]*\b64,[\d,]*{t}\]", line)
+                and " bitcast(" not in line]
     assert not per_head, per_head
 
 
@@ -542,10 +557,12 @@ def test_sparse_window_kernels_compile_at_published_geometry(
     """Each new kernel alone for the chip, decode step and 512-token
     chunk: the indexer (64 heads x 128 over a [L, NB, 128, 128] key
     pool), the latent kernel under a selection (128 heads on a 640-lane
-    row) and with a window of 512 back (64 heads on a 1,152-lane row,
-    the grid's block axis ceil((512 + tile) / 128) + 1 long instead of
-    261); pools read where they lie (under 1 MiB of temporaries beside
-    the selection's own reshape)."""
+    row) and with a window of 512 back (64 heads on a 1,152-lane row);
+    both walk the pages a tile can see inside the kernel, several a
+    step, out of a pool left in HBM, and the tile and group sizes
+    ``latent_query_tile`` derives fit the VMEM the call asks for; pools
+    read where they lie (under 1 MiB of temporaries beside the
+    selection's own reshape)."""
     sds = functools.partial(_sds, sharding=one_chip)
     i32 = functools.partial(sds, dtype=jnp.int32)
     mb, n = 261, 261 * 128
@@ -576,6 +593,14 @@ def test_sparse_window_kernels_compile_at_published_geometry(
         args = (sds((slots, t, 64, 1024), BF16),
                 sds((slots, t, 64, 64), BF16),
                 sds((LAYERS, 81, 128, 1152), BF16))
+    if kernel != "indexer":
+        heads, latent = args[0].shape[2:]
+        tq, pages = paged_mod.latent_query_tile(
+            heads, latent, 64, 128, t, BF16, kernel == "sparse", mb,
+            512 if kernel == "window" else -1)
+        assert t % tq == 0 and pages >= 2
+        _fits_the_vmem_the_call_asks_for(tq * heads, pages, latent,
+                                         kernel == "sparse")
     compiled = jax.jit(call).lower(*args, *common).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20 + (

@@ -30,7 +30,10 @@ from torchacc_tpu.config import ConfigError
 from torchacc_tpu.models import TransformerLM, mla, moe
 from torchacc_tpu.models.hf import config_from_hf
 from torchacc_tpu.ops.grouped_matmul import grouped_matmul, tile_schedule
-from torchacc_tpu.ops.paged_attention import latent_paged_attention
+from torchacc_tpu.ops.paged_attention import (
+    latent_paged_attention,
+    latent_query_tile,
+)
 from torchacc_tpu.serve import Request, ServeEngine
 from torchacc_tpu.train.trainer import Trainer
 
@@ -225,24 +228,51 @@ def test_absorbed_form_equals_expanded_form(whole):
     np.testing.assert_allclose(got, want, atol=1e-5)
 
 
+# (q_start, context_lens) a slot, for t query tokens; tables of 24 blocks
+# of 8, so the kernel's page walk takes 8 pages a step
+_WALKS = {
+    # 11 pages: a whole group and a partial one
+    "partial_last_group": lambda t: ([84 - t, 75 - t, 88 - t], [84, 75, 88]),
+    # 3 pages, 1 page: the walk ends inside its first group
+    "ends_in_first_group": lambda t: ([20 - t, 0, 3], [20, t, 3 + t]),
+    # q_start = 0 and ONE token banked (a chunk's other rows are padding)
+    "first_token": lambda t: ([0, 0, 0], [1, 1, 1]),
+    # free slots (length 0) either side of a live one
+    "empty_beside_live": lambda t: ([0, 70 - t, 0], [0, 70, 0]),
+}
+
+
 @pytest.mark.parametrize("t", [1, 8], ids=["decode", "chunk"])
-def test_latent_kernel_matches_the_gather_path(t):
+@pytest.mark.parametrize("walk", ["scattered"] + sorted(_WALKS))
+def test_latent_kernel_matches_the_gather_path(t, walk):
     """The Pallas kernel (interpret mode) against the jnp gather path on
     a scattered block table: a free slot (length 0), a chunk that ends
-    inside a block, query tiles whose causal reach stops early."""
-    s, h, r, p, bs, nb, mb = 3, 4, 32, 8, 8, 16, 4
+    inside a block, query tiles whose causal reach stops early — and
+    the page walk's own edges (``_WALKS``): the pages a tile can see are
+    walked several at a time, from a bound read at run time."""
+    s, h, r, p, bs, nb = 3, 4, 32, 8, 8, 80
+    mb = 4 if walk == "scattered" else 24
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q_lat = jax.random.normal(ks[0], (s, t, h, r))
     q_pe = jax.random.normal(ks[1], (s, t, h, p))
     pool = jax.random.normal(ks[2], (2, nb, bs, 128))
     tables = jnp.asarray(np.random.default_rng(0).permutation(nb - 1)[
         :s * mb].reshape(s, mb) + 1, jnp.int32)
-    q0 = jnp.asarray([0, 5, 17], jnp.int32)
-    ctx = jnp.asarray([t, 0, 17 + t] if t == 1 else [t, 5 + t - 3, 17 + t])
+    if walk == "scattered":
+        q0 = jnp.asarray([0, 5, 17], jnp.int32)
+        ctx = jnp.asarray([t, 0, 17 + t] if t == 1
+                          else [t, 5 + t - 3, 17 + t])
+    else:
+        assert latent_query_tile(h, r, p, bs, t, pool.dtype, False,
+                                 mb)[1] == 8
+        q0, ctx = (jnp.asarray(a, jnp.int32) for a in _WALKS[walk](t))
     args = (q_lat, q_pe, pool, tables, ctx, q0)
     got = latent_paged_attention(*args, layer=1, scale=0.3, impl="pallas")
     want = latent_paged_attention(*args, layer=1, scale=0.3, impl="xla")
     np.testing.assert_allclose(got, want, atol=2e-6)
+    live = np.asarray(ctx) > 0
+    assert float(jnp.abs(want[live]).max()) > 0.01
+    assert float(jnp.abs(got[~live]).max() if (~live).any() else 0.0) == 0.0
 
 
 def _brute_force_route(scores, choice, n_group, topk_group, k, scale):

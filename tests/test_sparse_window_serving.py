@@ -34,6 +34,7 @@ from torchacc_tpu.models.hf import config_from_hf
 from torchacc_tpu.ops.paged_attention import (
     indexer_scores,
     latent_paged_attention,
+    latent_query_tile,
     select_topk,
 )
 from torchacc_tpu.serve import Request, ServeEngine
@@ -327,38 +328,90 @@ def test_select_topk_is_exact_with_ties_to_the_lower_position(k):
     np.testing.assert_array_equal(mine, want)
 
 
+# context lengths a slot (the t query tokens are the last banked ones);
+# tables of 24 blocks of 8: the kernel's page walk takes 8 pages a step
+# (2 under the window of 40: a third of the 7 blocks it spans)
+_WALKS = {
+    # 11 and 10 pages: a whole group, then a partial one
+    "partial_last_group": [86, 75, 0],
+    # 3 pages and 1: the walk ends inside its first group
+    "ends_in_first_group": [20, 0, 6],
+    # q_start = 0, one token banked, beside a slot that holds nothing
+    "first_token": [1, 0, 1],
+    # two chunk tiles a slot: the second reaches a page group further
+    "two_tiles": [72, 0, 66],
+}
+
+
 @pytest.mark.parametrize("t", [1, 8], ids=["decode", "chunk"])
 @pytest.mark.parametrize("variant", ["window", "selection"])
-def test_latent_kernel_variants_match_the_gather_path(t, variant):
+@pytest.mark.parametrize("walk", ["as_served"] + sorted(_WALKS))
+def test_latent_kernel_variants_match_the_gather_path(t, variant, walk):
     """The Pallas kernel (interpret mode) with a window bound / under a
     selection against the jnp gather path, slots of different lengths,
-    tables that hold the null block where the window has passed."""
-    s_, h, r, pe, bs, mb = 3, 2, 32, 8, 8, 6
+    tables that hold the null block where the window has passed — and
+    the page walk's own edges (``_WALKS``): the first live block of a
+    window is not block 0 and the entries before it are 0, a
+    selection's threshold tie falls in the last, partial group."""
+    s_, h, r, pe, bs = 3, 2, 32, 8, 8
+    mb = 6 if walk == "as_served" else 24
+    if walk == "two_tiles" and t > 1:
+        t = 16 if variant == "selection" else 64
     ks = jax.random.split(jax.random.PRNGKey(t), 5)
     ql = jax.random.normal(ks[0], (s_, t, h, r))
     qp = jax.random.normal(ks[1], (s_, t, h, pe))
-    pool = jax.random.normal(ks[2], (2, 24, bs, 128))
-    ctx = jnp.asarray([41, 9, 0]) + jnp.asarray([t, t, 0]) - 1
+    pool = jax.random.normal(ks[2], (2, 80, bs, 128))
+    if walk == "as_served":
+        ctx = jnp.asarray([41, 9, 0]) + jnp.asarray([t, t, 0]) - 1
+    else:
+        ctx = jnp.asarray([max(c, t) if c else 0 for c in _WALKS[walk]])
+        if walk == "first_token":
+            ctx = jnp.minimum(ctx, 1)
     q0 = jnp.maximum(ctx - t, 0)
     tables = jnp.asarray(np.random.default_rng(0).permutation(
-        np.arange(1, 19)).reshape(3, mb), jnp.int32)
+        np.arange(1, 1 + s_ * mb)).reshape(s_, mb), jnp.int32)
+    window = {"as_served": 10}.get(walk, 40) if variant == "window" else -1
+    tq, pages = latent_query_tile(h, r, pe, bs, t, pool.dtype,
+                                  variant == "selection", mb, window)
+    if walk != "as_served":
+        assert pages == (2 if variant == "window" else 8)
+        assert walk != "two_tiles" or t == 1 or t // tq == 2
     kw = {}
     if variant == "window":
-        kw["window"] = 10
+        kw["window"] = window
         # the blocks wholly before the first query's window are freed
-        dead = (np.maximum(np.asarray(q0) - 10, 0) // bs)
+        dead = (np.maximum(np.asarray(q0) - window, 0) // bs)
+        if walk == "partial_last_group":
+            assert dead[0] >= 3
         tables = jnp.asarray(np.where(
             np.arange(mb)[None] < dead[:, None], 0, np.asarray(tables)),
             jnp.int32)
+    elif walk == "partial_last_group":
+        # every score ties but five: the k best are the five and the
+        # first k - 5 of the rest, so the tie's upper end lies in the
+        # walk's second, partial group of pages
+        scores = jnp.full((s_, t, mb * bs), 0.5).at[:, :, 3:40:8].set(1.0)
+        k = pages * bs + 9
+        thr, tie_hi = select_topk(scores, k)
+        assert float(thr.min()) == float(thr.max()) == 0.5
+        assert pages * bs <= int(tie_hi.min()) and int(tie_hi.max()) < 75
+        kw["selection"] = (scores, thr, tie_hi)
     else:
         scores = jax.random.normal(ks[3], (s_, t, mb * bs))
+        if walk != "as_served":
+            # as the indexer leaves them: NEG_INF where a query cannot see
+            pos = jnp.arange(mb * bs)
+            q_pos = q0[:, None] + jnp.arange(t)
+            scores = jnp.where((pos < ctx[:, None, None])
+                               & (pos <= q_pos[..., None]), scores, -1e30)
         kw["selection"] = (scores,) + select_topk(scores, 12)
     out = {impl: latent_paged_attention(
         ql, qp, pool, tables, ctx, q0, layer=1, scale=0.3, impl=impl,
         name="variant_under_test", **kw) for impl in ("xla", "pallas")}
     np.testing.assert_allclose(out["pallas"], out["xla"], atol=2e-5)
-    assert float(jnp.abs(out["xla"][:2]).max()) > 0.01
-    assert float(jnp.abs(out["pallas"][2]).max()) == 0.0
+    live = np.asarray(ctx) > 0
+    assert float(jnp.abs(out["xla"][live]).max()) > 0.01
+    assert float(jnp.abs(out["pallas"][~live]).max()) == 0.0
 
 
 def test_the_sixteen_shares_add_up_to_the_uncut_layer(whole):

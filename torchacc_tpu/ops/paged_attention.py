@@ -50,6 +50,7 @@ produce all-zero outputs.
 from __future__ import annotations
 
 import functools
+import types
 from typing import Optional, Tuple
 
 import jax
@@ -318,27 +319,45 @@ def _paged_attention_pallas_sharded(mesh, q, k_pool, v_pool, block_tables,
 # of its key.  Query rows of a slot are tiled (``tq`` tokens x all heads
 # a grid step, token-major, so the tiling is a reshape), which is what
 # lets a prefill chunk of 512 tokens x 64 heads through the same kernel
-# as a decode step; a page's index map stops at the last block the tile
-# can see (its causal reach, the slot's length), so blocks past it are
-# not fetched again.
-
+# as a decode step.
+#
+# The grid is (slots, query tiles); the PAGE WALK is a loop inside the
+# kernel.  The pool stays in HBM and a tile copies the pages it can see
+# itself — from the first block its window reaches (block 0 without one)
+# to its causal reach within the slot's length, a bound read at run time
+# — ``pages`` of them a step into one of two VMEM buffers, the next
+# group's copies in flight while this group is multiplied (and behind a
+# tile's last group the first group of the tile after it: the grid runs
+# in order).  So a tile early in a long table walks its own few pages
+# and a slot that holds nothing walks none (its output is zeros); a step
+# is one online-softmax update over ``pages * BS`` positions: one row
+# max, one exp, one rescale of the [rows, R] accumulator.  Pages of the
+# last group past the reach are masked like positions past the length,
+# and their buffer rows are zeroed instead of fetched (a masked weight
+# of 0 times a stale NaN in the value product would be NaN).
+# ``latent_query_tile`` sizes ``tq`` and ``pages`` from the shapes of
+# the call.
 #
 # Two more shapes of the same attention (a model that mixes a learned
 # sparse selection with windowed latent layers, models/mla.py):
 #
 # - ``window`` >= 0: a query at position t sees ``[t - window, t]``; the
-#   grid's block axis covers only the blocks a query tile's window
-#   reaches, counted from the tile's first live block, so the table's
-#   entries before the window are never read (serve/kv_cache.py frees
-#   them);
+#   walk starts at the tile's first live block, so the table's entries
+#   before the window are never read (serve/kv_cache.py frees them);
 # - ``selection = (scores, thr, tie_hi)``: query (s, t) attends position
 #   p iff ``scores[s, t, p] > thr[s, t]``, or ``== thr`` and ``p <=
 #   tie_hi[s, t]`` (:func:`select_topk`: exactly the k best, ties to the
-#   lower position).  The kernel walks the slot's live pages as before
-#   and masks what is not selected: it reads whole pages, not the
-#   selected rows alone (a gather of 1,280-byte rows costs more DMA
-#   issues than the pages cost bandwidth at the contexts served today;
-#   ROADMAP R3).
+#   lower position).  The kernel walks the tile's live pages as before,
+#   fetches the tile's scores of each page beside it and masks what is
+#   not selected: it reads whole pages, not the selected rows alone.  For
+#   prefill chunks that is settled by a count: each query has its own
+#   2,048 rows (they are shared by its heads, not by its neighbours), so
+#   a row gather issues one 1,280-byte copy a (query, selected position)
+#   pair — 1.05G copies a 51 s window of the long-context cell against
+#   the 28.6 s the masked walk took before it moved into the kernel, 27
+#   ns a copy to break even, index compaction included (ISSUE 31; PERF.md
+#   section 6).  A decode step's one query a slot is the gather's case
+#   (ROADMAP S1).
 
 @functools.partial(jax.jit, static_argnames=("scale", "window"))
 def _latent_paged_attention_xla(q_lat, q_pe, pool, block_tables,
@@ -373,114 +392,245 @@ def _latent_paged_attention_xla(q_lat, q_pe, pool, block_tables,
 
 
 def _latent_fwd_kernel(tbl_ref, lens_ref, layer_ref, ql_ref, qp_ref, *rest,
-                       scale, block_size, latent, rope, heads, tq, rows,
-                       num_kv_blocks, window=-1, select=False):
+                       scale, block_size, latent, rope, heads, tq, pages,
+                       window=-1, select=False):
     if select:
-        sc_ref, thr_ref, tie_ref, kv_ref, o_ref, m_scr, l_scr, acc_scr = rest
+        (sc_hbm, thr_ref, tie_ref, pool_hbm, o_ref, m_scr, l_scr, acc_scr,
+         kv_buf, kv_sem, slot_ref, sc_buf, sc_sem) = rest
     else:
-        kv_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    si = pl.program_id(0)
-    ti = pl.program_id(1)
-    bi = pl.program_id(2)
+        (pool_hbm, o_ref, m_scr, l_scr, acc_scr, kv_buf, kv_sem,
+         slot_ref) = rest
+    si, ti = pl.program_id(0), pl.program_id(1)
+    ns, nt = pl.num_programs(0), pl.num_programs(1)
+    bs, span = block_size, pages * block_size
+    last_entry = tbl_ref.shape[1] - 1
+    layer = layer_ref[0]
 
-    @pl.when(bi == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def walk(s, t):
+        """The pages tile ``t`` of slot ``s`` can see: from the block
+        its window reaches (block 0 without one) to its causal reach
+        within the slot's length, ``groups`` steps of ``pages``."""
+        ctx = lens_ref[s, 0]
+        q0 = lens_ref[s, 1] + t * tq        # the tile's first position
+        reach = jnp.minimum(ctx, q0 + tq)
+        first = jnp.maximum(q0 - window, 0) // bs if window >= 0 else 0
+        groups = jnp.maximum((reach - first * bs + span - 1) // span, 0)
+        return types.SimpleNamespace(s=s, t=t, ctx=ctx, q0=q0, reach=reach,
+                                     first=first, groups=groups)
 
-    ctx = lens_ref[si, 0]
-    q0 = lens_ref[si, 1] + ti * tq          # this tile's first position
-    k_start = bi * block_size
-    if window >= 0:
-        # the block axis counts from the first block the tile's window
-        # reaches
-        k_start += jnp.maximum(q0 - window, 0) // block_size * block_size
-
-    @pl.when((k_start < ctx) & (k_start <= q0 + tq - 1))
-    def _compute():
-        kv_pos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, block_size), 1)
-        # row r holds head r % heads of tile token r // heads
-        q_pos = q0 + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, block_size), 0) // heads
-        mask = (kv_pos < ctx) & (kv_pos <= q_pos)
-        if window >= 0:
-            mask &= kv_pos >= q_pos - window
+    def page_dmas(tile, g, j, slot):
+        """The copies of page ``j`` of a tile's group ``g`` into buffer
+        ``slot``: the pool page by its table entry and, under a
+        selection, the tile's scores of the page's positions."""
+        entry = jnp.minimum(tile.first + g * pages + j, last_entry)
+        dmas = [pltpu.make_async_copy(
+            pool_hbm.at[layer, tbl_ref[tile.s, entry]],
+            kv_buf.at[slot, pl.ds(pl.multiple_of(j * bs, bs), bs)],
+            kv_sem.at[slot])]
         if select:
-            # one token's selection for all its heads: [tq, BS] ->
-            # [tq * heads, BS], token-major like the rows
-            sc = sc_ref[0, 0]
-            pos = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (tq, block_size), 1)
-            chosen = ((sc > thr_ref[0, 0]) | (
+            dmas.append(pltpu.make_async_copy(
+                sc_hbm.at[tile.s, tile.t, :,
+                          pl.ds(pl.multiple_of(entry * bs, bs), bs)],
+                sc_buf.at[slot, j], sc_sem.at[slot]))
+        return dmas
+
+    def live(tile, g):
+        """Pages of a tile's group ``g`` inside its reach (the rest of
+        the group is dead: masked, and never fetched)."""
+        left = tile.reach - (tile.first + g * pages) * bs
+        return jnp.clip((left + bs - 1) // bs, 0, pages)
+
+    # loops over the pages, not Python ones: a kernel's trace holds each
+    # body once (per-page conditionals made tracing a program cost
+    # seconds, and a warm set-up pays the trace)
+    def start(tile, g, slot):
+        def fetch(j, carry):
+            for dma in page_dmas(tile, g, j, slot):
+                dma.start()
+            return carry
+
+        # a dead page must still be finite: a masked weight of 0 times a
+        # stale NaN in the value product is NaN
+        def clear(j, carry):
+            kv_buf[slot, pl.ds(pl.multiple_of(j * bs, bs), bs), :] = \
+                jnp.zeros((bs, kv_buf.shape[2]), kv_buf.dtype)
+            return carry
+
+        n = live(tile, g)
+        jax.lax.fori_loop(0, n, fetch, 0)
+        jax.lax.fori_loop(n, pages, clear, 0)
+
+    def wait(tile, g, slot):
+        def arrived(j, carry):
+            for dma in page_dmas(tile, g, j, slot):
+                dma.wait()
+            return carry
+
+        jax.lax.fori_loop(0, live(tile, g), arrived, 0)
+
+    def either(pick, a, b):
+        return types.SimpleNamespace(**{
+            k: jnp.where(pick, v, getattr(b, k)) for k, v in vars(a).items()})
+
+    here = walk(si, ti)
+    groups = here.groups
+    # the grid runs in order, so the tile after this one is known: its
+    # first group is fetched behind this tile's last, into the buffer
+    # ``slot_ref`` hands on
+    wrap = ti + 1 >= nt
+    s_after = jnp.where(wrap, si + 1, si)
+    after = walk(jnp.minimum(s_after, ns - 1), jnp.where(wrap, 0, ti + 1))
+    more = (s_after < ns) & (after.groups > 0)
+    boot = (si == 0) & (ti == 0)
+
+    @pl.when(boot)
+    def _first_tile():
+        slot_ref[0] = 0
+
+    base = slot_ref[0]
+    none = groups == 0
+
+    # nobody fetched the very first tile's first group; and a tile that
+    # walks nothing hands the next tile's first group on from here
+    @pl.when((boot & jnp.logical_not(none)) | (none & more))
+    def _first_group():
+        start(either(none, after, here), 0, base)
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def step(g, carry):
+        slot = jax.lax.rem(base + g, 2)
+        last = g + 1 == groups
+
+        @pl.when(jnp.logical_not(last) | more)
+        def _next_group():
+            start(either(last, after, here), jnp.where(last, 0, g + 1),
+                  1 - slot)
+
+        wait(here, g, slot)
+        # what each token of the tile may attend, [tq, span]: every head
+        # of a token shares it
+        pos = (here.first + g * pages) * bs + jax.lax.broadcasted_iota(
+            jnp.int32, (tq, span), 1)
+        q_pos = here.q0 + jax.lax.broadcasted_iota(
+            jnp.int32, (tq, span), 0)
+        seen = (pos < here.ctx) & (pos <= q_pos)
+        if window >= 0:
+            seen &= pos >= q_pos - window
+        if select:
+            sc = jnp.concatenate(
+                [sc_buf[slot, j] for j in range(pages)], axis=1)
+            seen &= (sc > thr_ref[0, 0]) | (
                 (sc == thr_ref[0, 0]) & (pos <= tie_ref[0, 0]))
-            ).astype(jnp.float32)
-            mask &= jnp.concatenate(
-                [jnp.broadcast_to(chosen[i:i + 1], (heads, block_size))
-                 for i in range(tq)], axis=0) > 0.0
-        c_kv = kv_ref[:, :latent]                             # [BS, R]
-        k_pe = kv_ref[:, latent:latent + rope]                # [BS, P]
+        # token-major rows: row r holds head r % heads of token r // heads
+        seen = seen.astype(jnp.float32)
+        mask = jnp.concatenate(
+            [jnp.broadcast_to(seen[i:i + 1], (heads, span))
+             for i in range(tq)], axis=0) > 0.0
+        c_kv = kv_buf[slot, :, :latent]                       # [span, R]
+        k_pe = kv_buf[slot, :, latent:latent + rope]          # [span, P]
         s = (jax.lax.dot_general(
             ql_ref[0, 0], c_kv, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
              + jax.lax.dot_general(
             qp_ref[0, 0], k_pe, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)) * scale      # [rows, BS]
+            preferred_element_type=jnp.float32)) * scale      # [rows, span]
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
-        alpha = jnp.where(m_prev == NEG_INF, 0.0, jnp.exp(m_prev - m_new))
-        l_scr[...] = jnp.broadcast_to(
-            (alpha * l_scr[:, 0] + jnp.sum(p, axis=1))[:, None], l_scr.shape)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
+        # one online-softmax update a group; m and l stay [rows, 1]
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # a row that has seen nothing yet (m = NEG_INF) is shifted by 0:
+        # exp(NEG_INF) is 0, as exp(NEG_INF - m) is for every masked
+        # score of a row that has; alpha of such a row scales zeros
+        p = jnp.exp(s - jnp.where(m_new == NEG_INF, 0.0, m_new))
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             p.astype(c_kv.dtype), c_kv, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
+        m_scr[...] = m_new
+        return carry
 
-    @pl.when(bi == num_kv_blocks - 1)
-    def _finalize():
-        l = l_scr[:, 0]
-        o_ref[0, 0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)[:, None]
-                       ).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, groups, step, 0)
+    slot_ref[0] = jax.lax.rem(base + groups, 2)
+    l = l_scr[...]
+    o_ref[0, 0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+        o_ref.dtype)
+
+
+# What a step of the latent kernel may hold in VMEM and what the call
+# asks of the compiler for it (its scoped default is 16 MiB of a v5e's
+# 128): a group of pages and its float32 score tiles are the step's bulk.
+_LATENT_VMEM_BUDGET = 40 * 1024 * 1024
+_LATENT_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def latent_vmem_bytes(rows: int, pages: int, latent: int, rope: int,
+                      block_size: int, itemsize: int, select: bool) -> int:
+    """VMEM one step of the latent kernel holds for ``rows`` query rows
+    and ``pages`` pool pages: q and out [rows, R] and q_pe [rows, P]
+    double-buffered, the f32 m/l/acc scratch, two groups of pages (and
+    of their scores under a selection) and the [rows, pages * bs] float32
+    score tiles."""
+    lat, pe = round_up(latent, _LANES), round_up(rope, _LANES)
+    span = pages * max(block_size, _LANES)
+    return (2 * rows * (2 * lat + pe) * itemsize
+            + rows * (2 * _LANES + lat) * 4
+            + 2 * pages * block_size * round_up(latent + rope, _LANES)
+            * itemsize
+            + (2 * 8 * span * 4 if select else 0)
+            + (5 if select else 4) * rows * span * 4)
 
 
 def latent_query_tile(num_heads: int, latent: int, rope: int,
                       block_size: int, t: int, dtype,
-                      select: bool = False) -> int:
-    """Query tokens one grid step of the latent kernel takes of ``t`` a
-    slot (all heads of each), or ``ValueError`` where no tile fits: the
-    largest divisor of ``t`` whose blocks — q and out [rows, R] and
-    q_pe [rows, P] double-buffered, the page, the f32 m/l/acc scratch
-    and the [rows, bs] score temporaries — stay inside the VMEM budget
-    (rows = tile tokens x heads)."""
+                      select: bool = False,
+                      max_blocks: Optional[int] = None,
+                      window: int = -1) -> Tuple[int, int]:
+    """``(tq, pages)``: the query tokens one grid step of the latent
+    kernel takes of ``t`` a slot (all heads of each) and the pool pages
+    one step of its page walk takes, or ``ValueError`` where nothing
+    fits.  Both follow what the call can see.  ``tq`` is the largest
+    divisor of ``t`` whose step fits the VMEM budget
+    (:func:`latent_vmem_bytes`; rows = tile tokens x heads).  ``pages``
+    is a power of two, at most 8 and at most a third of the longest
+    walk a tile can make — the table's ``max_blocks``, or the blocks a
+    ``window`` and the tile span: a walk's last group is multiplied
+    whole, its dead pages masked, so a long group is wasted on a short
+    walk (on a v5e 8 pages a step beat 4 by 13% over 33k-token tables
+    and lost 20% to 2 over a window's 6 blocks; PERF.md section 6,
+    PR 31)."""
     itemsize = jnp.dtype(dtype).itemsize
     if block_size % min_block_size(dtype):
         raise ValueError(
             f"latent paged attention kernel: block_size {block_size} is "
             f"not a multiple of {min_block_size(dtype)}, the TPU sublane "
             f"tile of a {jnp.dtype(dtype).name} pool")
-    lat, pe = round_up(latent, _LANES), round_up(rope, _LANES)
-    page = 2 * block_size * (lat + pe) * itemsize
-
-    def need(rows):
-        return (2 * rows * (2 * lat + pe) * itemsize
-                + rows * (2 * _LANES + lat) * 4
-                + (5 if select else 3) * rows * max(block_size, _LANES) * 4
-                + page)
+    need = functools.partial(
+        latent_vmem_bytes, latent=latent, rope=rope, block_size=block_size,
+        itemsize=itemsize, select=select)
     for tq in range(t, 0, -1):
         rows = tq * num_heads
-        # a selection's tile unrolls over its tokens: at most 8 of them
-        if t % tq == 0 and (rows % 8 == 0 or tq == t) \
-                and need(rows) <= _VMEM_BUDGET \
-                and (not select or tq <= 8):
-            return tq
+        # the mask unrolls over the tile's tokens: at most 8 of them
+        # under a selection (its score tile has 8 sublanes), 32 without
+        if t % tq or (rows % 8 and tq != t) or tq > (8 if select else 32):
+            continue
+        walk = max_blocks or 24
+        if window >= 0:
+            walk = min(walk, -(-(window + tq) // block_size) + 1)
+        pages = 1
+        while pages < 8 and pages * 2 <= walk // 3 \
+                and need(rows, pages * 2) <= _LATENT_VMEM_BUDGET:
+            pages *= 2
+        if need(rows, pages) <= _LATENT_VMEM_BUDGET:
+            return tq, pages
     raise ValueError(
         f"latent paged attention kernel: {num_heads} heads of a "
         f"{latent}+{rope} row do not fit the "
-        f"{_VMEM_BUDGET / 2**20:.0f} MiB VMEM budget even one token a "
-        f"step ({need(num_heads) / 2**20:.1f} MiB)")
+        f"{_LATENT_VMEM_BUDGET / 2**20:.0f} MiB VMEM budget even one token "
+        f"and one page a step ({need(num_heads, 1) / 2**20:.1f} MiB)")
 
 
 def _latent_paged_attention_pallas(q_lat, q_pe, pool, block_tables,
@@ -492,10 +642,9 @@ def _latent_paged_attention_pallas(q_lat, q_pe, pool, block_tables,
     bs, w = pool.shape[2], pool.shape[3]
     mb = block_tables.shape[1]
     select = selection is not None
-    tq = latent_query_tile(h, r, pe, bs, t_, pool.dtype, select)
+    tq, pages = latent_query_tile(h, r, pe, bs, t_, pool.dtype, select, mb,
+                                  window)
     nt, rows = t_ // tq, tq * h
-    # blocks one tile's window can reach: its positions span window + tq
-    nb = mb if window < 0 else min(mb, -(-(window + tq) // bs) + 1)
     lens = jnp.stack([context_lens.astype(jnp.int32),
                       q_start.astype(jnp.int32)], axis=1)
     layer = layer.reshape(1)
@@ -503,51 +652,48 @@ def _latent_paged_attention_pallas(q_lat, q_pe, pool, block_tables,
     ql = q_lat.reshape(s_, nt, rows, r)
     qp = q_pe.reshape(s_, nt, rows, pe)
 
-    def page(s, t, b, tbl, lens, layer):
-        # the last block this tile reads: its causal reach within the
-        # slot's length (the same index again = no new fetch)
-        reach = jnp.minimum(lens[s, 0], lens[s, 1] + (t + 1) * tq)
-        last = jnp.maximum(reach - 1, 0) // bs
-        if window >= 0:
-            b = b + jnp.maximum(lens[s, 1] + t * tq - window, 0) // bs
-        return (layer[0], tbl[s, jnp.minimum(b, last)], 0, 0)
-
-    q_map = lambda s, t, b, tbl, lens, layer: (s, t, 0, 0)  # noqa: E731
+    q_map = lambda s, t, tbl, lens, layer: (s, t, 0, 0)  # noqa: E731
     in_specs = [pl.BlockSpec((1, 1, rows, r), q_map),
                 pl.BlockSpec((1, 1, rows, pe), q_map)]
     operands = [ql, qp]
+    # m, l, acc; two groups of pages with a DMA semaphore each; the
+    # buffer the tile's first group is in
+    scratch = [pltpu.VMEM((rows, 1), jnp.float32),
+               pltpu.VMEM((rows, 1), jnp.float32),
+               pltpu.VMEM((rows, r), jnp.float32),
+               pltpu.VMEM((2, pages * bs, w), pool.dtype),
+               pltpu.SemaphoreType.DMA((2,)),
+               pltpu.SMEM((1,), jnp.int32)]
     if select:
         sel_scores, thr, tie_hi = selection
-        # the score tile of the page in hand, and the tile's thresholds
-        in_specs += [
-            pl.BlockSpec((1, 1, tq, bs),
-                         lambda s, t, b, tbl, lens, layer: (s, t, 0, b)),
-            pl.BlockSpec((1, 1, tq, 1), q_map),
-            pl.BlockSpec((1, 1, tq, 1), q_map)]
+        # the scores stay in HBM beside the pool: the walk copies the
+        # tile's [tq, bs] of each page it fetches
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY),
+                     pl.BlockSpec((1, 1, tq, 1), q_map),
+                     pl.BlockSpec((1, 1, tq, 1), q_map)]
         operands += [sel_scores.reshape(s_, nt, tq, mb * bs),
                      thr.reshape(s_, nt, tq, 1).astype(jnp.float32),
                      tie_hi.reshape(s_, nt, tq, 1).astype(jnp.int32)]
+        scratch += [pltpu.VMEM((2, pages, tq, bs), jnp.float32),
+                    pltpu.SemaphoreType.DMA((2,))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(s_, nt, nb),
-        in_specs=in_specs + [pl.BlockSpec((None, None, bs, w), page)],
+        grid=(s_, nt),
+        in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, 1, rows, r), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((rows, _LANES), jnp.float32),
-            pltpu.VMEM((rows, _LANES), jnp.float32),
-            pltpu.VMEM((rows, r), jnp.float32),
-        ],
+        scratch_shapes=scratch,
     )
     kernel = functools.partial(
         _latent_fwd_kernel, scale=scale, block_size=bs, latent=r, rope=pe,
-        heads=h, tq=tq, rows=rows, num_kv_blocks=nb, window=window,
-        select=select)
+        heads=h, tq=tq, pages=pages, window=window, select=select)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(ql.shape, q_lat.dtype),
+        # in order: a tile fetches the first pages of the one after it
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_LATENT_VMEM_LIMIT),
         interpret=_interpret(),
         name=name,
     )(block_tables.astype(jnp.int32), lens, layer, *operands, pool)
