@@ -605,3 +605,187 @@ def test_sparse_window_kernels_compile_at_published_geometry(
     assert compiled.as_text().count("tpu_custom_call") == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20 + (
         slots * t * n * 4 if kernel == "sparse" else 0)
+
+
+# -- grouped-query layers of two kinds: K-EXAONE-236B-A23B's published
+# geometry (chipbench/configs/k-exaone-236b-a23b.json, traffic/mixed16.json)
+
+KEXAONE_SERVE = dict(block_size=128, num_blocks=2129, max_slots=16,
+                     prefill_chunk=512)
+KEXAONE_SEQ = 17024
+# the cell's own depth: the dense sliding layer + s s g s s s g
+KEXAONE_DEPTH = 8
+
+
+def _kernel_grids(lowered_text):
+    """``iteration_bounds`` of every Mosaic kernel of a lowered program,
+    in program order, read out of the kernels' serialized IR."""
+    import base64
+    import json
+
+    from jax._src.interpreters import mlir as jmlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    grids = []
+    for m in re.finditer(r'backend_config = "((?:[^"\\]|\\.)*)"',
+                         lowered_text):
+        cfg = m.group(1).replace("\\22", '"').replace("\\5C", "\\")
+        try:
+            body = json.loads(cfg)["custom_call_config"]["body"]
+        except (ValueError, KeyError):
+            continue
+        ctx = jmlir.make_ir_context()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            asm = ir.Module.parse(base64.b64decode(body)).operation.get_asm(
+                enable_debug_info=False)
+        (bounds,) = re.findall(r"iteration_bounds = array<i64: ([\d, ]+)>",
+                               asm)
+        grids.append(tuple(int(x) for x in bounds.split(",")))
+    return grids
+
+
+@pytest.mark.parametrize("name,grid", [
+    ("decode", (16, 1, 33)), ("prefill_chunk", (1, 4, 33)),
+    ("prefill_final_chunk", (1, 4, 33))])
+def test_a_model_without_a_window_keeps_its_kernels_grid(one_chip,
+                                                         for_the_chip, name,
+                                                         grid):
+    """The grouped-query kernel gained a window walk (PR 33); with no
+    window it is the kernel it was: one call a program, the grid (slots,
+    kv-head groups, EVERY block of the table) of the Mistral serve cell.
+    (The builder's check of PR 33 went further: the three programs'
+    lowered and compiled text and the kernel's IR, parent against change,
+    equal but for source locations — PERF.md section 6.)"""
+    lowered, _ = _serve_program(name, one_chip)
+    assert _kernel_grids(lowered.as_text()) == [grid]
+
+
+def _kexaone_program(name, one_chip):
+    import json
+    import types
+
+    from chipbench.layouts import gqa_window_moe_decoder as layout
+    from chipbench.weights import gqa_window_moe_decoder as weights
+    from torchacc_tpu.models.hf import config_from_hf
+    from torchacc_tpu.serve.kv_cache import blocks_needed, make_pools
+    from torchacc_tpu.serve.scheduler import PagedDecoder
+
+    (row,) = [r for r in map(json.loads, open(
+        "/opt/skills/guides/model-configs/architectures.jsonl"))
+        if r["name"] == "K-EXAONE-236B-A23B"]
+    pub = dict(row["config"], num_experts=8, router_n_experts=128,
+               first_held_expert=64)
+    mc = config_from_hf(types.SimpleNamespace(**pub),
+                        num_layers=KEXAONE_DEPTH, max_seq_len=KEXAONE_SEQ,
+                        param_dtype=BF16)
+    sc = ServeConfig(**KEXAONE_SERVE)
+    decoder = PagedDecoder(mc, sc, "pallas")
+    sds = functools.partial(_sds, sharding=one_chip)
+    abstract = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: sds(x.shape, x.dtype), tree)
+    params = abstract(jax.eval_shape(
+        lambda k: layout.to_program_params(
+            weights.make(k, pub, KEXAONE_DEPTH, BF16), mc),
+        jax.random.PRNGKey(0)))
+    pools = abstract(jax.eval_shape(lambda: make_pools(mc, sc)))
+    s = sc.max_slots
+    mb = blocks_needed(KEXAONE_SEQ + sc.decode_depth, sc.block_size)
+    i32, f32 = jnp.int32, jnp.float32
+    if name == "decode":
+        carry = {"tok": sds((s,), i32), "key": sds((s, 2), jnp.uint32)}
+        return decoder._decode.lower(
+            params, pools, carry, sds((s, mb), i32), sds((s,), i32),
+            sds((s,), jnp.bool_), sds((s,), f32), sds((s,), i32),
+            sds((s,), f32), True, sds((s, mb), i32)), pools, mb
+    return decoder._prefill.lower(
+        params, pools, sds((mb,), i32), sds((), i32),
+        sds((sc.prefill_chunk,), i32), sds((), i32),
+        name == "prefill_final_chunk", sds((mb,), i32)), pools, mb
+
+
+@pytest.mark.parametrize("name",
+                         ["decode", "prefill_chunk", "prefill_final_chunk"])
+def test_window_gqa_serve_program_holds_four_pools_in_place(
+        one_chip, for_the_chip, name):
+    """The three serve programs of the K-EXAONE cell at its own settings
+    and depth (the dense sliding layer, then s s g s s s g; 8 held
+    experts of 6144 x 2048): FOUR pools — the global layers' k and v
+    [2, 2129, 128, 1024], the sliding layers' [6, 97, 128, 1024] — all
+    aliased in -> out, none copied, sliced or relayouted; 29 Mosaic
+    kernels (the dense layer's windowed attention, then seven expert
+    layers of one attention kernel and three grouped matmuls); a sliding
+    layer's kernel walks the 2 blocks (decode) or 4 (a tile of 256 of a
+    chunk's queries) its windows reach, a global layer's every block of
+    the table; no
+    instruction but a parameter yields an expert-stack-shaped array
+    ([8, 6144, 2048], 192 MiB) (sandbox compile, PR 33: PERF.md section
+    4 has the bytes)."""
+    lowered, pools, mb = _kexaone_program(name, one_chip)
+    # a chunk of 512 tokens x 8 query heads a kv head is more rows than a
+    # step holds: it runs as two tiles of 256, each a slot of the grid
+    t = 1 if name == "decode" else 512
+    tq = paged_mod.query_tile(64, 8, 128, 128, t, BF16)
+    assert tq == (1 if name == "decode" else 256)
+    slots = (16 if name == "decode" else 1) * (t // tq)
+    groups = 8 // paged_mod.heads_per_step(64, 8, 128, 128, tq, BF16)
+    walked = paged_mod.window_walk_blocks(127, tq, 128)
+    assert walked == (2 if name == "decode" else 4)
+    attention = [g for g in _kernel_grids(lowered.as_text()) if len(g) == 3
+                 and g[:2] == (slots, groups)]
+    assert sorted(g[2] for g in attention) == [walked] * 6 + [mb] * 2
+    compiled = lowered.compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    assert [p.shape for p in pools] == [
+        (2, 2129, 128, 1024)] * 2 + [(6, 97, 128, 1024)] * 2
+    assert mem.alias_size_in_bytes >= sum(
+        p.size * p.dtype.itemsize for p in pools)
+    assert mem.temp_size_in_bytes < (32 if name == "decode" else 256) * 2**20
+    # (a chunk that is not the prompt's last feeds no head: its last
+    # layer's expert matmuls are dead code, its router's counts are not)
+    assert text.count("tpu_custom_call") == (
+        26 if name == "prefill_chunk" else 29)
+    stack = r"bf16\[8,(6144,2048|2048,6144)\]"
+    copied = [line.strip()[:160] for line in text.splitlines()
+              if re.search(rf"= {stack}\S* (?!parameter\()", line)]
+    assert not copied, copied
+    shapes = "|".join(
+        re.escape(f"bf16[{','.join(str(d) for d in p.shape[i:])}]")
+        for p in pools[::2] for i in (0, 1))
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(rf"= ({shapes})\S* "
+                          r"(copy|dynamic-slice|dynamic-update-slice)\(",
+                          line)]
+    assert not moved, moved
+
+
+@pytest.mark.parametrize("slots,t", [(16, 1), (1, 512)],
+                         ids=["decode", "prefill_chunk"])
+def test_windowed_paged_kernel_compiles_at_published_geometry(
+        one_chip, for_the_chip, slots, t):
+    """The grouped-query kernel under K-EXAONE's window alone: 64 query
+    / 8 key-value heads of 128, a window of 127 back over blocks of 128,
+    a table of 134 blocks of which it walks 2 (decode) or 4 (each of a
+    chunk's two tiles of 256 queries); the pools read where they lie."""
+    sds = functools.partial(_sds, sharding=one_chip)
+    i32 = functools.partial(sds, dtype=jnp.int32)
+
+    def call(q, kp, vp, tables, ctx, q0, layer):
+        return paged_mod.paged_attention(
+            q, kp, vp, tables, ctx, q0, layer=layer, window=(127, -1),
+            impl="pallas", name="window_paged_attention")
+
+    pool = sds((LAYERS, 97, 128, 8 * 128), BF16)
+    lowered = jax.jit(call).lower(
+        sds((slots, t, 64, 128), BF16), pool, pool, i32((slots, 134)),
+        i32((slots,)), i32((slots,)), i32(()))
+    (grid,) = _kernel_grids(lowered.as_text())
+    tq = paged_mod.query_tile(64, 8, 128, 128, t, BF16)
+    assert grid[0] == slots * (t // tq)
+    assert grid[2] == paged_mod.window_walk_blocks(127, tq, 128) < 134
+    compiled = lowered.compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert "window_paged_attention" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20

@@ -351,22 +351,31 @@ def test_tp_mesh_pool_rows_split_at_head_boundaries(devices, kv_heads):
 
 
 def test_unsliceable_head_size_is_a_typed_error_at_construction():
-    # 9 kv heads of 72: no group of them is a multiple of 128 lanes, and
-    # the whole row's blocks for a 1024-token chunk are over the kernel's
-    # VMEM budget — refused when the decoder is built, not at the first
-    # request's lowering; a chunk whose whole row fits is served
+    # 9 kv heads of 72: no group of them is a multiple of 128 lanes, so a
+    # step takes the whole row.  Pages of 4096 tokens of it are over the
+    # kernel's VMEM budget whatever the chunk — refused when the decoder
+    # is built, not at the first request's lowering.  A 1024-token chunk
+    # over blocks of 128 is too tall for one step too, and since PR 33
+    # runs as tiles of its queries (ops/paged_attention.query_tile); a
+    # chunk whose whole row fits is served as it was
     import torchacc_tpu as ta
+    from torchacc_tpu.ops.paged_attention import query_tile
     from torchacc_tpu.serve.scheduler import PagedDecoder
     cfg = get_preset(
         "llama-tiny", dtype=jnp.bfloat16, num_layers=1, hidden_size=648,
         num_heads=9, num_kv_heads=9, intermediate_size=128,
         vocab_size=VOCAB, max_seq_len=2048)
     assert cfg.head_size == 72
-    wide = ServeConfig(block_size=128, num_blocks=64, max_slots=4,
+    huge = ServeConfig(block_size=4096, num_blocks=64, max_slots=4,
                        prefill_chunk=1024)
     with pytest.raises(ta.ConfigError, match="head size 72"):
-        PagedDecoder(cfg, wide, "pallas")
-    assert PagedDecoder(cfg, wide, "xla").impl == "xla"
+        PagedDecoder(cfg, huge, "pallas")
+    assert PagedDecoder(cfg, huge, "xla").impl == "xla"
+    wide = ServeConfig(block_size=128, num_blocks=64, max_slots=4,
+                       prefill_chunk=1024)
+    assert PagedDecoder(cfg, wide, "pallas").impl == "pallas"
+    assert query_tile(9, 9, 72, 128, 1024, jnp.bfloat16) == 256
+    assert query_tile(9, 9, 72, 128, 128, jnp.bfloat16) == 128
     narrow = ServeConfig(block_size=128, num_blocks=64, max_slots=4,
                          prefill_chunk=128)
     assert PagedDecoder(cfg, narrow, "pallas").impl == "pallas"

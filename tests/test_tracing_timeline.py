@@ -61,6 +61,14 @@ SPARSE_DECODE_SCOPES = ("index_write", "indexer", "index_topk",
 SELECT_SPAN_ATTRS = ("sel_attended", "sel_cached", "win_attended")
 ADMIT_BLOCK_ATTRS = ("blocks_full", "blocks_window", "window_blocks_freed")
 
+# the decode program of a grouped-query model of two layer kinds (PR 33):
+# the sliding layers' kernel call has its own scope beside paged_attn
+WINDOW_DECODE_SCOPES = ("window_paged_attn", "paged_attn", "qkv",
+                        "kv_write", "router", "experts")
+# what its serve/deliver spans carry (chipbench/rooflines/
+# gqa_window_common.py); its serve/admit spans carry ADMIT_BLOCK_ATTRS
+WINDOW_SPAN_ATTRS = ("ctx_attended", "win_attended")
+
 # the spans a traced serve loop / fit has to leave on the host plane
 SERVE_SPANS = ("serve/step", "serve/sweep", "serve/admit", "serve/prefill",
                "serve/decode", "serve/deliver", "serve/wait")
@@ -119,6 +127,35 @@ def _sparse_model():
         num_hidden_layers=3, attention_bias=False,
         layer_types=["full_attention", "full_attention",
                      "sliding_attention"], tie_word_embeddings=False)
+    mc = config_from_hf(types.SimpleNamespace(**pub), max_seq_len=128,
+                        dtype=jnp.float32, param_dtype=jnp.float32)
+    return mc, layout.to_program_params(
+        weights.make(weights.base_key(7), pub, 3, jnp.float32), mc)
+
+
+def _window_model():
+    """``(ModelConfig, params)`` of a toy of the grouped-query family of
+    two layer kinds: a dense sliding layer, then one period (sliding,
+    global); a window of 5.  Weights and layout are the benchmark's."""
+    import types
+
+    from chipbench.layouts import gqa_window_moe_decoder as layout
+    from chipbench.weights import gqa_window_moe_decoder as weights
+    from torchacc_tpu.models.hf import config_from_hf
+    pub = dict(
+        model_type="exaone_moe", hidden_size=64, intermediate_size=128,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        vocab_size=256, first_k_dense_replace=1, hidden_act="silu",
+        layer_types=["sliding_attention", "sliding_attention",
+                     "full_attention"],
+        mlp_layer_types=["dense", "sparse", "sparse"],
+        max_position_embeddings=128, moe_intermediate_size=32, n_group=1,
+        topk_group=1, norm_topk_prob=True, num_experts=4,
+        num_experts_per_tok=2, num_hidden_layers=3, num_shared_experts=1,
+        rms_norm_eps=1e-5,
+        rope_parameters={"rope_theta": 10000, "rope_type": "default"},
+        routed_scaling_factor=2.5, scoring_func="sigmoid", sliding_window=5,
+        tie_word_embeddings=False)
     mc = config_from_hf(types.SimpleNamespace(**pub), max_seq_len=128,
                         dtype=jnp.float32, param_dtype=jnp.float32)
     return mc, layout.to_program_params(
@@ -196,10 +233,19 @@ def program_scopes():
         sds((2,), jnp.float32), sds((2,), jnp.int32),
         sds((2,), jnp.float32), True, sds((2, 15), jnp.int32))
     sparse = sparse_lowered.compile().as_text()
+    wmc, wparams = _window_model()
+    window = PagedDecoder(wmc, sc, "xla")._decode.lower(
+        jax.eval_shape(lambda: wparams),
+        jax.eval_shape(lambda: make_pools(wmc, sc)), carry,
+        sds((2, 15), jnp.int32), sds((2,), jnp.int32), sds((2,), jnp.bool_),
+        sds((2,), jnp.float32), sds((2,), jnp.int32),
+        sds((2,), jnp.float32), True, sds((2, 15), jnp.int32)
+    ).compile().as_text()
     return {"train": _scopes_in(train), "decode": _scopes_in(decode),
             "prefill": _scopes_in(prefill),
             "latent_decode": _scopes_in(latent),
             "sparse_decode": _scopes_in(sparse),
+            "window_decode": _scopes_in(window),
             # read before the compile: the persistent compile cache's
             # key leaves op names out, so a scope moved with no change
             # to the computation comes back under its old names
@@ -213,7 +259,8 @@ def program_scopes():
     *(("decode", s) for s in DECODE_SCOPES),
     *(("prefill", s) for s in PREFILL_SCOPES),
     *(("latent_decode", s) for s in LATENT_DECODE_SCOPES),
-    *(("sparse_decode", s) for s in SPARSE_DECODE_SCOPES)])
+    *(("sparse_decode", s) for s in SPARSE_DECODE_SCOPES),
+    *(("window_decode", s) for s in WINDOW_DECODE_SCOPES)])
 def test_device_scope_in_compiled_program(program_scopes, program, scope):
     assert scope in tracing.DEVICE_SCOPES
     assert scope in program_scopes[program]
@@ -231,7 +278,8 @@ def test_every_pool_write_of_the_sparse_program_is_a_kv_write(
 
 def test_every_registered_scope_is_placed():
     placed = (set(TRAIN_SCOPES) | set(DECODE_SCOPES) | set(PREFILL_SCOPES)
-              | set(LATENT_DECODE_SCOPES) | set(SPARSE_DECODE_SCOPES))
+              | set(LATENT_DECODE_SCOPES) | set(SPARSE_DECODE_SCOPES)
+              | set(WINDOW_DECODE_SCOPES))
     assert placed == set(tracing.DEVICE_SCOPES)
 
 
@@ -304,6 +352,14 @@ def traced(tmp_path_factory):
                      max_new_tokens=3) for n in (5, 19)]
     sengine.generate(sreqs[:1])            # compile outside the trace
 
+    wmc, wparams = _window_model()
+    wengine = ServeEngine(TransformerLM(wmc), wparams, ta.Config(
+        serve=ta.config.ServeConfig(block_size=4, num_blocks=64,
+                                    max_slots=2, prefill_chunk=8)))
+    wreqs = [Request(prompt_ids=rng.integers(1, 256, size=n).tolist(),
+                     max_new_tokens=3) for n in (5, 19)]
+    wengine.generate(wreqs[:1])            # compile outside the trace
+
     assert not tracing.enabled()
     tracing.clear()
     trace_dir = str(tmp_path_factory.mktemp("timeline"))
@@ -316,11 +372,13 @@ def traced(tmp_path_factory):
         trainer.fit(batches, log_every=1)
         lengine.generate(lreqs)
         sengine.generate(sreqs)
+        wengine.generate(wreqs)
     finally:
         jax.profiler.stop_trace()
     engine.close()
     lengine.close()
     sengine.close()
+    wengine.close()
     return {"events": _host_events(trace_dir),
             "ring": tracing.snapshot()}
 
@@ -364,7 +422,7 @@ def test_deliver_spans_carry_the_expert_layers_counts(traced, attr):
     # spans are read by the next test)
     with_counts = [st for n, _, _, st in traced["events"]
                    if n == "serve/deliver" and "moe_pairs" in st
-                   and "sel_cached" not in st]
+                   and "sel_cached" not in st and "ctx_attended" not in st]
     without = [st for n, _, _, st in traced["events"]
                if n == "serve/deliver" and "moe_pairs" not in st]
     assert with_counts and without
@@ -405,6 +463,31 @@ def test_spans_carry_the_selection_and_the_blocks_by_kind(traced, attr):
     assert all(int(st["sel_attended"]) <= int(st["sel_cached"])
                and int(st["win_attended"]) <= int(st["sel_cached"])
                for st in have)
+
+
+@pytest.mark.parametrize("attr", WINDOW_SPAN_ATTRS + MOE_SPAN_ATTRS)
+def test_spans_carry_what_each_kind_of_grouped_query_layer_attended(
+        traced, attr):
+    """A grouped-query model of sliding and global layers: its
+    serve/deliver spans carry the positions a global layer attended
+    (all that were cached) and a sliding layer attended, for the queries
+    behind the tokens, beside the expert layers' counts; no selection's
+    counts.  Its admitting serve/admit spans carry the blocks by kind."""
+    have = [st for n, _, _, st in traced["events"]
+            if n == "serve/deliver" and "ctx_attended" in st]
+    assert have and all(attr in st and "sel_cached" not in st
+                        for st in have)
+    # a window of 5: a prompt of 19 tokens attends 1 + .. + 19 = 190
+    # positions on a global layer and 1 + .. + 5 + 14 x 5 = 85 on a
+    # sliding one
+    firsts = [st for st in have if str(st.get("kind")) == "first"]
+    assert ["190", "85"] in [[str(st[a]) for a in WINDOW_SPAN_ATTRS]
+                             for st in firsts]
+    assert all(int(st["win_attended"]) <= int(st["ctx_attended"])
+               for st in have)
+    admits = [st for n, _, _, st in traced["events"]
+              if n == "serve/admit" and "blocks_window" in st]
+    assert len(admits) >= 4                # this model's and the latent one's
 
 
 # -- the idle path and the ring ------------------------------------------------

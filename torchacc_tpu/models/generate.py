@@ -394,24 +394,23 @@ def _generate_cached_pp(cfg, params, prompt_ids, prompt_mask, rng,
 # heterogeneous-layer (gemma2-style) KV-cache decode
 # ---------------------------------------------------------------------------
 
-def _pattern_layers_with_cache(cfg, stacked_params, cache, x, positions,
-                               seg):
-    """Raw per-layer loop threading the kv cache through the canonical
-    [L, ...] stacked layout, with each layer's own pattern cfg — the
-    scan path cannot vary a static window per layer.  ``cache=None``
-    (prefill) creates the banked cache."""
-    from torchacc_tpu.models.transformer import ScanBlock, pattern_cfg
+def _pattern_layers_with_cache(cfg, params, cache, x, positions, seg):
+    """Raw per-layer loop threading the kv cache through the stacked
+    layout (models/transformer.layer_tree: the canonical [L, ...] stack,
+    leading dense layers, the serving layout of a period), with each
+    layer's own pattern cfg — the scan path cannot vary a static window
+    per layer.  ``cache=None`` (prefill) creates the banked cache."""
+    from torchacc_tpu.models.transformer import ScanBlock, layer_tree
 
     new_layers = []
     for i in range(cfg.num_layers):
-        blk = ScanBlock(pattern_cfg(cfg, i))
-        variables = {"params": jax.tree.map(
-            lambda a, i=i: a[i], stacked_params)}
+        tree, block_cfg = layer_tree(cfg, params, i)
+        variables = {"params": tree}
         if cache is not None:
             variables["cache"] = jax.tree.map(
                 lambda a, i=i: a[i], cache)
-        (carry, _), vs = blk.apply(variables, (x, positions, seg), None,
-                                   mutable=["cache"])
+        (carry, _), vs = ScanBlock(block_cfg).apply(
+            variables, (x, positions, seg), None, mutable=["cache"])
         x = carry[0]
         new_layers.append(vs["cache"])
     new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *new_layers)
@@ -437,13 +436,13 @@ def _generate_cached_pattern(cfg, params, prompt_ids, prompt_mask, rng,
 
     x = embed_ids(cfg, params, prompt_ids, positions)
     y, cache = _pattern_layers_with_cache(
-        blk_pre, params["layers"], None, x, positions, seg)
+        blk_pre, params, None, x, positions, seg)
     logits = head_logits(cfg, params, y)
 
     def step_fn(cache, tok, positions1):
         x1 = embed_ids(cfg, params, tok[:, None], positions1)
         y1, cache = _pattern_layers_with_cache(
-            blk_dec, params["layers"], cache, x1, positions1, None)
+            blk_dec, params, cache, x1, positions1, None)
         return head_logits(cfg, params, y1)[:, 0], cache
 
     return _drive_decode(logits, cache, step_fn, prompt_ids, row_len,

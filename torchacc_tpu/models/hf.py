@@ -332,6 +332,63 @@ def config_from_hf(hf_config: Any, **overrides) -> ModelConfig:
                       * _m.log(float(rs["factor"])) + 1.0) ** 2
         # two kinds of layer: each kind's head size gives its own scale
         kw["query_scale"] = None if mt == "dots3_note" else scale
+    if mt == "exaone_moe":
+        # K-EXAONE (LG AI Research): EXAONE 4.0's block under experts.
+        # Grouped-query attention with RMSNorm over each head of q and k
+        # before rope, the norms on the sublayers' OUTPUTS (post
+        # placement); `layer_types` mixes 'sliding_attention' layers
+        # (rope, `sliding_window` positions, the token itself counted)
+        # with 'full_attention' layers that carry NO rotary embedding;
+        # `mlp_layer_types` is `first_k_dense_replace` dense layers, then
+        # expert layers: sigmoid scores, selection by score + a
+        # per-expert bias, normalised weights times
+        # routed_scaling_factor, shared experts.  `num_experts` is the
+        # number of experts THIS program holds (`router_n_experts`,
+        # `first_held_expert` beside it state an expert-parallel share,
+        # as for axk1).  The multi-token-prediction block
+        # (`num_nextn_predict_layers`, a drafter) is not loaded.
+        types_ = list(get("layer_types") or [])
+        n = int(overrides.get("num_layers", kw["num_layers"]))
+        n_dense = int(get("first_k_dense_replace", 0) or 0)
+        mlp_types = list(get("mlp_layer_types") or [])
+        if len(types_) < n:
+            raise ValueError(
+                f"exaone_moe layer_types names {len(types_)} layers, "
+                f"num_layers is {n}")
+        if mlp_types != (["dense"] * n_dense
+                         + ["sparse"] * (len(mlp_types) - n_dense)):
+            raise NotImplementedError(
+                "exaone_moe with dense layers anywhere but in front "
+                "(mlp_layer_types against first_k_dense_replace)")
+        if get("hidden_act", "silu") != "silu":
+            raise NotImplementedError("exaone_moe hidden_act must be silu")
+        rp = get("rope_parameters") or {}
+        if rp.get("rope_type", "default") != "default":
+            raise NotImplementedError(
+                f"exaone_moe rope_type {rp.get('rope_type')!r}")
+        held = int(get("num_experts"))
+        kw.update(
+            rope_theta=float(rp.get("rope_theta", kw["rope_theta"])),
+            qkv_bias=False, o_bias=False, qk_norm=True,
+            norm_placement="post",
+            layer_pattern=tuple(
+                "sliding" if t == "sliding_attention" else "global"
+                for t in types_[:n]),
+            rope_kinds=("sliding",),
+            first_dense_layers=n_dense,
+            num_experts=held,
+            num_experts_per_tok=int(get("num_experts_per_tok")),
+            moe_intermediate_size=int(get("moe_intermediate_size")),
+            moe_scoring=get("scoring_func", "sigmoid"),
+            moe_n_group=int(get("n_group", 1) or 1),
+            moe_topk_group=int(get("topk_group", 1) or 1),
+            moe_route_scale=float(get("routed_scaling_factor", 1.0)),
+            moe_renorm_topk=bool(get("norm_topk_prob", True)),
+            moe_router_bias=True,
+            moe_shared_experts=int(get("num_shared_experts", 0) or 0),
+            moe_router_width=int(get("router_n_experts", held)),
+            moe_first_expert=int(get("first_held_expert", 0)),
+            moe_dispatch="grouped")
     if mt == "mixtral":
         # Mixtral 8x7B/8x22B: llama attention + top-k sparse MoE MLP.
         # HF routes softmax-then-topk-then-renormalise, which equals the

@@ -144,6 +144,11 @@ class ModelConfig:
     # the canonical stacked layout and checkpoints are unchanged;
     # execution uses the per-layer loop (scan_layers is ignored).
     layer_pattern: Optional[Tuple[str, ...]] = None
+    # the layer_pattern kinds whose layers carry the rotary embedding;
+    # None = every layer does.  ('sliding',) is the exaone_moe family's
+    # arrangement: rope on the windowed layers, NO position signal at all
+    # on the global ones (pattern_cfg gives those pos_emb='none')
+    rope_kinds: Optional[Tuple[str, ...]] = None
     # KV-cache decode mode (models/generate.py): __call__ consumes one
     # token per step, appending rotated k / raw v into the 'cache'
     # collection and attending over the filled prefix
@@ -1223,24 +1228,78 @@ def embed_ids(cfg: ModelConfig, params, ids: jax.Array,
 
 def pattern_cfg(cfg: ModelConfig, i: int) -> ModelConfig:
     """The effective per-layer config under ``cfg.layer_pattern``:
-    layer i takes pattern[i % len] — 'sliding' keeps cfg.window,
-    'global' lifts it to full attention.  Identity when no pattern."""
+    layer i takes pattern[i % len] (:func:`kind_cfg`).  Identity when no
+    pattern."""
     if not cfg.layer_pattern:
         return cfg
-    kind = cfg.layer_pattern[i % len(cfg.layer_pattern)]
-    if kind == "sliding":
+    return kind_cfg(cfg, cfg.layer_pattern[i % len(cfg.layer_pattern)])
+
+
+def kind_cfg(cfg: ModelConfig, kind: str) -> ModelConfig:
+    """The config a ``layer_pattern`` layer of ``kind`` computes under:
+    'sliding' keeps cfg.window, 'global' lifts it to full attention; a
+    kind outside ``cfg.rope_kinds`` (where those are named) has no
+    rotary embedding."""
+    if kind not in ("sliding", "global"):
+        raise ValueError(
+            f"layer_pattern entries must be 'sliding' | 'global', got "
+            f"{kind!r}")
+    if kind == "global":
+        cfg = dataclasses.replace(cfg, window=(-1, -1))
+    elif cfg.rope_local_theta is not None:
         # gemma3 dual rope: sliding layers use the local base frequency,
         # UNSCALED (HF applies rope_scaling to the global rotary only)
-        if cfg.rope_local_theta is not None:
-            return dataclasses.replace(cfg,
-                                       rope_theta=cfg.rope_local_theta,
-                                       rope_scale=1.0)
-        return cfg
-    if kind == "global":
-        return dataclasses.replace(cfg, window=(-1, -1))
-    raise ValueError(
-        f"layer_pattern entries must be 'sliding' | 'global', got "
-        f"{kind!r}")
+        cfg = dataclasses.replace(cfg, rope_theta=cfg.rope_local_theta,
+                                  rope_scale=1.0)
+    if cfg.rope_kinds is not None and kind not in cfg.rope_kinds:
+        if cfg.pos_emb != "rope":
+            raise ValueError("rope_kinds names the layers that carry "
+                             "rope: it needs pos_emb='rope'")
+        cfg = dataclasses.replace(cfg, pos_emb="none")
+    return cfg
+
+
+def layer_kinds(cfg: ModelConfig):
+    """The ``layer_pattern`` kind of every layer, in order."""
+    return [cfg.layer_pattern[i % len(cfg.layer_pattern)]
+            for i in range(cfg.num_layers)]
+
+
+def pattern_period(cfg: ModelConfig):
+    """``(kinds of the leading dense layers, kinds of one period)`` of a
+    model whose ``layer_pattern`` names every layer beside
+    ``first_dense_layers``: the layers after the dense ones repeat the
+    shortest period that divides them (all of them, where none does)."""
+    kinds = layer_kinds(cfg)
+    dense, rest = kinds[:cfg.first_dense_layers], \
+        kinds[cfg.first_dense_layers:]
+    for n in range(1, len(rest) + 1):
+        if len(rest) % n == 0 and rest == rest[:n] * (len(rest) // n):
+            return dense, rest[:n]
+    return dense, rest
+
+
+def layer_tree(cfg: ModelConfig, params, i: int):
+    """Layer ``i``'s raw tree ``{'block': ...}`` and the config its block
+    computes under, out of the stacked parameters: the canonical
+    ``layers`` [L, ...]; with leading dense layers their own stack
+    ``dense_layers`` first; and, for a pattern beside dense layers, one
+    stack a position of the pattern's period, ``layers/p<k>`` [periods,
+    ...] (the layout the serving decoder scans,
+    serve/scheduler.PagedDecoder._forward_periods)."""
+    block_cfg = pattern_cfg(cfg, i)
+    nd = cfg.first_dense_layers
+    if i < nd:
+        stack, at = params["dense_layers"], i
+        block_cfg = dataclasses.replace(block_cfg, num_experts=0,
+                                        first_dense_layers=0)
+    elif "p0" in params["layers"]:
+        plen = len(pattern_period(cfg)[1])
+        stack = params["layers"][f"p{(i - nd) % plen}"]
+        at = (i - nd) // plen
+    else:
+        stack, at = params["layers"], i - nd
+    return jax.tree.map(lambda a: a[at], stack), block_cfg
 
 
 def head_logits(cfg: ModelConfig, params, x: jax.Array) -> jax.Array:

@@ -105,6 +105,9 @@ SCOPES: Dict[str, str] = {
                 "paged pools at (layer, block, offset): k and v, or a "
                 "latent row (and a full layer's index key)",
     "paged_attn": "the paged-attention kernel and its relayouts",
+    "window_paged_attn": "the paged-attention kernel of a sliding "
+                         "grouped-query layer, bounded to the blocks its "
+                         "window reaches, in the sliding layers' own pools",
     "o_proj": "attention output projection + residual (serving)",
     "mlp": "feed-forward block",
     "final_norm": "norm before the head",

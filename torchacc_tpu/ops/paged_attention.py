@@ -45,6 +45,16 @@ cache write happens before the attention call), and ``q_start[s]`` is
 the global position of the slot's first query row — causality is
 ``kv_pos <= q_start + t``.  Slots with ``context_lens == 0`` (free slots parked on the null block)
 produce all-zero outputs.
+
+Two shapes of the same kernel call, both decided from the call's own
+arguments: under a LEFT window alone the block axis covers only the
+blocks the slot's windows reach (``window_walk_blocks``), from the first
+one on, so the table's entries before it are never read (a model that
+mixes sliding and global grouped-query layers frees them,
+serve/kv_cache.WindowBlocks); and a chunk whose rows exceed what one
+step holds in VMEM runs as tiles of its queries (``query_tile``), each a
+slot of the grid with its own first position.  With neither, the grid is
+(slots, kv-head groups, every block of the table).
 """
 
 from __future__ import annotations
@@ -129,7 +139,8 @@ def _paged_attention_xla(q, k_pool, v_pool, block_tables, context_lens,
 def _paged_fwd_kernel(tbl_ref, lens_ref, layer_ref, q_ref, k_ref, v_ref,
                       o_ref, m_scr, l_scr, acc_scr,
                       *, scale, block_size, head_dim, t_len, rows,
-                      heads_per_step, num_kv_blocks, window, logit_softcap):
+                      heads_per_step, num_kv_blocks, window, logit_softcap,
+                      walk_window=False):
     si = pl.program_id(0)
     bi = pl.program_id(2)
 
@@ -142,6 +153,11 @@ def _paged_fwd_kernel(tbl_ref, lens_ref, layer_ref, q_ref, k_ref, v_ref,
     ctx = lens_ref[si, 0]
     q0 = lens_ref[si, 1]
     k_start = bi * block_size
+    if walk_window:
+        # the block axis covers the blocks the slot's windows reach and
+        # no others: step bi is the bi-th block from the first one
+        k_start += _first_window_block(q0, window[0], block_size) \
+            * block_size
 
     @pl.when(k_start < ctx)
     def _compute():
@@ -190,6 +206,19 @@ def _paged_fwd_kernel(tbl_ref, lens_ref, layer_ref, q_ref, k_ref, v_ref,
                 o_ref.dtype)
 
 
+def _first_window_block(q_start, left, block_size):
+    """The logical block that holds the first position the window of a
+    query at ``q_start`` reaches."""
+    return jnp.maximum(q_start - left, 0) // block_size
+
+
+def window_walk_blocks(left: int, t: int, block_size: int) -> int:
+    """Blocks that ``t`` consecutive queries with a left window of
+    ``left`` positions can touch: ``left + t`` positions in a row lie in
+    at most one block more than they fill."""
+    return (left + t + block_size - 2) // block_size + 1
+
+
 _LANES = 128
 # What one grid step may hold in VMEM.  The compiler's scoped default on
 # the chips this targets is 16 MiB; the rest is left to its own
@@ -236,12 +265,55 @@ def heads_per_step(num_heads: int, kv_heads: int, head_dim: int,
         f"lower serve.prefill_chunk or serve.block_size")
 
 
+def query_tile(num_heads: int, kv_heads: int, head_dim: int,
+               block_size: int, t: int, dtype) -> int:
+    """Tokens of a slot one call of the kernel takes at a time: ``t``
+    where a step's blocks for all of them fit (:func:`heads_per_step`),
+    else the largest ``t / 2**k`` that does — a chunk of 512 tokens
+    under 8 query heads a kv head is 4,096 rows a head, more than a
+    step holds.  ``ValueError`` (the whole ``t``'s) where none does."""
+    tq, first = t, None
+    while True:
+        try:
+            heads_per_step(num_heads, kv_heads, head_dim, block_size, tq,
+                           dtype)
+            return tq
+        except ValueError as e:
+            first = first or e
+            if tq % 2:
+                raise first
+            tq //= 2
+
+
 def _paged_attention_pallas(q, k_pool, v_pool, block_tables, context_lens,
-                            q_start, layer, scale, window, logit_softcap):
+                            q_start, layer, scale, window, logit_softcap,
+                            name="paged_attention"):
     s_, t_, h, d = q.shape
     bs, kh = k_pool.shape[2], k_pool.shape[3] // d
     mb = block_tables.shape[1]
     group = h // kh
+    tq = query_tile(h, kh, d, bs, t_, k_pool.dtype)
+    if tq < t_:
+        # a chunk too tall for one step runs as tiles of tq tokens, each
+        # a slot of its own: the slot's table, its own first position,
+        # the context cut to its causal reach (the blocks past it are
+        # skipped, not multiplied and masked)
+        nt = t_ // tq
+        starts = (q_start[:, None]
+                  + tq * jnp.arange(nt, dtype=jnp.int32)).reshape(-1)
+        out = _paged_attention_pallas(
+            q.reshape(s_ * nt, tq, h, d), k_pool, v_pool,
+            jnp.repeat(block_tables, nt, axis=0),
+            jnp.minimum(jnp.repeat(context_lens, nt), starts + tq), starts,
+            layer, scale, window, logit_softcap, name)
+        return out.reshape(s_, t_, h, d)
+    # a left window alone: the block axis is the blocks the slot's
+    # windows reach, from the first one on — the table's entries before
+    # it are never read (serve/kv_cache.WindowBlocks frees them), and a
+    # long context costs a sliding layer no more steps than a short one
+    walk_window = window[0] >= 0 and window[1] < 0
+    nb = (min(mb, window_walk_blocks(window[0], t_, bs)) if walk_window
+          else mb)
     # three scalar-prefetch operands: the block table, lens = [S, 2]
     # (context_len, q_start) and the layer index, so every BlockSpec
     # index map can address the page for (layer, slot, kv-block) in the
@@ -257,12 +329,17 @@ def _paged_attention_pallas(q, k_pool, v_pool, block_tables, context_lens,
 
     q_spec = pl.BlockSpec((1, hb, rows, d),
                           lambda s, g, b, tbl, lens, layer: (s, g, 0, 0))
-    kv_spec = pl.BlockSpec(
-        (None, None, bs, hb * d),
-        lambda s, g, b, tbl, lens, layer: (layer[0], tbl[s, b], 0, g))
+    if walk_window:
+        def page(s, g, b, tbl, lens, layer):
+            first = _first_window_block(lens[s, 1], window[0], bs)
+            return (layer[0], tbl[s, jnp.minimum(first + b, mb - 1)], 0, g)
+    else:
+        def page(s, g, b, tbl, lens, layer):
+            return (layer[0], tbl[s, b], 0, g)
+    kv_spec = pl.BlockSpec((None, None, bs, hb * d), page)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(s_, kh // hb, mb),
+        grid=(s_, kh // hb, nb),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=q_spec,
         scratch_shapes=[
@@ -273,8 +350,8 @@ def _paged_attention_pallas(q, k_pool, v_pool, block_tables, context_lens,
     )
     kernel = functools.partial(
         _paged_fwd_kernel, scale=scale, block_size=bs, head_dim=d, t_len=t_,
-        rows=rows, heads_per_step=hb, num_kv_blocks=mb, window=window,
-        logit_softcap=logit_softcap)
+        rows=rows, heads_per_step=hb, num_kv_blocks=nb, window=window,
+        logit_softcap=logit_softcap, walk_window=walk_window)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -282,7 +359,7 @@ def _paged_attention_pallas(q, k_pool, v_pool, block_tables, context_lens,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
-        name="paged_attention",
+        name=name,
     )(block_tables.astype(jnp.int32), lens, layer, qg, k_pool, v_pool)
     return out.reshape(s_, kh, group, t_, d).transpose(0, 3, 1, 2, 4).reshape(
         s_, t_, h, d)
@@ -971,6 +1048,7 @@ def paged_attention(
     window: Tuple[int, int] = (-1, -1),
     logit_softcap: float = 0.0,
     impl: str = "auto",
+    name: str = "paged_attention",
 ) -> jax.Array:
     """Causal attention of ``q [S, T, H, D]`` over layer ``layer`` of a
     paged KV pool.
@@ -982,6 +1060,13 @@ def paged_attention(
     length per slot (chunk included); ``q_start [S]`` the global
     position of each slot's first query row.  Returns [S, T, H, D];
     slots with ``context_lens == 0`` return zeros.
+
+    ``window`` ``(left, right)`` bounds a query at t to ``[t - left,
+    t + right]`` (-1 = unbounded).  Under a left window alone the kernel
+    walks only the blocks the slot's windows reach: the table's entries
+    before them are never read.  ``name`` is the kernel's instruction
+    name (a profile reads a model's sliding layers apart from its global
+    ones by it).
 
     ``impl``: 'auto' (pallas on TPU, xla elsewhere) | 'pallas'
     (interpret mode off-TPU) | 'xla'.
@@ -1012,12 +1097,13 @@ def paged_attention(
         impl = "pallas" if _on_tpu() else "xla"
     if impl not in ("pallas", "xla"):
         raise ValueError(f"impl must be auto|pallas|xla, got {impl!r}")
-    fn = (_paged_attention_pallas if impl == "pallas"
-          else _paged_attention_xla)
-    mesh = ambient_mesh()
-    if impl == "pallas" and needs_shard_map(mesh):
-        fn = functools.partial(_paged_attention_pallas_sharded, mesh)
+    static = (float(scale), tuple(window), float(logit_softcap))
+    fn = _paged_attention_xla
+    if impl == "pallas":
+        static += (name,)
+        mesh = ambient_mesh()
+        fn = (functools.partial(_paged_attention_pallas_sharded, mesh)
+              if needs_shard_map(mesh) else _paged_attention_pallas)
     return fn(q, k_pool, v_pool, block_tables.astype(jnp.int32),
               context_lens.astype(jnp.int32), q_start.astype(jnp.int32),
-              jnp.asarray(layer, jnp.int32), float(scale), tuple(window),
-              float(logit_softcap))
+              jnp.asarray(layer, jnp.int32), *static)

@@ -48,6 +48,14 @@ them back as the window passes, so a 32k-token sequence costs those
 layers ``window_blocks_bound`` blocks (10 at a window of 513, chunks of
 512 and blocks of 128) where a full table would hold 260.
 
+A grouped-query model that mixes windowed and full layers (a
+``layer_pattern`` of 'sliding' and 'global', the 'exaone_moe' family)
+has the same two kinds of block over FOUR pools: the global layers' k
+and v stacks ``[L_global, NB, BS, KH*D]`` under the ordinary table, the
+sliding layers' ``[L_sliding, NB_win, BS, KH*D]`` under
+:class:`WindowBlocks` — 6 blocks a sequence at a window of 128, chunks of
+512 and blocks of 128, whatever its length.
+
 The allocator is deliberately host-side and synchronous: allocation
 decisions happen at admission time (serve/engine.py), outside the
 jitted hot path, exactly like the trainer's host/device split
@@ -371,7 +379,10 @@ def num_window_blocks(model_cfg, serve_cfg) -> int:
 
 def make_pools(model_cfg, serve_cfg, dtype=None):
     """The paged pools of a model, a tuple, in the model's compute
-    dtype: ``(k_pools, v_pools)`` of shape [L, NB, BS, KH*D], or for a
+    dtype: ``(k_pools, v_pools)`` of shape [L, NB, BS, KH*D] (with a
+    ``layer_pattern`` of windowed and full layers ``(k, v)`` of the
+    global layers and ``(k, v)`` [L_sliding, NB_win, BS, KH*D] of the
+    sliding ones), or for a
     latent-attention model ONE pool ``(latent,)`` of shape
     [L, NB, BS, latent_row_width] with no head dimension (every head
     reads the same row), or for a model of two latent kinds ``(full
@@ -406,15 +417,24 @@ def make_pools(model_cfg, serve_cfg, dtype=None):
                            serve_cfg.block_size,
                            latent_row_width(model_cfg)),
                           dtype or model_cfg.dtype),)
-    shape = (model_cfg.num_layers, serve_cfg.num_blocks,
-             serve_cfg.block_size,
-             model_cfg.kv_heads * model_cfg.head_size)
     dt = dtype or model_cfg.dtype
     # a row splits at head boundaries only: tp must divide the HEADS
     # (the constraint alone would split 128 lanes of one MQA head)
     tp = dict(jax.sharding.get_abstract_mesh().shape).get("tp", 1)
     axes = (None, None, None,
             "heads" if model_cfg.kv_heads % tp == 0 else None)
-    k = activation_constraint(jnp.zeros(shape, dt), axes)
-    v = activation_constraint(jnp.zeros(shape, dt), axes)
-    return k, v
+
+    def kv(layers, blocks):
+        shape = (layers, blocks, serve_cfg.block_size,
+                 model_cfg.kv_heads * model_cfg.head_size)
+        return tuple(activation_constraint(jnp.zeros(shape, dt), axes)
+                     for _ in range(2))
+
+    if model_cfg.layer_pattern:
+        # windowed and full grouped-query layers: (k, v) of the global
+        # layers, then (k, v) of the sliding ones under their own table
+        from torchacc_tpu.models.transformer import layer_kinds
+        n_win = layer_kinds(model_cfg).count("sliding")
+        return (kv(model_cfg.num_layers - n_win, serve_cfg.num_blocks)
+                + kv(n_win, num_window_blocks(model_cfg, serve_cfg)))
+    return kv(model_cfg.num_layers, serve_cfg.num_blocks)

@@ -60,6 +60,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -69,16 +70,21 @@ import numpy as np
 
 from torchacc_tpu.config import ConfigError
 from torchacc_tpu.models import block
-from torchacc_tpu.models.transformer import embed_ids, head_logits
+from torchacc_tpu.models.transformer import (
+    embed_ids,
+    head_logits,
+    kind_cfg,
+    pattern_period,
+)
 from torchacc_tpu.obs import tracing
 from torchacc_tpu.ops._common import on_tpu
 from torchacc_tpu.ops.paged_attention import (
-    heads_per_step,
     index_query_tile,
     indexer_scores,
     latent_paged_attention,
     latent_query_tile,
     paged_attention,
+    query_tile,
     select_topk,
 )
 from torchacc_tpu.resilience.chaos import failpoint
@@ -148,21 +154,19 @@ _AUDITED_MODEL_FIELDS = frozenset({
     "swa_kv_lora_rank", "swa_q_lora_rank", "swa_qk_nope_head_dim",
     "swa_qk_rope_head_dim", "swa_v_head_dim", "mla_lora_rescale",
     "attn_gate",
+    # PR-33 audit: windowed and full grouped-query layers under one
+    # layer_pattern (_attend's window kind over the sliding layers' own
+    # k/v pools, _forward's scan over periods); rope_kinds reaches the
+    # block through models/transformer.kind_cfg (a kind without rope
+    # computes under pos_emb='none', which block.qkv reads)
+    "rope_kinds",
 })
 
 
-def _period(cfg):
-    """``(kinds of the leading dense layers, kinds of one period)`` of a
-    model of two latent kinds: the layers after the dense ones repeat
-    the shortest period that divides them."""
-    from torchacc_tpu.models.mla import layer_kind
-    kinds = [layer_kind(cfg, i) for i in range(cfg.num_layers)]
-    dense, rest = kinds[:cfg.first_dense_layers], \
-        kinds[cfg.first_dense_layers:]
-    for n in range(1, len(rest) + 1):
-        if len(rest) % n == 0 and rest == rest[:n] * (len(rest) // n):
-            return dense, rest[:n]
-    return dense, rest
+#: ``(kinds of the leading dense layers, kinds of one period)`` of a
+#: model whose layer_pattern names two kinds of layer (the name
+#: chipbench/layouts reads it under)
+_period = pattern_period
 
 
 def _check_supported(cfg) -> None:
@@ -197,8 +201,7 @@ def _check_supported(cfg) -> None:
         bad.append("pipeline parallelism (pp_size > 1)")
     if cfg.context_parallel:
         bad.append("context parallelism")
-    two_kinds = bool(cfg.kv_lora_rank and cfg.swa_kv_lora_rank)
-    if two_kinds:
+    if cfg.kv_lora_rank and cfg.swa_kv_lora_rank:
         # windowed and full latent layers under one pattern: admitted
         # where the scan over periods and the three pools can hold it
         dense, period = _period(cfg)
@@ -215,11 +218,27 @@ def _check_supported(cfg) -> None:
             bad.append(f"attn_gate {cfg.attn_gate!r}")
     else:
         if cfg.layer_pattern:
-            bad.append("layer_pattern (per-layer sliding windows) outside "
-                       "the latent family of two kinds (windows on "
-                       "grouped-query pools)")
-        if tuple(cfg.window) != (-1, -1):
-            bad.append(f"sliding window {cfg.window}")
+            # windowed and full grouped-query layers under one pattern:
+            # admitted where the scan over periods and the pools of two
+            # geometries can hold it
+            dense, _ = pattern_period(cfg)
+            if (cfg.kv_lora_rank
+                    or set(cfg.layer_pattern) - {"global", "sliding"}
+                    or cfg.window[0] < 0 or not cfg.num_experts
+                    or not cfg.first_dense_layers or len(set(dense)) != 1):
+                bad.append("layer_pattern on grouped-query pools in any "
+                           "arrangement but: 'global' and 'sliding' (left "
+                           "window) layers, leading dense layers of one "
+                           "kind, expert layers after them (and on the "
+                           "pool of a one-kind latent model)")
+            if cfg.window[1] >= 0:
+                bad.append(f"a two-sided window {cfg.window} (the paged "
+                           f"cache holds no position after a query)")
+        elif tuple(cfg.window) != (-1, -1):
+            bad.append(f"sliding window {cfg.window} without a "
+                       f"layer_pattern")
+        elif cfg.rope_kinds is not None:
+            bad.append("rope_kinds without a layer_pattern")
         if (cfg.index_topk or cfg.swa_kv_lora_rank
                 or cfg.attn_gate != "none" or cfg.mla_lora_rescale):
             bad.append("indexed selection, a headwise gate or rescaled "
@@ -263,22 +282,27 @@ class PagedDecoder:
         impl = attention_impl or cfg.attention_impl
         if impl == "auto":
             impl = "pallas" if on_tpu() else "xla"
-        self.two_kinds = bool(cfg.swa_kv_lora_rank)
+        # two kinds of layer under one pattern (_check_supported admits
+        # no pattern otherwise): latent ones, or grouped-query ones
+        self.two_kinds = bool(cfg.layer_pattern)
+        self.latent_kinds = bool(cfg.swa_kv_lora_rank)
         if self.two_kinds:
-            from torchacc_tpu.models.mla import kind_config
             if serve_cfg.prefix_cache:
                 raise NotImplementedError(
                     "the serving engine does not yet support prefix "
                     "sharing across window layers (serve.prefix_cache "
-                    "with windowed latent layers: a window layer's "
-                    "blocks are freed as the window passes, so a cached "
-                    "prefix has no rows left to share there)")
-            self._full_cfg = kind_config(cfg, "global")
-            self._win_cfg = kind_config(cfg, "sliding")
+                    "with windowed layers: a window layer's blocks are "
+                    "freed as the window passes, so a cached prefix has "
+                    "no rows left to share there)")
+            of_kind = kind_cfg
+            if self.latent_kinds:
+                from torchacc_tpu.models.mla import kind_config as of_kind
+            self._full_cfg = of_kind(cfg, "global")
+            self._win_cfg = of_kind(cfg, "sliding")
         if impl == "pallas":
             for t in (1, serve_cfg.prefill_chunk):
                 try:
-                    if self.two_kinds:
+                    if self.latent_kinds:
                         f, w = self._full_cfg, self._win_cfg
                         latent_query_tile(
                             f.num_heads, f.kv_lora_rank, f.qk_rope_head_dim,
@@ -295,9 +319,9 @@ class PagedDecoder:
                             cfg.qk_rope_head_dim, serve_cfg.block_size, t,
                             cfg.dtype)
                     else:
-                        heads_per_step(cfg.num_heads, cfg.kv_heads,
-                                       cfg.head_size, serve_cfg.block_size,
-                                       t, cfg.dtype)
+                        query_tile(cfg.num_heads, cfg.kv_heads,
+                                   cfg.head_size, serve_cfg.block_size, t,
+                                   cfg.dtype)
                 except ValueError as e:
                     raise ConfigError(
                         f"serve.block_size={serve_cfg.block_size}, "
@@ -316,7 +340,7 @@ class PagedDecoder:
         # property; both advance the slot PRNG keys identically, so
         # flipping between variants cannot drift a sampled stream
         # (win_tables, an optional last argument, is None but for a model
-        # of two latent kinds: the window layers' block table)
+        # with window layers: their block table)
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1, 2),
                                static_argnums=(9,))
         # is_final is static: the non-final trace skips the vocab head
@@ -356,11 +380,11 @@ class PagedDecoder:
         ALL expert layers (:meth:`_forward` keeps them off the scan),
         read by the grouped matmul at this layer's index among them
         (``expert_layer``; None = ``layer`` less the dense ones).  In a
-        model of two latent kinds ``kind`` names this layer's ('global':
-        indexed selection over the full layers' pools, 'sliding': the
-        window layers' pool); ``layer`` is then its index among the
-        layers of its kind, ``tables`` and ``blk`` pairs ``(full,
-        window)``.  Returns ``(x, pools, load)``, ``load`` the expert
+        model of two kinds of layer ``kind`` names this layer's
+        ('global': the full layers' pools — under an indexed selection
+        in a latent model —, 'sliding': the window layers' pools);
+        ``layer`` is then its index among the layers of its kind,
+        ``tables`` and ``blk`` pairs ``(full, window)``.  Returns ``(x, pools, load)``, ``load`` the expert
         layer's counts or None."""
         cfg = self.cfg
         if kind:
@@ -371,7 +395,8 @@ class PagedDecoder:
             # a leading dense layer of an expert model: TransformerLM
             # gives that stack's blocks this config too
             cfg = dataclasses.replace(cfg, num_experts=0)
-        attend = (self._attend if not cfg.kv_lora_rank else
+        attend = (functools.partial(self._attend, cfg=cfg, kind=kind)
+                  if not cfg.kv_lora_rank else
                   {"": self._attend_latent, "global": self._attend_sparse,
                    "sliding": self._attend_window}[kind])
         # what the halves leave besides their output: the updated pools,
@@ -408,12 +433,16 @@ class PagedDecoder:
         return x, left["pools"], left["load"]
 
     def _attend(self, attn, layer, h, pools, positions, tables, ctx_lens,
-                blk, off):
+                blk, off, *, cfg, kind=""):
         """Grouped-query attention of the normed ``h`` over the k and v
         pools [L, NB, BS, KH*D]: ``(output before the residual,
-        pools)``."""
-        cfg = self.cfg
-        kp, vp = pools
+        pools)``.  ``cfg`` is the layer's own (a kind's window and rope);
+        in a model of two kinds the pools are ``(k, v)`` of the global
+        layers then ``(k, v)`` of the sliding ones, and a 'sliding'
+        layer's kernel call carries its own name and scope: a profile
+        reads the two kinds apart."""
+        at = 2 * (kind == "sliding")
+        kp, vp = pools[at:at + 2]
         s_, t_ = h.shape[:2]
         proj = block.tree_proj(cfg, attn)
         with jax.named_scope("qkv"):
@@ -430,13 +459,16 @@ class PagedDecoder:
                 k.reshape(s_ * t_, -1).astype(kp.dtype))
             vp = vp.at[layer, flat_b, flat_o].set(
                 v.reshape(s_ * t_, -1).astype(vp.dtype))
-        with jax.named_scope("paged_attn"):
+        scope, name = (("window_paged_attn", "window_paged_attention") if at
+                       else ("paged_attn", "paged_attention"))
+        with jax.named_scope(scope):
             out = paged_attention(
                 q, kp, vp, tables, ctx_lens, positions[:, 0], layer=layer,
                 scale=cfg.query_scale, window=cfg.window,
-                logit_softcap=cfg.attn_logit_softcap, impl=self.impl)
+                logit_softcap=cfg.attn_logit_softcap, impl=self.impl,
+                name=name)
         with jax.named_scope("o_proj"):
-            return proj("o_proj", out), (kp, vp)
+            return proj("o_proj", out), pools[:at] + (kp, vp) + pools[at + 2:]
 
     def _attend_latent(self, attn, layer, h, pools, positions, tables,
                        ctx_lens, blk, off):
@@ -560,18 +592,19 @@ class PagedDecoder:
 
     def _forward_periods(self, params, pools, x, positions, tables, ctx_lens,
                          blk, off, valid):
-        """The layer loop of a model of two latent kinds: one scan over
-        the leading dense layers (all 'global'), then one over the
+        """The layer loop of a model of two kinds of layer: one scan over
+        the leading dense layers (all of one kind), then one over the
         PERIODS of the pattern — the body runs a period's layers one
         after another, each position of the period its own stacked tree
         ``params['layers']['p<k>']`` [periods, ...] with its expert
-        stacks kept off ``xs`` (see :meth:`_forward`), all three pools
-        on the carry.  A layer's index in its kind's pools counts the
+        stacks kept off ``xs`` (see :meth:`_forward`), every pool on the
+        carry.  A layer's index in its kind's pools counts the
         layers of that kind before it."""
         cfg = self.cfg
-        dense, period = _period(cfg)
+        dense, period = pattern_period(cfg)
         n_periods = (cfg.num_layers - len(dense)) // len(period)
         per_kind = {k: period.count(k) for k in ("global", "sliding")}
+        first = {k: dense.count(k) for k in per_kind}
         before = [{k: period[:i].count(k) for k in per_kind}
                   for i in range(len(period))]
         stacks, layers = [], {}
@@ -588,7 +621,7 @@ class PagedDecoder:
             p_l, i = per
             x, pools, _ = self._layer(
                 p_l["block"], i, x, pools, positions, tables, ctx_lens, blk,
-                off, valid, kind="global")
+                off, valid, kind=dense[0])
             return (x, pools), None
 
         def body(carry, per):
@@ -596,10 +629,10 @@ class PagedDecoder:
             p_l, n = per
             load = 0
             for i, kind in enumerate(period):
-                first = len(dense) if kind == "global" else 0
                 x, pools, one = self._layer(
                     p_l[f"p{i}"]["block"],
-                    first + n * per_kind[kind] + before[i][kind], x, pools,
+                    first[kind] + n * per_kind[kind] + before[i][kind], x,
+                    pools,
                     positions, tables, ctx_lens, blk, off, valid,
                     expert_stacks=stacks[i], kind=kind, expert_layer=n)
                 load = load + one
@@ -973,8 +1006,8 @@ class Scheduler:
             blocks_needed(model_cfg.max_seq_len + serve_cfg.decode_depth,
                           serve_cfg.block_size))
         self.tables = np.zeros((s, self.max_blocks_per_seq), np.int32)
-        # a model of two latent kinds: the window layers' blocks, held
-        # only while the window reaches them, and their table
+        # a model with window layers: their blocks, held only while the
+        # window reaches them, and their table
         self.window = None
         if self.decoder.two_kinds:
             self.window = WindowBlocks(
@@ -1102,14 +1135,21 @@ class Scheduler:
             0 if seq.selected is None else seq.selected)
 
     def _note_selected(self, t0: int, n: int) -> np.ndarray:
+        """:meth:`_note_attended` for queries at positions [t0, t0 + n):
+        query t sees t + 1 cached positions."""
+        return self._note_attended(
+            np.arange(t0 + 1, t0 + n + 1, dtype=np.int64))
+
+    def _note_attended(self, seen: np.ndarray) -> np.ndarray:
         """(positions a full layer attends, positions cached for it,
-        positions a window layer attends) for queries at positions
-        [t0, t0 + n): query t sees t + 1 cached positions, a full layer
-        attends ``index_topk`` of them at most, a window layer those in
-        its window."""
-        t = np.arange(t0 + 1, t0 + n + 1, dtype=np.int64)
-        return np.array([np.minimum(t, self.cfg.index_topk).sum(), t.sum(),
-                         np.minimum(t, self.cfg.window[0] + 1).sum()])
+        positions a window layer attends) for queries that see ``seen``
+        cached positions each: a full layer attends all of them, or
+        ``index_topk`` at most under an indexed selection, a window
+        layer those in its window."""
+        topk = self.cfg.index_topk
+        return np.array([
+            (np.minimum(seen, topk) if topk else seen).sum(), seen.sum(),
+            np.minimum(seen, self.cfg.window[0] + 1).sum()])
 
     def _admit_impl(self, seq: Sequence) -> bool:
         slot = self.free_slot()
@@ -1381,11 +1421,9 @@ class Scheduler:
             for slot, seq in snapshot:
                 n = int(self.seq_lens[slot])
                 self._advance_window(seq, n, n + 1)
-            seen = self.seq_lens[[slot for slot, _ in snapshot]].astype(
-                np.int64) + 1                # cached positions a query
-            selected = np.array([
-                np.minimum(seen, self.cfg.index_topk).sum(), seen.sum(),
-                np.minimum(seen, self.cfg.window[0] + 1).sum()])
+            selected = self._note_attended(
+                self.seq_lens[[slot for slot, _ in snapshot]].astype(
+                    np.int64) + 1)           # cached positions a query
             if self._dev_win is None:
                 self._dev_win = _upload(self.win_tables)
             win = (self._dev_win,)
@@ -1531,8 +1569,11 @@ class Scheduler:
                 # kind: positions a full layer attended and had cached,
                 # positions a window layer attended
                 att, cached, win_att = (int(x) for x in entry.selected)
-                deliver.set(sel_attended=att, sel_cached=cached,
-                            win_attended=win_att)
+                if self.cfg.index_topk:
+                    deliver.set(sel_attended=att, sel_cached=cached,
+                                win_attended=win_att)
+                else:
+                    deliver.set(ctx_attended=cached, win_attended=win_att)
             now = time.monotonic()
             if entry.kind == "first":
                 self._record(entry.seq, int(toks), now)
