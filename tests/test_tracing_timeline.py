@@ -69,6 +69,12 @@ WINDOW_DECODE_SCOPES = ("window_paged_attn", "paged_attn", "qkv",
 # gqa_window_common.py); its serve/admit spans carry ADMIT_BLOCK_ATTRS
 WINDOW_SPAN_ATTRS = ("ctx_attended", "win_attended")
 
+# what the serve/deliver span of a request's FIRST token carries (PR 37;
+# chipbench/readers/first_token_spans.py): what its wait was made of
+FIRST_TOKEN_ATTRS = ("sid", "prefill_programs", "queue_steps",
+                     "wait_steps", "queue_ms", "prefill_ms", "lag_ms",
+                     "ttft_ms")
+
 # the spans a traced serve loop / fit has to leave on the host plane
 SERVE_SPANS = ("serve/step", "serve/sweep", "serve/admit", "serve/prefill",
                "serve/decode", "serve/deliver", "serve/wait")
@@ -490,6 +496,64 @@ def test_spans_carry_what_each_kind_of_grouped_query_layer_attended(
     assert len(admits) >= 4                # this model's and the latent one's
 
 
+@pytest.mark.parametrize("attr", FIRST_TOKEN_ATTRS)
+def test_first_tokens_deliver_span_says_what_the_wait_was_made_of(
+        traced, attr):
+    """Every model's serve/deliver span of kind 'first' reaches the
+    profiler with the request's way to its first token (set after the
+    token is recorded, on the open annotation); a 'decode' one carries
+    none of it.  tests/test_first_token_path.py reads the ring sink."""
+    delivers = [st for n, _, _, st in traced["events"]
+                if n == "serve/deliver"]
+    firsts = [st for st in delivers if str(st.get("kind")) == "first"]
+    decodes = [st for st in delivers if str(st.get("kind")) == "decode"]
+    assert len(firsts) == 8 and decodes    # four engines, two prompts each
+    assert all(attr in st for st in firsts)
+    assert not any(attr in st for st in decodes)
+    # chunks of 8: a prompt of 5 tokens is one program, of 9 two, of 19
+    # three (prompts of 5 + 9 once, of 5 + 19 three times)
+    assert sorted(int(st["prefill_programs"]) for st in firsts) == \
+        [1, 1, 1, 1, 2, 3, 3, 3]
+    for st in firsts:
+        assert int(st["wait_steps"]) >= int(st["prefill_programs"]) \
+            + int(st["queue_steps"])
+        assert float(st["queue_ms"]) + float(st["prefill_ms"]) \
+            + float(st["lag_ms"]) == pytest.approx(float(st["ttft_ms"]),
+                                                   abs=1e-3)
+
+
+def test_a_window_model_queues_one_step_for_its_reservation():
+    """What ``queue_steps`` is for: a closed loop whose clients equal the
+    slots never waits for a SLOT, yet with window layers every request
+    behind the first ones is admitted one step late — the window pool
+    holds exactly slots x bound blocks and a finished sequence's
+    reservation comes back with its deferred blocks an iteration on
+    (PERF.md section 7).  ``queue_wait_s`` alone reads that as a few
+    milliseconds of queue; counted in steps it is exactly one."""
+    from torchacc_tpu.serve import Request, ServeEngine
+    wmc, wparams = _window_model()
+    engine = ServeEngine(TransformerLM(wmc), wparams, ta.Config(
+        serve=ta.config.ServeConfig(block_size=4, num_blocks=64,
+                                    max_slots=2, prefill_chunk=8)))
+    rng = np.random.default_rng(0)
+    todo = [Request(prompt_ids=rng.integers(1, 256, size=n).tolist(),
+                    max_new_tokens=3) for n in (5, 19, 12, 9, 22, 7)]
+    live = {engine.submit(todo.pop(0)) for _ in range(2)}
+    results = {}
+    while live:
+        engine.step()
+        for rid in [r for r in live if engine._all[r].finished]:
+            live.remove(rid)
+            results[rid] = engine.result(rid, pop=True)
+            if todo:
+                live.add(engine.submit(todo.pop(0)))
+    engine.close()
+    assert [results[rid].queue_steps for rid in range(6)] == \
+        [0, 0, 1, 1, 1, 1]
+    assert all(r.wait_steps >= r.queue_steps + r.prefill_programs
+               for r in results.values())
+
+
 # -- the idle path and the ring ------------------------------------------------
 
 def test_span_is_the_shared_noop_with_both_sinks_idle():
@@ -586,6 +650,15 @@ def test_benchmark_seams_keep_their_names():
     assert isinstance(sched.decoder, PagedDecoder)
     assert sched.decoder.impl in ("pallas", "xla")
     assert len(sched.slot_seq) == 2 and sched._iter == 0
+    assert sched._step_idx == 0            # counts step() calls (PR 37)
+    from torchacc_tpu.serve.scheduler import Sequence
+    seq = Sequence(sid=0, prompt=np.zeros((3,), np.int32), max_new=1)
+    # the stamps the first token's serve/deliver attributes come from
+    for name in ("step_submit", "step_admit", "step_first",
+                 "t_first_dispatch", "prefill_programs", "queue_steps",
+                 "wait_steps", "queue_s", "prefill_s",
+                 "first_token_lag_s", "ttft_s"):
+        assert hasattr(seq, name)
     assert sched.seq_lens.shape == (2,) and sched.active.shape == (2,)
     for name in ("_decode", "_prefill"):
         assert hasattr(sched.decoder, name)

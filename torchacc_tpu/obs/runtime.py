@@ -371,8 +371,7 @@ class ServeObs:
     def on_request_done(self, seq) -> None:
         """Feed the latency histograms from a completed scheduler
         ``Sequence`` (called from the engine's completion drain)."""
-        hist.observe("serve_ttft_ms",
-                     max(seq.t_first_token - seq.t_submit, 0.0) * 1e3)
+        hist.observe("serve_ttft_ms", seq.ttft_s * 1e3)
         for a, b in zip(seq.token_times, seq.token_times[1:]):
             hist.observe("serve_token_gap_ms", (b - a) * 1e3)
 

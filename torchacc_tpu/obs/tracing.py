@@ -77,9 +77,16 @@ SPANS: Dict[str, str] = {
     "serve/decode": "Scheduler._decode_once — one batched decode "
                     "dispatch",
     "serve/deliver": "Scheduler._resolve_one — token readback + stream "
-                     "callbacks for one ring entry (expert models: "
-                     "attrs moe_pairs, moe_max, moe_hit, moe_slots, "
-                     "moe_layer_steps of the step(s) behind it)",
+                     "callbacks for one ring entry.  Attrs: kind; the one "
+                     "that delivers a request's FIRST token says what "
+                     "the wait was made of: sid, prefill_programs, "
+                     "queue_steps, wait_steps, "
+                     "queue_ms + prefill_ms + lag_ms = ttft_ms; expert "
+                     "models: moe_pairs, moe_max, moe_hit, moe_slots, "
+                     "moe_layer_steps of the step(s) behind it; an "
+                     "indexed selection: sel_attended, sel_cached, "
+                     "win_attended; sliding + global grouped-query "
+                     "layers: ctx_attended, win_attended",
     "serve/wait": "inside deliver — the one blocking token fetch",
 }
 

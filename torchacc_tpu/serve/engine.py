@@ -98,6 +98,14 @@ class RequestResult:
     # the id every serve span of this request carried (filter the
     # Chrome-trace export on it to see this request's full timeline)
     trace_id: str = ""
+    # what ttft_s was made of beside queue_wait_s (docs/serving.md "SLO
+    # metrics"): queue_wait_s + prefill_s + first_token_lag_s == ttft_s
+    prefill_s: float = 0.0                   # slot -> first token sampled
+    first_token_lag_s: float = 0.0           # sampled -> delivered
+    # ... and counted in scheduler steps, which no step's cost moves
+    queue_steps: int = 0                     # submit -> slot
+    wait_steps: int = 0                      # submit -> last chunk's step
+    prefill_programs: int = 0                # programs that ran a chunk
 
 
 def _percentile(xs: List[float], q: float) -> float:
@@ -312,6 +320,7 @@ class ServeEngine:
                 f"admission queue full ({serve.max_queue}); shed load "
                 f"upstream or raise serve.max_queue")
         seq.t_submit = time.monotonic()
+        seq.step_submit = self.scheduler._step_idx
         if req.deadline_s is not None:
             seq.deadline = seq.t_submit + req.deadline_s
         # the id is BURNED from here on, even if the journal append
@@ -451,7 +460,7 @@ class ServeEngine:
                     max_new=int(rec.get("max_new_tokens") or 0),
                     trace_id=rec.get("trace_id") or "")
                 stub.t_submit = stub.t_admit = now_mono
-                stub.t_first_token = now_mono
+                stub.t_first_dispatch = stub.t_first_token = now_mono
                 # shed (journal-first) BEFORE registering the stub: a
                 # failed append leaves no half-shed record for a
                 # recover() retry to skip over
@@ -465,6 +474,7 @@ class ServeEngine:
             # recovery (the dead incarnation's wall time is not
             # observable here — the journal's t_accept is, for audits)
             seq.t_submit = now_mono
+            seq.step_submit = self.scheduler._step_idx
             dl = rec.get("deadline_unix")
             if dl is not None:
                 seq.deadline = now_mono + (float(dl) - now_wall)
@@ -904,8 +914,8 @@ class ServeEngine:
             a = self._agg
             a["requests"] += 1
             a["tokens"] += len(seq.out_tokens)
-            a["ttft"].append(max(seq.t_first_token - seq.t_submit, 0.0))
-            a["waits"].append(max(seq.t_admit - seq.t_submit, 0.0))
+            a["ttft"].append(seq.ttft_s)
+            a["waits"].append(seq.queue_s)
             a["gaps"].extend(b - x for x, b in
                              zip(seq.token_times, seq.token_times[1:]))
             a["t0"] = (seq.t_submit if a["t0"] is None
@@ -951,8 +961,8 @@ class ServeEngine:
             prompt_ids=[int(t) for t in seq.prompt],
             tokens=list(seq.out_tokens),
             finish_reason=seq.finish_reason,
-            queue_wait_s=max(seq.t_admit - seq.t_submit, 0.0),
-            ttft_s=max(seq.t_first_token - seq.t_submit, 0.0),
+            queue_wait_s=seq.queue_s,
+            ttft_s=seq.ttft_s,
             total_s=total,
             token_latencies_s=gaps,
             tokens_per_sec=len(seq.out_tokens) / total,
@@ -960,6 +970,11 @@ class ServeEngine:
             deadline_met=(None if seq.deadline == float("inf")
                           else bool(seq.t_finish <= seq.deadline)),
             trace_id=seq.trace_id,
+            prefill_s=seq.prefill_s,
+            first_token_lag_s=seq.first_token_lag_s,
+            queue_steps=seq.queue_steps,
+            wait_steps=seq.wait_steps,
+            prefill_programs=seq.prefill_programs,
         )
         if pop:
             del self._all[request_id]

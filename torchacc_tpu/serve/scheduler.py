@@ -915,12 +915,21 @@ class Sequence:
     cached_tokens: int = 0                   # prompt tokens NOT recomputed
     shared_blocks: int = 0                   # blocks reused via refcount
     cow: bool = False                        # fully-cached prompt path
-    # metrics timestamps (host wall clock; engine fills t_submit)
+    # metrics timestamps (all on time.monotonic; engine fills t_submit)
     t_submit: float = 0.0
     t_admit: float = 0.0
-    t_first_token: float = 0.0
+    t_first_dispatch: float = 0.0            # first token sampled on device
+    t_first_token: float = 0.0               # ... and delivered to the host
     t_finish: float = 0.0
     token_times: List[float] = dataclasses.field(default_factory=list)
+    # the same way to the first token counted in Scheduler.step() calls
+    # (Scheduler._step_idx: the running step's index; between two steps
+    # the next one's), always on: what a request waits in steps does not
+    # depend on what a step costs
+    step_submit: int = 0
+    step_admit: int = 0
+    step_first: int = -1                     # the step of the last chunk
+    prefill_programs: int = 0                # programs that ran a chunk of it
     # expert-layer counts of this request's prefill programs, handed to
     # the ring with its first token (device arrays; empty without experts)
     loads: List[Any] = dataclasses.field(default_factory=list)
@@ -935,6 +944,47 @@ class Sequence:
     @property
     def prompt_len(self) -> int:
         return int(self.prompt.shape[0])
+
+    # The first token's way, from the stamps alone (0 for a stage the
+    # request never reached): queue_s + prefill_s + first_token_lag_s ==
+    # ttft_s, and wait_steps >= prefill_programs >= 1 once it is out.
+
+    @property
+    def queue_s(self) -> float:
+        """submit -> slot."""
+        return max(self.t_admit - self.t_submit, 0.0)
+
+    @property
+    def queue_steps(self) -> int:
+        """submit -> slot in steps: 0 = admitted ahead of the first step
+        after submit; each one above it is a step that ran with the
+        request still queued (no free slot, or a reservation refused)."""
+        return max(self.step_admit - self.step_submit, 0)
+
+    @property
+    def prefill_s(self) -> float:
+        """slot -> first token sampled on device: the prompt's own
+        chunks and the turns other prompts' chunks took."""
+        return max(self.t_first_dispatch - self.t_admit, 0.0)
+
+    @property
+    def first_token_lag_s(self) -> float:
+        """last chunk dispatched (its token sampled on device) ->
+        delivered: what the device still owed then (the host runs
+        ``decode_depth - 1`` iterations ahead), that chunk's program
+        and the one blocking fetch."""
+        return max(self.t_first_token - self.t_first_dispatch, 0.0)
+
+    @property
+    def ttft_s(self) -> float:
+        """submit -> first token delivered."""
+        return max(self.t_first_token - self.t_submit, 0.0)
+
+    @property
+    def wait_steps(self) -> int:
+        """Scheduler steps from the first after submit to the one that
+        ran the prompt's last chunk, both counted."""
+        return max(self.step_first - self.step_submit + 1, 0)
 
 
 def priority_key(seq: "Sequence", now: float, aging_s: float):
@@ -1032,6 +1082,7 @@ class Scheduler:
         }
         self._ring: "collections.deque[_InFlight]" = collections.deque()
         self._iter = 0            # decode iterations dispatched
+        self._step_idx = 0        # step() calls completed
         self._resolved = 0        # decode iterations resolved
         self._deferred: List[Tuple[int, List[int]]] = []
         # the same for an evicted sequence's window-layer blocks
@@ -1098,8 +1149,7 @@ class Scheduler:
                 sp.set(admitted=0)
                 return False
             # the queue wait, known only now (submit -> slot admission)
-            queue_s = (max(seq.t_admit - seq.t_submit, 0.0)
-                       if seq.t_submit else 0.0)
+            queue_s = seq.queue_s if seq.t_submit else 0.0
             sp.set(admitted=1, cached_tokens=seq.cached_tokens,
                    queue_ms=queue_s * 1e3, **self.blocks_by_kind())
         if seq.t_submit and tracing.enabled():
@@ -1195,6 +1245,7 @@ class Scheduler:
         seq.blocks = blocks
         seq.key = jax.random.PRNGKey(seq.seed)
         seq.t_admit = time.monotonic()
+        seq.step_admit = self._step_idx
         cached = len(shared) * self.serve_cfg.block_size
         if cow_src is not None:
             # dst is fresh[0] == table index len(shared): the copy sits
@@ -1290,6 +1341,7 @@ class Scheduler:
                 self._resolve_one()
                 did = True
         self._release_matured()
+        self._step_idx += 1
         return did
 
     def _prefill_one(self, seq: Sequence) -> None:
@@ -1313,6 +1365,7 @@ class Scheduler:
                 jnp.asarray(n_valid, jnp.int32), final, *win)
         if load is not None:
             seq.loads.append(load)
+        seq.prefill_programs += 1
         seq.prefilled += n_valid
         self.seq_lens[seq.slot] = seq.prefilled
         self._register_prefix(seq)
@@ -1359,6 +1412,7 @@ class Scheduler:
         if load is not None:
             seqs[0].loads.append(load)       # one program, counted once
         for r, seq in enumerate(seqs):
+            seq.prefill_programs += 1
             seq.prefilled += taken[r]
             self.seq_lens[seq.slot] = seq.prefilled
             self._register_prefix(seq)
@@ -1395,9 +1449,11 @@ class Scheduler:
             slot_key.astype(jnp.uint32))
         self.active[seq.slot] = True
         self._dev_stable = None
+        seq.step_first = self._step_idx
+        seq.t_first_dispatch = time.monotonic()
         self._ring.append(_InFlight(
             kind="first", tokens=tok, seq=seq, loads=seq.loads,
-            selected=seq.selected, t_dispatch=time.monotonic()))
+            selected=seq.selected, t_dispatch=seq.t_first_dispatch))
         seq.loads, seq.selected = [], None
 
     def _dev_stable_arrays(self):
@@ -1576,7 +1632,20 @@ class Scheduler:
                     deliver.set(ctx_attended=cached, win_attended=win_att)
             now = time.monotonic()
             if entry.kind == "first":
-                self._record(entry.seq, int(toks), now)
+                seq = entry.seq
+                self._record(seq, int(toks), now)
+                if deliver.live and seq.out_tokens:
+                    # what this request's wait for its first token was
+                    # made of (Sequence's stamps; docs/observability.md)
+                    deliver.set(
+                        sid=seq.sid,
+                        prefill_programs=seq.prefill_programs,
+                        queue_steps=seq.queue_steps,
+                        wait_steps=seq.wait_steps,
+                        queue_ms=seq.queue_s * 1e3,
+                        prefill_ms=seq.prefill_s * 1e3,
+                        lag_ms=seq.first_token_lag_s * 1e3,
+                        ttft_ms=seq.ttft_s * 1e3)
             else:
                 for slot, seq in entry.slots:
                     self._record(seq, int(toks[slot]), now)
