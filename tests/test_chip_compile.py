@@ -75,6 +75,14 @@ def for_the_chip(monkeypatch):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
+@pytest.fixture
+def train_step_for_the_chip(for_the_chip, monkeypatch):
+    """A whole train step also asks ``ops/attn`` which attention
+    ``auto`` means: the flash kernel, as on the chip."""
+    import torchacc_tpu.ops.attn as attn_mod
+    monkeypatch.setattr(attn_mod, "_on_tpu", lambda: True)
+
+
 def _compiled_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
@@ -396,8 +404,58 @@ def test_latent_serve_program_holds_one_latent_pool(one_chip, for_the_chip,
     assert not moved, moved
 
 
-def test_fused_head_under_fsdp_reduces_its_logits_once(topo, for_the_chip,
-                                                       monkeypatch):
+def _compiled_train_step(topo, chips, mc, cfg, batch, seq, optimizer=None):
+    """``Trainer._train_step`` of ``accelerate()``'s model for ``mc``
+    under ``cfg``, compiled for ``chips`` of the described chips
+    (``chipbench/tools/sandbox_compile.py``'s construction)."""
+    from torchacc_tpu.models.transformer import TransformerLM
+    from torchacc_tpu.train.accelerate import apply_config_to_model
+    from torchacc_tpu.train.trainer import Trainer
+
+    cfg.validate()
+    names = tuple(cfg.dist.topology)
+    sizes = cfg.dist.axis_sizes(chips)
+    mesh = Mesh(np.asarray(topo.devices[:chips]).reshape(
+        [sizes[a] for a in names]), names)
+    trainer = Trainer(TransformerLM(apply_config_to_model(mc, cfg)), cfg,
+                      optimizer=optimizer, mesh=mesh)
+    state = trainer.abstract_state()
+    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
+    b = {"input_ids": _sds(ids.shape, ids.dtype,
+                           trainer._batch_shardings({"input_ids": ids})
+                           ["input_ids"])}
+    with jax.sharding.set_mesh(mesh):
+        compiled = trainer._build_train_step(b).lower(state, b).compile()
+    return trainer, compiled
+
+
+def _layer_whiles(text):
+    """The compiled step's ``while`` instructions that belong to the
+    model's layer loop (op_name under ``layers/``)."""
+    return [ln.strip()[:200] for ln in text.splitlines()
+            if re.search(r" while\(", ln)
+            and re.search(r'op_name="[^"]*/layers/while"', ln)]
+
+
+@functools.cache
+def _fsdp4_toy_step(topo):
+    """A small fsdp=4 train step at the framework's own choice of layer
+    loop, compiled whole."""
+    import torchacc_tpu as ta
+    from torchacc_tpu.models import get_preset
+
+    vocab, hidden, batch, seq = 4096, 512, 8, 1024
+    mc = get_preset("llama-tiny", vocab_size=vocab, hidden_size=hidden,
+                    num_layers=2, num_heads=4, num_kv_heads=4,
+                    intermediate_size=1024, max_seq_len=seq, dtype=BF16)
+    cfg = ta.Config()
+    cfg.dist.fsdp.size = 4
+    cfg.compute.bf16_compute_params = True
+    return _compiled_train_step(topo, 4, mc, cfg, batch, seq)
+
+
+def test_fused_head_under_fsdp_reduces_its_logits_once(
+        topo, train_step_for_the_chip):
     """A small fsdp=4 train step, compiled whole: the partitioner shards
     the head matmul's contraction (hidden) dimension inside the chunk
     loop, so every pass over a chunk's logits costs an all-reduce of the
@@ -405,36 +463,8 @@ def test_fused_head_under_fsdp_reduces_its_logits_once(topo, for_the_chip,
     in the forward loop (ops/fused.py), so there is ONE such pass — the
     recompute and its all-reduce are gone.  ROADMAP S2 takes this to
     zero (rows kept data-sharded, dW reduced once after the loop)."""
-    import torchacc_tpu as ta
-    import torchacc_tpu.ops.attn as attn_mod
-    from torchacc_tpu.models import get_preset
-    from torchacc_tpu.models.transformer import TransformerLM
-    from torchacc_tpu.train.accelerate import apply_config_to_model
-    from torchacc_tpu.train.trainer import Trainer
-
-    monkeypatch.setattr(attn_mod, "_on_tpu", lambda: True)
-    vocab, hidden, batch, seq, chunk_rows = 4096, 512, 8, 1024, 2048
-    mc = get_preset("llama-tiny", vocab_size=vocab, hidden_size=hidden,
-                    num_layers=2, num_heads=4, num_kv_heads=4,
-                    intermediate_size=1024, max_seq_len=seq, dtype=BF16)
-    cfg = ta.Config()
-    cfg.dist.fsdp.size = 4
-    cfg.compute.bf16_compute_params = True
-    cfg.validate()
-    names = tuple(cfg.dist.topology)
-    sizes = cfg.dist.axis_sizes(len(topo.devices))
-    mesh = Mesh(np.asarray(topo.devices).reshape(
-        [sizes[a] for a in names]), names)
-    trainer = Trainer(TransformerLM(apply_config_to_model(mc, cfg)), cfg,
-                      mesh=mesh)
-    state = trainer.abstract_state()
-    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
-    b = {"input_ids": _sds(ids.shape, ids.dtype,
-                           trainer._batch_shardings({"input_ids": ids})
-                           ["input_ids"])}
-    with jax.sharding.set_mesh(mesh):
-        text = trainer._build_train_step(b).lower(state, b).compile(
-            ).as_text()
+    vocab, chunk_rows = 4096, 2048
+    text = _fsdp4_toy_step(topo)[1].as_text()
     head = [ln for ln in text.splitlines()
             if re.search(r'op_name="[^"]*fused_ce', ln)]
     assert head and not any("rematted_computation" in ln for ln in head)
@@ -443,6 +473,53 @@ def test_fused_head_under_fsdp_reduces_its_logits_once(topo, for_the_chip,
         if re.search(rf"= f32\[{chunk_rows},{vocab}\]\S* all-reduce"
                      r"(-start)?\(", ln)]
     assert len(logits_reduces) == 1, logits_reduces
+
+
+def test_layers_under_fsdp_stay_in_the_scan(topo, train_step_for_the_chip):
+    """Where the mesh shards the parameters nobody unrolls the layers:
+    the scan's loop is what holds back the gathers of later layers'
+    weights (unrolled, the cell's depth-6 step under fsdp=4 needs 15.80
+    of 15.75 GiB: PERF.md section 7, PR 38)."""
+    trainer, compiled = _fsdp4_toy_step(topo)
+    assert trainer.layer_loop == "scan"
+    assert trainer.model.cfg.scan_layers is True
+    assert _layer_whiles(compiled.as_text())
+    m = compiled.memory_analysis()
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes
+            < 15.75 * 2**30)
+
+
+def test_one_chip_train_step_applies_its_layers_unrolled(
+        topo, train_step_for_the_chip):
+    """``mistral7b.train.dense4k``'s step (the cell's own configuration,
+    traffic and depth 2 out of ``chipbench/``) at the framework's own
+    choice of layer loop: one chip holds the parameters whole, so the
+    layers are applied unrolled — no loop over the layers, no
+    ``[L, ...]`` stack of the MLP's saved activations, each layer's
+    three flash kernels its own instructions, and 2.7 GiB fewer
+    temporaries than the scan's 7.32 (PERF.md section 4)."""
+    from chipbench import program, spec
+
+    cell = spec.Cell("mistral7b.train.dense4k")
+    traffic = cell.traffic
+    batch, seq, depth = traffic["batch"], traffic["seq"], cell.depth
+    assert (batch, seq, depth) == (4, SEQ, 2)
+    mc = program.model_config(cell.published, depth, max_seq_len=seq,
+                              **traffic.get("model_overrides", {}))
+    assert mc.scan_layers is None                # nobody chose
+    assert (mc.hidden_size, mc.intermediate_size) == (4096, 14336)
+    trainer, compiled = _compiled_train_step(
+        topo, cell.chips, mc,
+        program.framework_config(traffic["settings"], 0), batch, seq,
+        optimizer=program.optimizer(traffic["optimizer"]))
+    assert trainer.layer_loop == "unrolled"
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert not _layer_whiles(text), _layer_whiles(text)
+    stacked = [ln.strip()[:200] for ln in text.splitlines()
+               if re.search(rf"bf16\[{depth},{batch},{seq},14336\]", ln)]
+    assert not stacked, stacked[:3]
+    assert text.count("tpu_custom_call") == 3 * depth
+    assert mem.temp_size_in_bytes <= 5.2 * 2**30, mem.temp_size_in_bytes
 
 
 # -- two kinds of latent layer: dots3-note-prev's published geometry ---------

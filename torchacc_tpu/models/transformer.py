@@ -77,7 +77,11 @@ class ModelConfig:
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16               # activation dtype
     param_dtype: Any = jnp.float32
-    scan_layers: bool = True
+    # how the stacked layers are APPLIED: True = one lax.scan, False =
+    # a Python-unrolled loop over static slices, None = the framework
+    # chooses (train/accelerate.apply_config_to_model, from the mesh);
+    # readers go through scans_layers(), which takes None as the scan
+    scan_layers: Optional[bool] = None
     remat: bool = False                     # remat each block (memory.gc)
     remat_policy: str = "nothing"           # see utils/remat.py
     # selective remat (reference gc_cls/gc_cnt, utils/checkpoint.py:67-81):
@@ -662,6 +666,27 @@ class ScanBlock(nn.Module):
         return (x, positions, segment_ids), None
 
 
+def scans_layers(cfg: "ModelConfig") -> bool:
+    """Whether ``cfg.scan_layers`` says scan — the ONE reader of the
+    field's truth.  ``None`` (nobody chose: a bare ``TransformerLM``,
+    ``models.generate``, anything outside ``accelerate()``) is the
+    scan; read as plain truth it would unroll every bare model and
+    every pp stage."""
+    return cfg.scan_layers is None or bool(cfg.scan_layers)
+
+
+def layer_loop(cfg: "ModelConfig") -> str:
+    """``'scan'`` or ``'unrolled'``: how a train step of this config
+    applies its layers (the branches of ``TransformerLM.__call__`` in
+    their order; decode and initialisation always scan).  The Trainer's
+    start-up line and its ``train/dispatch`` span carry the word."""
+    if cfg.first_dense_layers:
+        return "scan"
+    if cfg.layer_pattern or (cfg.overlap_fsdp and cfg.pp_size <= 1):
+        return "unrolled"
+    return "scan" if scans_layers(cfg) else "unrolled"
+
+
 def pp_block_appliers(cfg: "ModelConfig", wrap):
     """(apply_block_or_slots, unroll_stage) for the pp pipelines.
 
@@ -672,7 +697,7 @@ def pp_block_appliers(cfg: "ModelConfig", wrap):
     so slot j's kind is the same on every stage and virtual chunk.
     ``wrap`` adapts the raw ``fn(p, carry, seed)`` to the pipeline's
     applier signature (the gpipe and 1f1b callers differ)."""
-    unroll = not cfg.scan_layers
+    unroll = not scans_layers(cfg)
     if not cfg.layer_pattern:
         return wrap(_raw_block_fn(cfg)), unroll
     plen = len(cfg.layer_pattern)
@@ -773,17 +798,23 @@ class TransformerLM(nn.Module):
         # 'layers' logical axis) is the layout regardless of scan_layers
         # — checkpoints are portable between the two execution paths.
         # scan_layers picks how the layers are APPLIED: True = lax.scan
-        # over the stack (fast compiles; policy-saved residuals stack
-        # [L, ...] via dynamic-update-slice — the scan-stacking tax,
-        # docs/PERF.md), False = Python-unrolled loop over static slices
-        # (separate per-layer residual buffers; slower compiles,
-        # amortised by the persistent compile cache).  The decode/cache
+        # over the stack (compile time flat in depth; policy-saved
+        # residuals and weight gradients stack [L, ...] through
+        # dynamic-update-slices fused into the matmuls that produce
+        # them — the scan-stacking tax), False = Python-unrolled loop
+        # over static slices (separate per-layer buffers; compile time
+        # grows with depth; under ZeRO-3 nothing holds back the gathers
+        # of later layers, which then live at once).  Who chooses:
+        # accelerate() from the mesh (apply_config_to_model) — unrolled
+        # where every device holds the layer parameters whole, the scan
+        # otherwise; None outside it is the scan (scans_layers).  The
+        # chip's numbers for both: PERF.md section 6, PR 38.  The decode/cache
         # path ALWAYS applies via plain scan — the cache collection only
         # flows through scan_mod's variable_axes (raw .apply in the
         # unrolled/split paths would silently drop prefill cache
         # writes), and decode compute is trivial either way.
         cache_live = cfg.decode or self.is_mutable_collection("cache")
-        use_scan_apply = cfg.scan_layers or cache_live
+        use_scan_apply = scans_layers(cfg) or cache_live
         quant_on = cfg.quant != "none"
         if quant_on and not self.is_initializing():
             # the quantized sites' delayed-scaling state threads through
@@ -804,7 +835,7 @@ class TransformerLM(nn.Module):
                     "quant != 'none' decode must go through "
                     "models.generate (it strips quant — inference runs "
                     "in the compute dtype)")
-            if (split_n is not None and cfg.scan_layers
+            if (split_n is not None and scans_layers(cfg)
                     and not cfg.overlap_fsdp):
                 # overlap_fsdp forces the unrolled loop below, which
                 # honors remat_cnt AND threads quant — only the
@@ -986,9 +1017,10 @@ class TransformerLM(nn.Module):
             # unrolled application from the stacked layout: static
             # per-layer slices keep each layer's policy-saved residuals
             # as SEPARATE buffers, so the step's autodiff carries no
-            # [L, ...] DUS stacking (the scan-stacking tax — measured
-            # ~7 MFU points on the v5e bench, docs/PERF.md).  Honors
-            # remat_cnt: layers past split_n run without remat.
+            # [L, ...] DUS stacking (the scan-stacking tax: PERF.md
+            # section 6, PR 38, and the ledger's line of that PR for the
+            # one-chip train cells).  Honors remat_cnt: layers past
+            # split_n run without remat.
             #
             # overlap_fsdp rides this loop: each layer's block fn FIRST
             # constrains its param slice to REPLICATED (an explicit

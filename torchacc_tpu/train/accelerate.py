@@ -57,6 +57,18 @@ def apply_config_to_model(mc: ModelConfig, config: Config) -> ModelConfig:
         pp_virtual=config.dist.pp.virtual_stages,
         logical_axis_rules=tuple(make_rules(config)),
     )
+    # the layer loop, where the model config leaves it open: unrolled
+    # when every device holds the layer parameters whole (one chip, or
+    # plain data parallelism; sp splits activations, not parameters) —
+    # no [L, ...] stacking of saved residuals and weight gradients, and
+    # less live memory; the scan otherwise — under ZeRO-3 the unrolled
+    # step gathers many layers' weights at once and does not fit
+    # (PERF.md sections 4 and 7, PR 38); pp, tp and ep keep the scan
+    # until a cell reads them
+    if mc.scan_layers is None:
+        dist = config.dist
+        updates["scan_layers"] = any(
+            axis.size > 1 for axis in (dist.fsdp, dist.pp, dist.tp, dist.ep))
     # expert capacity: the dist-level knob feeds the model's dispatcher;
     # an explicit model-config value wins
     if (config.dist.ep.capacity_factor is not None

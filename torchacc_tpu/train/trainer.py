@@ -149,7 +149,12 @@ class Trainer:
         self._quant_on = (getattr(getattr(model, "cfg", None),
                                   "quant", "none") != "none")
         # fused linear+CE (ops/fused.py): default loss only, zoo model only
-        from torchacc_tpu.models.transformer import TransformerLM
+        from torchacc_tpu.models.transformer import TransformerLM, layer_loop
+        # 'scan' | 'unrolled' for a zoo model ('custom' for any other
+        # module): which layer loop the step runs, said by the start-up
+        # line and on every train/dispatch span
+        self.layer_loop = (layer_loop(model.cfg)
+                           if isinstance(model, TransformerLM) else "custom")
         self._use_fused_ce = (loss is None
                               and config.compute.fused_kernels
                               and isinstance(model, TransformerLM)
@@ -298,10 +303,13 @@ class Trainer:
             self.state = jax.jit(
                 init_fn, out_shardings=self.state_shardings)(rng)
         self._host_step = 0
+        self._log_initialised()
+        return self.state
+
+    def _log_initialised(self) -> None:
         n_params = sum(x.size for x in jax.tree.leaves(self.state.params))
         logger.info(f"initialised {n_params/1e6:.1f}M params on mesh "
-                    f"{dict(self.mesh.shape)}")
-        return self.state
+                    f"{dict(self.mesh.shape)} layers={self.layer_loop}")
 
     def init_from_params(self, params: Any) -> TrainState:
         """Sharded state from EXISTING params (e.g. HF-converted
@@ -338,6 +346,7 @@ class Trainer:
             self.state = jax.jit(mk, out_shardings=sh,
                                  donate_argnums=0)(params)
         self._host_step = 0
+        self._log_initialised()
         return self.state
 
     def swap_params(self, params: Any, *, reinit_opt: bool = True,
@@ -1015,7 +1024,8 @@ class Trainer:
                 self._train_step_nodonate = self._build_train_step(
                     batch, donate=False)
             fn = self._train_step_nodonate
-        with tracing.span("train/dispatch", step=si):
+        with tracing.span("train/dispatch", step=si,
+                          layers=self.layer_loop):
             with jax.sharding.set_mesh(self.mesh):
                 out = fn(*args)
         if self._guard_on:
