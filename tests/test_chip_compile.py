@@ -404,9 +404,9 @@ def test_latent_serve_program_holds_one_latent_pool(one_chip, for_the_chip,
     assert not moved, moved
 
 
-def _compiled_train_step(topo, chips, mc, cfg, batch, seq, optimizer=None):
+def _traced_train_step(topo, chips, mc, cfg, batch, seq, optimizer=None):
     """``Trainer._train_step`` of ``accelerate()``'s model for ``mc``
-    under ``cfg``, compiled for ``chips`` of the described chips
+    under ``cfg``, traced for ``chips`` of the described chips
     (``chipbench/tools/sandbox_compile.py``'s construction)."""
     from torchacc_tpu.models.transformer import TransformerLM
     from torchacc_tpu.train.accelerate import apply_config_to_model
@@ -425,8 +425,13 @@ def _compiled_train_step(topo, chips, mc, cfg, batch, seq, optimizer=None):
                            trainer._batch_shardings({"input_ids": ids})
                            ["input_ids"])}
     with jax.sharding.set_mesh(mesh):
-        compiled = trainer._build_train_step(b).lower(state, b).compile()
-    return trainer, compiled
+        return trainer, trainer._build_train_step(b).trace(state, b)
+
+
+def _compiled_train_step(*args, **kw):
+    """The same step, compiled."""
+    trainer, traced = _traced_train_step(*args, **kw)
+    return trainer, traced.lower().compile()
 
 
 def _layer_whiles(text):
@@ -454,25 +459,47 @@ def _fsdp4_toy_step(topo):
     return _compiled_train_step(topo, 4, mc, cfg, batch, seq)
 
 
-def test_fused_head_under_fsdp_reduces_its_logits_once(
+def _under(text, scope, op):
+    """The compiled text's ``op`` instructions (sync or ``-start``)
+    whose op_name lies under ``scope``."""
+    return [ln.strip()[:240] for ln in text.splitlines()
+            if re.search(rf'op_name="[^"]*{scope}', ln)
+            and re.search(rf" {op}(-start)?\(", ln)]
+
+
+def test_fused_head_under_fsdp_reduces_no_logits(
         topo, train_step_for_the_chip):
-    """A small fsdp=4 train step, compiled whole: the partitioner shards
-    the head matmul's contraction (hidden) dimension inside the chunk
-    loop, so every pass over a chunk's logits costs an all-reduce of the
-    ``[chunk_rows, vocab]`` float32 block.  The head forms its gradient
-    in the forward loop (ops/fused.py), so there is ONE such pass — the
-    recompute and its all-reduce are gone.  ROADMAP S2 takes this to
-    zero (rows kept data-sharded, dW reduced once after the loop)."""
-    vocab, chunk_rows = 4096, 2048
-    text = _fsdp4_toy_step(topo)[1].as_text()
+    """A small fsdp=4 train step, compiled whole.  Left to the
+    partitioner the head's chunk loop shards the matmul's contraction
+    (hidden) dimension, and every chunk's ``[chunk_rows, vocab]``
+    float32 logits cost an all-reduce (one a chunk until PR 41, two
+    before PR 29).  The head keeps a chip's rows on that chip instead
+    (``ops/fused._head_rows``: one ``shard_map`` over the data axes
+    around the loop), so under ``fused_ce`` there is NO such
+    all-reduce: ONE all-gather brings the head weight whole before the
+    loop, ONE reduce-scatter takes the ``[hidden, vocab]`` dW to the
+    parameter's shards after it, and nothing is rematerialised."""
+    vocab, hidden, chunk_rows, fsdp = 4096, 512, 2048, 4
+    trainer, compiled = _fsdp4_toy_step(topo)
+    assert trainer.head_rows == "sharded"
+    text = compiled.as_text()
     head = [ln for ln in text.splitlines()
             if re.search(r'op_name="[^"]*fused_ce', ln)]
     assert head and not any("rematted_computation" in ln for ln in head)
-    logits_reduces = [
-        ln for ln in head
-        if re.search(rf"= f32\[{chunk_rows},{vocab}\]\S* all-reduce"
-                     r"(-start)?\(", ln)]
-    assert len(logits_reduces) == 1, logits_reduces
+    reduces = _under(text, "fused_ce", "all-reduce")
+    assert not [ln for ln in reduces
+                if re.search(rf"= f32\[{chunk_rows},{vocab}\]", ln)], reduces
+    # what is summed across the chips is loss_sum and count: scalars
+    assert all(re.search(r"= \(?f32\[\]", ln) for ln in reduces), reduces
+    gathers = _under(text, "fused_ce", "all-gather")
+    assert len(gathers) == 1 and re.search(
+        rf"= bf16\[{hidden},{vocab}\]", gathers[0]), gathers
+    scatters = _under(text, "fused_ce", "reduce-scatter")
+    assert len(scatters) == 1 and re.search(
+        rf"= f32\[{hidden // fsdp},{vocab}\]", scatters[0]), scatters
+    m = compiled.memory_analysis()
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes
+            < 15.75 * 2**30)
 
 
 def test_layers_under_fsdp_stay_in_the_scan(topo, train_step_for_the_chip):
@@ -489,6 +516,22 @@ def test_layers_under_fsdp_stay_in_the_scan(topo, train_step_for_the_chip):
             < 15.75 * 2**30)
 
 
+def _dense4k_cell():
+    """``mistral7b.train.dense4k``'s own configuration, traffic and
+    depth out of ``chipbench/``: the arguments of ``_traced_train_step``
+    after ``topo``."""
+    from chipbench import program, spec
+
+    cell = spec.Cell("mistral7b.train.dense4k")
+    traffic = cell.traffic
+    mc = program.model_config(cell.published, cell.depth,
+                              max_seq_len=traffic["seq"],
+                              **traffic.get("model_overrides", {}))
+    return (cell.chips, mc, program.framework_config(traffic["settings"], 0),
+            traffic["batch"], traffic["seq"],
+            program.optimizer(traffic["optimizer"]))
+
+
 def test_one_chip_train_step_applies_its_layers_unrolled(
         topo, train_step_for_the_chip):
     """``mistral7b.train.dense4k``'s step (the cell's own configuration,
@@ -498,20 +541,13 @@ def test_one_chip_train_step_applies_its_layers_unrolled(
     ``[L, ...]`` stack of the MLP's saved activations, each layer's
     three flash kernels its own instructions, and 2.7 GiB fewer
     temporaries than the scan's 7.32 (PERF.md section 4)."""
-    from chipbench import program, spec
-
-    cell = spec.Cell("mistral7b.train.dense4k")
-    traffic = cell.traffic
-    batch, seq, depth = traffic["batch"], traffic["seq"], cell.depth
-    assert (batch, seq, depth) == (4, SEQ, 2)
-    mc = program.model_config(cell.published, depth, max_seq_len=seq,
-                              **traffic.get("model_overrides", {}))
+    chips, mc, cfg, batch, seq, optimizer = _dense4k_cell()
+    depth = mc.num_layers
+    assert (chips, batch, seq, depth) == (1, 4, SEQ, 2)
     assert mc.scan_layers is None                # nobody chose
     assert (mc.hidden_size, mc.intermediate_size) == (4096, 14336)
     trainer, compiled = _compiled_train_step(
-        topo, cell.chips, mc,
-        program.framework_config(traffic["settings"], 0), batch, seq,
-        optimizer=program.optimizer(traffic["optimizer"]))
+        topo, chips, mc, cfg, batch, seq, optimizer=optimizer)
     assert trainer.layer_loop == "unrolled"
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert not _layer_whiles(text), _layer_whiles(text)
@@ -520,6 +556,20 @@ def test_one_chip_train_step_applies_its_layers_unrolled(
     assert not stacked, stacked[:3]
     assert text.count("tpu_custom_call") == 3 * depth
     assert mem.temp_size_in_bytes <= 5.2 * 2**30, mem.temp_size_in_bytes
+
+
+def test_one_chip_train_step_takes_the_head_whole(
+        topo, train_step_for_the_chip):
+    """The same cell's step, traced: one device shards no batch, so the
+    head reads the mesh and takes its rows whole — no ``shard_map`` in
+    the step (the flash kernels need none on one device either), the
+    parent's program (PERF.md section 6, PR 41: the lowered text is
+    byte-equal)."""
+    trainer, traced = _traced_train_step(topo, *_dense4k_cell())
+    jaxpr = str(traced.jaxpr)
+    assert trainer.head_rows == "whole"
+    # the layers are unrolled: the head's chunk loop is the one scan
+    assert jaxpr.count(" scan[") == 1 and "shard_map" not in jaxpr
 
 
 # -- two kinds of latent layer: dots3-note-prev's published geometry ---------

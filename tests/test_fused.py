@@ -201,3 +201,94 @@ def test_fused_ce_forms_its_gradient_in_the_forward_loop():
     # loss-only (eval): the loop alone, one matmul a chunk
     fwd = jax.jit(loss).lower(hidden, w).compile().as_text()
     assert sum(" dot(" in ln for ln in fwd.splitlines()) == 1
+
+
+# -- the rows kept where they lie: the head under data axes ------------------
+
+def _data_mesh(dp, fsdp):
+    from jax.sharding import Mesh
+    devs = np.asarray(jax.devices()[:dp * fsdp]).reshape(dp, fsdp)
+    return Mesh(devs, ("dp", "fsdp"))
+
+
+def _rows_case(name):
+    """(hidden, w_head, labels, kwargs, differentiate) for one case of
+    the head under a data mesh.  8 sequences of 24 rows: two a shard
+    under fsdp=4 (3 chunks of 16), so rows 0-1 are one shard's all."""
+    batch = {"batch_not_divided": 7, "batch_divided_by_dp_alone": 6}.get(
+        name, 8)
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    hidden = jax.random.normal(ks[0], (batch, 24, 32))
+    w = jax.random.normal(ks[1], (32, 128)) * 0.1
+    labels = jax.random.randint(ks[2], (batch, 24), 0, 128)
+    kw = dict(chunk_rows=16)
+    if name == "one_shard_all_ignored":
+        labels = (labels.at[:2].set(-100).at[3, 5:20].set(-100)
+                  .at[6, -3:].set(-100))
+    elif name == "softcap":
+        w, kw = w * 20.0, dict(chunk_rows=16, logit_softcap=5.0)
+    elif name == "bf16_head":               # dW summed in bf16 a shard
+        hidden, w = hidden.astype(jnp.bfloat16), w.astype(jnp.bfloat16)
+    return hidden, w, labels, kw, name != "eval"
+
+
+@pytest.mark.parametrize("dp,fsdp", [(1, 4), (2, 2)],
+                         ids=["fsdp4", "dp2_fsdp2"])
+@pytest.mark.parametrize("case", [
+    "one_shard_all_ignored", "softcap", "bf16_head", "float32_head",
+    "batch_not_divided", "batch_divided_by_dp_alone", "eval"])
+def test_fused_ce_under_data_axes_matches_one_device(devices, dp, fsdp,
+                                                     case):
+    """Under a mesh whose data axes shard the batch the chunk loop runs
+    per shard inside one ``shard_map`` (the head weight whole, the sums
+    ``psum``-ed, dW reduced once): loss_sum, count, d(hidden) and dW are
+    the one-device path's.  A batch the data extent does not divide
+    takes the one-device path itself."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from torchacc_tpu.ops.fused import head_row_axes
+    hidden, w, labels, kw, differentiate = _rows_case(case)
+    batch = hidden.shape[0]
+    want_axes = tuple(a for a, n in (("dp", dp), ("fsdp", fsdp)) if n > 1)
+    if batch % (dp * fsdp):
+        want_axes = ("dp",) if dp > 1 and batch % dp == 0 else ()
+
+    def sums(h, w):             # (loss_sum, count): value and aux
+        return fused_linear_cross_entropy(h, w, labels, **kw)
+
+    f = (jax.value_and_grad(sums, argnums=(0, 1), has_aux=True)
+         if differentiate else sums)
+    # no mesh: the rows whole.  A bf16 head is held to the one-device
+    # path that sums dW in float32 (the same bf16 values, a float32
+    # head): two bf16 sums in different orders differ by both their
+    # roundings
+    want = f(hidden, w.astype(jnp.float32))
+    assert head_row_axes(batch) == ()
+    mesh = _data_mesh(dp, fsdp)
+    with jax.sharding.set_mesh(mesh):
+        assert head_row_axes(batch) == want_axes
+        args = (jax.device_put(hidden, NamedSharding(mesh, P(want_axes))),
+                jax.device_put(w, NamedSharding(mesh, P("fsdp"))))
+        assert ("shard_map" in str(jax.make_jaxpr(f)(*args))) == bool(
+            want_axes)
+        got = jax.jit(f)(*args)
+    bf16 = hidden.dtype == jnp.bfloat16
+    for a, b, name in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                          ("loss_sum", "count", "d_hidden", "d_w")):
+        assert a.shape == b.shape, name
+        assert a.dtype == (w.dtype if name == "d_w" else b.dtype), name
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        atol = 2e-3 if bf16 else 1e-6
+        if bf16 and name == "d_w":
+            # a shard's partial sums are rounded to bf16 at THEIR size
+            # (three chunks a shard, half an ulp each) and cancel to an
+            # entry that may be far smaller: four ulps (2^-8) of the
+            # largest entry, over 192 rows where the rule's own bf16
+            # case sums 48
+            atol = 4 * 2.0 ** -8 * np.abs(b).max()
+        np.testing.assert_allclose(a, b, rtol=3e-2 if bf16 else 1e-5,
+                                   atol=atol, err_msg=name)
+    if differentiate and "fsdp" in want_axes:
+        # dW lands where the head parameter's shards lie
+        assert got[1][1].sharding.spec == P("fsdp")

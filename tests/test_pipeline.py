@@ -548,6 +548,36 @@ def test_pp_1f1b_data_sharded_matches_single(devices):
     np.testing.assert_allclose(losses_pp, losses_1, rtol=2e-4)
 
 
+def test_pp_1f1b_head_takes_its_rows_whole(devices, monkeypatch):
+    """Inside the 1F1B region the fused head takes its rows whole though
+    fsdp x dp divide the micro-batch: it reads that 'pp' is manual on
+    the ambient mesh (the region has arranged the devices, and only the
+    last stage takes the branch) — no flag tells it."""
+    import optax
+    from torchacc_tpu.ops import fused
+    seen = []
+    real = fused.head_row_axes
+
+    def spy(batch):
+        mesh = jax.sharding.get_abstract_mesh()
+        seen.append((tuple(mesh.manual_axes),
+                     batch % (mesh.shape["dp"] * mesh.shape["fsdp"]),
+                     real(batch)))
+        return seen[-1][-1]
+
+    monkeypatch.setattr(fused, "head_row_axes", spy)
+    cfg = ta.Config(dist=ta.DistConfig(
+        pp=ta.PPConfig(size=2, num_micro_batches=2, schedule="1f1b"),
+        fsdp=ta.FSDPConfig(size=2, min_weight_size=0),
+        dp=ta.DPConfig(size=2)))
+    tr, _ = accelerate(_model(), None, cfg, optimizer=optax.sgd(1e-2))
+    batch = {"input_ids": np.zeros((8, 32), np.int32)}
+    with jax.sharding.set_mesh(tr.mesh):
+        tr._build_train_step(batch).trace(tr.abstract_state(), batch)
+    assert seen and set(seen) == {(("pp",), 0, ())}, seen
+    assert tr.head_rows is None     # the Trainer's own head did not run
+
+
 def test_pp_1f1b_no_full_micro_gather(devices):
     """No collective in the compiled 1F1B step moves a FULL micro-batch
     activation: the signature of the removed per-tick all-replica

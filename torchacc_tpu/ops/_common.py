@@ -96,3 +96,22 @@ def dropout_keep(seed, b_idx, h_idx, q_pos, k_pos, dropout_p: float):
     bits = mix32(row[..., :, None] ^ col[..., None, :])
     threshold = jnp.uint32(min(int(dropout_p * 4294967296.0), 4294967295))
     return bits >= threshold
+
+
+def batch_axes(mesh, batch: int) -> tuple:
+    """The data axes a ``[batch, ...]`` activation is sharded over under
+    ``mesh``: the longest prefix of ``("dp", "fsdp")`` whose extent
+    divides ``batch`` (``parallel/sharding._divisible``'s rule, which
+    ``ops/attn.py::_sharded_flash`` follows too), the axes of extent 1
+    left out.  A manual axis counts as extent 1: inside its region the
+    arrays are already per shard."""
+    axes, n = [], 1
+    for a in ("dp", "fsdp"):
+        extent = (1 if a in mesh.manual_axes
+                  else int(mesh.shape.get(a, 1)))
+        if batch % (n * extent):
+            break
+        n *= extent
+        if extent > 1:
+            axes.append(a)
+    return tuple(axes)

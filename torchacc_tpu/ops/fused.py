@@ -23,7 +23,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from torchacc_tpu.ops._common import scoped
+from torchacc_tpu.ops._common import ambient_mesh, batch_axes, scoped
 
 
 def _scan_free_chunk(n: int, chunk_rows: int) -> int:
@@ -153,15 +153,82 @@ def _head_chunks(hidden, w_head, labels, chunk_rows: int,
     return loss_sum, count, dx, dw
 
 
+def head_row_axes(batch: int) -> Tuple[str, ...]:
+    """The data axes of the ambient mesh over which the head keeps a
+    ``[batch, seq, H]`` hidden's rows where they lie (``batch_axes``),
+    read at trace time; ``()`` = the rows are taken whole.  Whole too
+    where some mesh axis is manual: the caller's region (the pp-manual
+    1F1B tick) has made its own arrangement of the devices."""
+    mesh = ambient_mesh()
+    if mesh is None or mesh.manual_axes:
+        return ()
+    return batch_axes(mesh, batch)
+
+
+def _head_rows(hidden, w_head, labels, chunk_rows: int,
+               logit_softcap: float, scan_free: bool, with_grads: bool):
+    """``_head_chunks`` where the rows live.  Under data axes that shard
+    the batch (``head_row_axes``) the chunk loop runs per shard inside
+    ONE ``shard_map`` over those axes alone (tp / sp stay the
+    partitioner's): each chip loops over its own rows against the whole
+    head weight, which enters replicated over the data axes — gathered
+    once, before the loop — and the sums leave ``psum``-ed, d(hidden)
+    row-sharded as hidden came, dW reduced once after the loop into the
+    head parameter's layout (``parallel/sharding``'s ``embed`` rule:
+    hidden over ``fsdp``) by a reduce-scatter.  Left to the partitioner
+    the loop shards the head matmul's CONTRACTION instead (the weight
+    arrives ``[H / fsdp, V]``): every chunk's ``[chunk_rows, V]``
+    float32 logits are all-reduced and every chip runs the softmax over
+    every row (PERF.md section 6, PR 41)."""
+    axes = head_row_axes(hidden.shape[0])
+    if not axes:
+        return _head_chunks(hidden, w_head, labels, chunk_rows,
+                            logit_softcap, scan_free, with_grads)
+    from jax.sharding import PartitionSpec as P
+
+    mesh = ambient_mesh()
+    scatter = ("fsdp" if "fsdp" in axes
+               and w_head.shape[0] % mesh.shape["fsdp"] == 0 else None)
+
+    def local(h, w, y):
+        out = _head_chunks(h, w, y, chunk_rows, logit_softcap, scan_free,
+                           with_grads)
+        sums = jax.lax.psum(out[:2], axes)
+        if not with_grads:
+            return sums
+        dx, dw = out[2:]
+        # the shards' partial dW, each summed over its own chunks in
+        # w_head's dtype, meet in float32 and are rounded once: XLA:CPU
+        # (tier-1's backend) CHECK-crashes on a bf16 cross-device sum,
+        # as the tp head below notes, and one reduction a step is cheap
+        part = dw.astype(jnp.promote_types(dw.dtype, jnp.float32))
+        if scatter:
+            part = jax.lax.psum_scatter(part, scatter, scatter_dimension=0,
+                                        tiled=True)
+        rest = tuple(a for a in axes if a != scatter)
+        if rest:
+            part = jax.lax.psum(part, rest)
+        return (*sums, dx, part.astype(dw.dtype))
+
+    rows = P(axes)
+    out_specs = (P(), P())
+    if with_grads:
+        out_specs += (rows, P(scatter))
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(rows, P(), rows), out_specs=out_specs,
+        axis_names=frozenset(axes), check_vma=False,
+    )(hidden, w_head, labels)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _fused_ce(hidden, w_head, labels, chunk_rows, logit_softcap, scan_free):
-    return _head_chunks(hidden, w_head, labels, chunk_rows, logit_softcap,
-                        scan_free, with_grads=False)
+    return _head_rows(hidden, w_head, labels, chunk_rows, logit_softcap,
+                      scan_free, with_grads=False)
 
 
 def _fused_ce_fwd(hidden, w_head, labels, chunk_rows, logit_softcap,
                   scan_free):
-    loss_sum, count, dx, dw = _head_chunks(
+    loss_sum, count, dx, dw = _head_rows(
         hidden, w_head, labels, chunk_rows, logit_softcap, scan_free,
         with_grads=True)
     return (loss_sum, count), (dx, dw)
@@ -205,6 +272,19 @@ def fused_linear_cross_entropy(
     the 32k-vocab bench; 4096 is equal but doubles the chunk buffer).
     ``logit_softcap`` > 0 applies Gemma2's c * tanh(logits / c) before
     the loss.
+
+    Where the rows live is read from the ambient mesh at trace time
+    (``head_row_axes``), not set: under data axes (``dp``, ``fsdp``)
+    of extent > 1 that divide the batch, each chip loops over its OWN
+    rows inside one ``shard_map`` over those axes (``_head_rows``: the
+    head weight gathered once a step, the sums ``psum``-ed, d(hidden)
+    left row-sharded, dW reduce-scattered once into the parameter's
+    ``[H / fsdp, V]`` shards, summed across the shards in float32); on
+    one device, under a batch the data extent does not divide, or
+    inside a region that has made some mesh axis manual (the 1F1B
+    tick), the rows are taken whole and the partitioner shards what it
+    will.  The ``custom_vjp`` is around either: nothing is transposed
+    across the ``shard_map``.
 
     ``scan_free=True`` unrolls the chunk loop (a Python loop over the
     same chunk function instead of ``lax.scan``).  Required when this

@@ -543,7 +543,7 @@ class Trainer:
             w = cnt / jnp.maximum(jnp.sum(cnt), 1.0)
             extra["moe_aux_row_weights"] = jnp.repeat(w, mb)
         if self._use_fused_ce:
-            from torchacc_tpu.ops.fused import fused_linear_cross_entropy
+            from torchacc_tpu.ops import fused
             hidden, mutated = self.model.apply(
                 variables, batch["input_ids"],
                 positions=batch.get("positions"),
@@ -558,9 +558,16 @@ class Trainer:
             # _use_fused_ce is gated on isinstance(model, TransformerLM),
             # so .cfg is always present here — no defensive default that
             # could silently drop the cap
-            l_sum, count = fused_linear_cross_entropy(
+            l_sum, count = fused.fused_linear_cross_entropy(
                 hidden, w_head, labels,
                 logit_softcap=self.model.cfg.logit_softcap)
+            rows = ("sharded" if fused.head_row_axes(hidden.shape[0])
+                    else "whole")
+            if rows != self.head_rows:      # trace time: once a program
+                self.head_rows = rows
+                logger.info(f"traced the loss on mesh "
+                            f"{dict(self.mesh.shape)} "
+                            f"layers={self.layer_loop} head={rows}")
         else:
             out = self.model.apply(
                 variables, batch["input_ids"],
@@ -578,6 +585,14 @@ class Trainer:
             l_sum = l_sum + self._aux_weight * _sown_aux_sum(mutated) * count
         return l_sum, count, (mutated.get("quant")
                               if quant is not None else None)
+
+    # 'sharded' | 'whole': where the fused head's rows lived in the
+    # program traced last (ops/fused.head_row_axes reads the mesh and
+    # the batch at trace time, so None until a step is traced).  A
+    # class-level default BELOW _forward_sum_count: the line numbers of
+    # its model.apply call are serialized into every Pallas kernel's
+    # body, so a line added above it changes each program's bytes
+    head_rows: Optional[str] = None
 
     def _build_train_step(self, sample_batch, donate: bool = True):
         accum = self.config.grad_accum
