@@ -35,6 +35,7 @@ import torchacc_tpu.ops.flash_attention as flash_mod
 import torchacc_tpu.ops.grouped_matmul as grouped_mod
 import torchacc_tpu.ops.paged_attention as paged_mod
 import torchacc_tpu.ops.quantized_matmul as quant_mod
+import torchacc_tpu.ops.ssm_scan as ssm_mod
 from torchacc_tpu.config import ServeConfig
 from torchacc_tpu.ops.attn import attention
 
@@ -71,7 +72,7 @@ def one_chip(topo):
 def for_the_chip(monkeypatch):
     """The kernels ask ``interpret_mode()``, which sees the CPU backend
     here; steer them to the Mosaic lowering for the described chip."""
-    for mod in (flash_mod, grouped_mod, paged_mod, quant_mod):
+    for mod in (flash_mod, grouped_mod, paged_mod, quant_mod, ssm_mod):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -916,3 +917,92 @@ def test_windowed_paged_kernel_compiles_at_published_geometry(
     assert compiled.as_text().count("tpu_custom_call") == 1
     assert "window_paged_attention" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+# -- layers of one mixer each: a state pool by slot beside the k/v pool ------
+
+NEMOTRON_SERVE = dict(block_size=128, num_blocks=1296, max_slots=48,
+                      prefill_chunk=512)
+NEMOTRON_SEQ = 3456
+NEMOTRON_DEPTH = 52
+
+
+def _nemotron_program(name, one_chip):
+    import json
+    import types
+
+    from chipbench.layouts import ssm_attn_moe_decoder as layout
+    from chipbench.weights import ssm_attn_moe_decoder as weights
+    from torchacc_tpu.models.hf import config_from_hf
+    from torchacc_tpu.serve.kv_cache import blocks_needed, make_pools
+    from torchacc_tpu.serve.scheduler import PagedDecoder
+
+    pub = json.load(open(
+        "chipbench/configs/nemotron-3-nano-30b-a3b.json"))["published"]
+    mc = config_from_hf(types.SimpleNamespace(**pub),
+                        num_layers=NEMOTRON_DEPTH, max_seq_len=NEMOTRON_SEQ,
+                        param_dtype=BF16)
+    sc = ServeConfig(**NEMOTRON_SERVE)
+    decoder = PagedDecoder(mc, sc, "pallas")
+    sds = functools.partial(_sds, sharding=one_chip)
+    abstract = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: sds(x.shape, x.dtype), tree)
+    params = abstract(jax.eval_shape(
+        lambda k: layout.to_program_params(
+            weights.make(k, pub, NEMOTRON_DEPTH, BF16), mc),
+        jax.random.PRNGKey(0)))
+    pools = abstract(jax.eval_shape(lambda: make_pools(mc, sc)))
+    s = sc.max_slots
+    mb = blocks_needed(NEMOTRON_SEQ + sc.decode_depth, sc.block_size)
+    i32, f32 = jnp.int32, jnp.float32
+    if name == "decode":
+        carry = {"tok": sds((s,), i32), "key": sds((s, 2), jnp.uint32)}
+        return decoder._decode.lower(
+            params, pools, carry, sds((s, mb), i32), sds((s,), i32),
+            sds((s,), jnp.bool_), sds((s,), f32), sds((s,), i32),
+            sds((s,), f32), True), params, pools
+    return decoder._prefill.lower(
+        params, pools, sds((mb,), i32), sds((), i32),
+        sds((sc.prefill_chunk,), i32), sds((), i32),
+        name == "prefill_final_chunk", slot=sds((), i32)), params, pools
+
+
+@pytest.mark.parametrize("name",
+                         ["decode", "prefill_chunk", "prefill_final_chunk"])
+def test_ssm_serve_program_fits_the_chip_with_both_pools_in_place(
+        one_chip, for_the_chip, name):
+    """The three serve programs of the Nemotron-3-Nano cell at its own
+    settings and WHOLE depth (52 layers unrolled: 23 state-space, 23
+    expert, 6 attention; 16 held experts whose kernels are stored at
+    1920 for 1856): the k/v pool [6, 1296, 128, 256] x 2, the
+    convolution rows [23, 49, 18432] and the float32 state
+    [23, 49, 64, 64, 128] — 3.19 GiB, all aliased in -> out; beside
+    11.18 GiB of weights the temporaries stay under 64 MiB (256 for the
+    chunk that feeds the head), so the program fits a v5e's 15.75 GiB
+    (a compile that does not fit fails here as it would on the chip);
+    no instruction but a parameter yields an expert-stack- or
+    state-pool-shaped array; a chunk runs 23 scan kernels (a decode
+    step 23 state-update kernels), 6 attention kernels and two grouped
+    matmuls an expert layer (sandbox compile, PR 42)."""
+    lowered, params, pools = _nemotron_program(name, one_chip)
+    compiled = lowered.compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    assert [p.shape for p in pools] == [
+        (6, 1296, 128, 256)] * 2 + [(23, 49, 18432), (23, 49, 64, 64, 128)]
+    pool_bytes = sum(p.size * p.dtype.itemsize for p in pools)
+    weight_bytes = sum(x.size * x.dtype.itemsize
+                       for x in jax.tree.leaves(params))
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < (
+        256 if name == "prefill_final_chunk" else 64) * 2**20
+    assert (pool_bytes + weight_bytes + mem.temp_size_in_bytes
+            < 14.6 * 2**30)
+    # (a chunk that is not the prompt's last feeds no head: its last
+    # layer's expert matmuls are dead code)
+    assert text.count("tpu_custom_call") == {
+        "decode": 6 + 23 + 46, "prefill_chunk": 6 + 23 + 44,
+        "prefill_final_chunk": 6 + 23 + 46}[name]
+    shapes = r"bf16\[23,16,(2688,1920|1920,2688)\]|f32\[23,49,64,64,128\]"
+    made = [line.strip()[:160] for line in text.splitlines()
+            if re.search(rf"= ({shapes})\S* (copy|transpose)\(", line)]
+    assert not made, made

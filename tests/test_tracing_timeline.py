@@ -69,6 +69,16 @@ WINDOW_DECODE_SCOPES = ("window_paged_attn", "paged_attn", "qkv",
 # gqa_window_common.py); its serve/admit spans carry ADMIT_BLOCK_ATTRS
 WINDOW_SPAN_ATTRS = ("ctx_attended", "win_attended")
 
+# the programs of a model of single-mixer layers (PR 42): a decode step
+# updates every slot's recurrent state, a prefill chunk scans from it
+SSM_DECODE_SCOPES = ("ssm_mixer", "ssm_conv", "ssm_step", "ln1",
+                     "paged_attn", "kv_write", "router", "experts",
+                     "shared_expert")
+SSM_PREFILL_SCOPES = ("ssm_mixer", "ssm_conv", "ssm_scan", "paged_attn")
+# what its decode steps' serve/deliver spans carry (chipbench/rooflines/
+# ssm_common.py) and its admitting serve/admit spans
+STATE_SPAN_ATTRS = ("state_bytes", "ssm_layers", "ctx_attended")
+
 # what the serve/deliver span of a request's FIRST token carries (PR 37;
 # chipbench/readers/first_token_spans.py): what its wait was made of
 FIRST_TOKEN_ATTRS = ("sid", "prefill_programs", "queue_steps",
@@ -168,6 +178,36 @@ def _window_model():
         weights.make(weights.base_key(7), pub, 3, jnp.float32), mc)
 
 
+def _ssm_model():
+    """``(ModelConfig, params)`` of a toy of the single-mixer family:
+    ``MEM*E`` — state-space, expert and attention layers, one mixer
+    each; weights and layout are the benchmark's (the module's forward
+    refuses the family)."""
+    import types
+
+    from chipbench.layouts import ssm_attn_moe_decoder as layout
+    from chipbench.weights import ssm_attn_moe_decoder as weights
+    from torchacc_tpu.models.hf import config_from_hf
+    pub = dict(
+        model_type="nemotron_h", hidden_size=64, intermediate_size=32,
+        num_attention_heads=2, num_key_value_heads=2, head_dim=16,
+        vocab_size=256, hybrid_override_pattern="MEM*E",
+        num_hidden_layers=5, mamba_num_heads=4, mamba_head_dim=8,
+        n_groups=2, ssm_state_size=16, conv_kernel=4, chunk_size=8,
+        layer_norm_epsilon=1e-5, mamba_hidden_act="silu",
+        mlp_hidden_act="relu2", mamba_proj_bias=False, use_conv_bias=True,
+        use_bias=False, attention_bias=False, mlp_bias=False,
+        moe_intermediate_size=32, moe_shared_expert_intermediate_size=48,
+        n_routed_experts=4, n_shared_experts=1, num_experts_per_tok=2,
+        n_group=1, topk_group=1, norm_topk_prob=True,
+        routed_scaling_factor=2.5, tie_word_embeddings=False,
+        time_step_min=0.001, time_step_max=0.1, time_step_floor=1e-4)
+    mc = config_from_hf(types.SimpleNamespace(**pub), max_seq_len=128,
+                        dtype=jnp.float32, param_dtype=jnp.float32)
+    return mc, layout.to_program_params(
+        weights.make(weights.base_key(7), pub, 5, jnp.float32), mc)
+
+
 def _scopes_in(hlo_text):
     """Every registered scope named by some op_name of a compiled
     program (a transform wraps the first name under it:
@@ -247,7 +287,20 @@ def program_scopes():
         sds((2,), jnp.float32), sds((2,), jnp.int32),
         sds((2,), jnp.float32), True, sds((2, 15), jnp.int32)
     ).compile().as_text()
+    mmc, mparams = _ssm_model()
+    mdecoder = PagedDecoder(mmc, sc, "xla")
+    mpools = jax.eval_shape(lambda: make_pools(mmc, sc))
+    ssm_decode = mdecoder._decode.lower(
+        jax.eval_shape(lambda: mparams), mpools, carry,
+        sds((2, 15), jnp.int32), sds((2,), jnp.int32), sds((2,), jnp.bool_),
+        sds((2,), jnp.float32), sds((2,), jnp.int32),
+        sds((2,), jnp.float32), True).compile().as_text()
+    ssm_prefill = mdecoder._prefill.lower(
+        jax.eval_shape(lambda: mparams), mpools, sds((15,), jnp.int32), i32,
+        sds((8,), jnp.int32), i32, True, slot=i32).compile().as_text()
     return {"train": _scopes_in(train), "decode": _scopes_in(decode),
+            "ssm_decode": _scopes_in(ssm_decode),
+            "ssm_prefill": _scopes_in(ssm_prefill),
             "prefill": _scopes_in(prefill),
             "latent_decode": _scopes_in(latent),
             "sparse_decode": _scopes_in(sparse),
@@ -266,7 +319,9 @@ def program_scopes():
     *(("prefill", s) for s in PREFILL_SCOPES),
     *(("latent_decode", s) for s in LATENT_DECODE_SCOPES),
     *(("sparse_decode", s) for s in SPARSE_DECODE_SCOPES),
-    *(("window_decode", s) for s in WINDOW_DECODE_SCOPES)])
+    *(("window_decode", s) for s in WINDOW_DECODE_SCOPES),
+    *(("ssm_decode", s) for s in SSM_DECODE_SCOPES),
+    *(("ssm_prefill", s) for s in SSM_PREFILL_SCOPES)])
 def test_device_scope_in_compiled_program(program_scopes, program, scope):
     assert scope in tracing.DEVICE_SCOPES
     assert scope in program_scopes[program]
@@ -285,7 +340,8 @@ def test_every_pool_write_of_the_sparse_program_is_a_kv_write(
 def test_every_registered_scope_is_placed():
     placed = (set(TRAIN_SCOPES) | set(DECODE_SCOPES) | set(PREFILL_SCOPES)
               | set(LATENT_DECODE_SCOPES) | set(SPARSE_DECODE_SCOPES)
-              | set(WINDOW_DECODE_SCOPES))
+              | set(WINDOW_DECODE_SCOPES) | set(SSM_DECODE_SCOPES)
+              | set(SSM_PREFILL_SCOPES))
     assert placed == set(tracing.DEVICE_SCOPES)
 
 
@@ -366,6 +422,14 @@ def traced(tmp_path_factory):
                      max_new_tokens=3) for n in (5, 19)]
     wengine.generate(wreqs[:1])            # compile outside the trace
 
+    mmc, mparams = _ssm_model()
+    mengine = ServeEngine(TransformerLM(mmc), mparams, ta.Config(
+        serve=ta.config.ServeConfig(block_size=4, num_blocks=64,
+                                    max_slots=2, prefill_chunk=8)))
+    mreqs = [Request(prompt_ids=rng.integers(1, 256, size=n).tolist(),
+                     max_new_tokens=3) for n in (5, 19)]
+    mengine.generate(mreqs[:1])            # compile outside the trace
+
     assert not tracing.enabled()
     tracing.clear()
     trace_dir = str(tmp_path_factory.mktemp("timeline"))
@@ -379,8 +443,10 @@ def traced(tmp_path_factory):
         lengine.generate(lreqs)
         sengine.generate(sreqs)
         wengine.generate(wreqs)
+        mengine.generate(mreqs)
     finally:
         jax.profiler.stop_trace()
+    mengine.close()
     engine.close()
     lengine.close()
     sengine.close()
@@ -428,7 +494,8 @@ def test_deliver_spans_carry_the_expert_layers_counts(traced, attr):
     # spans are read by the next test)
     with_counts = [st for n, _, _, st in traced["events"]
                    if n == "serve/deliver" and "moe_pairs" in st
-                   and "sel_cached" not in st and "ctx_attended" not in st]
+                   and "sel_cached" not in st and "ctx_attended" not in st
+                   and "ssm_layers" not in st]
     without = [st for n, _, _, st in traced["events"]
                if n == "serve/deliver" and "moe_pairs" not in st]
     assert with_counts and without
@@ -480,7 +547,8 @@ def test_spans_carry_what_each_kind_of_grouped_query_layer_attended(
     behind the tokens, beside the expert layers' counts; no selection's
     counts.  Its admitting serve/admit spans carry the blocks by kind."""
     have = [st for n, _, _, st in traced["events"]
-            if n == "serve/deliver" and "ctx_attended" in st]
+            if n == "serve/deliver" and "ctx_attended" in st
+            and "state_bytes" not in st]
     assert have and all(attr in st and "sel_cached" not in st
                         for st in have)
     # a window of 5: a prompt of 19 tokens attends 1 + .. + 19 = 190
@@ -496,6 +564,34 @@ def test_spans_carry_what_each_kind_of_grouped_query_layer_attended(
     assert len(admits) >= 4                # this model's and the latent one's
 
 
+@pytest.mark.parametrize("attr", STATE_SPAN_ATTRS + MOE_SPAN_ATTRS)
+def test_spans_carry_the_state_a_decode_step_moved(traced, attr):
+    """A model with state-space layers: its decode steps' serve/deliver
+    spans carry the recurrent-state bytes the step read and wrote (every
+    decoding slot's, once a state-space layer: 2 layers x (a float32
+    state of 4 x 8 x 16 and 3 rows of 96 float32 channels) x 2), the
+    state-space layers and the positions an attention layer attended,
+    beside the expert layers' counts; a request's admission says that
+    the slot's state restarts."""
+    have = [st for n, _, _, st in traced["events"]
+            if n == "serve/deliver" and "state_bytes" in st]
+    firsts = [st for n, _, _, st in traced["events"]
+              if n == "serve/deliver" and "ssm_layers" in st
+              and "state_bytes" not in st]
+    assert len(firsts) == 2 and all(str(st["kind"]) == "first"
+                                    for st in firsts)
+    assert have and all(attr in st and "win_attended" not in st
+                        and str(st["kind"]) == "decode" for st in have)
+    a_slot = 2 * 2 * (4 * 4 * 8 * 16 + 3 * 96 * 4)
+    assert {int(st["state_bytes"]) for st in have} <= {a_slot, 2 * a_slot}
+    assert all(int(st["ssm_layers"]) == 2 and int(st["moe_layer_steps"]) == 2
+               and int(st["ctx_attended"]) > 0 for st in have)
+    resets = [st for n, _, _, st in traced["events"]
+              if n == "serve/admit" and "state_reset" in st]
+    assert len(resets) == 2 and all(int(st["state_reset"]) == 1
+                                    for st in resets)
+
+
 @pytest.mark.parametrize("attr", FIRST_TOKEN_ATTRS)
 def test_first_tokens_deliver_span_says_what_the_wait_was_made_of(
         traced, attr):
@@ -507,13 +603,13 @@ def test_first_tokens_deliver_span_says_what_the_wait_was_made_of(
                 if n == "serve/deliver"]
     firsts = [st for st in delivers if str(st.get("kind")) == "first"]
     decodes = [st for st in delivers if str(st.get("kind")) == "decode"]
-    assert len(firsts) == 8 and decodes    # four engines, two prompts each
+    assert len(firsts) == 10 and decodes   # five engines, two prompts each
     assert all(attr in st for st in firsts)
     assert not any(attr in st for st in decodes)
     # chunks of 8: a prompt of 5 tokens is one program, of 9 two, of 19
-    # three (prompts of 5 + 9 once, of 5 + 19 three times)
+    # three (prompts of 5 + 9 once, of 5 + 19 four times)
     assert sorted(int(st["prefill_programs"]) for st in firsts) == \
-        [1, 1, 1, 1, 2, 3, 3, 3]
+        [1, 1, 1, 1, 1, 2, 3, 3, 3, 3]
     for st in firsts:
         assert int(st["wait_steps"]) >= int(st["prefill_programs"]) \
             + int(st["queue_steps"])

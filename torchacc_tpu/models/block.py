@@ -7,7 +7,8 @@ caller supplies — ``proj(name, x)``, apply the projection whose
 parameters are named ``name``, and ``norm(name, x, cfg=cfg)``, apply the
 norm named ``name`` — and two closures for the block's halves,
 ``attention(h)`` and ``ffn(h)``, which the caller builds from ``qkv`` /
-``mlp`` and its own attention core.  ``TransformerLM``'s modules
+``mlp`` and its own attention core (a layer of a single mixer:
+:func:`mixer_block`).  ``TransformerLM``'s modules
 (models/transformer.py) implement the verbs with Flax submodules that
 create and apply the parameter; the serving decoder (serve/scheduler.py)
 and latent attention (models/mla.py) with :func:`tree_proj` /
@@ -278,6 +279,16 @@ def block(cfg, x, norm, attention, ffn):
     if post:
         mlp_out = norm("ln2", mlp_out)
     return h + checkpoint_name(mlp_out, "mlp_out")
+
+
+def mixer_block(cfg, x, norm, mixer, routed=False):
+    """A layer of ONE mixer (``cfg.mixer_pattern``): ``x + mixer(ln(x))``,
+    whatever the mixer is — state-space, attention or experts.  A
+    ``routed`` mixer (the grouped expert layer) is handed float32, as in
+    :func:`block`: a bf16-rounded router input flips near-tied experts."""
+    norm_cfg = (dataclasses.replace(cfg, dtype=jnp.float32) if routed
+                else cfg)
+    return x + checkpoint_name(mixer(norm("ln", x, norm_cfg)), "mixer_out")
 
 
 def tree_proj(cfg, tree):

@@ -302,6 +302,17 @@ def generate(
             out = jnp.concatenate([out[:, :p + n1], tail], axis=1)
         return out
 
+    if cfg is not None and getattr(cfg, "mixer_pattern", None):
+        # layers of one mixer each exist on the serving layout alone:
+        # states and caches thread through a raw per-layer loop
+        if prompt_mask is not None or not use_cache:
+            raise NotImplementedError(
+                "generate() runs a mixer_pattern model from its states "
+                "and caches, on whole prompts (no prompt_mask, no "
+                "use_cache=False: the module has no forward to recompute)")
+        return _generate_mixers(cfg, params, prompt_ids, rng,
+                                float(temperature), int(max_new_tokens),
+                                eos_id, int(top_k), float(top_p))
     if (can_cache and pp_live
             and (not cp_cfg or _mesh_extent("sp", "spu") > 1)):
         # pp x cp composes: the cp attention shard_map nests inside the
@@ -448,6 +459,100 @@ def _generate_cached_pattern(cfg, params, prompt_ids, prompt_mask, rng,
     return _drive_decode(logits, cache, step_fn, prompt_ids, row_len,
                          rng, temperature, max_new, eos_id, top_k,
                          top_p)
+
+
+# ---------------------------------------------------------------------------
+# layers of one mixer each (mixer_pattern): state-space layers carry a
+# state, attention layers a k/v cache, expert layers nothing
+# ---------------------------------------------------------------------------
+
+def _mixer_layers(cfg, params, cache, x, positions, pos):
+    """The layers of a ``mixer_pattern`` model on the serving layout
+    (models/transformer.layer_tree: ``layers/<kind>`` stacks), each
+    ``x + mixer(ln(x))`` (models/block.mixer_block).  ``cache`` is None
+    for the prompt (``x`` [b, p, H]; returns the caches it leaves: an
+    attention layer's ``(k, v)`` of ``cfg.cache_len`` positions, a
+    state-space layer's ``(conv, ssm)``) or the list those calls
+    returned, for one token at cache position ``pos``."""
+    from torchacc_tpu.models import block, mamba2
+    from torchacc_tpu.models.moe import moe_ffn
+    from torchacc_tpu.models.transformer import layer_kinds, layer_tree
+    from torchacc_tpu.ops.attention import attention_reference
+
+    b, s, _ = x.shape
+    new_cache = []
+    for i, kind in enumerate(layer_kinds(cfg)):
+        tree, _ = layer_tree(cfg, params, i)
+        p, kept = tree["block"], None
+
+        def attention(h, p=p, i=i):
+            proj = block.tree_proj(cfg, p["attn"])
+            q, k, v = block.qkv(cfg, h, positions, proj,
+                                block.tree_norm(cfg, p["attn"]))
+            if cache is None:
+                room = ((0, 0), (0, cfg.cache_len - s), (0, 0), (0, 0))
+                banked = (jnp.pad(k, room), jnp.pad(v, room))
+                out = attention_reference(q, k, v, causal=True,
+                                          scale=cfg.query_scale)
+            else:
+                banked = tuple(jax.lax.dynamic_update_slice(
+                    c, t.astype(c.dtype), (0, pos, 0, 0))
+                    for c, t in zip(cache[i], (k, v)))
+                out = attention_reference(
+                    q, *banked, causal=True, scale=cfg.query_scale,
+                    q_offset=pos - (banked[0].shape[1] - s))
+            return proj("o_proj", out), banked
+
+        def experts(h, p=p):
+            y = moe_ffn(cfg, p["moe"], h.reshape(b * s, -1))[0]
+            return y.reshape(b, s, -1), None
+
+        def mamba(h, p=p, i=i):
+            if cache is None:
+                return mamba2.mixer_sequence(cfg, p["mixer"], h)
+            conv, ssm = cache[i]
+            out, conv, ssm = mamba2.mixer_step(
+                cfg, p["mixer"], h, conv[None], ssm[None], 0,
+                jnp.ones((b,), bool))
+            return out, (conv[0], ssm[0])
+
+        def mixer(h, fn={"attention": attention, "moe": experts,
+                         "mamba": mamba}[kind]):
+            nonlocal kept
+            out, kept = fn(h)
+            return out
+
+        x = block.mixer_block(cfg, x, block.tree_norm(cfg, p), mixer,
+                              routed=kind == "moe")
+        new_cache.append(kept)
+    return x, new_cache
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "cfg", "temperature", "max_new", "eos_id", "top_k", "top_p"))
+def _generate_mixers(cfg, params, prompt_ids, rng, temperature, max_new,
+                     eos_id, top_k, top_p):
+    """Cached decode for ``mixer_pattern`` models: the prompt through
+    the whole-sequence mixers, then one token a step from the states
+    and caches they left."""
+    from torchacc_tpu.models.transformer import embed_ids, head_logits
+
+    b, p = prompt_ids.shape
+    cfg = dataclasses.replace(cfg, cache_len=p + max_new)
+    positions = jnp.broadcast_to(jnp.arange(p), (b, p))
+    x = embed_ids(cfg, params, prompt_ids, positions)
+    y, cache = _mixer_layers(cfg, params, None, x, positions, 0)
+    logits = head_logits(cfg, params, y)
+
+    def step_fn(cache, tok, positions1):
+        x1 = embed_ids(cfg, params, tok[:, None], positions1)
+        y1, cache = _mixer_layers(cfg, params, cache, x1, positions1,
+                                  positions1[0, 0])
+        return head_logits(cfg, params, y1)[:, 0], cache
+
+    return _drive_decode(logits, cache, step_fn, prompt_ids,
+                         jnp.full((b,), p, jnp.int32), rng, temperature,
+                         max_new, eos_id, top_k, top_p)
 
 
 # ---------------------------------------------------------------------------

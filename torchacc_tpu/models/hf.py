@@ -389,6 +389,74 @@ def config_from_hf(hf_config: Any, **overrides) -> ModelConfig:
             moe_router_width=int(get("router_n_experts", held)),
             moe_first_expert=int(get("first_held_expert", 0)),
             moe_dispatch="grouped")
+    if mt == "nemotron_h":
+        # Nemotron-H / Nemotron 3 (NVIDIA): every layer is ONE mixer under
+        # a pre-norm, x + mixer(RMSNorm(x)), named by a character of
+        # `hybrid_override_pattern`: 'M' a Mamba-2 mixer (mamba_num_heads
+        # heads of mamba_head_dim, n_groups groups, ssm_state_size,
+        # conv_kernel; d_inner is heads x head_dim, not expand x hidden),
+        # 'E' routed experts (sigmoid scores, a selection bias,
+        # renormalised top-k times routed_scaling_factor, one shared
+        # expert of its own width; relu2 FFNs of two matrices), '*'
+        # grouped-query attention WITHOUT a rotary embedding (the
+        # nemotron_h attention applies none; `rope_theta` stays unread).
+        # `n_routed_experts` is the number of experts THIS program holds
+        # (`router_n_experts`, `first_held_expert` beside it state an
+        # expert-parallel share, as for axk1).
+        kinds = {"M": "mamba", "E": "moe", "*": "attention"}
+        pattern = str(get("hybrid_override_pattern") or "")
+        n = int(overrides.get("num_layers", kw["num_layers"]))
+        if set(pattern) - set(kinds):
+            raise NotImplementedError(
+                f"nemotron_h layers {sorted(set(pattern) - set(kinds))} "
+                f"('-': a dense MLP layer) are not implemented; 'M', 'E' "
+                f"and '*' are")
+        if len(pattern) < n:
+            raise ValueError(
+                f"nemotron_h hybrid_override_pattern names {len(pattern)} "
+                f"layers, num_layers is {n}")
+        if get("mlp_hidden_act", "relu2") != "relu2" \
+                or get("mamba_hidden_act", "silu") != "silu":
+            raise NotImplementedError(
+                "nemotron_h with mlp_hidden_act other than relu2 or "
+                "mamba_hidden_act other than silu")
+        if (get("mamba_proj_bias", False) or get("use_bias", False)
+                or get("attention_bias", False) or get("mlp_bias", False)
+                or not get("use_conv_bias", True)):
+            raise NotImplementedError(
+                "nemotron_h with projection biases, or without the "
+                "convolution's bias")
+        held = int(get("n_routed_experts"))
+        shared = int(get("n_shared_experts", 0) or 0)
+        kw.update(
+            mixer_pattern=tuple(kinds[c] for c in pattern[:n]),
+            pos_emb="none", qkv_bias=False, o_bias=False, mlp_bias=False,
+            norm_eps=float(get("layer_norm_epsilon",
+                               get("norm_eps", kw["norm_eps"]))),
+            activation="relu2",
+            ssm_heads=int(get("mamba_num_heads")),
+            ssm_head_dim=int(get("mamba_head_dim")),
+            ssm_state=int(get("ssm_state_size")),
+            ssm_groups=int(get("n_groups", 1) or 1),
+            ssm_conv=int(get("conv_kernel", 4)),
+            ssm_chunk=int(get("chunk_size", 128)),
+            num_experts=held,
+            num_experts_per_tok=int(get("num_experts_per_tok")),
+            moe_intermediate_size=int(get("moe_intermediate_size")),
+            moe_shared_experts=shared,
+            moe_shared_intermediate_size=(
+                int(get("moe_shared_expert_intermediate_size"))
+                if shared and get("moe_shared_expert_intermediate_size")
+                else None),
+            moe_scoring="sigmoid",
+            moe_n_group=int(get("n_group", 1) or 1),
+            moe_topk_group=int(get("topk_group", 1) or 1),
+            moe_route_scale=float(get("routed_scaling_factor", 1.0)),
+            moe_renorm_topk=bool(get("norm_topk_prob", True)),
+            moe_router_bias=True,
+            moe_router_width=int(get("router_n_experts", held)),
+            moe_first_expert=int(get("first_held_expert", 0)),
+            moe_dispatch="grouped")
     if mt == "mixtral":
         # Mixtral 8x7B/8x22B: llama attention + top-k sparse MoE MLP.
         # HF routes softmax-then-topk-then-renormalise, which equals the

@@ -117,15 +117,47 @@ def swiglu(x, w_gate, w_up, w_down, dtype):
     return ff @ w_down.astype(dtype)
 
 
+def relu2(x, w_up, w_down, dtype):
+    """One ``down(relu(up(x))**2)`` FFN on raw kernels ([in, f],
+    [f, out]): two matrices, no gate."""
+    ff = jnp.square(nn.relu(x.astype(dtype) @ w_up.astype(dtype)))
+    return ff @ w_down.astype(dtype)
+
+
+def stored_expert_width(width: int) -> int:
+    """The width the held experts' stacked kernels are STORED at: whole
+    128-lane tiles, ``up`` (and ``gate``) padded with zero columns and
+    ``down`` with zero rows, which change nothing (``relu(0)**2`` and
+    ``silu(0) * 0`` are 0).  A TPU keeps an array whose last dimension
+    is not whole tiles with its last two dimensions swapped, and a
+    Mosaic call wants its operand row-major: at a width of 1856 XLA
+    relayouts the whole [L, e, hidden, 1856] stack before every grouped
+    matmul (sandbox compile, PR 42: a stack-sized copy in each serve
+    program).  The published widths so far are whole tiles but this
+    one; the padding is the layout's (chipbench/layouts), the
+    arithmetic reads whatever width the kernels have."""
+    return -(-width // 128) * 128
+
+
+def _gated(cfg) -> bool:
+    """Whether the experts are gated FFNs of three matrices (SwiGLU) or
+    ``relu2`` ones of two."""
+    if cfg.activation not in ("swiglu", "relu2"):
+        raise ValueError(f"the held-expert layer computes 'swiglu' or "
+                         f"'relu2' experts, not {cfg.activation!r}")
+    return cfg.activation == "swiglu"
+
+
 def held_experts_ffn(cfg, x, sel, weights, w_gate, w_up, w_down,
                      valid=None, layer=None):
     """The held experts' part of ``sum_i w_i E_i(x)``, dropless.
 
     ``x`` [n, h]; ``sel``/``weights`` [n, k] name experts of the router's
-    whole width; this program holds the ``e = w_gate.shape[-3]`` experts
+    whole width; this program holds the ``e = w_up.shape[-3]`` experts
     ``[cfg.moe_first_expert, +e)``.  The (token, expert) pairs that chose
     a held expert are sorted by expert and pass through three grouped
-    matmuls (``ops/grouped_matmul.py``: a Pallas kernel that visits only
+    matmuls (two where ``w_gate`` is None: ``relu2`` experts,
+    ``down(relu(up(x))**2)``; ``ops/grouped_matmul.py``: a Pallas kernel that visits only
     the row tiles that hold pairs and the weights of the groups they
     belong to), so FLOPs follow the routed pairs and an expert that drew
     no token is not read.  The kernels are one layer's [e, in, out] or,
@@ -142,7 +174,7 @@ def held_experts_ffn(cfg, x, sel, weights, w_gate, w_up, w_down,
     the largest held expert's count, held experts that drew a pair.
     """
     n, k = sel.shape
-    e = w_gate.shape[-3]
+    e = w_up.shape[-3]
     nk = n * k
     with jax.named_scope("moe_dispatch"):
         local = sel - cfg.moe_first_expert
@@ -156,10 +188,15 @@ def held_experts_ffn(cfg, x, sel, weights, w_gate, w_up, w_down,
         xs = x.astype(cfg.dtype)[tok_sorted]                    # [nk, h]
     with jax.named_scope("experts"):
         dt = cfg.dtype
-        gate = grouped_matmul(xs, w_gate, counts, layer=layer)
-        up = grouped_matmul(xs, w_up, counts, layer=layer)
-        out = grouped_matmul((nn.silu(gate) * up).astype(dt), w_down,
-                             counts, layer=layer)               # [nk, h]
+        if w_gate is not None:
+            gate = grouped_matmul(xs, w_gate, counts, layer=layer)
+            up = grouped_matmul(xs, w_up, counts, layer=layer)
+            ff = nn.silu(gate) * up
+        else:
+            ff = jnp.square(nn.relu(
+                grouped_matmul(xs, w_up, counts, layer=layer)))
+        out = grouped_matmul(ff.astype(dt), w_down, counts,
+                             layer=layer)                       # [nk, h]
     with jax.named_scope("moe_combine"):
         # rows past the held groups belong to no group: whatever the
         # kernel left there is masked, not multiplied by a zero weight
@@ -178,7 +215,9 @@ def held_experts_ffn(cfg, x, sel, weights, w_gate, w_up, w_down,
 def moe_ffn(cfg, p, x, valid=None, layer=None):
     """``shared(x) + sum_i w_i E_i(x)`` over the held experts, on the raw
     parameter tree ``p`` of :class:`MoEMlp` (``router``, ``experts/*``,
-    ``shared``) — the one definition behind the module's 'grouped' path
+    ``shared``; SwiGLU experts of three matrices or, under
+    ``cfg.activation='relu2'``, of two: no ``experts/gate``, no
+    ``gate_proj``) — the one definition behind the module's 'grouped' path
     and the serving decoder's expert layer.  With ``layer`` the three
     ``experts/*`` leaves are the stacks of every expert layer and
     ``layer`` the index into them (:func:`held_experts_ffn`); the other
@@ -192,15 +231,21 @@ def moe_ffn(cfg, p, x, valid=None, layer=None):
                          p["router"]["kernel"].astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
         sel, weights, scores = route(cfg, logits, p.get("router_bias"))
-    y, load = held_experts_ffn(cfg, x, sel, weights, p["experts/gate"],
+    gated = _gated(cfg)
+    y, load = held_experts_ffn(cfg, x, sel, weights,
+                               p["experts/gate"] if gated else None,
                                p["experts/up"], p["experts/down"], valid,
                                layer)
     if cfg.moe_shared_experts:
         with jax.named_scope("shared_expert"):
             sh = p["shared"]
-            shared = swiglu(x, sh["gate_proj"]["kernel"],
-                            sh["up_proj"]["kernel"],
-                            sh["down_proj"]["kernel"], cfg.dtype)
+            if gated:
+                shared = swiglu(x, sh["gate_proj"]["kernel"],
+                                sh["up_proj"]["kernel"],
+                                sh["down_proj"]["kernel"], cfg.dtype)
+            else:
+                shared = relu2(x, sh["up_proj"]["kernel"],
+                               sh["down_proj"]["kernel"], cfg.dtype)
         with jax.named_scope("moe_combine"):
             y = y + shared.astype(jnp.float32)
     return y.astype(cfg.dtype), scores, sel, load
@@ -223,10 +268,12 @@ class _Kernel(nn.Module):
 
 class _SwigluKernels(nn.Module):
     """``gate_proj`` / ``up_proj`` / ``down_proj`` kernels of one SwiGLU
-    FFN, named as :class:`Mlp` names them."""
+    FFN, named as :class:`Mlp` names them (no ``gate_proj`` where the FFN
+    is not ``gated``)."""
     hidden: int
     ffn: int
     param_dtype: object
+    gated: bool = True
 
     @nn.compact
     def __call__(self):
@@ -234,7 +281,8 @@ class _SwigluKernels(nn.Module):
         return {name: _Kernel(shape, self.param_dtype, name=name)()
                 for name, shape in (("gate_proj", (h, f)),
                                     ("up_proj", (h, f)),
-                                    ("down_proj", (f, h)))}
+                                    ("down_proj", (f, h)))
+                if self.gated or name != "gate_proj"}
 
 
 class MoEMlp(nn.Module):
@@ -398,15 +446,17 @@ class MoEMlp(nn.Module):
         if cfg.moe_router_bias:
             p["router_bias"] = self.param(
                 "router_bias", nn.initializers.zeros, (width,), jnp.float32)
-        p["experts/gate"] = self.param("experts/gate", init, (e, h, f),
-                                       cfg.param_dtype)
+        gated = _gated(cfg)
+        if gated:
+            p["experts/gate"] = self.param("experts/gate", init, (e, h, f),
+                                           cfg.param_dtype)
         p["experts/up"] = self.param("experts/up", init, (e, h, f),
                                      cfg.param_dtype)
         p["experts/down"] = self.param("experts/down", init, (e, f, h),
                                        cfg.param_dtype)
         if cfg.moe_shared_experts:
-            fs = cfg.moe_shared_experts * f
-            p["shared"] = _SwigluKernels(h, fs, cfg.param_dtype,
+            p["shared"] = _SwigluKernels(h, cfg.shared_ffn_size,
+                                         cfg.param_dtype, gated,
                                          name="shared")()
         b, s, _ = x.shape
         y, scores, sel, _ = moe_ffn(cfg, p, x.reshape(b * s, h))

@@ -72,7 +72,9 @@ SPANS: Dict[str, str] = {
                    "admit time; the profiler sink carries it as "
                    "serve/admit's queue_ms)",
     "serve/admit": "Scheduler.admit — block reservation + prefix match "
-                   "(ring: successful admissions only)",
+                   "(ring: successful admissions only; state_reset=1 "
+                   "where the slot's recurrent state restarts with the "
+                   "request)",
     "serve/prefill": "Scheduler — one prefill chunk (single or batched)",
     "serve/decode": "Scheduler._decode_once — one batched decode "
                     "dispatch",
@@ -86,7 +88,10 @@ SPANS: Dict[str, str] = {
                      "moe_layer_steps of the step(s) behind it; an "
                      "indexed selection: sel_attended, sel_cached, "
                      "win_attended; sliding + global grouped-query "
-                     "layers: ctx_attended, win_attended",
+                     "layers: ctx_attended, win_attended; a model "
+                     "with state-space layers: ssm_layers, and on a "
+                     "decode step state_bytes (the recurrent state it "
+                     "read and wrote) and ctx_attended",
     "serve/wait": "inside deliver — the one blocking token fetch",
 }
 
@@ -103,7 +108,7 @@ SCOPES: Dict[str, str] = {
     "layers": "the layer scan's own ops: per-layer slices of the stacked "
               "weights, the loop counter (serving: the KV pools ride the "
               "carry untouched)",
-    "ln1": "pre-attention norm",
+    "ln1": "pre-attention norm (a single-mixer layer's one pre-norm)",
     "ln2": "pre-MLP norm",
     "attn": "attention block outside its kernels: projections, rope, "
             "relayouts (training)",
@@ -155,11 +160,21 @@ SCOPES: Dict[str, str] = {
               "top-k, weights",
     "moe_dispatch": "expert layer: sort of the (token, expert) pairs on "
                     "held experts and the gather of their rows",
-    "experts": "expert layer: the three grouped matmuls over the held "
-               "experts",
+    "experts": "expert layer: the grouped matmuls over the held experts "
+               "(three of a SwiGLU expert, two of a relu2 one)",
     "shared_expert": "expert layer: the shared experts' FFN",
     "moe_combine": "expert layer: unsort, weight and sum the pairs' "
                    "outputs, add the shared expert",
+    "ssm_mixer": "state-space (Mamba-2) layer outside the three scopes "
+                 "below: input projection, skip term, gate, grouped norm, "
+                 "output projection (serving)",
+    "ssm_conv": "state-space layer: the causal depthwise convolution over "
+                "the slot's last inputs and the write of the next ones",
+    "ssm_scan": "state-space layer, a prefill chunk: softplus and decays, "
+                "the chunked-scan kernel from the slot's state, the state "
+                "written back in place",
+    "ssm_step": "state-space layer, a decode step: every decoding slot's "
+                "state read, updated with one token and written back",
 }
 
 DEVICE_SCOPES = tuple(SCOPES)

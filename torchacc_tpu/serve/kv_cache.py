@@ -385,7 +385,10 @@ def make_pools(model_cfg, serve_cfg, dtype=None):
     sliding ones), or for a
     latent-attention model ONE pool ``(latent,)`` of shape
     [L, NB, BS, latent_row_width] with no head dimension (every head
-    reads the same row), or for a model of two latent kinds ``(full
+    reads the same row), or for a ``mixer_pattern`` model ``(k, v)`` of
+    its attention layers and ``(conv, ssm)`` of its state-space layers
+    (indexed by slot; ``ssm`` always float32), or for a model of two
+    latent kinds ``(full
     latent [L_full, NB, BS, W], index keys [L_full, NB, BS, dI], window
     latent [L_win, NB_win, BS, W_win])``.  When a mesh is live and its 'tp' divides the
     kv heads, the k/v rows are sharded over it in whole-head groups (the
@@ -430,6 +433,23 @@ def make_pools(model_cfg, serve_cfg, dtype=None):
         return tuple(activation_constraint(jnp.zeros(shape, dt), axes)
                      for _ in range(2))
 
+    if model_cfg.mixer_pattern:
+        # layers of one mixer each: (k, v) of the attention layers alone,
+        # then the state-space layers' state BY SLOT, not by block — the
+        # convolution's last inputs [L_mamba, slots + 1, (K - 1) * channels]
+        # and the recurrent state [L_mamba, slots + 1, Hm, P, N] in
+        # float32, the same size at any context.  The last slot is the
+        # null slot (a batched prefill's padded rows write there)
+        from torchacc_tpu.models import mamba2
+        from torchacc_tpu.models.transformer import layer_kinds
+        kinds = layer_kinds(model_cfg)
+        n_ssm, slots = kinds.count("mamba"), serve_cfg.max_slots + 1
+        return kv(kinds.count("attention"), serve_cfg.num_blocks) + (
+            jnp.zeros((n_ssm, slots, (model_cfg.ssm_conv - 1)
+                       * mamba2.conv_width(model_cfg)), dt),
+            jnp.zeros((n_ssm, slots, model_cfg.ssm_heads,
+                       model_cfg.ssm_head_dim, model_cfg.ssm_state),
+                      jnp.float32))
     if model_cfg.layer_pattern:
         # windowed and full grouped-query layers: (k, v) of the global
         # layers, then (k, v) of the sliding ones under their own table

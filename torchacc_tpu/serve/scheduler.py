@@ -71,9 +71,11 @@ import numpy as np
 from torchacc_tpu.config import ConfigError
 from torchacc_tpu.models import block
 from torchacc_tpu.models.transformer import (
+    MIXER_KINDS,
     embed_ids,
     head_logits,
     kind_cfg,
+    layer_kinds,
     pattern_period,
 )
 from torchacc_tpu.obs import tracing
@@ -160,6 +162,15 @@ _AUDITED_MODEL_FIELDS = frozenset({
     # block through models/transformer.kind_cfg (a kind without rope
     # computes under pos_emb='none', which block.qkv reads)
     "rope_kinds",
+    # PR-42 audit: layers of ONE mixer each (_forward_mixers walks
+    # mixer_pattern: 'attention' layers on the k/v pools through _attend,
+    # 'moe' layers through models/moe.moe_ffn — whose experts follow
+    # `activation`, swiglu or relu2, and whose shared expert may have its
+    # own width —, 'mamba' layers through models/mamba2 over the state
+    # pools of serve/kv_cache.py, by slot); the ssm_* sizes reach only
+    # models/mamba2 and the state pools' shapes
+    "mixer_pattern", "ssm_heads", "ssm_head_dim", "ssm_state", "ssm_groups",
+    "ssm_conv", "ssm_chunk", "moe_shared_intermediate_size",
 })
 
 
@@ -243,6 +254,41 @@ def _check_supported(cfg) -> None:
                 or cfg.attn_gate != "none" or cfg.mla_lora_rescale):
             bad.append("indexed selection, a headwise gate or rescaled "
                        "latents outside the latent family of two kinds")
+    if cfg.mixer_pattern:
+        # layers of one mixer each: admitted with what the slot state
+        # and the attention layers' one k/v pool can hold
+        if set(cfg.mixer_pattern) - set(MIXER_KINDS):
+            bad.append(f"mixer_pattern entries other than {MIXER_KINDS}")
+        if len(cfg.mixer_pattern) < cfg.num_layers:
+            bad.append(f"a mixer_pattern of {len(cfg.mixer_pattern)} entries "
+                       f"for {cfg.num_layers} layers")
+        if tuple(cfg.window) != (-1, -1) or cfg.layer_pattern:
+            bad.append("a window beside state-space layers (a mixer_pattern "
+                       "with a sliding window or a layer_pattern)")
+        if cfg.kv_lora_rank:
+            bad.append("latent keys beside state-space layers (a "
+                       "mixer_pattern with kv_lora_rank)")
+        if cfg.first_dense_layers:
+            bad.append("first_dense_layers with a mixer_pattern (a layer "
+                       "holds one mixer)")
+        if (cfg.norm_placement != "pre" or cfg.parallel_block
+                or cfg.sandwich_norms):
+            bad.append("a mixer_pattern with anything but one pre-norm a "
+                       "layer")
+        if "moe" in cfg.mixer_pattern and not cfg.num_experts:
+            bad.append("'moe' layers in a mixer_pattern without experts")
+        if "mamba" in cfg.mixer_pattern and (
+                min(cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
+                    cfg.ssm_groups) < 1 or cfg.ssm_conv < 2
+                or cfg.ssm_heads % cfg.ssm_groups):
+            bad.append("'mamba' layers without their sizes (ssm_heads a "
+                       "multiple of ssm_groups, ssm_head_dim, ssm_state, "
+                       "ssm_conv >= 2)")
+    elif cfg.moe_shared_intermediate_size is not None:
+        bad.append("moe_shared_intermediate_size outside a mixer_pattern")
+    if cfg.activation == "relu2" and cfg.num_experts \
+            and not cfg.mixer_pattern:
+        bad.append("relu2 experts outside a mixer_pattern")
     if cfg.pos_emb == "alibi":
         bad.append("pos_emb='alibi'")
     if bad:
@@ -286,6 +332,24 @@ class PagedDecoder:
         # no pattern otherwise): latent ones, or grouped-query ones
         self.two_kinds = bool(cfg.layer_pattern)
         self.latent_kinds = bool(cfg.swa_kv_lora_rank)
+        # layers of one mixer each, state-space layers among them: their
+        # state lives by slot, beside the attention layers' paged pool
+        self.mixers = bool(cfg.mixer_pattern)
+        if self.mixers:
+            if serve_cfg.prefix_cache:
+                raise NotImplementedError(
+                    "the serving engine does not yet support prefix "
+                    "sharing with state-space layers (serve.prefix_cache "
+                    "with a mixer_pattern: a cached block holds keys and "
+                    "values, the recurrent state at its end is kept "
+                    "nowhere, so a shared prefix could not be resumed)")
+            if ("mamba" in cfg.mixer_pattern and serve_cfg.prefill_chunk
+                    > cfg.ssm_chunk and serve_cfg.prefill_chunk
+                    % cfg.ssm_chunk):
+                raise ConfigError(
+                    f"serve.prefill_chunk={serve_cfg.prefill_chunk} is not "
+                    f"whole sub-chunks of the state-space scan "
+                    f"(ssm_chunk={cfg.ssm_chunk})")
         if self.two_kinds:
             if serve_cfg.prefix_cache:
                 raise NotImplementedError(
@@ -648,8 +712,88 @@ class PagedDecoder:
                 (layers, jnp.arange(n_periods, dtype=jnp.int32)))
         return pools, x, jnp.sum(load, axis=0)
 
+    def _forward_mixers(self, params, pools, x, positions, tables, ctx_lens,
+                        blk, off, valid, state):
+        """The layer walk of a ``mixer_pattern`` model: its layers one
+        after another in the published order (no period to scan), each
+        ONE mixer under a pre-norm (models/block.mixer_block) on its
+        kind's stacked tree ``params['layers'][kind]`` at a static index.
+        ``pools`` are ``(k, v, conv, ssm)``: an 'attention' layer reads
+        and writes the first two through :meth:`_attend`, a 'mamba' layer
+        the last two through models/mamba2 — ``state`` says how: None for
+        a decode step (slot i's state at index i, ``valid[:, 0]`` the
+        slots that decode), else ``(slots [R], fresh [R], n_valid [R])``
+        of a prefill's rows.  Nothing copies a stack: an XLA dot reads
+        its layer's slice where it lies, the grouped matmul and the scan
+        kernel take the whole stack (or pool) and the layer's index."""
+        from torchacc_tpu.models import mamba2
+        from torchacc_tpu.models.moe import moe_ffn
+
+        cfg = self.cfg
+        stacks = {kind: tree["block"]
+                  for kind, tree in params["layers"].items()}
+        expert_stacks = {}
+        if "moe" in stacks:
+            moe = stacks["moe"]["moe"]
+            expert_stacks = {k: moe[k].astype(cfg.dtype)
+                             for k in _EXPERT_STACKS if k in moe}
+            stacks["moe"] = {**stacks["moe"], "moe": {
+                k: v for k, v in moe.items() if k not in _EXPERT_STACKS}}
+        seen = dict.fromkeys(MIXER_KINDS, 0)
+        load = None
+        with jax.named_scope("layers"):
+            for kind in layer_kinds(cfg):
+                i = seen[kind]
+                seen[kind] += 1
+                p = jax.tree.map(lambda a, i=i: a[i], stacks[kind])
+                left = {"pools": pools, "load": None}
+
+                def norm(name, t, ncfg, p=p):
+                    with jax.named_scope("ln1"):
+                        return block.tree_norm(ncfg, p)(name, t)
+
+                def attention(h, p=p, i=i, left=left):
+                    out, kv = self._attend(
+                        p["attn"], i, h, left["pools"][:2], positions,
+                        tables, ctx_lens, blk, off, cfg=cfg)
+                    left["pools"] = kv + left["pools"][2:]
+                    return out
+
+                def experts(h, p=p, i=i, left=left):
+                    s_, t_, hd = h.shape
+                    y, _, _, left["load"] = moe_ffn(
+                        cfg, {**p["moe"], **expert_stacks},
+                        h.reshape(s_ * t_, hd),
+                        None if valid is None else valid.reshape(-1),
+                        layer=i)
+                    return y.reshape(s_, t_, hd)
+
+                def mamba(h, p=p, i=i, left=left):
+                    k_, v_, conv, ssm = left["pools"]
+                    with jax.named_scope("ssm_mixer"):
+                        if state is None:
+                            out, conv, ssm = mamba2.mixer_step(
+                                cfg, p["mixer"], h, conv, ssm, i,
+                                valid[:, 0], impl=self.impl)
+                        else:
+                            out, conv, ssm = mamba2.mixer_chunk(
+                                cfg, p["mixer"], h, conv, ssm, i, *state,
+                                impl=self.impl)
+                    left["pools"] = (k_, v_, conv, ssm)
+                    return out
+
+                x = block.mixer_block(
+                    cfg, x, norm, {"attention": attention, "moe": experts,
+                                   "mamba": mamba}[kind],
+                    routed=kind == "moe")
+                pools = left["pools"]
+                if left["load"] is not None:
+                    load = left["load"] if load is None \
+                        else load + left["load"]
+        return pools, x, load
+
     def _forward(self, params, pools, ids, positions, tables, ctx_lens,
-                 blk, off, valid):
+                 blk, off, valid, state=None):
         """(pools', hidden [S, T, H], load): embed -> layer scan(s).  The
         stacked pools ride the scan's CARRY with the residual — each
         layer writes its rows in place and the kernel reads its pages
@@ -672,12 +816,16 @@ class PagedDecoder:
         trees ('dense_layers', then 'layers'), the pool on both carries,
         the layer index counting on; ``load`` is the expert layers'
         counts summed (int32[3], models/moe.held_experts_ffn) or None
-        for a model without them."""
+        for a model without them.  ``state`` is a ``mixer_pattern``
+        model's alone (:meth:`_forward_mixers`)."""
         with jax.named_scope("embed"):
             x = embed_ids(self.cfg, params, ids, positions)
         if self.two_kinds:
             return self._forward_periods(params, pools, x, positions, tables,
                                          ctx_lens, blk, off, valid)
+        if self.mixers:
+            return self._forward_mixers(params, pools, x, positions, tables,
+                                        ctx_lens, blk, off, valid, state)
 
         layers, expert_stacks = params["layers"], None
         moe = layers["block"].get("moe")
@@ -764,6 +912,8 @@ class PagedDecoder:
         off = jnp.where(active, seq_lens % bs, 0)
         ctx = jnp.where(active, seq_lens + 1, 0)
         if win_tables is None:
+            # (a mixer_pattern model's decode step reads its slots' state
+            # by slot index: _forward's state=None)
             pools, x, load = self._forward(params, pools, tok[:, None],
                                            positions, tables, ctx,
                                            blk[:, None], off[:, None],
@@ -785,7 +935,7 @@ class PagedDecoder:
         return pools, {"tok": toks, "key": split[:, 0]}, toks, load
 
     def _prefill_impl(self, params, pools, table_row, t0, tokens, n_valid,
-                      is_final, win_row=None):
+                      is_final, win_row=None, slot=None):
         """One chunk of ONE sequence: bank k/v for tokens
         [t0, t0 + n_valid) and return the last valid row's logits (the
         first-token sampling input when this is the final chunk;
@@ -804,9 +954,14 @@ class PagedDecoder:
         off = jnp.where(valid, pos % bs, 0)
         ctx = (t0 + n_valid)[None]
         if win_row is None:
+            # a mixer_pattern model's chunk starts from its slot's state,
+            # from zero where the chunk is the request's first
+            state = (None if slot is None else
+                     (slot[None], (t0 == 0)[None], n_valid[None]))
             pools, x, load = self._forward(params, pools, tokens[None],
                                            positions, table_row[None], ctx,
-                                           blk[None], off[None], valid[None])
+                                           blk[None], off[None], valid[None],
+                                           state)
         else:
             win_blk = jnp.where(valid, win_row[pos // bs], 0)
             pools, x, load = self._forward(
@@ -816,6 +971,13 @@ class PagedDecoder:
         if not is_final:
             return pools, None, load
         with jax.named_scope("head"):
+            if self.mixers:
+                # the last valid row alone through the head: a chunk's
+                # logits over this family's vocabulary are 256 MiB of
+                # float32 beside pools that leave no such room
+                row = jnp.take_along_axis(
+                    x, jnp.maximum(n_valid - 1, 0)[None, None, None], axis=1)
+                return pools, head_logits(self.cfg, params, row)[0, 0], load
             logits = head_logits(self.cfg, params, x)
             last = jnp.take_along_axis(
                 logits[0], jnp.maximum(n_valid - 1, 0)[None, None],
@@ -823,7 +985,7 @@ class PagedDecoder:
         return pools, last, load
 
     def _prefill_batch_impl(self, params, pools, table_rows, t0s, tokens,
-                            n_valids, win_rows=None):
+                            n_valids, win_rows=None, slots=None):
         """One chunk each of up to ``prefill_batch`` DISTINCT sequences
         in one program: ``table_rows`` [PB, MB], ``t0s``/``n_valids``
         [PB] (0 valid = padded row: runs on the null block, output
@@ -832,7 +994,8 @@ class PagedDecoder:
         rows sample their first token from them; non-final and padded
         rows are ignored by the host), so the head is a [PB, H] x
         [H, V] matmul, not the full-chunk head, and final-vs-non-final
-        needs no static flag: trace count is 1."""
+        needs no static flag: trace count is 1.  ``slots`` [PB] are the
+        rows' slots in a ``mixer_pattern`` model's state pools."""
         bs, c = self.block_size, self.chunk
         i = jnp.arange(c, dtype=jnp.int32)[None, :]              # [1, C]
         valid = i < n_valids[:, None]                            # [PB, C]
@@ -847,8 +1010,13 @@ class PagedDecoder:
             blk = (blk, jnp.where(valid, jnp.take_along_axis(
                 win_rows, pos // bs, axis=1), 0))
             table_rows = (table_rows, win_rows)
+        # (a mixer_pattern model: the rows' slots — the null slot for a
+        # padded row, which starts fresh and is read by no one)
+        state = (None if slots is None else
+                 (slots, (t0s == 0) | (n_valids == 0), n_valids))
         pools, x, load = self._forward(params, pools, tokens, positions,
-                                       table_rows, ctx, blk, off, valid)
+                                       table_rows, ctx, blk, off, valid,
+                                       state)
         with jax.named_scope("head"):
             last = jnp.take_along_axis(
                 x, jnp.maximum(n_valids - 1, 0)[:, None, None], axis=1)
@@ -1017,6 +1185,9 @@ class _InFlight:
     # Scheduler._note_selected's counts of the step(s) behind this entry
     # (None for a model without an indexed selection)
     selected: Any = None
+    # a mixer_pattern model's decode step: (slots whose state it read and
+    # wrote, positions its attention layers' queries attended); else None
+    state: Any = None
 
 
 class Scheduler:
@@ -1068,6 +1239,14 @@ class Scheduler:
                                     serve_cfg.block_size))
             self.win_tables = np.zeros_like(self.tables)
             self._dev_win = None
+        # a model with state-space layers: bytes one slot's state takes
+        # in all of them (what a decode step reads and writes a slot)
+        self._ssm_layers = self._slot_state_bytes = 0
+        if self.decoder.mixers and "mamba" in model_cfg.mixer_pattern:
+            from torchacc_tpu.models import mamba2
+            self._ssm_layers = layer_kinds(model_cfg).count("mamba")
+            self._slot_state_bytes = (self._ssm_layers
+                                      * mamba2.state_bytes(model_cfg))
         self.seq_lens = np.zeros((s,), np.int32)
         self.active = np.zeros((s,), bool)
         self.temp = np.zeros((s,), np.float32)
@@ -1152,6 +1331,10 @@ class Scheduler:
             queue_s = seq.queue_s if seq.t_submit else 0.0
             sp.set(admitted=1, cached_tokens=seq.cached_tokens,
                    queue_ms=queue_s * 1e3, **self.blocks_by_kind())
+            if self._ssm_layers:
+                # the slot's recurrent state restarts with the request:
+                # its first chunk reads zeros, not the last tenant's state
+                sp.set(state_reset=1)
         if seq.t_submit and tracing.enabled():
             now = time.perf_counter()
             tracing.record_span("serve/queue", now - queue_s, now,
@@ -1352,17 +1535,19 @@ class Scheduler:
         if n_valid < c:
             chunk = np.pad(chunk, (0, c - n_valid))
         final = (t0 + n_valid) >= seq.prompt_len
-        win = ()
+        win, state = (), {}
         if self.window is not None:
             self._before_prefill(seq, t0, n_valid)
             win = (_upload(self.win_tables[seq.slot]),)
+        if self.decoder.mixers:
+            state = {"slot": jnp.asarray(seq.slot, jnp.int32)}
         with tracing.span("serve/prefill", sid=seq.sid, t0=t0,
                           tokens=n_valid, batched=False,
                           trace=seq.trace_id):
             self.pools, last_logits, load = self.decoder._prefill(
                 self.params, self.pools, _upload(self.tables[seq.slot]),
                 jnp.asarray(t0, jnp.int32), jnp.asarray(chunk, jnp.int32),
-                jnp.asarray(n_valid, jnp.int32), final, *win)
+                jnp.asarray(n_valid, jnp.int32), final, *win, **state)
         if load is not None:
             seq.loads.append(load)
         seq.prefill_programs += 1
@@ -1395,12 +1580,17 @@ class Scheduler:
             taken.append(n)
             if self.window is not None:
                 self._before_prefill(seq, t0, n)
-        win = ()
+        win, state = (), {}
         if self.window is not None:
             win_rows = np.zeros_like(tables)
             for r, seq in enumerate(seqs):
                 win_rows[r] = self.win_tables[seq.slot]
             win = (jnp.asarray(win_rows),)
+        if self.decoder.mixers:
+            # a padded row runs on the null slot, behind the real ones
+            slots = np.full((pb,), self.serve_cfg.max_slots, np.int32)
+            slots[:len(seqs)] = [seq.slot for seq in seqs]
+            state = {"slots": jnp.asarray(slots)}
         with tracing.span("serve/prefill", batched=True,
                           sids=[s.sid for s in seqs],
                           traces=[s.trace_id for s in seqs],
@@ -1408,7 +1598,7 @@ class Scheduler:
             self.pools, logits, load = self.decoder._prefill_batch(
                 self.params, self.pools, jnp.asarray(tables),
                 jnp.asarray(t0s), jnp.asarray(toks), jnp.asarray(n_valids),
-                *win)
+                *win, **state)
         if load is not None:
             seqs[0].loads.append(load)       # one program, counted once
         for r, seq in enumerate(seqs):
@@ -1483,6 +1673,13 @@ class Scheduler:
             if self._dev_win is None:
                 self._dev_win = _upload(self.win_tables)
             win = (self._dev_win,)
+        state = None
+        if self.decoder.mixers:
+            # every decoding slot's state is read and written once a
+            # state-space layer; an attention layer's query sees the
+            # slot's cached positions and its own
+            state = (len(snapshot), int(self.seq_lens[
+                [slot for slot, _ in snapshot]].sum()) + len(snapshot))
         tables, active, temp, top_k, top_p = self._dev_stable_arrays()
         all_greedy = bool((self.temp[self.active] <= 0.0).all())
         # per-request trace ids on the batched span: built only while
@@ -1501,7 +1698,7 @@ class Scheduler:
         self._ring.append(_InFlight(
             kind="decode", tokens=toks, slots=snapshot,
             loads=[] if load is None else [load], selected=selected,
-            iter_idx=self._iter, t_dispatch=time.monotonic()))
+            state=state, iter_idx=self._iter, t_dispatch=time.monotonic()))
         self._iter += 1
 
     # -- resolution / eviction ----------------------------------------------
@@ -1615,6 +1812,8 @@ class Scheduler:
                 pairs, largest, hit = np.sum(
                     [np.asarray(x) for x in entry.loads], axis=0)
                 layer_steps = len(entry.loads) * (
+                    layer_kinds(self.cfg).count("moe")
+                    if self.cfg.mixer_pattern else
                     self.cfg.num_layers - self.cfg.first_dense_layers)
                 deliver.set(
                     moe_pairs=int(pairs), moe_max=int(largest),
@@ -1630,6 +1829,17 @@ class Scheduler:
                                 win_attended=win_att)
                 else:
                     deliver.set(ctx_attended=cached, win_attended=win_att)
+            if self._ssm_layers and deliver.live:
+                deliver.set(ssm_layers=self._ssm_layers)
+                if entry.state is not None:
+                    # a decode step over state-space layers: the state
+                    # bytes it read and wrote (every decoding slot's, once
+                    # a layer) beside the positions an attention layer
+                    # attended
+                    slots, attended = entry.state
+                    deliver.set(
+                        state_bytes=2 * slots * self._slot_state_bytes,
+                        ctx_attended=attended)
             now = time.monotonic()
             if entry.kind == "first":
                 seq = entry.seq
