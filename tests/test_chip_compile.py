@@ -92,19 +92,60 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("segments", [False, True],
-                         ids=["plain", "segment_ids"])
-def test_flash_fwd_bwd_compiles(one_chip, for_the_chip, segments):
-    def loss(q, k, v, seg):
-        kw = dict(q_segment_ids=seg, kv_segment_ids=seg) if segments else {}
+@pytest.mark.parametrize("case", ["plain", "segment_ids", "left_window",
+                                  "sq_lt_sk", "alibi"])
+def test_flash_fwd_bwd_compiles(one_chip, for_the_chip, case):
+    """``plain`` is the train cells' call: dead steps clamped, diagonal
+    tiles in two pieces (512-row and 512-key slices of the 1024-blocks),
+    dk/dv on [k, q] tiles with lse / delta as ``[b, h, 1, sq]`` lane
+    vectors.  The others are the paths that stand aside from the split
+    (segment ids, a left window) or shift the band (``sq < sk``), and
+    the ALiBi bias in both orientations (its positions are int32 iotas:
+    Mosaic takes no float one)."""
+    sq = SEQ // 2 if case == "sq_lt_sk" else SEQ
+    kw = dict(window=(1500, -1)) if case == "left_window" else {}
+
+    def loss(q, k, v, seg_q, seg_kv, slopes):
+        if case == "segment_ids":
+            kw.update(q_segment_ids=seg_q, kv_segment_ids=seg_kv)
+        if case == "alibi":
+            kw.update(alibi_slopes=slopes)
         return attention(q, k, v, impl="pallas", **kw).astype(
             jnp.float32).sum()
 
-    q = _sds((1, SEQ, H, D), BF16, one_chip)
+    plan = flash_mod.tile_plan(sq, SEQ, 1024, 1024, True,
+                               kw.get("window", (-1, -1)), SEQ - sq,
+                               has_seg=case == "segment_ids")
+    assert plan["dead_fetching"] == 0 and plan["live"] < plan["steps"]
+    assert plan["diagonal_split"] == {"plain": 4, "alibi": 4,
+                                      "sq_lt_sk": 2}.get(case, 0)
+    q = _sds((1, sq, H, D), BF16, one_chip)
     kv = _sds((1, SEQ, KH, D), BF16, one_chip)
-    seg = _sds((1, SEQ), jnp.int32, one_chip)
-    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv, seg)
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv,
+                          _sds((1, sq), jnp.int32, one_chip),
+                          _sds((1, SEQ), jnp.int32, one_chip),
+                          _sds((H,), jnp.float32, one_chip))
     assert text.count("tpu_custom_call") >= 3      # fwd, dq, dkv
+
+
+@pytest.mark.parametrize("blocks", [(64, 128), (192, 1024), (1024, 64)])
+def test_flash_refuses_blocks_the_lanes_cannot_tile(for_the_chip, blocks):
+    """On the chip lse leaves ``flash_fwd`` along the lanes in q blocks
+    and the kv segment ids arrive in kv blocks, so an explicit block
+    tiles by 128 — or, a q block alone, covers the whole q length.  The
+    forward and the standalone backward say so before Mosaic would."""
+    block_q, block_k = blocks
+    q = jnp.zeros((1, SEQ, H, D), BF16)
+    kv = jnp.zeros((1, SEQ, KH, D), BF16)
+    lse = jnp.zeros((1, H, SEQ), jnp.float32)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        flash_mod.flash_attention(q, kv, kv, block_q=block_q,
+                                  block_k=block_k)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        flash_mod.flash_attention_bwd(q, kv, kv, q, lse, q, block_q=block_q,
+                                      block_k=block_k)
+    # a short q in one block of its own length is taken
+    flash_mod._check_blocks(64, 128, 64)
 
 
 def _paged_at_a_traced_layer(q, k_pool, v_pool, tables, lens, q_start,
@@ -511,7 +552,13 @@ def test_layers_under_fsdp_stay_in_the_scan(topo, train_step_for_the_chip):
     trainer, compiled = _fsdp4_toy_step(topo)
     assert trainer.layer_loop == "scan"
     assert trainer.model.cfg.scan_layers is True
-    assert _layer_whiles(compiled.as_text())
+    text = compiled.as_text()
+    assert _layer_whiles(text)
+    # the scanned step holds each flash kernel once — exactly the
+    # ``min_kernels`` of chipbench/traffic/dense4k.fsdp4.json, whose
+    # driver refuses a step with fewer (ROADMAP "Also open in the
+    # yardstick": a one-kernel flash backward waits for that to change)
+    assert text.count("tpu_custom_call") == 3
     m = compiled.memory_analysis()
     assert (m.argument_size_in_bytes + m.temp_size_in_bytes
             < 15.75 * 2**30)

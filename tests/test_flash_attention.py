@@ -7,12 +7,14 @@ ops/attention.py and the kernel runs in interpret mode on CPU.
 """
 
 import functools
+import itertools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from torchacc_tpu.ops import flash_attention as fa
 from torchacc_tpu.ops.attention import attention_reference
 from torchacc_tpu.ops.flash_attention import (
     flash_attention,
@@ -387,3 +389,147 @@ def test_logit_softcap_matches_reference(window):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-5, rtol=5e-5,
                                    err_msg=f"standalone d{name}")
+
+
+# ---------------------------------------------------------------------------
+# only the work the mask leaves: dead steps, diagonal tiles, the dk/dv
+# kernel's [k, q] tiles
+# ---------------------------------------------------------------------------
+
+_GEOMETRIES = [
+    # block_q, block_k, causal, window, shift, nq, nk
+    (256, 256, True, (-1, -1), 0, 4, 4),        # the cells' shape, scaled
+    (256, 256, True, (-1, -1), 512, 2, 4),      # sq < sk, bottom-right
+    (256, 256, True, (-1, -1), -512, 4, 2),     # sq > sk: rows with no key
+    (128, 128, True, (200, -1), 0, 4, 4),       # a left window
+    (128, 256, True, (300, -1), 128, 5, 3),     # ragged blocks and shift
+    (64, 128, False, (100, 30), 0, 6, 3),       # a band without causality
+    (64, 64, False, (-1, 40), 64, 4, 5),        # a right window alone
+    (128, 128, False, (-1, -1), 0, 3, 3),       # no positional mask
+]
+
+
+@pytest.mark.parametrize("geometry", _GEOMETRIES,
+                         ids=[f"g{i}" for i in range(len(_GEOMETRIES))])
+def test_live_range_agrees_with_block_should_run(geometry):
+    """``_live_range`` (what the index maps clamp to) and
+    ``_block_should_run`` (what lets a body run) are one rule: on every
+    (qi, ki), from the q side and from the kv side."""
+    bq, bk, causal, window, shift, nq, nk = geometry
+    runs = np.array([[bool(fa._block_should_run(
+        qi * bq, ki * bk, bq, bk, causal, window, shift))
+        for ki in range(nk)] for qi in range(nq)])
+    for qi in range(nq):
+        lo, hi = fa._live_range(qi, bq, bk, causal, window, shift, nk)
+        assert [lo <= ki <= hi for ki in range(nk)] == list(runs[qi]), qi
+        # traced, as an index map calls it
+        tlo, thi = jax.jit(lambda i: fa._live_range(
+            i, bq, bk, causal, window, shift, nk))(jnp.int32(qi))
+        assert (int(tlo), int(thi)) == (lo, hi)
+    for ki in range(nk):
+        lo, hi = fa._live_range(ki, bq, bk, causal, window, shift, nq,
+                                of_kv_block=True)
+        assert [lo <= qi <= hi for qi in range(nq)] == list(runs[:, ki]), ki
+    # a dead step names a block its row's live steps hold (where any is)
+    for qi, ki in itertools.product(range(nq), range(nk)):
+        named = int(fa._live_block(ki, qi, bq, bk, causal, window, shift, nk))
+        assert 0 <= named < nk
+        if runs[qi, ki]:
+            assert named == ki
+        elif runs[qi].any():
+            assert runs[qi, named]
+    plan = fa.tile_plan(nq * bq, nk * bk, bq, bk, causal, window, shift)
+    assert plan["steps"] == nq * nk and plan["live"] == runs.sum()
+    # only a q row or a kv column with no live step at all can still
+    # copy a block in vain (its own q block, its own kv block)
+    assert plan["dead_fetching"] <= ((~runs.any(axis=1)).sum()
+                                     + (~runs.any(axis=0)).sum())
+
+
+def test_tile_plan_at_the_cells_shape():
+    """seq 4096 in 1024x1024 blocks under a causal mask (all three train
+    cells): 16 steps, 10 live, no dead step copies a block, the four
+    diagonal tiles leave out their masked quarter.  With traced offsets
+    (the ring) the band is not known when the kernel is built."""
+    assert fa._block_sizes(4096, 4096) == (1024, 1024)
+    assert fa.tile_plan(4096, 4096, 1024, 1024, True, (-1, -1), 0) == dict(
+        steps=16, live=10, dead_fetching=0, diagonal_split=4)
+    assert fa.tile_plan(4096, 4096, 1024, 1024, True, (-1, -1), 0,
+                        has_seg=True)["diagonal_split"] == 0
+    assert fa.tile_plan(4096, 4096, 1024, 1024, True, (-1, -1), None) == dict(
+        steps=16, live=None, dead_fetching=None, diagonal_split=0)
+
+
+@pytest.mark.parametrize("unclamped", ["kv_side", "q_side"])
+def test_tile_plan_reads_the_index_maps_the_kernels_are_given(monkeypatch,
+                                                              unclamped):
+    """``dead_fetching`` is walked off the BlockSpecs the three
+    ``pallas_call``s take, not off the range function: index maps that
+    name a dead step's own block again — flash_fwd / flash_dq's kv side,
+    or flash_dkv's q side — read the six copies in vain the parent made."""
+    clamp = fa._live_block
+
+    def own_block(j, *args, of_kv_block=False, **kw):
+        if of_kv_block == (unclamped == "q_side"):
+            return j
+        return clamp(j, *args, of_kv_block=of_kv_block, **kw)
+
+    monkeypatch.setattr(fa, "_live_block", own_block)
+    assert fa.tile_plan(4096, 4096, 1024, 1024, True, (-1, -1), 0) == dict(
+        steps=16, live=10, dead_fetching=6, diagonal_split=4)
+
+
+def _segments(b, s, cuts):
+    """[b, s] packed segment ids with boundaries at ``cuts``."""
+    ids = np.zeros((b, s), np.int32)
+    for c in cuts:
+        ids[:, c:] += 1
+    return jnp.asarray(ids)
+
+
+@pytest.mark.parametrize("feature", ["alibi", "dropout", "softcap"])
+@pytest.mark.parametrize("group", [1, 4], ids=["mha", "gqa4"])
+@pytest.mark.parametrize("sk", [512, 1024], ids=["sq_eq_sk", "sq_lt_sk"])
+@pytest.mark.parametrize("segments", [False, True],
+                         ids=["dense", "packed"])
+@pytest.mark.parametrize("window", [(-1, -1), (300, -1)],
+                         ids=["causal", "left300"])
+def test_masked_work_is_skipped_not_changed(window, segments, sk, group,
+                                            feature):
+    """Forward, dq, dk and dv against the XLA reference where the grids
+    hold dead steps and diagonal tiles: 256-blocks over 512 queries (the
+    halves of a diagonal tile still tile the lanes, so the causal dense
+    cases take the split; windows and segment ids stand aside)."""
+    sq, hk, d = 512, 1, 32
+    q, k, v = _make_qkv(1, sq, sk, hk * group, hk, d, seed=21)
+    kw = dict(causal=True, window=window)
+    if segments:
+        kw.update(q_segment_ids=_segments(1, sq, (200, 390)),
+                  kv_segment_ids=_segments(1, sk, (sk - sq + 200,
+                                                   sk - sq + 390)))
+    if feature == "alibi":
+        kw["alibi_slopes"] = jnp.asarray(
+            [2.0 ** -(i + 2) for i in range(hk * group)], jnp.float32)
+    elif feature == "dropout":
+        kw.update(dropout_p=0.2, dropout_seed=5)
+    else:
+        kw["logit_softcap"] = 15.0
+    plan = fa.tile_plan(sq, sk, 256, 256, True, window, sk - sq,
+                        has_seg=segments)
+    assert plan["live"] < plan["steps"] and plan["dead_fetching"] == 0
+    assert plan["diagonal_split"] == (
+        2 if window == (-1, -1) and not segments else 0)
+
+    def loss(fn, **blocks):
+        return lambda q, k, v: jnp.sum(fn(q, k, v, **kw, **blocks) ** 2)
+
+    out = flash_attention(q, k, v, block_q=256, block_k=256, **kw)
+    ref = attention_reference(q, k, v, **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=3e-5, rtol=3e-5)
+    g_flash = jax.grad(loss(flash_attention, block_q=256, block_k=256),
+                       argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(loss(attention_reference), argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(g_flash, g_ref, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-4, rtol=1e-3, err_msg=f"d{name}")
