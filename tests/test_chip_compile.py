@@ -32,6 +32,7 @@ from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
 import torchacc_tpu.ops.flash_attention as flash_mod
+import torchacc_tpu.ops.fused as fused_mod
 import torchacc_tpu.ops.grouped_matmul as grouped_mod
 import torchacc_tpu.ops.paged_attention as paged_mod
 import torchacc_tpu.ops.quantized_matmul as quant_mod
@@ -72,16 +73,26 @@ def one_chip(topo):
 def for_the_chip(monkeypatch):
     """The kernels ask ``interpret_mode()``, which sees the CPU backend
     here; steer them to the Mosaic lowering for the described chip."""
-    for mod in (flash_mod, grouped_mod, paged_mod, quant_mod, ssm_mod):
+    for mod in (flash_mod, fused_mod, grouped_mod, paged_mod, quant_mod,
+                ssm_mod):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
 @pytest.fixture
 def train_step_for_the_chip(for_the_chip, monkeypatch):
     """A whole train step also asks ``ops/attn`` which attention
-    ``auto`` means: the flash kernel, as on the chip."""
+    ``auto`` means, and ``ops/fused`` what runs the head's chunk: the
+    flash and the head kernels, as on the chip."""
     import torchacc_tpu.ops.attn as attn_mod
     monkeypatch.setattr(attn_mod, "_on_tpu", lambda: True)
+    monkeypatch.setattr(fused_mod, "on_tpu", lambda: True)
+
+
+def _kernels(text, prefix):
+    """The compiled text's Pallas kernels whose instruction name starts
+    with ``prefix``, read as the benchmark's train driver reads them."""
+    from chipbench.drivers.train_fit import _kernel_names
+    return [n for n in _kernel_names(text) if n.startswith(prefix)]
 
 
 def _compiled_text(fn, *args):
@@ -446,6 +457,36 @@ def test_latent_serve_program_holds_one_latent_pool(one_chip, for_the_chip,
     assert not moved, moved
 
 
+@pytest.mark.parametrize("rows,hidden,vocab", [
+    (2048, 2048, 100352),       # olmo2-1b.train.dense4k
+    (2048, 4096, 32768),        # mistral7b.train.dense4k / fsdp4
+], ids=["olmo2_100k", "mistral_32k"])
+def test_head_kernels_compile(one_chip, for_the_chip, rows, hidden, vocab):
+    """``head_fwd`` / ``head_dx`` / ``head_dw`` at the train cells'
+    published geometries, a 2048-row chunk of a bf16 head: the tiles
+    ``_head_tiles`` picks from (rows, hidden, vocab) divide them, the
+    blocks fit the VMEM limit the kernels state, and the dW sum goes
+    in and out through one buffer."""
+    tiles = fused_mod._head_tiles(rows, hidden, vocab, 2, 2)
+    assert tiles is not None
+    sds = functools.partial(_sds, sharding=one_chip)
+    assert fused_mod._HEAD_VMEM_LIMIT <= 110 * 2**20    # of a v5e's 128
+
+    def chunk(x, y, w, dw_sum):
+        return fused_mod._head_chunk_kernels(x, y, w, dw_sum, tiles)
+
+    compiled = jax.jit(chunk, donate_argnums=(3,)).lower(
+        sds((rows, hidden), BF16), sds((rows,), jnp.int32),
+        sds((hidden, vocab), BF16), sds((hidden, vocab), BF16)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert sorted(n.split(".")[0] for n in _kernels(text, "head_")) == [
+        "head_dw", "head_dx", "head_fwd"]
+    # the dW sum is updated in place: what the chunk holds besides its
+    # arguments is the float32 logits and small change
+    assert mem.alias_size_in_bytes >= hidden * vocab * 2
+    assert mem.temp_size_in_bytes < rows * vocab * 4 + 64 * 2**20
+
+
 def _traced_train_step(topo, chips, mc, cfg, batch, seq, optimizer=None):
     """``Trainer._train_step`` of ``accelerate()``'s model for ``mc``
     under ``cfg``, traced for ``chips`` of the described chips
@@ -524,7 +565,12 @@ def test_fused_head_under_fsdp_reduces_no_logits(
     vocab, hidden, chunk_rows, fsdp = 4096, 512, 2048, 4
     trainer, compiled = _fsdp4_toy_step(topo)
     assert trainer.head_rows == "sharded"
+    # the chunk runs as kernels on each chip's own rows: the shard_map
+    # is manual over the whole mesh (every other axis has extent 1)
+    assert trainer.head_impl == "pallas"
     text = compiled.as_text()
+    assert sorted(n.split(".")[0] for n in _kernels(text, "head_")) == [
+        "head_dw", "head_dx", "head_fwd"]
     head = [ln for ln in text.splitlines()
             if re.search(r'op_name="[^"]*fused_ce', ln)]
     assert head and not any("rematted_computation" in ln for ln in head)
@@ -558,7 +604,8 @@ def test_layers_under_fsdp_stay_in_the_scan(topo, train_step_for_the_chip):
     # ``min_kernels`` of chipbench/traffic/dense4k.fsdp4.json, whose
     # driver refuses a step with fewer (ROADMAP "Also open in the
     # yardstick": a one-kernel flash backward waits for that to change)
-    assert text.count("tpu_custom_call") == 3
+    assert len(_kernels(text, "flash_")) == 3
+    assert text.count("tpu_custom_call") == 3 + len(_kernels(text, "head_"))
     m = compiled.memory_analysis()
     assert (m.argument_size_in_bytes + m.temp_size_in_bytes
             < 15.75 * 2**30)
@@ -602,7 +649,15 @@ def test_one_chip_train_step_applies_its_layers_unrolled(
     stacked = [ln.strip()[:200] for ln in text.splitlines()
                if re.search(rf"bf16\[{depth},{batch},{seq},14336\]", ln)]
     assert not stacked, stacked[:3]
-    assert text.count("tpu_custom_call") == 3 * depth
+    # by name: the head's three kernels sit in its chunk loop, once
+    assert len(_kernels(text, "flash_")) == 3 * depth
+    assert sorted(n.split(".")[0] for n in _kernels(text, "head_")) == [
+        "head_dw", "head_dx", "head_fwd"]
+    assert text.count("tpu_custom_call") == 3 * depth + 3
+    assert trainer.head_impl == "pallas"
+    head = [ln for ln in text.splitlines()
+            if "tpu_custom_call" in ln and re.search(r"%head_", ln)]
+    assert all(re.search(r'op_name="[^"]*fused_ce', ln) for ln in head)
     assert mem.temp_size_in_bytes <= 5.2 * 2**30, mem.temp_size_in_bytes
 
 

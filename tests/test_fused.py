@@ -292,3 +292,257 @@ def test_fused_ce_under_data_axes_matches_one_device(devices, dp, fsdp,
     if differentiate and "fsdp" in want_axes:
         # dW lands where the head parameter's shards lie
         assert got[1][1].sharding.spec == P("fsdp")
+
+
+# -- the chunk as Pallas kernels (interpret mode here) ------------------------
+
+def _as_on_tpu(monkeypatch):
+    """The selection asks ``on_tpu()``; the kernels themselves still ask
+    ``_common.interpret_mode()`` and run interpreted on the CPU."""
+    from torchacc_tpu.ops import fused
+    monkeypatch.setattr(fused, "on_tpu", lambda: True)
+
+
+def _kernel_case(name):
+    """(hidden, w_head, labels, kwargs) for one case of the kernel path:
+    128 hidden columns, a vocabulary of 384 = three 128-column tiles."""
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    batch, seq, h, v = 2, 256, 128, 384
+    kw = dict(chunk_rows=256)
+    if name == "rows_need_the_pad":
+        seq = 200                           # 400 rows: 2 chunks, 112 padded
+    elif name == "row_tiles":
+        kw = dict(chunk_rows=2048)          # 2048 rows: two row tiles of 1024
+        batch, seq = 1, 2048
+    hidden = jax.random.normal(ks[0], (batch, seq, h))
+    w = jax.random.normal(ks[1], (h, v)) * 0.1
+    labels = jax.random.randint(ks[2], (batch, seq), 0, v)
+    if name == "some_rows_ignored":
+        labels = labels.at[0, 3:90].set(-100).at[1, -5:].set(-100)
+    elif name == "one_chunk_all_ignored":
+        labels = labels.at[0].set(-100)
+    elif name == "every_row_ignored":
+        labels = jnp.full_like(labels, -100)
+    elif name == "bf16_hidden_f32_weight":
+        hidden = hidden.astype(jnp.bfloat16)
+    elif name == "bf16_head":                # dW summed in bf16
+        hidden, w = hidden.astype(jnp.bfloat16), w.astype(jnp.bfloat16)
+    elif name == "tied_head":
+        w = w.T
+    elif name == "scan_free":
+        kw = dict(chunk_rows=256, scan_free=True)
+    return hidden, w, labels, kw
+
+
+@pytest.mark.parametrize("case", [
+    "float32", "some_rows_ignored", "one_chunk_all_ignored",
+    "every_row_ignored", "bf16_head", "bf16_hidden_f32_weight",
+    "rows_need_the_pad", "row_tiles", "tied_head", "scan_free"])
+def test_head_kernels_match_the_xla_body(monkeypatch, case):
+    """``head_fwd`` / ``head_dx`` / ``head_dw`` against ``_head_chunk``'s
+    XLA body through the whole ``custom_vjp``: loss sum, count,
+    d(hidden) and dW."""
+    from torchacc_tpu.ops import fused
+    hidden, w, labels, kw = _kernel_case(case)
+    tied = case == "tied_head"
+
+    def grad():     # a new function each time: a trace is cached by it
+        def sums(h, w):
+            return fused_linear_cross_entropy(h, w.T if tied else w,
+                                              labels, **kw)
+        return jax.value_and_grad(sums, argnums=(0, 1), has_aux=True)
+
+    f = grad()
+    assert "head_fwd" not in str(jax.make_jaxpr(f)(hidden, w))
+    want = f(hidden, w)
+    _as_on_tpu(monkeypatch)
+    f = grad()
+    jaxpr = str(jax.make_jaxpr(f)(hidden, w))
+    assert all(k in jaxpr for k in ("head_fwd", "head_dx", "head_dw"))
+    assert fused.head_impl(hidden, w.T if tied else w, **kw) == "pallas"
+    got = jax.jit(f)(hidden, w)
+    bf16 = hidden.dtype == jnp.bfloat16
+    for a, b, name in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                          ("loss_sum", "count", "d_hidden", "d_w")):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        atol = 2e-3 if bf16 else 2e-6
+        if name == "d_w":
+            # to the size of the largest entry: the kernel rounds dlogits
+            # to the model dtype (the CPU's default precision does not)
+            # and a bf16 sum once where the XLA body rounds each chunk's
+            # dW before a bf16 add; float32 sums in another order
+            atol = (2.0 ** -8 if bf16 else 2e-6) * max(1, np.abs(b).max())
+        np.testing.assert_allclose(a, b, rtol=2e-2 if bf16 else 2e-5,
+                                   atol=atol, err_msg=name)
+    if case == "every_row_ignored":
+        assert float(got[0][0]) == 0.0 and float(got[0][1]) == 0.0
+        assert not np.asarray(got[1][0]).any()
+        assert not np.asarray(got[1][1]).any()
+
+
+def test_head_kernels_add_to_the_dw_sum_in_place(monkeypatch):
+    """``head_dw`` takes the chunks' sum in and out through one buffer
+    (``input_output_aliases``) and rounds the float32 sum once."""
+    from torchacc_tpu.ops import fused
+    hidden, w, labels, _ = _kernel_case("bf16_head")
+    x, y = hidden.reshape(-1, 128)[:256], labels.reshape(-1)[:256]
+    before = jnp.full(w.shape, 0.5, jnp.bfloat16)
+    tiles = fused._head_tiles(256, 128, 384, 2, 2)
+    (_, _, after), _ = fused._head_chunk_kernels(x, y, w, before, tiles)
+    (_, _, dw), _ = fused._head_chunk(x, y, w, 0.0, True)
+    want = np.asarray(0.5 + dw, np.float32)
+    np.testing.assert_allclose(np.asarray(after, np.float32), want,
+                               rtol=2.0 ** -8, atol=2.0 ** -8 * want.max())
+    jaxpr = str(jax.make_jaxpr(
+        lambda acc: fused._head_chunk_kernels(x, y, w, acc, tiles))(
+            before))
+    call = jaxpr.split("pallas_call[")[-1]
+    assert "name=head_dw" in call
+    assert "input_output_aliases=((4, 0),)" in call.split("name=head_dw")[0]
+
+
+@pytest.mark.parametrize("case,want", [
+    ("one_device", "pallas"),
+    ("cpu_backend", "xla"),
+    ("fsdp4", "pallas"),
+    ("dp2_fsdp2", "pallas"),
+    ("fsdp2_tp2", "xla"),
+    ("tp4", "xla"),
+    ("batch_not_divided", "xla"),
+    ("partly_manual_region", "xla"),
+    ("softcap", "xla"),
+    ("vocab_not_tiled", "xla"),
+    ("float16", "xla"),
+    ("eval", "xla"),
+])
+def test_head_kernels_are_chosen_by_what_the_call_shows(
+        devices, monkeypatch, case, want):
+    """The kernels where the backend is a TPU, the arrays at the call are
+    one device's (one device, or the head's ``shard_map`` manual over the
+    whole mesh), the dtype and the tiles fit; the XLA body elsewhere.
+    ``head_impl`` (the trainer's word) says what the traced program
+    holds."""
+    from jax.sharding import Mesh
+    from jax.sharding import PartitionSpec as P
+
+    from torchacc_tpu.ops import fused
+    if case != "cpu_backend":
+        _as_on_tpu(monkeypatch)
+    batch = 7 if case == "batch_not_divided" else 8
+    v = 200 if case == "vocab_not_tiled" else 256
+    dtype = jnp.float16 if case == "float16" else jnp.float32
+    hidden = jnp.zeros((batch, 128, 128), dtype)
+    w = jnp.zeros((128, v), dtype)
+    labels = jnp.zeros((batch, 128), jnp.int32)
+    kw = dict(chunk_rows=128)
+    if case == "softcap":
+        kw["logit_softcap"] = 5.0
+    shape = {"fsdp4": (1, 4, 1), "dp2_fsdp2": (2, 2, 1),
+             "fsdp2_tp2": (1, 2, 2), "tp4": (1, 1, 4),
+             "batch_not_divided": (1, 4, 1),
+             "partly_manual_region": (2, 2, 1)}.get(case)
+
+    def sums(h, w, y):
+        return fused_linear_cross_entropy(h, w, y, **kw)
+
+    f = sums if case == "eval" else jax.value_and_grad(
+        sums, argnums=(0, 1), has_aux=True)
+
+    def read():
+        return (fused.head_impl(hidden, w, **kw),
+                str(jax.make_jaxpr(f)(hidden, w, labels)))
+
+    if shape is None:
+        word, jaxpr = read()
+    else:
+        mesh = Mesh(np.asarray(devices[:4]).reshape(shape),
+                    ("dp", "fsdp", "tp"))
+        with jax.sharding.set_mesh(mesh):
+            if case == "partly_manual_region":
+                # as the 1F1B tick: a region manual over one axis only
+                said = []
+
+                def region(h, w, y):
+                    said.append(fused.head_impl(h, w, **kw))
+                    return f(h, w, y)[0]
+
+                jaxpr = str(jax.make_jaxpr(jax.shard_map(
+                    region, mesh=mesh, in_specs=(P("dp"), P(), P("dp")),
+                    out_specs=P(), axis_names=frozenset({"dp"}),
+                    check_vma=False))(hidden, w, labels))
+                word = said[0]
+            else:
+                word, jaxpr = read()
+    if case == "eval":          # the loss alone stays the XLA loop
+        assert "head_fwd" not in jaxpr
+        return
+    assert word == want
+    assert ("head_fwd" in jaxpr) == (want == "pallas")
+    if shape is not None and case != "partly_manual_region":
+        # the head's shard_map: manual over the whole mesh where the row
+        # axes are all that is sharded, else over the row axes alone
+        manual = re.findall(r"manual_axes=frozenset\(\{([^}]*)\}\)", jaxpr)
+        whole = [m for m in manual if m.count("'") == 6]
+        assert bool(whole) == (want == "pallas"), manual
+
+
+@pytest.mark.parametrize("rows,h,v,itemsize,sum_itemsize,want", [
+    (2048, 2048, 100352, 2, 2, (1024, 1024, 1024, 1024, 512)),  # OLMo-2
+    (2048, 4096, 32768, 2, 2, (1024, 1024, 1024, 1024, 512)),   # Mistral
+    (2048, 4096, 32768, 2, 4, (1024, 1024, 1024, 1024, 256)),   # f32 sum
+    (2048, 4096, 128256, 2, 2, (1024, 256, 1024, 256, 256)),    # 2^8 x 501
+    (256, 128, 384, 4, 4, (256, 128, 256, 128, 128)),
+    (2048, 4096, 32768, 4, 4, None),    # head_dw's rows whole: 64 MiB
+    (8192, 4096, 32768, 2, 2, None),    # a scan_free chunk of 8192 rows
+    (2048, 4096, 32000, 2, 2, None),    # 32000 = 128 x 250: tiles; below
+    (2000, 4096, 32768, 2, 2, None),    # rows the lanes do not tile
+])
+def test_head_tiles_come_from_the_geometry(rows, h, v, itemsize,
+                                           sum_itemsize, want):
+    """Tiles are the largest 128-multiples that divide (rows, vocab)
+    and fit the VMEM budget at (hidden, itemsize); None sends the chunk
+    to the XLA body."""
+    from torchacc_tpu.ops import fused
+    got = fused._head_tiles(rows, h, v, itemsize, sum_itemsize)
+    if (rows, v) == (2048, 32000):
+        # 128 divides 32000: tiled by 256-column tiles (32000 = 2^8 x 125)
+        assert got == (1024, 256, 1024, 256, 256)
+        return
+    assert got == want
+    if got:
+        fr, fv, xr, xv, wv = got
+        assert rows % fr == rows % xr == v % fv == v % xv == v % wv == 0
+
+
+def test_trainer_says_which_head_it_traced(devices):
+    """``traced the loss … head=whole|sharded kernels=pallas|xla``, once
+    a program, and ``Trainer.head_impl`` beside ``head_rows``."""
+    import logging
+
+    import optax
+
+    from torchacc_tpu.utils.logger import logger
+    mc = get_preset("llama-tiny", vocab_size=128, hidden_size=64,
+                    num_layers=1, num_heads=4, num_kv_heads=2,
+                    intermediate_size=128, dtype=jnp.float32)
+    lines = []
+    handler = logging.Handler(level=logging.INFO)
+    handler.emit = lambda record: lines.append(record.getMessage())
+    level = logger.level
+    logger.addHandler(handler)
+    logger.setLevel(logging.INFO)
+    try:
+        t, _ = accelerate(mc, None, ta.Config(), optimizer=optax.sgd(0.1))
+        assert t.head_impl is None and t.head_rows is None
+        t.init()
+        batch = {"input_ids": np.zeros((8, 32), np.int32)}
+        t.step(batch)
+        t.step(batch)
+    finally:
+        logger.removeHandler(handler)
+        logger.setLevel(level)
+    said = [ln for ln in lines if ln.startswith("traced the loss")]
+    assert len(said) == 1 and said[0].endswith(
+        f"head={t.head_rows} kernels=xla"), said
+    assert t.head_impl == "xla"             # the CPU backend

@@ -129,8 +129,8 @@ SCOPES: Dict[str, str] = {
     "flash_dq": "flash-attention backward kernel, dq",
     "flash_dkv": "flash-attention backward kernel, dk and dv",
     "fused_ce": "chunked head matmul + cross-entropy: the chunk loop "
-                "that forms the loss and its gradients, and the "
-                "backward's scaling of them",
+                "(head_fwd / head_dx / head_dw kernels or XLA fusions) that "
+                "forms the loss and its gradients, and the backward's scaling",
     "optimizer": "gradient norm, clipping, optimizer update and "
                  "parameter apply",
     "mla_q": "latent attention: query projections, rope and (serving) "

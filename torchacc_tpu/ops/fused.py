@@ -13,17 +13,32 @@ forward's chunk loop computes dlogits, d(hidden) and dW from each
 chunk's block and saves d(hidden) and one [hidden, vocab] dW; the
 backward scales them by the loss's cotangent and recomputes nothing
 (peak memory: O(chunk x vocab) logits plus that one accumulator).
+On a TPU a chunk's three matmuls are Pallas kernels (``head_fwd``,
+``head_dx``, ``head_dw``) wherever the call holds one device's arrays;
+the XLA body beside them is every other path and their reference.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from torchacc_tpu.ops._common import ambient_mesh, batch_axes, scoped
+from torchacc_tpu.ops._common import (
+    NEG_INF,
+    ambient_mesh,
+    batch_axes,
+    interpret_mode as _interpret,
+    needs_shard_map,
+    on_tpu,
+    scoped,
+)
+from torchacc_tpu.ops.flash_attention import _LANES, _across_lanes
 
 
 def _scan_free_chunk(n: int, chunk_rows: int) -> int:
@@ -84,6 +99,286 @@ def _head_chunk(xi, yi, w, logit_softcap: float, with_grads: bool):
     return (loss, count, dw), dx.astype(xi.dtype)
 
 
+# ---------------------------------------------------------------------------
+# The chunk as Pallas kernels.  XLA's three fusions above run the MXU at
+# its peak and then wait for their side traffic (the float32 logits
+# written and read three times, the dW accumulator read and written:
+# ~4 GB a 2048-row chunk at a 100k vocabulary).  A kernel's blocks are
+# double-buffered, so that traffic goes behind the matmul, and the
+# softmax's sums and dlogits are made in VMEM from the tile the MXU
+# just produced or is about to consume.
+# ---------------------------------------------------------------------------
+
+# the most VMEM a head kernel asks of the compiler (its scoped default is
+# 16 MiB of a v5e's 128): the chunk's rows resident beside double-buffered
+# float32 logits tiles
+_HEAD_VMEM_LIMIT = 100 * 1024 * 1024
+# what ``_head_tiles`` lets a kernel's blocks and tiles take of it
+_HEAD_VMEM_BUDGET = 72 * 1024 * 1024
+# ignored rows enter the backward kernels with this as their lse:
+# exp(z - lse) is 0 there and their label (-100) matches no column, so
+# dlogits is 0 without a select
+_LSE_IGNORED = 1e30
+
+
+def _label_hits(y, v0, shape):
+    """Where the column of a ``shape`` logits tile starting at vocabulary
+    id ``v0`` is the row's label (``y`` lane-broadcast [rows, 128])."""
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return col == _across_lanes(y - v0, shape[1])
+
+
+def _head_fwd_kernel(x_ref, w_ref, y_ref, z_ref, lse_ref, ll_ref,
+                     m_scr, l_scr, ll_scr, *, tv, nv):
+    """One vocabulary tile of one row tile: the float32 logits tile out,
+    the running max and sum (online logsumexp) and the label's logit by
+    the masked compare carried lane-broadcast across the tiles."""
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        ll_scr[...] = jnp.zeros_like(ll_scr)
+
+    z = jnp.dot(x_ref[...], w_ref[...], preferred_element_type=jnp.float32)
+    z_ref[...] = z
+    m_prev = m_scr[...]
+    m_new = jnp.maximum(m_prev, jnp.max(z, axis=1, keepdims=True))
+    p = jnp.exp(z - _across_lanes(m_new, tv))
+    l_scr[...] = (jnp.exp(m_prev - m_new) * l_scr[...]
+                  + jnp.sum(p, axis=1, keepdims=True))
+    m_scr[...] = m_new
+    hit = _label_hits(y_ref[...], j * tv, z.shape)
+    ll_scr[...] += jnp.sum(jnp.where(hit, z, 0.0), axis=1, keepdims=True)
+
+    @pl.when(j == nv - 1)
+    def _finalize():
+        lse_ref[...] = m_scr[...] + jnp.log(l_scr[...])
+        ll_ref[...] = ll_scr[...]
+
+
+def _dlogits(z_ref, lse_ref, y_ref, v0, dtype):
+    """softmax - onehot of a logits tile (0 on ignored rows by their
+    lse, ``_LSE_IGNORED``), rounded to the model dtype as default
+    precision rounds the float32 dlogits that meets a model-dtype
+    operand."""
+    z = z_ref[...]
+    p = jnp.exp(z - _across_lanes(lse_ref[...], z.shape[1]))
+    hit = _label_hits(y_ref[...], v0, z.shape)
+    return jnp.where(hit, p - 1.0, p).astype(dtype)
+
+
+def _head_dx_kernel(z_ref, lse_ref, y_ref, w_ref, dx_ref, acc_scr, *,
+                    tv, nv):
+    """d(hidden) of one row tile, summed over the vocabulary tiles:
+    dlogits [rows, tv] against w[:, tile] [h, tv], the lanes of both
+    contracted (the MXU takes the transposed operand as it is pushed:
+    no w^T array, no tile transposed)."""
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    dl = _dlogits(z_ref, lse_ref, y_ref, j * tv, w_ref.dtype)
+    acc_scr[...] += jax.lax.dot_general(
+        dl, w_ref[...], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+    @pl.when(j == nv - 1)
+    def _finalize():
+        dx_ref[...] = acc_scr[...].astype(dx_ref.dtype)
+
+
+def _head_dw_kernel(xt_ref, z_ref, lse_ref, y_ref, sum_ref, dw_ref, *, tv):
+    """One vocabulary tile of dW over the chunk's rows, added to the sum
+    of the chunks before (aliased in and out): x^T [h, rows] @ dlogits
+    [rows, tv], the sum rounded once to its dtype."""
+    j = pl.program_id(0)
+    dl = _dlogits(z_ref, lse_ref, y_ref, j * tv, xt_ref.dtype)
+    dw = jnp.dot(xt_ref[...], dl, preferred_element_type=jnp.float32)
+    dw_ref[...] = (sum_ref[...].astype(jnp.float32) + dw).astype(
+        dw_ref.dtype)
+
+
+def _head_tiles(rows: int, h: int, v: int, itemsize: int,
+                sum_itemsize: int):
+    """(fwd row tile, fwd vocab tile, dx row tile, dx vocab tile, dW vocab
+    tile) of the head kernels for a chunk of ``rows`` against a ``[h, v]``
+    head of ``itemsize``-byte elements, dW summed in ``sum_itemsize``-byte
+    ones: the largest 128-multiples that divide them, up to the size past
+    which a grid step's ~0.35 us no longer shows, whose blocks
+    (double-buffered) and float32 tiles fit ``_HEAD_VMEM_BUDGET``; None
+    where nothing divides or fits (``head_dw`` holds the chunk's rows
+    whole)."""
+    if rows % _LANES or h % _LANES or v % _LANES:
+        return None
+
+    def tiles(n, most):
+        return [t for t in (2048, 1024, 512, 256, 128)
+                if t <= most and n % t == 0]
+
+    def largest(pairs, vmem):
+        fit = [p for p in pairs if vmem(*p) <= _HEAD_VMEM_BUDGET]
+        return max(fit, key=lambda p: (p[0] * p[1], p[0]), default=None)
+
+    it = itemsize
+    square = [(r, t) for r in tiles(rows, 1024) for t in tiles(v, 1024)]
+    fwd = largest(
+        square, lambda r, t: 2 * it * (r * h + h * t) + 3 * 4 * r * t)
+    dx = largest(
+        square,
+        lambda r, t: (2 * 4 * r * t + 2 * it * t * h + (4 + 2 * it) * r * h
+                      + (4 + it) * r * t))
+    dw = largest(
+        [(rows, t) for t in tiles(v, 512)],
+        lambda r, t: (2 * it * h * r + 2 * 4 * r * t
+                      + (4 * sum_itemsize + 4) * h * t + (4 + it) * r * t))
+    if not (fwd and dx and dw):
+        return None
+    return (*fwd, *dx, dw[1])
+
+
+def _params(*semantics):
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=_HEAD_VMEM_LIMIT)
+
+
+def _head_fwd(xi, w, y, fr, fv):
+    """(float32 logits [rows, V], lse, the label's logit; the last two
+    lane-broadcast [rows, 128]) of a chunk: ``head_fwd``."""
+    rows, h = xi.shape
+    v = w.shape[1]
+    stat = jax.ShapeDtypeStruct((rows, _LANES), jnp.float32)
+    row_stat = pl.BlockSpec((fr, _LANES), lambda i, j: (i, 0))
+    return pl.pallas_call(
+        functools.partial(_head_fwd_kernel, tv=fv, nv=v // fv),
+        grid=(rows // fr, v // fv),
+        in_specs=[
+            pl.BlockSpec((fr, h), lambda i, j: (i, 0)),
+            pl.BlockSpec((h, fv), lambda i, j: (0, j)),
+            row_stat,
+        ],
+        out_specs=[pl.BlockSpec((fr, fv), lambda i, j: (i, j)),
+                   row_stat, row_stat],
+        out_shape=[jax.ShapeDtypeStruct((rows, v), jnp.float32), stat, stat],
+        scratch_shapes=[pltpu.VMEM((fr, _LANES), jnp.float32)] * 3,
+        compiler_params=_params("parallel", "arbitrary"),
+        interpret=_interpret(),
+        name="head_fwd",
+    )(xi, w, y)
+
+
+def _head_dx(z, lse, y, w, dtype, xr, xv):
+    """d(hidden) [rows, h] of a chunk from its logits: ``head_dx``."""
+    rows, v = z.shape
+    h = w.shape[0]
+    row_stat = pl.BlockSpec((xr, _LANES), lambda i, j: (i, 0))
+    return pl.pallas_call(
+        functools.partial(_head_dx_kernel, tv=xv, nv=v // xv),
+        grid=(rows // xr, v // xv),
+        in_specs=[
+            pl.BlockSpec((xr, xv), lambda i, j: (i, j)),
+            row_stat, row_stat,
+            pl.BlockSpec((h, xv), lambda i, j: (0, j)),
+        ],
+        out_specs=pl.BlockSpec((xr, h), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, h), dtype),
+        scratch_shapes=[pltpu.VMEM((xr, h), jnp.float32)],
+        compiler_params=_params("parallel", "arbitrary"),
+        interpret=_interpret(),
+        name="head_dx",
+    )(z, lse, y, w)
+
+
+def _head_dw(xt, z, lse, y, dw_sum, wv):
+    """``dw_sum`` + the chunk's dW, in ``dw_sum``'s buffer: ``head_dw``."""
+    h, rows = xt.shape
+    v = z.shape[1]
+    whole = pl.BlockSpec((rows, _LANES), lambda j: (0, 0))
+    tile = pl.BlockSpec((h, wv), lambda j: (0, j))
+    return pl.pallas_call(
+        functools.partial(_head_dw_kernel, tv=wv),
+        grid=(v // wv,),
+        in_specs=[
+            pl.BlockSpec((h, rows), lambda j: (0, 0)),
+            pl.BlockSpec((rows, wv), lambda j: (0, j)),
+            whole, whole, tile,
+        ],
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct(dw_sum.shape, dw_sum.dtype),
+        input_output_aliases={4: 0},
+        compiler_params=_params("arbitrary"),
+        interpret=_interpret(),
+        name="head_dw",
+    )(xt, z, lse, y, dw_sum)
+
+
+def _head_chunk_kernels(xi, yi, w, dw_sum, tiles):
+    """``_head_chunk`` with its gradients, as three kernels: returns
+    ``(loss_sum, valid_count, dw_sum + the chunk's dW)`` and dx."""
+    fr, fv, xr, xv, wv = tiles
+    y = jnp.broadcast_to(yi.astype(jnp.int32)[:, None],
+                         (yi.shape[0], _LANES))
+    z, lse, ll = _head_fwd(xi, w, y, fr, fv)
+    valid = yi != -100
+    loss = jnp.sum(jnp.where(valid, lse[:, 0] - ll[:, 0], 0.0))
+    count = jnp.sum(valid).astype(jnp.float32)
+    lse = jnp.where(valid[:, None], lse, _LSE_IGNORED)
+    dx = _head_dx(z, lse, y, w, xi.dtype, xr, xv)
+    dw_sum = _head_dw(xi.T, z, lse, y, dw_sum, wv)
+    return (loss, count, dw_sum), dx
+
+
+def _kernel_tiles(rows: int, h: int, v: int, dtype, sum_dtype,
+                  logit_softcap: float, per_device: bool):
+    """The kernels' tiles where a chunk of ``rows`` runs as kernels, else
+    None (``_head_chunk``'s XLA body): the backend is a TPU, the arrays
+    at the call are one device's (``per_device``: a Mosaic kernel is
+    not the partitioner's to split), the model dtype is bfloat16 or
+    float32, the logits are not capped (the kernels take no tanh) and
+    the tiles divide the chunk."""
+    if (not on_tpu() or not per_device or logit_softcap > 0.0
+            or dtype not in (jnp.bfloat16, jnp.float32)):
+        return None
+    return _head_tiles(rows, h, v, jnp.dtype(dtype).itemsize,
+                       jnp.dtype(sum_dtype).itemsize)
+
+
+def _region_axes(mesh, axes) -> frozenset:
+    """The mesh axes the head's ``shard_map`` over the row axes ``axes``
+    makes manual: with them every other axis where all of those have
+    extent 1 — nothing else is sharded there, and a region manual over
+    the whole mesh holds one device's arrays, which the kernels need
+    (``_common.needs_shard_map``); else ``axes`` alone, tp / sp left to
+    the partitioner."""
+    rest = [a for a in mesh.axis_names if a not in axes]
+    if all(mesh.shape[a] == 1 for a in rest):
+        return frozenset(mesh.axis_names)
+    return frozenset(axes)
+
+
+def head_impl(hidden, w_head, *, chunk_rows: int = 2048,
+              logit_softcap: float = 0.0, scan_free: bool = False) -> str:
+    """'pallas' | 'xla': what runs a chunk of the differentiated
+    ``fused_linear_cross_entropy(hidden, w_head, ...)`` under the
+    ambient mesh, read at trace time from the arrays' shapes and dtypes
+    as ``_head_rows`` and ``_head_chunks`` read it."""
+    b, s, h = hidden.shape
+    mesh = ambient_mesh()
+    axes = head_row_axes(b)
+    n = b * s
+    per_device = not needs_shard_map(mesh)
+    if axes:
+        n //= math.prod(mesh.shape[a] for a in axes)
+        per_device = _region_axes(mesh, axes) == frozenset(mesh.axis_names)
+    rows = _scan_free_chunk(n, chunk_rows) if scan_free else chunk_rows
+    tiles = _kernel_tiles(rows, h, w_head.shape[1], hidden.dtype,
+                          w_head.dtype, logit_softcap, per_device)
+    return "pallas" if tiles else "xla"
+
+
 def _head_chunks(hidden, w_head, labels, chunk_rows: int,
                  logit_softcap: float, scan_free: bool, with_grads: bool):
     """Drive ``_head_chunk`` over the rows, ``chunk_rows`` at a time, by
@@ -127,6 +422,9 @@ def _head_chunks(hidden, w_head, labels, chunk_rows: int,
     xc = x.reshape(chunks, chunk_rows, h)
     yc = y.reshape(chunks, chunk_rows)
     w = w_head.astype(x.dtype)
+    tiles = _kernel_tiles(
+        chunk_rows, h, w.shape[1], x.dtype, w_head.dtype, logit_softcap,
+        not needs_shard_map(ambient_mesh())) if with_grads else None
 
     # the sums ride the loop's carry; the chunks' dx are stacked
     zero = jnp.zeros((), jnp.float32)
@@ -135,6 +433,15 @@ def _head_chunks(hidden, w_head, labels, chunk_rows: int,
         acc += (jnp.zeros(w.shape, w_head.dtype),)
 
     def body(acc, xy):
+        if tiles:       # the dW sum goes through head_dw, in place
+            (loss, count, dw), dx = _head_chunk_kernels(
+                *xy, w, acc[2], tiles)
+            # kept apart from the loop's stacking of dx: fused into
+            # head_dx (XLA writes a kernel's result into the stack in
+            # place where it can) the kernel is held to the fusion's
+            # 16 MiB of VMEM, not to the limit it states
+            dx = jax.lax.optimization_barrier(dx)
+            return (acc[0] + loss, acc[1] + count, dw), dx
         sums, dx = _head_chunk(*xy, w, logit_softcap, with_grads)
         return tuple(a + s.astype(a.dtype) for a, s in zip(acc, sums)), dx
 
@@ -216,7 +523,7 @@ def _head_rows(hidden, w_head, labels, chunk_rows: int,
         out_specs += (rows, P(scatter))
     return jax.shard_map(
         local, mesh=mesh, in_specs=(rows, P(), rows), out_specs=out_specs,
-        axis_names=frozenset(axes), check_vma=False,
+        axis_names=_region_axes(mesh, axes), check_vma=False,
     )(hidden, w_head, labels)
 
 
@@ -268,6 +575,27 @@ def fused_linear_cross_entropy(
     loss_sum.  Without differentiation (eval) it is the chunk loop
     alone: one matmul a chunk.  Peak memory is O(chunk x vocab) for the
     logits plus one [H, V] accumulator.
+
+    What runs a differentiated chunk is read at trace time too
+    (``head_impl`` says which; no option chooses).  Three Pallas
+    kernels (``_head_chunk_kernels``) where the backend is a TPU, the
+    arrays at the call are one device's — one device, or the head's
+    ``shard_map`` manual over the whole mesh, which it is wherever the
+    row axes are all the mesh shards — the model dtype is bfloat16 or
+    float32, ``logit_softcap`` is 0 and 128-multiples tile the chunk:
+    ``head_fwd`` sweeps the vocabulary tiles once (the float32 logits
+    out, the row max, sum and label logit online, in VMEM), ``head_dx``
+    and ``head_dw`` each rebuild dlogits from a logits tile in their
+    prologue and are plain tiled matmuls (``head_dx`` contracts the
+    lanes of dlogits and of the ``w_head`` tile, ``head_dw`` takes the
+    chunk's transpose, made once a chunk), dW added into
+    the chunks' sum through one aliased buffer and rounded once; every
+    large array crosses HBM once per use, double-buffered behind the
+    MXU.  ``_head_chunk``'s XLA body — three fusions that each pay
+    their side traffic on top of their matmul — everywhere else: the
+    CPU, a ``tp`` / ``sp`` extent left to the partitioner, the 1F1B
+    tick's partly manual region, Gemma-2's softcap, eval; it is also
+    the kernels' reference in the tests.
     chunk_rows=2048 measured best on v5e (1024 costs ~1.5 MFU points on
     the 32k-vocab bench; 4096 is equal but doubles the chunk buffer).
     ``logit_softcap`` > 0 applies Gemma2's c * tanh(logits / c) before

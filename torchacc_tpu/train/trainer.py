@@ -563,11 +563,15 @@ class Trainer:
                 logit_softcap=self.model.cfg.logit_softcap)
             rows = ("sharded" if fused.head_row_axes(hidden.shape[0])
                     else "whole")
-            if rows != self.head_rows:      # trace time: once a program
-                self.head_rows = rows
+            impl = fused.head_impl(
+                hidden, w_head, logit_softcap=self.model.cfg.logit_softcap)
+            if (rows, impl) != (self.head_rows, self.head_impl):
+                # trace time: once a program
+                self.head_rows, self.head_impl = rows, impl
                 logger.info(f"traced the loss on mesh "
                             f"{dict(self.mesh.shape)} "
-                            f"layers={self.layer_loop} head={rows}")
+                            f"layers={self.layer_loop} head={rows} "
+                            f"kernels={impl}")
         else:
             out = self.model.apply(
                 variables, batch["input_ids"],
@@ -593,6 +597,9 @@ class Trainer:
     # its model.apply call are serialized into every Pallas kernel's
     # body, so a line added above it changes each program's bytes
     head_rows: Optional[str] = None
+    # 'pallas' | 'xla': what ran a chunk of that head (ops/fused.head_impl
+    # reads the backend, the mesh and the shapes the same way)
+    head_impl: Optional[str] = None
 
     def _build_train_step(self, sample_batch, donate: bool = True):
         accum = self.config.grad_accum
