@@ -234,7 +234,7 @@ def _live_pool_check(engine):
     sched = engine.scheduler
     cfg = engine.cfg
     chunk = sched.serve_cfg.prefill_chunk
-    kp, vp = sched.pools
+    kp, vp = sched.pools["k"], sched.pools["v"]
     layer = cfg.num_layers - 1
     active = np.flatnonzero(sched.active)
     worst = 0.0
@@ -266,13 +266,14 @@ def _program_kernels(engine) -> dict:
     pools = sched.pools
     tables, active, temp, top_k, top_p = sched._dev_stable_arrays()
     decode = dec._decode.lower(
-        sched.params, pools, sched.carry, tables, jnp.array(sched.seq_lens),
-        active, temp, top_k, top_p, True).compile().as_text()
+        sched.params, pools, sched.carry, {"blocks": tables},
+        jnp.array(sched.seq_lens), active, temp, top_k, top_p,
+        True).compile().as_text()
     i32 = lambda x: jnp.asarray(x, jnp.int32)  # noqa: E731
     chunk = sched.serve_cfg.prefill_chunk
     prefill = dec._prefill.lower(
-        sched.params, pools, tables[0], i32(0), jnp.zeros((chunk,), jnp.int32),
-        i32(1), True).compile().as_text()
+        sched.params, pools, {"blocks": tables[0]}, i32(0),
+        jnp.zeros((chunk,), jnp.int32), i32(1), True).compile().as_text()
     return {"decode": decode.count("tpu_custom_call"),
             "prefill": prefill.count("tpu_custom_call")}
 

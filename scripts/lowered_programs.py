@@ -10,16 +10,22 @@ Written per program: ``<name>.txt``, ``lowered.as_text()`` with the
 process-wide numeric suffixes of private symbols normalised (``@name_N``);
 ``<name>.scope_paths``, the set of named-scope paths ops sit under, and
 ``<name>.scopes``, the op-name paths themselves (both from
-``as_text(debug_info=True)``, traceback frames left out).  Programs:
+``as_text(debug_info=True)``, traceback frames left out); for the serve
+programs on the XLA path also ``<name>.compiled_ops``, the opcode counts
+of the module the CPU backend compiles from it.  Programs:
 ``Trainer``'s train step on Mistral- and OLMo-2-shaped toys with the
 benchmark's ``dense4k`` settings; ``jit(grad(loss))`` over block shapes;
 the A.X-K1 toy's forward; ``PagedDecoder._decode/_prefill/_prefill_batch``
-on Mistral- and A.X-K1-shaped toys.  Beside them ``init_digests.json`` /
+on a toy of each served family (Mistral- and A.X-K1-shaped ones from the
+module's init; the dots3-, K-EXAONE- and Nemotron-shaped toys their test
+files build, parameters in the benchmark's layout).  Beside them
+``init_digests.json`` /
 ``preset_digests.json`` (parameter paths, shapes, dtypes and value sums for
 a fixed key: the block shapes, and every preset at toy widths) and
 ``generate.<shape>.tokens``.  CPU only: it says what the programs are,
 never how fast.
 """
+import collections
 import dataclasses
 import json
 import os
@@ -77,6 +83,19 @@ def write(name, lowered):
     with open(os.path.join(OUT, name + ".scope_paths"), "w") as f:
         f.write("\n".join(paths) + "\n")
     print(name, len(text), len(scopes), flush=True)
+
+
+HLO_OP = re.compile(r"^\s*(?:ROOT )?\S+ = \S+ ([a-z][a-z0-9-]*)\(", re.M)
+
+
+def write_compiled_ops(name, lowered):
+    """``<name>.compiled_ops``: how many instructions of each opcode the
+    CPU backend's OPTIMIZED module holds — what is left of a difference
+    in the lowered text (a ``0 + n * 1`` on a loop counter, the order two
+    independent values are computed in) once XLA has simplified it."""
+    ops = collections.Counter(HLO_OP.findall(lowered.compile().as_text()))
+    with open(os.path.join(OUT, name + ".compiled_ops"), "w") as f:
+        f.write("".join(f"{op} {n}\n" for op, n in sorted(ops.items())))
 
 
 def digest(params):
@@ -214,45 +233,87 @@ with open(os.path.join(OUT, "preset_digests.json"), "w") as f:
     json.dump(presets, f, indent=0, sort_keys=True)
 print("presets", len(presets), flush=True)
 
-# -- (b) the serve programs --------------------------------------------------
+# -- (b) the serve programs: one toy a served family ------------------------
 mistral = get_preset("llama-tiny", num_layers=2, hidden_size=128, num_heads=4,
                      num_kv_heads=2, intermediate_size=256, vocab_size=512,
                      max_seq_len=256, dtype=jnp.bfloat16,
                      param_dtype=jnp.bfloat16)
 SERVE = dict(block_size=16, num_blocks=64, max_slots=4, prefill_chunk=16,
              prefill_batch=2)
-for tag, mc in (("mistral", mistral), ("axk1", axk1)):
+
+
+def module_params(mc):
+    return jax.eval_shape(
+        lambda k: TransformerLM(mc).init(
+            k, jnp.zeros((1, 8), jnp.int32))["params"],
+        jax.random.PRNGKey(0))
+
+
+def test_toy(test_file):
+    """``(ModelConfig, abstract params, serve settings)`` of the toy a
+    family's test file builds: its published config through
+    ``config_from_hf``, its parameters from the benchmark's weights in
+    the benchmark's layout (the module's own init refuses these
+    families)."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        test_file[:-3], os.path.join("tests", test_file))
+    toy = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(toy)
+    mc = toy.model_config(toy.TOY)
+    params = jax.eval_shape(
+        lambda k: toy.layout.to_program_params(
+            toy.weights.make(k, toy.TOY, toy.DEPTH, jnp.float32), mc),
+        toy.weights.base_key(5))
+    return mc, params, dict(toy.SERVE, prefill_batch=2)
+
+
+FAMILIES = [
+    ("mistral", mistral, module_params(mistral), SERVE),
+    ("axk1", axk1, module_params(axk1), SERVE),
+    ("dots3", *test_toy("test_sparse_window_serving.py")),
+    ("kexaone", *test_toy("test_window_gqa_serving.py")),
+    ("nemotron", *test_toy("test_ssm_serving.py")),
+]
+for tag, mc, params, serve in FAMILIES:
     for impl in ("xla", "pallas"):
-        sc = ServeConfig(**SERVE)
+        sc = ServeConfig(**serve)
         dec = PagedDecoder(mc, sc, impl)
         sds = jax.ShapeDtypeStruct
-        params = jax.eval_shape(
-            lambda k, mc=mc: TransformerLM(mc).init(
-                k, jnp.zeros((1, 8), jnp.int32))["params"],
-            jax.random.PRNGKey(0))
         pools = jax.eval_shape(lambda: make_pools(mc, sc))
         s = sc.max_slots
         mb = min(sc.num_blocks - 1,
                  blocks_needed(mc.max_seq_len + sc.decode_depth,
                                sc.block_size))
         i32, f32 = jnp.int32, jnp.float32
-        carry = {"tok": sds((s,), i32), "key": sds((s, 2), jnp.uint32)}
-        for greedy in (True, False):
-            write(f"serve.{tag}.{impl}.decode.greedy{int(greedy)}",
-                  dec._decode.lower(
-                      params, pools, carry, sds((s, mb), i32), sds((s,), i32),
-                      sds((s,), jnp.bool_), sds((s,), f32), sds((s,), i32),
-                      sds((s,), f32), greedy))
-        for final in (False, True):
-            write(f"serve.{tag}.{impl}.prefill.final{int(final)}",
-                  dec._prefill.lower(
-                      params, pools, sds((mb,), i32), sds((), i32),
-                      sds((sc.prefill_chunk,), i32), sds((), i32), final))
         pb = sc.prefill_batch
-        write(f"serve.{tag}.{impl}.prefill_batch",
-              dec._prefill_batch.lower(
-                  params, pools, sds((pb, mb), i32), sds((pb,), i32),
-                  sds((pb, sc.prefill_chunk), i32), sds((pb,), i32)))
+        # the steps' addressing pytree: every table by name and, in a
+        # prefill of a model that keeps state by slot, the slot
+        tables = ["blocks"] + ["window"] * bool(mc.layer_pattern)
+        slot = bool(mc.mixer_pattern)
+
+        def addr(*rows, prefill=True):
+            return {**{name: sds(rows + (mb,), i32) for name in tables},
+                    **({"slot": sds(rows, i32)} if slot and prefill else {})}
+
+        carry = {"tok": sds((s,), i32), "key": sds((s, 2), jnp.uint32)}
+        programs = {}
+        for greedy in (True, False):
+            programs[f"decode.greedy{int(greedy)}"] = dec._decode.lower(
+                params, pools, carry, addr(s, prefill=False),
+                sds((s,), i32), sds((s,), jnp.bool_), sds((s,), f32),
+                sds((s,), i32), sds((s,), f32), greedy)
+        for final in (False, True):
+            programs[f"prefill.final{int(final)}"] = dec._prefill.lower(
+                params, pools, addr(), sds((), i32),
+                sds((sc.prefill_chunk,), i32), sds((), i32), final)
+        programs["prefill_batch"] = dec._prefill_batch.lower(
+            params, pools, addr(pb), sds((pb,), i32),
+            sds((pb, sc.prefill_chunk), i32), sds((pb,), i32))
+        for name, lowered in programs.items():
+            write(f"serve.{tag}.{impl}.{name}", lowered)
+            if impl == "xla":
+                write_compiled_ops(f"serve.{tag}.{impl}.{name}", lowered)
 
 # -- generate()'s cached programs (the dense-cache branch of Attention) -----
 from torchacc_tpu.models.generate import generate  # noqa: E402

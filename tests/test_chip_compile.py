@@ -254,7 +254,7 @@ def _serve_program(name, one_chip):
             lambda k: TransformerLM(mc).init(
                 k, jnp.zeros((1, 8), jnp.int32))["params"],
             jax.random.PRNGKey(0)))
-    pool = jax.eval_shape(lambda: make_pools(mc, sc)[0])
+    pool = jax.eval_shape(lambda: make_pools(mc, sc)["k"])
     pool = sds(pool.shape, pool.dtype)
     s = sc.max_slots
     mb = min(sc.num_blocks - 1,
@@ -263,12 +263,14 @@ def _serve_program(name, one_chip):
     if name == "decode":
         carry = {"tok": sds((s,), i32), "key": sds((s, 2), jnp.uint32)}
         lowered = decoder._decode.lower(
-            params, (pool, pool), carry, sds((s, mb), i32), sds((s,), i32),
+            params, {"k": pool, "v": pool}, carry,
+            {"blocks": sds((s, mb), i32)}, sds((s,), i32),
             sds((s,), jnp.bool_), sds((s,), f32), sds((s,), i32),
             sds((s,), f32), True)
     else:
         lowered = decoder._prefill.lower(
-            params, (pool, pool), sds((mb,), i32), sds((), i32),
+            params, {"k": pool, "v": pool}, {"blocks": sds((mb,), i32)},
+            sds((), i32),
             sds((sc.prefill_chunk,), i32), sds((), i32),
             name == "prefill_final_chunk")
     return lowered, pool
@@ -407,11 +409,11 @@ def _axk1_program(name, one_chip):
     if name == "decode":
         carry = {"tok": sds((s,), i32), "key": sds((s, 2), jnp.uint32)}
         return decoder._decode.lower(
-            params, pools, carry, sds((s, mb), i32), sds((s,), i32),
-            sds((s,), jnp.bool_), sds((s,), f32), sds((s,), i32),
-            sds((s,), f32), True), pools
+            params, pools, carry, {"blocks": sds((s, mb), i32)},
+            sds((s,), i32), sds((s,), jnp.bool_), sds((s,), f32),
+            sds((s,), i32), sds((s,), f32), True), pools
     return decoder._prefill.lower(
-        params, pools, sds((mb,), i32), sds((), i32),
+        params, pools, {"blocks": sds((mb,), i32)}, sds((), i32),
         sds((sc.prefill_chunk,), i32), sds((), i32),
         name == "prefill_final_chunk"), pools
 
@@ -433,7 +435,8 @@ def test_latent_serve_program_holds_one_latent_pool(one_chip, for_the_chip,
     activations: 4,096 pairs x 7168 bf16 = 56 MiB and the like).  With
     the stacks on the scan's ``xs`` the same compile read 338 / 401 /
     401 MiB (sandbox compile, PR 27)."""
-    lowered, (pool,) = _axk1_program(name, one_chip)
+    lowered, pools = _axk1_program(name, one_chip)
+    (pool,) = pools.values()
     compiled = lowered.compile()
     mem, text = compiled.memory_analysis(), compiled.as_text()
     pool_bytes = pool.size * pool.dtype.itemsize
@@ -718,13 +721,15 @@ def _dots3_program(name, one_chip):
     if name == "decode":
         carry = {"tok": sds((s,), i32), "key": sds((s, 2), jnp.uint32)}
         return decoder._decode.lower(
-            params, pools, carry, sds((s, mb), i32), sds((s,), i32),
-            sds((s,), jnp.bool_), sds((s,), f32), sds((s,), i32),
-            sds((s,), f32), True, sds((s, mb), i32)), pools, mb
+            params, pools, carry,
+            {"blocks": sds((s, mb), i32), "window": sds((s, mb), i32)},
+            sds((s,), i32), sds((s,), jnp.bool_), sds((s,), f32),
+            sds((s,), i32), sds((s,), f32), True), pools, mb
     return decoder._prefill.lower(
-        params, pools, sds((mb,), i32), sds((), i32),
+        params, pools,
+        {"blocks": sds((mb,), i32), "window": sds((mb,), i32)}, sds((), i32),
         sds((sc.prefill_chunk,), i32), sds((), i32),
-        name == "prefill_final_chunk", sds((mb,), i32)), pools, mb
+        name == "prefill_final_chunk"), pools, mb
 
 
 @pytest.mark.parametrize("name",
@@ -751,8 +756,10 @@ def test_sparse_window_serve_program_holds_three_pools_in_place(
     lowered, pools, mb = _dots3_program(name, one_chip)
     compiled = lowered.compile()
     mem, text = compiled.memory_analysis(), compiled.as_text()
-    assert [p.shape for p in pools] == [
-        (3, 2081, 128, 640), (3, 2081, 128, 128), (6, 81, 128, 1152)]
+    assert {name: p.shape for name, p in pools.items()} == {
+        "latent": (3, 2081, 128, 640), "index": (3, 2081, 128, 128),
+        "latent_win": (6, 81, 128, 1152)}
+    pools = list(pools.values())
     assert mem.alias_size_in_bytes >= sum(
         p.size * p.dtype.itemsize for p in pools)
     assert mem.temp_size_in_bytes < (32 if name == "decode" else 640) * 2**20
@@ -927,13 +934,15 @@ def _kexaone_program(name, one_chip):
     if name == "decode":
         carry = {"tok": sds((s,), i32), "key": sds((s, 2), jnp.uint32)}
         return decoder._decode.lower(
-            params, pools, carry, sds((s, mb), i32), sds((s,), i32),
-            sds((s,), jnp.bool_), sds((s,), f32), sds((s,), i32),
-            sds((s,), f32), True, sds((s, mb), i32)), pools, mb
+            params, pools, carry,
+            {"blocks": sds((s, mb), i32), "window": sds((s, mb), i32)},
+            sds((s,), i32), sds((s,), jnp.bool_), sds((s,), f32),
+            sds((s,), i32), sds((s,), f32), True), pools, mb
     return decoder._prefill.lower(
-        params, pools, sds((mb,), i32), sds((), i32),
+        params, pools,
+        {"blocks": sds((mb,), i32), "window": sds((mb,), i32)}, sds((), i32),
         sds((sc.prefill_chunk,), i32), sds((), i32),
-        name == "prefill_final_chunk", sds((mb,), i32)), pools, mb
+        name == "prefill_final_chunk"), pools, mb
 
 
 @pytest.mark.parametrize("name",
@@ -968,8 +977,10 @@ def test_window_gqa_serve_program_holds_four_pools_in_place(
     assert sorted(g[2] for g in attention) == [walked] * 6 + [mb] * 2
     compiled = lowered.compile()
     mem, text = compiled.memory_analysis(), compiled.as_text()
-    assert [p.shape for p in pools] == [
-        (2, 2129, 128, 1024)] * 2 + [(6, 97, 128, 1024)] * 2
+    assert {name: p.shape for name, p in pools.items()} == {
+        "k": (2, 2129, 128, 1024), "v": (2, 2129, 128, 1024),
+        "k_win": (6, 97, 128, 1024), "v_win": (6, 97, 128, 1024)}
+    pools = [pools[name] for name in ("k", "v", "k_win", "v_win")]
     assert mem.alias_size_in_bytes >= sum(
         p.size * p.dtype.itemsize for p in pools)
     assert mem.temp_size_in_bytes < (32 if name == "decode" else 256) * 2**20
@@ -1060,13 +1071,13 @@ def _nemotron_program(name, one_chip):
     if name == "decode":
         carry = {"tok": sds((s,), i32), "key": sds((s, 2), jnp.uint32)}
         return decoder._decode.lower(
-            params, pools, carry, sds((s, mb), i32), sds((s,), i32),
-            sds((s,), jnp.bool_), sds((s,), f32), sds((s,), i32),
-            sds((s,), f32), True), params, pools
+            params, pools, carry, {"blocks": sds((s, mb), i32)},
+            sds((s,), i32), sds((s,), jnp.bool_), sds((s,), f32),
+            sds((s,), i32), sds((s,), f32), True), params, pools
     return decoder._prefill.lower(
-        params, pools, sds((mb,), i32), sds((), i32),
-        sds((sc.prefill_chunk,), i32), sds((), i32),
-        name == "prefill_final_chunk", slot=sds((), i32)), params, pools
+        params, pools, {"blocks": sds((mb,), i32), "slot": sds((), i32)},
+        sds((), i32), sds((sc.prefill_chunk,), i32), sds((), i32),
+        name == "prefill_final_chunk"), params, pools
 
 
 @pytest.mark.parametrize("name",
@@ -1089,9 +1100,10 @@ def test_ssm_serve_program_fits_the_chip_with_both_pools_in_place(
     lowered, params, pools = _nemotron_program(name, one_chip)
     compiled = lowered.compile()
     mem, text = compiled.memory_analysis(), compiled.as_text()
-    assert [p.shape for p in pools] == [
-        (6, 1296, 128, 256)] * 2 + [(23, 49, 18432), (23, 49, 64, 64, 128)]
-    pool_bytes = sum(p.size * p.dtype.itemsize for p in pools)
+    assert {name: p.shape for name, p in pools.items()} == {
+        "k": (6, 1296, 128, 256), "v": (6, 1296, 128, 256),
+        "conv": (23, 49, 18432), "ssm": (23, 49, 64, 64, 128)}
+    pool_bytes = sum(p.size * p.dtype.itemsize for p in pools.values())
     weight_bytes = sum(x.size * x.dtype.itemsize
                        for x in jax.tree.leaves(params))
     assert mem.alias_size_in_bytes >= pool_bytes

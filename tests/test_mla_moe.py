@@ -188,7 +188,8 @@ def test_serving_through_the_latent_pool_matches_the_reference(
     cfg.serve.max_slots, cfg.serve.prefill_chunk = 3, 12
     eng = ServeEngine(TransformerLM(dataclasses.replace(
         mc, attention_impl=impl)), params, cfg)
-    (pool,) = eng.scheduler.pools
+    (pool,) = eng.scheduler.pools.values()
+    assert list(eng.scheduler.pools) == ["latent"]
     assert pool.shape == (DEPTH, 64, 8, 128)     # 32 + 8 values -> 128 lanes
     rng = np.random.default_rng(1)
     prompts = [rng.integers(1, 256, size=n).tolist() for n in (5, 30, 17, 9)]

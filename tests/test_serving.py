@@ -290,9 +290,16 @@ def test_layer_write_leaves_other_layers_bit_identical(tiny, layer):
     blk = jnp.asarray([[9, 9, 9, 9], [7, 7, 7, 7]], jnp.int32)
     off = positions % sc.block_size
     p_l = jax.tree.map(lambda a: a[layer], params["layers"])["block"]
-    _, (kp2, vp2), _ = jax.jit(decoder._layer)(
-        p_l, jnp.int32(layer), x, (kp, vp), positions, tables,
-        positions[:, -1] + 1, blk, off)
+    from torchacc_tpu.models.transformer import LayerAt
+
+    def one_layer(p_l, row, x, pools):
+        return decoder._layer(
+            p_l, LayerAt(("layers",), row, "", row), x, pools, positions,
+            {"blocks": (tables, blk, off)}, positions[:, -1] + 1, None, None)
+
+    _, pools, _ = jax.jit(one_layer)(p_l, jnp.int32(layer), x,
+                                     {"k": kp, "v": vp})
+    kp2, vp2 = pools["k"], pools["v"]
     for old, new in ((kp, kp2), (vp, vp2)):
         old, new = np.asarray(old), np.asarray(new)
         written = np.zeros(shape[:3], bool)
@@ -311,8 +318,9 @@ def test_cow_clones_one_block_across_every_layer(tiny):
     kp = rng.standard_normal(shape).astype(np.float32)
     vp = rng.standard_normal(shape).astype(np.float32)
     src, dst = 11, 40
-    kp2, vp2 = decoder._cow((jnp.asarray(kp), jnp.asarray(vp)),
-                            jnp.int32(src), jnp.int32(dst))
+    pools = decoder._cow({"k": jnp.asarray(kp), "v": jnp.asarray(vp)},
+                         jnp.int32(src), jnp.int32(dst))
+    kp2, vp2 = pools["k"], pools["v"]
     for old, new in ((kp, np.asarray(kp2)), (vp, np.asarray(vp2))):
         assert np.array_equal(new[:, dst], old[:, src])
         keep = np.arange(sc.num_blocks) != dst
@@ -339,7 +347,7 @@ def test_tp_mesh_pool_rows_split_at_head_boundaries(devices, kv_heads):
     mesh = Mesh(np.asarray(devices[:4]).reshape(2, 2), ("fsdp", "tp"))
     kernel = TransformerLM(dataclasses.replace(cfg, attention_impl="pallas"))
     eng = ServeEngine(kernel, params, _serve_cfg(), mesh=mesh)
-    pool = eng.scheduler.pools[0]
+    pool = eng.scheduler.pools["k"]
     assert pool.shape == (2, 64, 8, kv_heads * 16)
     assert pool.sharding.spec == P(None, None, None,
                                    "tp" if kv_heads == 2 else None)
@@ -508,7 +516,7 @@ def test_block_free_reuse_never_leaks_or_aliases(tiny):
     while eng.step():
         live = [b for s in sched.slot_seq if s is not None
                 for b in s.blocks]
-        deferred = [b for _, blks in sched._deferred for b in blks]
+        deferred = [b for _, blks, _ in sched._deferred for b in blks]
         assert len(live) == len(set(live)), "live block aliased"
         assert 0 not in live + deferred, "null block allocated"
         assert set(live).isdisjoint(deferred), \

@@ -244,7 +244,8 @@ def test_serving_through_the_three_pools_matches_the_reference(
         mc = model_config(pub)
         params = layout.to_program_params(w, mc)
     eng = engine(mc, params, impl)
-    full, keys, win = eng.scheduler.pools
+    pools = eng.scheduler.pools
+    full, keys, win = pools["latent"], pools["index"], pools["latent_win"]
     assert full.shape == (3, 64, 8, 128)    # 32 + 8 values -> 128 lanes
     assert keys.shape == (3, 64, 8, 16)
     # 3 slots x (ceil((11 + 12) / 8) + 1) blocks and the null block

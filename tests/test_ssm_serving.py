@@ -3,8 +3,9 @@ layers on a per-slot recurrent state, grouped-query layers without
 rotary embedding on the paged pool, routed relu2 experts with a shared
 expert, one pre-norm a layer, in a published order that has no period;
 models/mamba2.py, ops/ssm_scan.py, models/block.mixer_block, the state
-pools of serve/kv_cache.py, PagedDecoder._forward_mixers) at a toy preset
-on the CPU, against the benchmark's plain float32 reference
+pools of serve/kinds.py's 'state_space' record, the layer plan's static
+walk) at a toy preset on the CPU, against the benchmark's plain float32
+reference
 (chipbench/reference/ssm_attn_moe_decoder.py: the layer equations of
 ISSUE 42 with the recurrence ONE TOKEN AT A TIME, nothing imported from
 the program).
@@ -315,9 +316,9 @@ def test_prefill_in_chunks_gives_the_references_logits(whole, impl):
     sched = eng.scheduler
     rng = np.random.default_rng(3)
     for n in (7, 16, 17, 45) if impl == "xla" else (17, 45):
-        k, v, conv, ssm = sched.pools
-        sched.pools = (k, v, conv.at[:, 0].set(jnp.nan),
-                       ssm.at[:, 0].set(jnp.nan))
+        sched.pools = {**sched.pools, **{
+            name: sched.pools[name].at[:, 0].set(jnp.nan)
+            for name in ("conv", "ssm")}}
         prompt = rng.integers(1, 256, size=n)
         got = _prefill_logits(eng, prompt)
         want = ref_logits(pub, w, prompt, [n - 1])[0]
@@ -424,7 +425,7 @@ def test_preemption_returns_every_block_and_the_state_restarts(whole):
     for _ in range(12):
         eng.step()
     assert sched.pool.in_use > 0
-    assert float(jnp.abs(sched.pools[3][:, :3]).max()) > 0
+    assert float(jnp.abs(sched.pools["ssm"][:, :3]).max()) > 0
     victim = next(s for s in sched.slot_seq if s is not None)
     sched.preempt(victim, 0.0)
     late = eng.submit(Request(prompt_ids=prompts[1], max_new_tokens=6))
@@ -433,7 +434,7 @@ def test_preemption_returns_every_block_and_the_state_restarts(whole):
                                                            "preempted"}
     assert sched.pool.in_use == 0
     assert sched.pool.available == SERVE["num_blocks"] - 1
-    assert not np.asarray(sched.pools[3][:, 3]).any()       # the null slot
+    assert not np.asarray(sched.pools["ssm"][:, 3]).any()   # the null slot
     assert _served_gap(pub, w, [prompts[1]],
                        [eng.result(late).tokens]) < 2e-5
     eng.close()
@@ -586,14 +587,16 @@ def test_the_cells_pools_are_the_issues(whole):
     sc = ServeConfig(block_size=128, num_blocks=1296, max_slots=48,
                      prefill_chunk=512)
     pools = jax.eval_shape(lambda: make_pools(mc, sc))
-    assert [(p.shape, p.dtype) for p in pools] == [
-        ((6, 1296, 128, 256), jnp.bfloat16)] * 2 + [
-        ((23, 49, 3 * 6144), jnp.bfloat16),
-        ((23, 49, 64, 64, 128), jnp.float32)]
+    assert {name: (p.shape, p.dtype) for name, p in pools.items()} == {
+        "k": ((6, 1296, 128, 256), jnp.bfloat16),
+        "v": ((6, 1296, 128, 256), jnp.bfloat16),
+        "conv": ((23, 49, 3 * 6144), jnp.bfloat16),
+        "ssm": ((23, 49, 64, 64, 128), jnp.float32)}
     assert mamba2.state_bytes(mc) == 2 * 2**20 + 36 * 2**10
-    gib = [p.size * p.dtype.itemsize / 2**30 for p in pools]
-    assert abs(gib[0] + gib[1] - 0.949) < 0.001
-    assert abs(gib[2] + gib[3] - 2.24) < 0.01
+    gib = {name: p.size * p.dtype.itemsize / 2**30
+           for name, p in pools.items()}
+    assert abs(gib["k"] + gib["v"] - 0.949) < 0.001
+    assert abs(gib["conv"] + gib["ssm"] - 2.24) < 0.01
 
 
 def _programs(eng):

@@ -249,17 +249,19 @@ def program_scopes():
         lambda: TransformerLM(mc).init(
             jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
     from torchacc_tpu.serve import make_pools
-    pool = jax.eval_shape(lambda: make_pools(mc, sc)[0])
+    pools = jax.eval_shape(lambda: make_pools(mc, sc))
     sds = jax.ShapeDtypeStruct
     carry = {"tok": sds((2,), jnp.int32), "key": sds((2, 2), jnp.uint32)}
+    # the steps' addressing pytree: every slot's table rows, one row
+    tables, row = sds((2, 15), jnp.int32), sds((15,), jnp.int32)
     decode = decoder._decode.lower(
-        params, (pool, pool), carry, sds((2, 15), jnp.int32),
+        params, pools, carry, {"blocks": tables},
         sds((2,), jnp.int32), sds((2,), jnp.bool_), sds((2,), jnp.float32),
         sds((2,), jnp.int32), sds((2,), jnp.float32), False
     ).compile().as_text()
     i32 = sds((), jnp.int32)
     prefill = decoder._prefill.lower(
-        params, (pool, pool), sds((15,), jnp.int32), i32,
+        params, pools, {"blocks": row}, i32,
         sds((8,), jnp.int32), i32, True).compile().as_text()
     lmc = _latent_model_cfg()
     ldecoder = PagedDecoder(lmc, sc, "xla")
@@ -268,36 +270,37 @@ def program_scopes():
             jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
     latent = ldecoder._decode.lower(
         lparams, jax.eval_shape(lambda: make_pools(lmc, sc)), carry,
-        sds((2, 15), jnp.int32), sds((2,), jnp.int32), sds((2,), jnp.bool_),
+        {"blocks": tables}, sds((2,), jnp.int32), sds((2,), jnp.bool_),
         sds((2,), jnp.float32), sds((2,), jnp.int32),
         sds((2,), jnp.float32), True).compile().as_text()
     smc, sparams = _sparse_model()
     sparse_lowered = PagedDecoder(smc, sc, "xla")._decode.lower(
         jax.eval_shape(lambda: sparams),
         jax.eval_shape(lambda: make_pools(smc, sc)), carry,
-        sds((2, 15), jnp.int32), sds((2,), jnp.int32), sds((2,), jnp.bool_),
-        sds((2,), jnp.float32), sds((2,), jnp.int32),
-        sds((2,), jnp.float32), True, sds((2, 15), jnp.int32))
+        {"blocks": tables, "window": tables}, sds((2,), jnp.int32),
+        sds((2,), jnp.bool_), sds((2,), jnp.float32), sds((2,), jnp.int32),
+        sds((2,), jnp.float32), True)
     sparse = sparse_lowered.compile().as_text()
     wmc, wparams = _window_model()
     window = PagedDecoder(wmc, sc, "xla")._decode.lower(
         jax.eval_shape(lambda: wparams),
         jax.eval_shape(lambda: make_pools(wmc, sc)), carry,
-        sds((2, 15), jnp.int32), sds((2,), jnp.int32), sds((2,), jnp.bool_),
-        sds((2,), jnp.float32), sds((2,), jnp.int32),
-        sds((2,), jnp.float32), True, sds((2, 15), jnp.int32)
+        {"blocks": tables, "window": tables}, sds((2,), jnp.int32),
+        sds((2,), jnp.bool_), sds((2,), jnp.float32), sds((2,), jnp.int32),
+        sds((2,), jnp.float32), True
     ).compile().as_text()
     mmc, mparams = _ssm_model()
     mdecoder = PagedDecoder(mmc, sc, "xla")
     mpools = jax.eval_shape(lambda: make_pools(mmc, sc))
     ssm_decode = mdecoder._decode.lower(
         jax.eval_shape(lambda: mparams), mpools, carry,
-        sds((2, 15), jnp.int32), sds((2,), jnp.int32), sds((2,), jnp.bool_),
+        {"blocks": tables}, sds((2,), jnp.int32), sds((2,), jnp.bool_),
         sds((2,), jnp.float32), sds((2,), jnp.int32),
         sds((2,), jnp.float32), True).compile().as_text()
     ssm_prefill = mdecoder._prefill.lower(
-        jax.eval_shape(lambda: mparams), mpools, sds((15,), jnp.int32), i32,
-        sds((8,), jnp.int32), i32, True, slot=i32).compile().as_text()
+        jax.eval_shape(lambda: mparams), mpools,
+        {"blocks": row, "slot": i32}, i32,
+        sds((8,), jnp.int32), i32, True).compile().as_text()
     return {"train": _scopes_in(train), "decode": _scopes_in(decode),
             "ssm_decode": _scopes_in(ssm_decode),
             "ssm_prefill": _scopes_in(ssm_prefill),

@@ -173,7 +173,8 @@ def test_param_tree_is_a_stack_a_period_position_and_the_count_is_exact(
     cfg = ta.Config()
     for key, value in SERVE.items():
         setattr(cfg.serve, key, value)
-    kg, vg, kw_, vw = make_pools(mc, cfg.serve)
+    pools = make_pools(mc, cfg.serve)
+    kg, vg, kw_, vw = (pools[name] for name in ("k", "v", "k_win", "v_win"))
     assert kg.shape == vg.shape == (2, 64, 8, 32)
     # 3 slots x (ceil((11 + 12) / 8) + 1) blocks and the null block
     assert kw_.shape == vw.shape == (6, 13, 8, 32)
@@ -418,9 +419,9 @@ def test_blocks_before_the_window_are_freed_and_what_is_freed_is_not_read(
     assert len(seq.win_blocks) <= sched.window.bound
     held = sorted(seq.win_blocks.values())
     dead = [b for b in range(sched.window.pool.num_blocks) if b not in held]
-    kg, vg, kw_, vw = sched.pools
-    sched.pools = (kg, vg, kw_.at[:, jnp.asarray(dead)].set(jnp.nan),
-                   vw.at[:, jnp.asarray(dead)].set(jnp.nan))
+    sched.pools = {**sched.pools, **{
+        name: sched.pools[name].at[:, jnp.asarray(dead)].set(jnp.nan)
+        for name in ("k_win", "v_win")}}
     eng.run()
     tokens = eng.result(rid).tokens
     assert len(tokens) == 6

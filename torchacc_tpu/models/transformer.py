@@ -15,9 +15,12 @@ parallelism a natural stage-stacked layout.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import functools
+import operator
 from dataclasses import dataclass
-from typing import Any, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -1374,35 +1377,101 @@ def pattern_period(cfg: ModelConfig):
     return dense, rest
 
 
+class LayerAt(NamedTuple):
+    """Where one layer lies: the path of its stacked ``tree`` in the
+    parameters, its index ``at`` in that stack, its ``kind``
+    (:func:`layer_kinds`; '' in a model of one kind) and its index
+    ``of_kind`` among the layers of its kind: its row in whatever is kept
+    a kind (the serving pools)."""
+
+    tree: Tuple[str, ...]
+    at: Any
+    kind: str
+    of_kind: Any
+
+    def stack(self, params):
+        """The stacked tree ``{'block': ...}`` this layer lies in."""
+        return functools.reduce(operator.getitem, self.tree, params)
+
+
+class LayerRun(NamedTuple):
+    """Consecutive layers of the plan.  A ``scanned`` run repeats its
+    ``body`` ``repeats`` times, every body layer in a stacked tree of its
+    own with one entry a repetition; any other holds its layers at the
+    static indices its body names."""
+
+    body: Tuple[LayerAt, ...]
+    repeats: int
+    scanned: bool
+
+    def layers(self, n=0):
+        """The body's layers at repetition ``n`` (an int, or the scan's
+        counter, which is where they lie in their stacks)."""
+        if not self.scanned:
+            return list(self.body)
+        per = collections.Counter(e.kind for e in self.body)
+        return [e._replace(at=n, of_kind=e.of_kind + n * per[e.kind])
+                for e in self.body]
+
+
+def layer_plan(cfg: ModelConfig) -> Tuple[LayerRun, ...]:
+    """How a model's layers are stacked, every layer in order, in runs:
+    the ONE place that says it (chipbench/layouts builds these trees;
+    :func:`layer_tree`, through it models/generate.py, and the serving
+    decoder's walk read them from here).  The canonical ``layers``
+    [L, ...] is one scanned run with a body of one; leading dense layers
+    are a run of their own over ``dense_layers``; a ``layer_pattern``
+    beside dense layers is scanned a period at a time, one stack a
+    position of the period, ``layers/p<k>`` [periods, ...].  A
+    ``mixer_pattern`` (one stack a kind of mixer, ``layers/<kind>``) has
+    no period, a ``layer_pattern`` on the canonical stack no two layers
+    of one config in a row: both are walked at static indices."""
+    n, nd = cfg.num_layers, cfg.first_dense_layers
+    patterned = bool(cfg.mixer_pattern or cfg.layer_pattern)
+    kinds = layer_kinds(cfg) if patterned else [""] * n
+    periodic = bool(cfg.layer_pattern and nd)
+    period = len(pattern_period(cfg)[1]) if periodic else 1
+    seen, flat = collections.Counter(), []
+    for i, kind in enumerate(kinds):
+        if cfg.mixer_pattern:
+            tree, at = ("layers", kind), seen[kind]
+        elif i < nd:
+            tree, at = ("dense_layers",), i
+        elif periodic:
+            tree, at = ("layers", f"p{(i - nd) % period}"), (i - nd) // period
+        else:
+            tree, at = ("layers",), i - nd
+        flat.append(LayerAt(tree, at, kind, seen[kind]))
+        seen[kind] += 1
+    if cfg.mixer_pattern or (cfg.layer_pattern and not nd):
+        return (LayerRun(tuple(flat), 1, False),)
+    runs = []
+    if nd:
+        # leading dense layers scan where they are of one kind
+        scan = len(set(kinds[:nd])) == 1
+        runs.append(LayerRun(tuple(flat[:1 if scan else nd]),
+                             nd if scan else 1, scan))
+    runs.append(LayerRun(tuple(flat[nd:nd + period]), (n - nd) // period,
+                         True))
+    return tuple(runs)
+
+
+def planned_layers(cfg: ModelConfig):
+    """Every layer of :func:`layer_plan`, in order."""
+    return [e for run in layer_plan(cfg) for n in range(run.repeats)
+            for e in run.layers(n)]
+
+
 def layer_tree(cfg: ModelConfig, params, i: int):
     """Layer ``i``'s raw tree ``{'block': ...}`` and the config its block
-    computes under, out of the stacked parameters: the canonical
-    ``layers`` [L, ...]; with leading dense layers their own stack
-    ``dense_layers`` first; and, for a pattern beside dense layers, one
-    stack a position of the pattern's period, ``layers/p<k>`` [periods,
-    ...] (the layout the serving decoder scans,
-    serve/scheduler.PagedDecoder._forward_periods); for a
-    ``mixer_pattern`` one stack a kind of mixer, ``layers/<kind>``."""
-    if cfg.mixer_pattern:
-        # one stack a kind; the layer's index in it counts the layers of
-        # its kind before it
-        kinds = layer_kinds(cfg)
-        at = kinds[:i].count(kinds[i])
-        return (jax.tree.map(lambda a: a[at], params["layers"][kinds[i]]),
-                cfg)
-    block_cfg = pattern_cfg(cfg, i)
-    nd = cfg.first_dense_layers
-    if i < nd:
-        stack, at = params["dense_layers"], i
+    computes under, out of the stacked parameters as :func:`layer_plan`
+    lays them out."""
+    at = planned_layers(cfg)[i]
+    block_cfg = cfg if cfg.mixer_pattern else pattern_cfg(cfg, i)
+    if i < cfg.first_dense_layers:
         block_cfg = dataclasses.replace(block_cfg, num_experts=0,
                                         first_dense_layers=0)
-    elif "p0" in params["layers"]:
-        plen = len(pattern_period(cfg)[1])
-        stack = params["layers"][f"p{(i - nd) % plen}"]
-        at = (i - nd) // plen
-    else:
-        stack, at = params["layers"], i - nd
-    return jax.tree.map(lambda a: a[at], stack), block_cfg
+    return jax.tree.map(lambda a: a[at.at], at.stack(params)), block_cfg
 
 
 def head_logits(cfg: ModelConfig, params, x: jax.Array) -> jax.Array:

@@ -37,24 +37,14 @@ pressure.  Eviction only ever takes refcount-0 cached blocks, so the
 whole-reservation admission guarantee survives: blocks owned by an
 admitted sequence are untouchable until that sequence frees them.
 
-A model that mixes windowed and full latent layers (models/mla.py, the
-'dots3_note' family) has THREE pools and two kinds of block: the full
-layers' latent pool and their index-key pool share one block table
-(a token's latent row and its index key lie at the same block and
-offset), reserved whole at admission like any other; the window layers'
-latent pool has its own :class:`WindowBlocks` — a sequence holds only
-the blocks its window still reaches, takes them as it grows and gives
-them back as the window passes, so a 32k-token sequence costs those
-layers ``window_blocks_bound`` blocks (10 at a window of 513, chunks of
-512 and blocks of 128) where a full table would hold 260.
-
-A grouped-query model that mixes windowed and full layers (a
-``layer_pattern`` of 'sliding' and 'global', the 'exaone_moe' family)
-has the same two kinds of block over FOUR pools: the global layers' k
-and v stacks ``[L_global, NB, BS, KH*D]`` under the ordinary table, the
-sliding layers' ``[L_sliding, NB_win, BS, KH*D]`` under
-:class:`WindowBlocks` — 6 blocks a sequence at a window of 128, chunks of
-512 and blocks of 128, whatever its length.
+What a kind of layer keeps here, under which names and addressed by
+what, is one table: serve/kinds.py.  Two kinds of block exist: those of a
+sequence's table, reserved whole at admission, and those of the window
+layers' table (:class:`WindowBlocks`) — a sequence holds only the blocks
+its window still reaches, takes them as it grows and gives them back as
+the window passes, so a 32k-token sequence costs those layers
+``window_blocks_bound`` blocks (10 at a window of 513, chunks of 512 and
+blocks of 128; 6 at a window of 128) where a full table would hold 260.
 
 The allocator is deliberately host-side and synchronous: allocation
 decisions happen at admission time (serve/engine.py), outside the
@@ -378,83 +368,29 @@ def num_window_blocks(model_cfg, serve_cfg) -> int:
 
 
 def make_pools(model_cfg, serve_cfg, dtype=None):
-    """The paged pools of a model, a tuple, in the model's compute
-    dtype: ``(k_pools, v_pools)`` of shape [L, NB, BS, KH*D] (with a
-    ``layer_pattern`` of windowed and full layers ``(k, v)`` of the
-    global layers and ``(k, v)`` [L_sliding, NB_win, BS, KH*D] of the
-    sliding ones), or for a
-    latent-attention model ONE pool ``(latent,)`` of shape
-    [L, NB, BS, latent_row_width] with no head dimension (every head
-    reads the same row), or for a ``mixer_pattern`` model ``(k, v)`` of
-    its attention layers and ``(conv, ssm)`` of its state-space layers
-    (indexed by slot; ``ssm`` always float32), or for a model of two
-    latent kinds ``(full
-    latent [L_full, NB, BS, W], index keys [L_full, NB, BS, dI], window
-    latent [L_win, NB_win, BS, W_win])``.  When a mesh is live and its 'tp' divides the
-    kv heads, the k/v rows are sharded over it in whole-head groups (the
-    same activation-constraint seam the model layers use, so the TP head
-    composes — parallel/sharding.py); a latent pool is replicated."""
+    """The pools of a model BY NAME, in the model's compute dtype (the
+    recurrent state always float32): for every kind of layer its plan
+    holds (serve/kinds.kinds_of) the pools that kind's record owns,
+    stacked over the layers of the kind, with as many rows as what
+    addresses them has — ``serve.num_blocks`` blocks of the sequences'
+    table, :func:`num_window_blocks` of the window layers' table, or
+    ``serve.max_slots`` slots and the null slot.  When a mesh is live and
+    its 'tp' divides the kv heads, the k/v rows are sharded over it in
+    whole-head groups (the same activation-constraint seam the model
+    layers use, so the TP head composes — parallel/sharding.py); a pool
+    whose record names no axes is replicated."""
     from torchacc_tpu.parallel.sharding import activation_constraint
+    from torchacc_tpu.serve.kinds import kinds_of
 
-    if model_cfg.swa_kv_lora_rank:
-        # two kinds of latent layer: (full layers' latent rows, their
-        # index keys, window layers' latent rows), each stacked over the
-        # layers of its kind; the first two share the block table
-        from torchacc_tpu.models.mla import kind_config, layer_kind
-        kinds = [layer_kind(model_cfg, i)
-                 for i in range(model_cfg.num_layers)]
-        n_win = kinds.count("sliding")
-        n_full = len(kinds) - n_win
-        dt = dtype or model_cfg.dtype
-        bs = serve_cfg.block_size
-        return (
-            jnp.zeros((n_full, serve_cfg.num_blocks, bs, latent_row_width(
-                kind_config(model_cfg, "global"))), dt),
-            jnp.zeros((n_full, serve_cfg.num_blocks, bs,
-                       model_cfg.index_head_dim), dt),
-            jnp.zeros((n_win, num_window_blocks(model_cfg, serve_cfg), bs,
-                       latent_row_width(kind_config(model_cfg, "sliding"))),
-                      dt))
-    if model_cfg.kv_lora_rank:
-        return (jnp.zeros((model_cfg.num_layers, serve_cfg.num_blocks,
-                           serve_cfg.block_size,
-                           latent_row_width(model_cfg)),
-                          dtype or model_cfg.dtype),)
-    dt = dtype or model_cfg.dtype
-    # a row splits at head boundaries only: tp must divide the HEADS
-    # (the constraint alone would split 128 lanes of one MQA head)
-    tp = dict(jax.sharding.get_abstract_mesh().shape).get("tp", 1)
-    axes = (None, None, None,
-            "heads" if model_cfg.kv_heads % tp == 0 else None)
-
-    def kv(layers, blocks):
-        shape = (layers, blocks, serve_cfg.block_size,
-                 model_cfg.kv_heads * model_cfg.head_size)
-        return tuple(activation_constraint(jnp.zeros(shape, dt), axes)
-                     for _ in range(2))
-
-    if model_cfg.mixer_pattern:
-        # layers of one mixer each: (k, v) of the attention layers alone,
-        # then the state-space layers' state BY SLOT, not by block — the
-        # convolution's last inputs [L_mamba, slots + 1, (K - 1) * channels]
-        # and the recurrent state [L_mamba, slots + 1, Hm, P, N] in
-        # float32, the same size at any context.  The last slot is the
-        # null slot (a batched prefill's padded rows write there)
-        from torchacc_tpu.models import mamba2
-        from torchacc_tpu.models.transformer import layer_kinds
-        kinds = layer_kinds(model_cfg)
-        n_ssm, slots = kinds.count("mamba"), serve_cfg.max_slots + 1
-        return kv(kinds.count("attention"), serve_cfg.num_blocks) + (
-            jnp.zeros((n_ssm, slots, (model_cfg.ssm_conv - 1)
-                       * mamba2.conv_width(model_cfg)), dt),
-            jnp.zeros((n_ssm, slots, model_cfg.ssm_heads,
-                       model_cfg.ssm_head_dim, model_cfg.ssm_state),
-                      jnp.float32))
-    if model_cfg.layer_pattern:
-        # windowed and full grouped-query layers: (k, v) of the global
-        # layers, then (k, v) of the sliding ones under their own table
-        from torchacc_tpu.models.transformer import layer_kinds
-        n_win = layer_kinds(model_cfg).count("sliding")
-        return (kv(model_cfg.num_layers - n_win, serve_cfg.num_blocks)
-                + kv(n_win, num_window_blocks(model_cfg, serve_cfg)))
-    return kv(model_cfg.num_layers, serve_cfg.num_blocks)
+    rows = {"blocks": serve_cfg.num_blocks,
+            "window": num_window_blocks(model_cfg, serve_cfg),
+            "slot": serve_cfg.max_slots + 1}
+    pools = {}
+    for record, cfg, n in kinds_of(model_cfg).values():
+        for name, (shape, dt, axes) in zip(record.names, record.shapes(
+                cfg, n, rows[record.by], serve_cfg.block_size,
+                dtype or model_cfg.dtype)):
+            pools[name] = jnp.zeros(shape, dt)
+            if axes is not None:
+                pools[name] = activation_constraint(pools[name], axes)
+    return pools

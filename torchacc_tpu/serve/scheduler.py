@@ -74,22 +74,14 @@ from torchacc_tpu.models.transformer import (
     MIXER_KINDS,
     embed_ids,
     head_logits,
-    kind_cfg,
     layer_kinds,
+    layer_plan,
     pattern_period,
 )
 from torchacc_tpu.obs import tracing
 from torchacc_tpu.ops._common import on_tpu
-from torchacc_tpu.ops.paged_attention import (
-    index_query_tile,
-    indexer_scores,
-    latent_paged_attention,
-    latent_query_tile,
-    paged_attention,
-    query_tile,
-    select_topk,
-)
 from torchacc_tpu.resilience.chaos import failpoint
+from torchacc_tpu.serve.kinds import _check_supported, kinds_of
 from torchacc_tpu.serve.kv_cache import (
     BlockPool,
     PrefixIndex,
@@ -103,200 +95,10 @@ from torchacc_tpu.utils.logger import logger
 from torchacc_tpu.utils.metrics import counters
 
 
-# every ModelConfig field serving has been audited against — the
-# rejection below is effectively an ALLOWLIST.  The block's arithmetic is
-# the model's own (models/block.py): a field it reads serves as it
-# trains.  What stays serving's own, and what this list guards, is the
-# attention core over the paged cache (_attend/_attend_latent: scale,
-# window, softcap, alibi), the cache row (serve/kv_cache.py) and the
-# layer loop (_forward: stacks, patterns, pipeline): a field added to
-# ModelConfig after this audit raises at engine construction instead of
-# being silently ignored there (decoding tokens that diverge from
-# generate() with no error).  If those three would have to read a new
-# field, handle it there or in the denylist checks; then add it here.
-_AUDITED_MODEL_FIELDS = frozenset({
-    "activation", "attention_impl", "attn_dropout", "attn_logit_softcap",
-    "cache_len", "context_parallel", "decode", "dtype", "embed_scale",
-    "head_bias", "head_dim", "hidden_size", "intermediate_size",
-    "layer_pattern", "logical_axis_rules", "logit_scale", "logit_softcap",
-    "max_seq_len", "mlp_bias", "moe_capacity_factor", "moe_dispatch",
-    "moe_renorm_topk", "norm", "norm_bias", "norm_eps", "norm_placement",
-    "num_experts", "num_experts_per_tok", "num_heads", "num_kv_heads",
-    "num_layers", "o_bias", "parallel_block",
-    "parallel_block_shared_norm", "param_dtype", "partial_rotary",
-    "pos_emb", "pp_num_micro", "pp_size", "pp_virtual", "qk_norm",
-    "qk_norm_proj", "qkv_bias", "query_scale", "remat", "remat_cls",
-    "remat_cnt", "remat_policy", "rope_interleaved", "rope_llama3",
-    "rope_local_theta", "rope_longrope", "rope_scale", "rope_theta",
-    "rope_yarn", "router_aux_weight", "sandwich_norms", "scan_layers",
-    "tie_embeddings", "tp_vocab_head", "vocab_size", "window",
-    # PR-7 audit: quant* select TRAIN-forward matmul execution only —
-    # the param layout is unchanged and inference runs in the compute
-    # dtype (generate() strips quant; block.tree_proj never
-    # quantizes), so a quant-trained model serves exactly like its
-    # unquantized twin.  overlap_fsdp only reshapes the train
-    # layer loop (scan vs unrolled prefetch); PagedDecoder owns its
-    # own loop and never consults it.
-    "quant", "quant_sites", "quant_amax_history_len", "quant_impl",
-    "overlap_fsdp",
-    # PR-26 audit: latent attention (_attend_latent), the two layer
-    # stacks (_forward) and the sigmoid/grouped router, shared experts
-    # and held-expert share of moe_dispatch='grouped' (_moe ->
-    # models/moe.moe_ffn, the module's own definition)
-    "kv_lora_rank", "q_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
-    "v_head_dim", "first_dense_layers", "moe_intermediate_size",
-    "moe_scoring", "moe_n_group", "moe_topk_group", "moe_route_scale",
-    "moe_router_bias", "moe_shared_experts", "moe_router_width",
-    "moe_first_expert",
-    # PR-30 audit: two kinds of latent layer under one layer_pattern
-    # (_attend_sparse / _attend_window, the three pools of
-    # serve/kv_cache.py, _forward's scan over periods), the latents'
-    # rescale and the headwise gate (models/mla.py)
-    "index_topk", "index_n_heads", "index_head_dim", "swa_num_heads",
-    "swa_kv_lora_rank", "swa_q_lora_rank", "swa_qk_nope_head_dim",
-    "swa_qk_rope_head_dim", "swa_v_head_dim", "mla_lora_rescale",
-    "attn_gate",
-    # PR-33 audit: windowed and full grouped-query layers under one
-    # layer_pattern (_attend's window kind over the sliding layers' own
-    # k/v pools, _forward's scan over periods); rope_kinds reaches the
-    # block through models/transformer.kind_cfg (a kind without rope
-    # computes under pos_emb='none', which block.qkv reads)
-    "rope_kinds",
-    # PR-42 audit: layers of ONE mixer each (_forward_mixers walks
-    # mixer_pattern: 'attention' layers on the k/v pools through _attend,
-    # 'moe' layers through models/moe.moe_ffn — whose experts follow
-    # `activation`, swiglu or relu2, and whose shared expert may have its
-    # own width —, 'mamba' layers through models/mamba2 over the state
-    # pools of serve/kv_cache.py, by slot); the ssm_* sizes reach only
-    # models/mamba2 and the state pools' shapes
-    "mixer_pattern", "ssm_heads", "ssm_head_dim", "ssm_state", "ssm_groups",
-    "ssm_conv", "ssm_chunk", "moe_shared_intermediate_size",
-})
-
-
 #: ``(kinds of the leading dense layers, kinds of one period)`` of a
 #: model whose layer_pattern names two kinds of layer (the name
 #: chipbench/layouts reads it under)
 _period = pattern_period
-
-
-def _check_supported(cfg) -> None:
-    """The serving surface: any dense decoder block TransformerLM
-    trains (models/block.py is the one definition of both), and
-    latent-attention decoders whose expert layers are the dropless
-    held-expert layer (moe_dispatch='grouped') — minus what the paged
-    cache, its kernel or the layer loop cannot hold, which raises a
-    typed error here instead of decoding garbage."""
-    unknown = ({f.name for f in dataclasses.fields(cfg)}
-               - _AUDITED_MODEL_FIELDS)
-    if unknown:
-        raise NotImplementedError(
-            f"ModelConfig grew fields the serving forward has not been "
-            f"audited against: {sorted(unknown)}.  Audit their effect "
-            f"on PagedDecoder's attention core, cache and layer loop "
-            f"(scheduler.py) and add them to _AUDITED_MODEL_FIELDS.")
-    bad = []
-    if cfg.num_experts > 0 and cfg.moe_dispatch != "grouped":
-        bad.append("MoE outside moe_dispatch='grouped' (the dense and "
-                   "capacity dispatch paths)")
-    if cfg.first_dense_layers and not cfg.num_experts:
-        bad.append("first_dense_layers without expert layers")
-    if cfg.kv_lora_rank and (
-            cfg.pos_emb != "rope" or cfg.qk_norm or cfg.qkv_bias
-            or cfg.o_bias or cfg.attn_logit_softcap or cfg.rope_scale != 1.0
-            or cfg.partial_rotary != 1.0 or cfg.mlp_bias
-            or cfg.activation != "swiglu"):
-        bad.append("latent attention with anything but plain rope, "
-                   "bias-free projections and SwiGLU")
-    if cfg.pp_size > 1:
-        bad.append("pipeline parallelism (pp_size > 1)")
-    if cfg.context_parallel:
-        bad.append("context parallelism")
-    if cfg.kv_lora_rank and cfg.swa_kv_lora_rank:
-        # windowed and full latent layers under one pattern: admitted
-        # where the scan over periods and the three pools can hold it
-        dense, period = _period(cfg)
-        if (not cfg.layer_pattern or set(cfg.layer_pattern)
-                - {"global", "sliding"} or cfg.window[0] < 0
-                or cfg.window[1] >= 0 or not cfg.index_topk
-                or not cfg.num_experts or not cfg.first_dense_layers
-                or "sliding" in dense or not cfg.q_lora_rank):
-            bad.append("two kinds of latent layer in any arrangement but: "
-                       "a layer_pattern of 'global' (indexed) and "
-                       "'sliding' (left window) layers, the leading dense "
-                       "layers all 'global', expert layers after them")
-        if cfg.attn_gate not in ("none", "headwise"):
-            bad.append(f"attn_gate {cfg.attn_gate!r}")
-    else:
-        if cfg.layer_pattern:
-            # windowed and full grouped-query layers under one pattern:
-            # admitted where the scan over periods and the pools of two
-            # geometries can hold it
-            dense, _ = pattern_period(cfg)
-            if (cfg.kv_lora_rank
-                    or set(cfg.layer_pattern) - {"global", "sliding"}
-                    or cfg.window[0] < 0 or not cfg.num_experts
-                    or not cfg.first_dense_layers or len(set(dense)) != 1):
-                bad.append("layer_pattern on grouped-query pools in any "
-                           "arrangement but: 'global' and 'sliding' (left "
-                           "window) layers, leading dense layers of one "
-                           "kind, expert layers after them (and on the "
-                           "pool of a one-kind latent model)")
-            if cfg.window[1] >= 0:
-                bad.append(f"a two-sided window {cfg.window} (the paged "
-                           f"cache holds no position after a query)")
-        elif tuple(cfg.window) != (-1, -1):
-            bad.append(f"sliding window {cfg.window} without a "
-                       f"layer_pattern")
-        elif cfg.rope_kinds is not None:
-            bad.append("rope_kinds without a layer_pattern")
-        if (cfg.index_topk or cfg.swa_kv_lora_rank
-                or cfg.attn_gate != "none" or cfg.mla_lora_rescale):
-            bad.append("indexed selection, a headwise gate or rescaled "
-                       "latents outside the latent family of two kinds")
-    if cfg.mixer_pattern:
-        # layers of one mixer each: admitted with what the slot state
-        # and the attention layers' one k/v pool can hold
-        if set(cfg.mixer_pattern) - set(MIXER_KINDS):
-            bad.append(f"mixer_pattern entries other than {MIXER_KINDS}")
-        if len(cfg.mixer_pattern) < cfg.num_layers:
-            bad.append(f"a mixer_pattern of {len(cfg.mixer_pattern)} entries "
-                       f"for {cfg.num_layers} layers")
-        if tuple(cfg.window) != (-1, -1) or cfg.layer_pattern:
-            bad.append("a window beside state-space layers (a mixer_pattern "
-                       "with a sliding window or a layer_pattern)")
-        if cfg.kv_lora_rank:
-            bad.append("latent keys beside state-space layers (a "
-                       "mixer_pattern with kv_lora_rank)")
-        if cfg.first_dense_layers:
-            bad.append("first_dense_layers with a mixer_pattern (a layer "
-                       "holds one mixer)")
-        if (cfg.norm_placement != "pre" or cfg.parallel_block
-                or cfg.sandwich_norms):
-            bad.append("a mixer_pattern with anything but one pre-norm a "
-                       "layer")
-        if "moe" in cfg.mixer_pattern and not cfg.num_experts:
-            bad.append("'moe' layers in a mixer_pattern without experts")
-        if "mamba" in cfg.mixer_pattern and (
-                min(cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
-                    cfg.ssm_groups) < 1 or cfg.ssm_conv < 2
-                or cfg.ssm_heads % cfg.ssm_groups):
-            bad.append("'mamba' layers without their sizes (ssm_heads a "
-                       "multiple of ssm_groups, ssm_head_dim, ssm_state, "
-                       "ssm_conv >= 2)")
-    elif cfg.moe_shared_intermediate_size is not None:
-        bad.append("moe_shared_intermediate_size outside a mixer_pattern")
-    if cfg.activation == "relu2" and cfg.num_experts \
-            and not cfg.mixer_pattern:
-        bad.append("relu2 experts outside a mixer_pattern")
-    if cfg.pos_emb == "alibi":
-        bad.append("pos_emb='alibi'")
-    if bad:
-        raise NotImplementedError(
-            "the serving engine (torchacc_tpu/serve) does not yet "
-            "support: " + ", ".join(bad) + ".  Use models.generate for "
-            "these models (batch-synchronous decode covers the full "
-            "model zoo).")
 
 
 def _upload(host_mirror: np.ndarray) -> jax.Array:
@@ -315,8 +117,9 @@ _EXPERT_STACKS = ("experts/gate", "experts/up", "experts/down")
 class PagedDecoder:
     """The jitted device steps: the model's forward on raw params over
     the paged pool — ``embed_ids`` / ``head_logits`` and the block of
-    models/block.py, the definitions the module's own apply runs, with
-    the attention core and the layer loop that are serving's own."""
+    models/block.py, the definitions the module's own apply runs, over
+    the model's layer plan (models/transformer.layer_plan), with the
+    attention of each kind of layer from serve/kinds.py."""
 
     def __init__(self, cfg, serve_cfg, attention_impl: Optional[str] = None):
         _check_supported(cfg)
@@ -328,14 +131,14 @@ class PagedDecoder:
         impl = attention_impl or cfg.attention_impl
         if impl == "auto":
             impl = "pallas" if on_tpu() else "xla"
-        # two kinds of layer under one pattern (_check_supported admits
-        # no pattern otherwise): latent ones, or grouped-query ones
-        self.two_kinds = bool(cfg.layer_pattern)
-        self.latent_kinds = bool(cfg.swa_kv_lora_rank)
-        # layers of one mixer each, state-space layers among them: their
-        # state lives by slot, beside the attention layers' paged pool
-        self.mixers = bool(cfg.mixer_pattern)
-        if self.mixers:
+        self.plan = layer_plan(cfg)
+        # what each kind of the plan's layers keeps in the cache, the
+        # config it computes under, and what addresses the pools in all
+        self.kinds = kinds_of(cfg)
+        self.by = {record.by for record, _, _ in self.kinds.values()}
+        if cfg.mixer_pattern:
+            # layers of one mixer each, state-space layers among them: their
+            # state lives by slot, beside the attention layers' paged pool
             if serve_cfg.prefix_cache:
                 raise NotImplementedError(
                     "the serving engine does not yet support prefix "
@@ -350,42 +153,19 @@ class PagedDecoder:
                     f"serve.prefill_chunk={serve_cfg.prefill_chunk} is not "
                     f"whole sub-chunks of the state-space scan "
                     f"(ssm_chunk={cfg.ssm_chunk})")
-        if self.two_kinds:
-            if serve_cfg.prefix_cache:
-                raise NotImplementedError(
-                    "the serving engine does not yet support prefix "
-                    "sharing across window layers (serve.prefix_cache "
-                    "with windowed layers: a window layer's blocks are "
-                    "freed as the window passes, so a cached prefix has "
-                    "no rows left to share there)")
-            of_kind = kind_cfg
-            if self.latent_kinds:
-                from torchacc_tpu.models.mla import kind_config as of_kind
-            self._full_cfg = of_kind(cfg, "global")
-            self._win_cfg = of_kind(cfg, "sliding")
+        if cfg.layer_pattern and serve_cfg.prefix_cache:
+            raise NotImplementedError(
+                "the serving engine does not yet support prefix "
+                "sharing across window layers (serve.prefix_cache "
+                "with windowed layers: a window layer's blocks are "
+                "freed as the window passes, so a cached prefix has "
+                "no rows left to share there)")
         if impl == "pallas":
             for t in (1, serve_cfg.prefill_chunk):
                 try:
-                    if self.latent_kinds:
-                        f, w = self._full_cfg, self._win_cfg
-                        latent_query_tile(
-                            f.num_heads, f.kv_lora_rank, f.qk_rope_head_dim,
-                            serve_cfg.block_size, t, cfg.dtype, True)
-                        latent_query_tile(
-                            w.num_heads, w.kv_lora_rank, w.qk_rope_head_dim,
-                            serve_cfg.block_size, t, cfg.dtype)
-                        index_query_tile(
-                            cfg.index_n_heads, cfg.index_head_dim,
-                            serve_cfg.block_size, t, cfg.dtype)
-                    elif cfg.kv_lora_rank:
-                        latent_query_tile(
-                            cfg.num_heads, cfg.kv_lora_rank,
-                            cfg.qk_rope_head_dim, serve_cfg.block_size, t,
-                            cfg.dtype)
-                    else:
-                        query_tile(cfg.num_heads, cfg.kv_heads,
-                                   cfg.head_size, serve_cfg.block_size, t,
-                                   cfg.dtype)
+                    for record, kind_cfg, _ in self.kinds.values():
+                        record.tile(kind_cfg, serve_cfg.block_size, t,
+                                    cfg.dtype)
                 except ValueError as e:
                     raise ConfigError(
                         f"serve.block_size={serve_cfg.block_size}, "
@@ -395,16 +175,21 @@ class PagedDecoder:
         self.impl = impl
         self.block_size = serve_cfg.block_size
         self.chunk = serve_cfg.prefill_chunk
-        self.max_slots = serve_cfg.max_slots
+        # a mixer_pattern model's final chunk sends its last valid row
+        # alone through the head: a chunk's logits over this family's
+        # vocabulary are 256 MiB of float32 beside pools that leave no
+        # such room
+        self._head_last_row = bool(cfg.mixer_pattern)
         # pools are donated: every step consumes and returns them, so
         # XLA updates the one preallocated buffer in place.  all_greedy
         # is static: the all-greedy trace (the serving default) skips
         # the two full-vocab sampling sorts entirely — argmax only —
         # while the mixed trace keeps the one-program-per-request-mix
         # property; both advance the slot PRNG keys identically, so
-        # flipping between variants cannot drift a sampled stream
-        # (win_tables, an optional last argument, is None but for a model
-        # with window layers: their block table)
+        # flipping between variants cannot drift a sampled stream.
+        # ``addr`` is every step's addressing pytree: the tables by name
+        # ('blocks'; 'window' for a model with window layers) and, in a
+        # prefill of a model that keeps state by slot, 'slot'
         self._decode = jax.jit(self._decode_impl, donate_argnums=(1, 2),
                                static_argnums=(9,))
         # is_final is static: the non-final trace skips the vocab head
@@ -427,56 +212,47 @@ class PagedDecoder:
 
     # -- model forward ------------------------------------------------------
 
-    def _layer(self, p, layer, x, pools, positions, tables, ctx_lens, blk,
-               off, valid=None, expert_stacks=None, kind="",
-               expert_layer=None):
-        """Decoder layer ``layer`` over the paged cache: the model's own
-        block (models/block.py) on this layer's raw tree ``p``, with the
-        two halves that are serving's own.  The attention is
-        grouped-query over a k and a v pool or latent over one pool
-        (``cfg.kv_lora_rank``), the feed-forward the model's MLP or the
-        held-expert layer (the layer's tree holds ``mlp`` or ``moe``).
-        ``pools`` are the whole stacks; ``blk``/``off`` [S, T] name the
-        pool slot every token writes its row to (the null block for
-        masked tokens); ``ctx_lens`` is the post-write context length
-        per slot; ``valid`` [S, T] marks the real tokens (the expert
-        layer routes no others); ``expert_stacks`` the expert kernels of
-        ALL expert layers (:meth:`_forward` keeps them off the scan),
-        read by the grouped matmul at this layer's index among them
-        (``expert_layer``; None = ``layer`` less the dense ones).  In a
-        model of two kinds of layer ``kind`` names this layer's
-        ('global': the full layers' pools — under an indexed selection
-        in a latent model —, 'sliding': the window layers' pools);
-        ``layer`` is then its index among the layers of its kind,
-        ``tables`` and ``blk`` pairs ``(full, window)``.  Returns ``(x, pools, load)``, ``load`` the expert
+    def _layer(self, p, at, x, pools, positions, where, ctx_lens, valid,
+               expert_stacks):
+        """The plan's layer ``at`` over the cache: the model's own block
+        (models/block.py) on this layer's raw tree ``p`` — both halves of
+        a decoder block, or the ONE mixer of a ``mixer_pattern`` layer
+        under its pre-norm (``at.kind`` is a mixer's name) — with the two
+        halves that are serving's own.  The attention is its kind's
+        record's (serve/kinds.py) on row ``at.of_kind`` of the pools that
+        record names, addressed by ``where[record.by]``; the feed-forward
+        the model's MLP or the held-expert layer (the layer's tree holds
+        ``mlp`` or ``moe``).  ``pools`` are the whole stacks, by name;
+        ``ctx_lens`` is the post-write context length per slot; ``valid``
+        [S, T] marks the real tokens (the expert layer routes no
+        others); ``expert_stacks`` the expert kernels of ALL the layers in
+        this layer's stacked tree (:meth:`_forward` keeps them off the
+        scan), read by the grouped matmul at this layer's index in it,
+        ``at.at``.  Returns ``(x, pools, load)``, ``load`` the expert
         layer's counts or None."""
-        cfg = self.cfg
-        if kind:
-            cfg = self._full_cfg if kind == "global" else self._win_cfg
-            which = int(kind == "sliding")
-            tables, blk = tables[which], blk[which]
-        if cfg.num_experts and "moe" not in p:
+        record, cfg, _ = self.kinds.get(at.kind, (None, self.cfg, 0))
+        if cfg.num_experts and "mlp" in p:
             # a leading dense layer of an expert model: TransformerLM
             # gives that stack's blocks this config too
             cfg = dataclasses.replace(cfg, num_experts=0)
-        attend = (functools.partial(self._attend, cfg=cfg, kind=kind)
-                  if not cfg.kv_lora_rank else
-                  {"": self._attend_latent, "global": self._attend_sparse,
-                   "sliding": self._attend_window}[kind])
         # what the halves leave besides their output: the updated pools,
         # the expert layer's counts (all traced in this layer's own trace)
-        left = {"load": None}
+        left = {"pools": pools, "load": None}
 
         # the named scopes are registered device scopes (obs/tracing.py
         # DEVICE_SCOPES): a profiler trace reads each part's device
         # time under the same names the training step's modules carry
+        # (a single mixer's one norm, 'ln', under the block's 'ln1')
         def norm(name, t, cfg=cfg):
-            with jax.named_scope(name):
+            with jax.named_scope("ln1" if name == "ln" else name):
                 return block.tree_norm(cfg, p)(name, t)
 
         def attention(h):
-            out, left["pools"] = attend(p["attn"], layer, h, pools,
-                                        positions, tables, ctx_lens, blk, off)
+            out, own = record.attend(
+                cfg, self.impl, p[record.params], at.of_kind, h,
+                tuple(pools[name] for name in record.names), positions,
+                where[record.by], ctx_lens)
+            left["pools"] = {**pools, **dict(zip(record.names, own))}
             return out
 
         def ffn(h2):
@@ -488,375 +264,91 @@ class PagedDecoder:
             y, _, _, left["load"] = moe_ffn(
                 cfg, {**p["moe"], **expert_stacks},
                 h2.reshape(s_ * t_, hd),
-                None if valid is None else valid.reshape(-1),
-                layer=(layer - cfg.first_dense_layers
-                       if expert_layer is None else expert_layer))
+                None if valid is None else valid.reshape(-1), layer=at.at)
             return y.reshape(s_, t_, hd)
 
-        x = block.block(cfg, x, norm, attention, ffn)
+        if at.kind in MIXER_KINDS:
+            x = block.mixer_block(cfg, x, norm,
+                                  ffn if at.kind == "moe" else attention,
+                                  routed=at.kind == "moe")
+        else:
+            x = block.block(cfg, x, norm, attention, ffn)
         return x, left["pools"], left["load"]
 
-    def _attend(self, attn, layer, h, pools, positions, tables, ctx_lens,
-                blk, off, *, cfg, kind=""):
-        """Grouped-query attention of the normed ``h`` over the k and v
-        pools [L, NB, BS, KH*D]: ``(output before the residual,
-        pools)``.  ``cfg`` is the layer's own (a kind's window and rope);
-        in a model of two kinds the pools are ``(k, v)`` of the global
-        layers then ``(k, v)`` of the sliding ones, and a 'sliding'
-        layer's kernel call carries its own name and scope: a profile
-        reads the two kinds apart."""
-        at = 2 * (kind == "sliding")
-        kp, vp = pools[at:at + 2]
-        s_, t_ = h.shape[:2]
-        proj = block.tree_proj(cfg, attn)
-        with jax.named_scope("qkv"):
-            q, k, v = block.qkv(cfg, h, positions, proj,
-                                block.tree_norm(cfg, attn))
-        # bank this chunk's (rotated) k / raw v into the pool, THEN
-        # attend over the updated pool — same write-before-read order
-        # as the module's dense-cache decode branch.  One scatter per
-        # pool: token n's [KH*D] row lands at (layer, block, offset),
-        # a contiguous window of the carried buffer, updated in place
-        flat_b, flat_o = blk.reshape(-1), off.reshape(-1)
-        with jax.named_scope("kv_write"):
-            kp = kp.at[layer, flat_b, flat_o].set(
-                k.reshape(s_ * t_, -1).astype(kp.dtype))
-            vp = vp.at[layer, flat_b, flat_o].set(
-                v.reshape(s_ * t_, -1).astype(vp.dtype))
-        scope, name = (("window_paged_attn", "window_paged_attention") if at
-                       else ("paged_attn", "paged_attention"))
-        with jax.named_scope(scope):
-            out = paged_attention(
-                q, kp, vp, tables, ctx_lens, positions[:, 0], layer=layer,
-                scale=cfg.query_scale, window=cfg.window,
-                logit_softcap=cfg.attn_logit_softcap, impl=self.impl,
-                name=name)
-        with jax.named_scope("o_proj"):
-            return proj("o_proj", out), pools[:at] + (kp, vp) + pools[at + 2:]
-
-    def _attend_latent(self, attn, layer, h, pools, positions, tables,
-                       ctx_lens, blk, off):
-        """Latent attention in the absorbed form (models/mla.py) over
-        the one pool [L, NB, BS, W]: a token banks the row
-        ``[c_kv | rope(k_pe)]`` (padded to W lanes), written in place
-        like a k row; the kernel reads it as key and value of every
-        head; ``W_kvb`` is folded into the query and the output."""
-        from torchacc_tpu.models import mla
-
-        cfg = self.cfg
-        (pool,) = pools
-        with jax.named_scope("mla_q"):
-            q_nope, q_pe = mla.project_q(cfg, attn, h, positions)
-            q_lat = mla.absorb_q(cfg, attn, q_nope)
-        pool = self._bank_latent(cfg, attn, pool, layer, h, positions, blk,
-                                 off)
-        with jax.named_scope("latent_attn"):
-            o_lat = latent_paged_attention(
-                q_lat, q_pe.astype(q_lat.dtype), pool, tables, ctx_lens,
-                positions[:, 0], layer=layer, scale=mla.query_scale(cfg),
-                impl=self.impl)
-        with jax.named_scope("o_proj"):
-            return mla.project_out(
-                cfg, attn, mla.expand_out(cfg, attn, o_lat)), (pool,)
-
-    def _bank_latent(self, cfg, attn, pool, layer, h, positions, blk, off):
-        """Project this chunk's latent rows and write them in place:
-        ``pool`` with ``[c_kv | rope(k_pe) | padding]`` at (layer, blk,
-        off)."""
-        from torchacc_tpu.models import mla
-        s_, t_ = h.shape[:2]
-        with jax.named_scope("mla_kv"):
-            c_kv, k_pe = mla.project_latent(cfg, attn, h, positions)
-            row = jnp.concatenate([c_kv, k_pe], axis=-1)
-            row = jnp.pad(row, ((0, 0), (0, 0),
-                                (0, pool.shape[-1] - row.shape[-1])))
-        with jax.named_scope("kv_write"):
-            return pool.at[layer, blk.reshape(-1), off.reshape(-1)].set(
-                row.reshape(s_ * t_, -1).astype(pool.dtype))
-
-    def _gated_out(self, cfg, attn, h, o_lat):
-        """Latent outputs -> the block's attention output: ``W_kvb^V``,
-        the headwise gate, ``W_o``."""
-        from torchacc_tpu.models import mla
-        with jax.named_scope("attn_gate"):
-            out = mla.head_gate(cfg, attn, h,
-                                mla.expand_out(cfg, attn, o_lat))
-        with jax.named_scope("o_proj"):
-            return mla.project_out(cfg, attn, out)
-
-    def _attend_sparse(self, attn, layer, h, pools, positions, tables,
-                       ctx_lens, blk, off):
-        """A 'global' layer of a model of two latent kinds: latent
-        attention over the ``index_topk`` cached positions its indexer
-        scores highest.  The token banks its latent row in the full
-        layers' pool and ONE index key in the index-key pool, same block
-        and offset; the indexer kernel scores every visible position of
-        the slot, :func:`select_topk` finds the exact k best, and the
-        latent kernel attends them.  While no slot holds more than k
-        positions the selection is every position and the search for the
-        k-th best is skipped (its cache writes are not)."""
-        from torchacc_tpu.models import mla
-
-        cfg = self._full_cfg
-        pool, keys, win = pools
-        s_, t_ = h.shape[:2]
-        with jax.named_scope("mla_q"):
-            c_q = mla.latent_q(cfg, attn, h)
-            q_nope, q_pe = mla.project_q(cfg, attn, h, positions, c_q)
-            q_lat = mla.absorb_q(cfg, attn, q_nope)
-        pool = self._bank_latent(cfg, attn, pool, layer, h, positions, blk,
-                                 off)
-        with jax.named_scope("index_write"):
-            k_idx = mla.index_key(cfg, attn, h, positions)
-        with jax.named_scope("kv_write"):
-            keys = keys.at[layer, blk.reshape(-1), off.reshape(-1)].set(
-                k_idx.reshape(s_ * t_, -1).astype(keys.dtype))
-        q_start = positions[:, 0]
-        with jax.named_scope("indexer"):
-            scores = indexer_scores(
-                mla.index_query(cfg, attn, c_q, positions).astype(keys.dtype),
-                mla.index_weights(cfg, attn, h), keys, tables, ctx_lens,
-                q_start, layer=layer, impl=self.impl)
-        with jax.named_scope("index_topk"):
-            thr, tie_hi = jax.lax.cond(
-                jnp.max(ctx_lens) <= cfg.index_topk,
-                lambda sc: (jnp.full(sc.shape[:2], -jnp.inf, jnp.float32),
-                            jnp.zeros(sc.shape[:2], jnp.int32)),
-                lambda sc: select_topk(sc, cfg.index_topk), scores)
-        with jax.named_scope("sparse_latent_attn"):
-            o_lat = latent_paged_attention(
-                q_lat, q_pe.astype(q_lat.dtype), pool, tables, ctx_lens,
-                q_start, layer=layer, scale=mla.query_scale(cfg),
-                impl=self.impl, selection=(scores, thr, tie_hi),
-                name="sparse_latent_attention")
-        return self._gated_out(cfg, attn, h, o_lat), (pool, keys, win)
-
-    def _attend_window(self, attn, layer, h, pools, positions, tables,
-                       ctx_lens, blk, off):
-        """A 'sliding' layer of a model of two latent kinds: latent
-        attention of its own sizes over ``cfg.window`` positions back,
-        in the window layers' pool through their own table (entries
-        before the window are 0: freed, never read)."""
-        from torchacc_tpu.models import mla
-
-        cfg = self._win_cfg
-        pool, keys, win = pools
-        with jax.named_scope("mla_q"):
-            q_nope, q_pe = mla.project_q(cfg, attn, h, positions)
-            q_lat = mla.absorb_q(cfg, attn, q_nope)
-        win = self._bank_latent(cfg, attn, win, layer, h, positions, blk,
-                                off)
-        with jax.named_scope("window_latent_attn"):
-            o_lat = latent_paged_attention(
-                q_lat, q_pe.astype(q_lat.dtype), win, tables, ctx_lens,
-                positions[:, 0], layer=layer, scale=mla.query_scale(cfg),
-                impl=self.impl, window=cfg.window[0],
-                name="window_latent_attention")
-        return self._gated_out(cfg, attn, h, o_lat), (pool, keys, win)
-
-    def _forward_periods(self, params, pools, x, positions, tables, ctx_lens,
-                         blk, off, valid):
-        """The layer loop of a model of two kinds of layer: one scan over
-        the leading dense layers (all of one kind), then one over the
-        PERIODS of the pattern — the body runs a period's layers one
-        after another, each position of the period its own stacked tree
-        ``params['layers']['p<k>']`` [periods, ...] with its expert
-        stacks kept off ``xs`` (see :meth:`_forward`), every pool on the
-        carry.  A layer's index in its kind's pools counts the
-        layers of that kind before it."""
-        cfg = self.cfg
-        dense, period = pattern_period(cfg)
-        n_periods = (cfg.num_layers - len(dense)) // len(period)
-        per_kind = {k: period.count(k) for k in ("global", "sliding")}
-        first = {k: dense.count(k) for k in per_kind}
-        before = [{k: period[:i].count(k) for k in per_kind}
-                  for i in range(len(period))]
-        stacks, layers = [], {}
-        for i in range(len(period)):
-            tree = params["layers"][f"p{i}"]
-            moe = tree["block"]["moe"]
-            stacks.append({k: moe[k].astype(cfg.dtype)
-                           for k in _EXPERT_STACKS})
-            layers[f"p{i}"] = {**tree, "block": {**tree["block"], "moe": {
-                k: v for k, v in moe.items() if k not in _EXPERT_STACKS}}}
-
-        def dense_body(carry, per):
-            x, pools = carry
-            p_l, i = per
-            x, pools, _ = self._layer(
-                p_l["block"], i, x, pools, positions, tables, ctx_lens, blk,
-                off, valid, kind=dense[0])
-            return (x, pools), None
-
-        def body(carry, per):
-            x, pools = carry
-            p_l, n = per
-            load = 0
-            for i, kind in enumerate(period):
-                x, pools, one = self._layer(
-                    p_l[f"p{i}"]["block"],
-                    first[kind] + n * per_kind[kind] + before[i][kind], x,
-                    pools,
-                    positions, tables, ctx_lens, blk, off, valid,
-                    expert_stacks=stacks[i], kind=kind, expert_layer=n)
-                load = load + one
-            return (x, pools), load
-
-        with jax.named_scope("layers"):
-            (x, pools), _ = jax.lax.scan(
-                dense_body, (x, pools),
-                (params["dense_layers"],
-                 jnp.arange(len(dense), dtype=jnp.int32)))
-            (x, pools), load = jax.lax.scan(
-                body, (x, pools),
-                (layers, jnp.arange(n_periods, dtype=jnp.int32)))
-        return pools, x, jnp.sum(load, axis=0)
-
-    def _forward_mixers(self, params, pools, x, positions, tables, ctx_lens,
-                        blk, off, valid, state):
-        """The layer walk of a ``mixer_pattern`` model: its layers one
-        after another in the published order (no period to scan), each
-        ONE mixer under a pre-norm (models/block.mixer_block) on its
-        kind's stacked tree ``params['layers'][kind]`` at a static index.
-        ``pools`` are ``(k, v, conv, ssm)``: an 'attention' layer reads
-        and writes the first two through :meth:`_attend`, a 'mamba' layer
-        the last two through models/mamba2 — ``state`` says how: None for
-        a decode step (slot i's state at index i, ``valid[:, 0]`` the
-        slots that decode), else ``(slots [R], fresh [R], n_valid [R])``
-        of a prefill's rows.  Nothing copies a stack: an XLA dot reads
-        its layer's slice where it lies, the grouped matmul and the scan
-        kernel take the whole stack (or pool) and the layer's index."""
-        from torchacc_tpu.models import mamba2
-        from torchacc_tpu.models.moe import moe_ffn
-
-        cfg = self.cfg
-        stacks = {kind: tree["block"]
-                  for kind, tree in params["layers"].items()}
-        expert_stacks = {}
-        if "moe" in stacks:
-            moe = stacks["moe"]["moe"]
-            expert_stacks = {k: moe[k].astype(cfg.dtype)
-                             for k in _EXPERT_STACKS if k in moe}
-            stacks["moe"] = {**stacks["moe"], "moe": {
-                k: v for k, v in moe.items() if k not in _EXPERT_STACKS}}
-        seen = dict.fromkeys(MIXER_KINDS, 0)
-        load = None
-        with jax.named_scope("layers"):
-            for kind in layer_kinds(cfg):
-                i = seen[kind]
-                seen[kind] += 1
-                p = jax.tree.map(lambda a, i=i: a[i], stacks[kind])
-                left = {"pools": pools, "load": None}
-
-                def norm(name, t, ncfg, p=p):
-                    with jax.named_scope("ln1"):
-                        return block.tree_norm(ncfg, p)(name, t)
-
-                def attention(h, p=p, i=i, left=left):
-                    out, kv = self._attend(
-                        p["attn"], i, h, left["pools"][:2], positions,
-                        tables, ctx_lens, blk, off, cfg=cfg)
-                    left["pools"] = kv + left["pools"][2:]
-                    return out
-
-                def experts(h, p=p, i=i, left=left):
-                    s_, t_, hd = h.shape
-                    y, _, _, left["load"] = moe_ffn(
-                        cfg, {**p["moe"], **expert_stacks},
-                        h.reshape(s_ * t_, hd),
-                        None if valid is None else valid.reshape(-1),
-                        layer=i)
-                    return y.reshape(s_, t_, hd)
-
-                def mamba(h, p=p, i=i, left=left):
-                    k_, v_, conv, ssm = left["pools"]
-                    with jax.named_scope("ssm_mixer"):
-                        if state is None:
-                            out, conv, ssm = mamba2.mixer_step(
-                                cfg, p["mixer"], h, conv, ssm, i,
-                                valid[:, 0], impl=self.impl)
-                        else:
-                            out, conv, ssm = mamba2.mixer_chunk(
-                                cfg, p["mixer"], h, conv, ssm, i, *state,
-                                impl=self.impl)
-                    left["pools"] = (k_, v_, conv, ssm)
-                    return out
-
-                x = block.mixer_block(
-                    cfg, x, norm, {"attention": attention, "moe": experts,
-                                   "mamba": mamba}[kind],
-                    routed=kind == "moe")
-                pools = left["pools"]
-                if left["load"] is not None:
-                    load = left["load"] if load is None \
-                        else load + left["load"]
-        return pools, x, load
-
-    def _forward(self, params, pools, ids, positions, tables, ctx_lens,
-                 blk, off, valid, state=None):
-        """(pools', hidden [S, T, H], load): embed -> layer scan(s).  The
-        stacked pools ride the scan's CARRY with the residual — each
-        layer writes its rows in place and the kernel reads its pages
-        through the layer index, so nothing slices a layer out of the
-        stack or puts it back; ``xs`` are the stacked params and the
-        layer index.  An expert model's three expert kernel stacks
+    def _forward(self, params, pools, ids, positions, where, ctx_lens,
+                 valid):
+        """(pools', hidden [S, T, H], load): embed -> the plan's runs of
+        layers, a ``lax.scan`` of a run's body where it repeats, a walk
+        at static indices where it does not.  The stacked pools ride the
+        scan's CARRY with the residual — each layer writes its rows in
+        place and the kernel reads its pages through the layer index, so
+        nothing slices a layer out of the stack or puts it back; ``xs``
+        are the stacked params of the body's layers and the run's
+        counter.  An expert model's three expert kernel stacks
         [L, E, in, out] are NOT on ``xs``: a scan hands its body a slice
         of every ``xs`` leaf, and a custom call's operand cannot absorb
         that slice, so XLA would copy each layer's 336 MiB stacks into a
         second buffer before every grouped matmul (PERF.md PR 27).  The
         body closes over them whole — loop invariants of the ``while`` —
         and the kernel reads its layer through an index, like the pools.
-        The split is made here, at trace time, on the jitted function's
-        own argument: ``params`` stays the pytree it is and no weight is
-        copied.  The head projection is the caller's: decode
-        projects every slot's single row, prefill projects ONLY the
-        last valid row (the full-chunk head would be a C x hidden x
-        vocab matmul that is discarded for every row but one).  A model
-        with leading dense layers runs two scans over its two stacked
-        trees ('dense_layers', then 'layers'), the pool on both carries,
-        the layer index counting on; ``load`` is the expert layers'
-        counts summed (int32[3], models/moe.held_experts_ffn) or None
-        for a model without them.  ``state`` is a ``mixer_pattern``
-        model's alone (:meth:`_forward_mixers`)."""
+        The split is made here, once, at trace time, on the jitted
+        function's own argument: ``params`` stays the pytree it is and no
+        weight is copied (nor by a walk at static indices: an XLA dot
+        reads its layer's slice where it lies).  The head projection is
+        the caller's: decode projects every slot's single row, prefill
+        projects ONLY the last valid row (the full-chunk head would be a
+        C x hidden x vocab matmul that is discarded for every row but
+        one).  ``where`` is what addresses the pools in this step, by
+        what a kind's record says addresses its own (serve/kinds.py);
+        ``load`` is the expert layers' counts summed (int32[3],
+        models/moe.held_experts_ffn) or None for a model without them."""
         with jax.named_scope("embed"):
             x = embed_ids(self.cfg, params, ids, positions)
-        if self.two_kinds:
-            return self._forward_periods(params, pools, x, positions, tables,
-                                         ctx_lens, blk, off, valid)
-        if self.mixers:
-            return self._forward_mixers(params, pools, x, positions, tables,
-                                        ctx_lens, blk, off, valid, state)
+        trees, stacks = {}, {}
+        for at in (at for run in self.plan for at in run.body):
+            if at.tree in trees:
+                continue
+            tree = at.stack(params)["block"]
+            if "moe" in tree:
+                # in cfg.dtype the kernel wants them: a no-op on weights
+                # cast to serving precision, one conversion outside the
+                # scan otherwise
+                moe = tree["moe"]
+                stacks[at.tree] = {k: moe[k].astype(self.cfg.dtype)
+                                   for k in _EXPERT_STACKS if k in moe}
+                tree = {**tree, "moe": {k: v for k, v in moe.items()
+                                        if k not in _EXPERT_STACKS}}
+            trees[at.tree] = tree
 
-        layers, expert_stacks = params["layers"], None
-        moe = layers["block"].get("moe")
-        if moe is not None:
-            # in cfg.dtype the kernel wants them: a no-op on weights cast
-            # to serving precision, one conversion outside the scan
-            # otherwise
-            expert_stacks = {k: moe[k].astype(self.cfg.dtype)
-                             for k in _EXPERT_STACKS}
-            layers = {**layers, "block": {**layers["block"], "moe": {
-                k: v for k, v in moe.items() if k not in _EXPERT_STACKS}}}
-
-        def body(carry, per):
+        def body(run, carry, per):
             x, pools = carry
-            p_l, layer = per
-            x, pools, load = self._layer(
-                p_l["block"], layer, x, pools, positions, tables, ctx_lens,
-                blk, off, valid, expert_stacks)
+            layers, n = per
+            load = None
+            for at in run.layers(n):
+                p = (layers[at.tree] if run.scanned else jax.tree.map(
+                    lambda a, at=at: a[at.at], trees[at.tree]))
+                x, pools, one = self._layer(
+                    p, at, x, pools, positions, where, ctx_lens, valid,
+                    stacks.get(at.tree))
+                if one is not None:
+                    load = one if load is None else load + one
             return (x, pools), load
 
-        n_dense, n = self.cfg.first_dense_layers, self.cfg.num_layers
-        with jax.named_scope("layers"):
-            if n_dense:
-                (x, pools), _ = jax.lax.scan(
-                    body, (x, pools),
-                    (params["dense_layers"],
-                     jnp.arange(n_dense, dtype=jnp.int32)))
-            (x, pools), load = jax.lax.scan(
-                body, (x, pools),
-                (layers, jnp.arange(n_dense, n, dtype=jnp.int32)))
-        return pools, x, (None if load is None else jnp.sum(load, axis=0))
+        total = None
+        for run in self.plan:
+            step = functools.partial(body, run)
+            with jax.named_scope("layers"):
+                if run.scanned:
+                    (x, pools), load = jax.lax.scan(step, (x, pools), (
+                        {at.tree: trees[at.tree] for at in run.body},
+                        jnp.arange(run.repeats, dtype=jnp.int32)))
+                else:
+                    (x, pools), load = step((x, pools), (None, 0))
+            if load is not None:
+                # a scan stacks its body's counts a repetition
+                load = jnp.sum(load, axis=0) if run.scanned else load
+                total = load if total is None else total + load
+        return pools, x, total
 
     # -- sampling -----------------------------------------------------------
 
@@ -893,36 +385,27 @@ class PagedDecoder:
 
     # -- jitted steps -------------------------------------------------------
 
-    def _decode_impl(self, params, pools, carry, tables, seq_lens, active,
-                     temp, top_k, top_p, all_greedy, win_tables=None):
+    def _decode_impl(self, params, pools, carry, addr, seq_lens, active,
+                     temp, top_k, top_p, all_greedy):
         """One decode token for every slot.  ``seq_lens`` is the banked
         length BEFORE this token; free slots (active=False) run on the
-        null block and their sampled tokens are ignored by the host."""
+        null block and their sampled tokens are ignored by the host.
+        ``addr`` holds every table by name, [S, MB]; what is kept by slot
+        is read at the slot's own index."""
         bs = self.block_size
         tok = carry["tok"]
         positions = seq_lens[:, None]
-
-        def block_of(table):
-            return jnp.where(
-                active,
-                jnp.take_along_axis(table, (seq_lens // bs)[:, None],
-                                    axis=1)[:, 0],
-                0)
-        blk = block_of(tables)
         off = jnp.where(active, seq_lens % bs, 0)
+        where = {name: (table, jnp.where(
+            active,
+            jnp.take_along_axis(table, (seq_lens // bs)[:, None],
+                                axis=1)[:, 0],
+            0)[:, None], off[:, None]) for name, table in addr.items()}
+        where["slot"] = {"active": active}
         ctx = jnp.where(active, seq_lens + 1, 0)
-        if win_tables is None:
-            # (a mixer_pattern model's decode step reads its slots' state
-            # by slot index: _forward's state=None)
-            pools, x, load = self._forward(params, pools, tok[:, None],
-                                           positions, tables, ctx,
-                                           blk[:, None], off[:, None],
-                                           active[:, None])
-        else:
-            pools, x, load = self._forward(
-                params, pools, tok[:, None], positions, (tables, win_tables),
-                ctx, (blk[:, None], block_of(win_tables)[:, None]),
-                off[:, None], active[:, None])
+        pools, x, load = self._forward(params, pools, tok[:, None],
+                                       positions, where, ctx,
+                                       active[:, None])
         with jax.named_scope("head"):
             logits = head_logits(self.cfg, params, x)
         with jax.named_scope("sample"):
@@ -934,8 +417,8 @@ class PagedDecoder:
                                           top_k, top_p)
         return pools, {"tok": toks, "key": split[:, 0]}, toks, load
 
-    def _prefill_impl(self, params, pools, table_row, t0, tokens, n_valid,
-                      is_final, win_row=None, slot=None):
+    def _prefill_impl(self, params, pools, addr, t0, tokens, n_valid,
+                      is_final):
         """One chunk of ONE sequence: bank k/v for tokens
         [t0, t0 + n_valid) and return the last valid row's logits (the
         first-token sampling input when this is the final chunk;
@@ -943,38 +426,31 @@ class PagedDecoder:
         output is 100% discarded — and return None).  The pad tail
         writes to the null block and its positions clamp to the newest
         real position (keeps learned-position table lookups in range
-        and longrope's max(positions) regime switch exact)."""
+        and longrope's max(positions) regime switch exact).  ``addr``
+        holds the sequence's row [MB] of every table by name and, where
+        a kind keeps state by slot, its 'slot': the chunk starts from
+        that slot's state, from zero where it is the request's first."""
         bs, c = self.block_size, self.chunk
         i = jnp.arange(c, dtype=jnp.int32)
         valid = i < n_valid
         pos = t0 + i
         last_pos = jnp.maximum(t0 + n_valid - 1, 0)
         positions = jnp.where(valid, pos, last_pos)[None]          # [1, C]
-        blk = jnp.where(valid, table_row[pos // bs], 0)
         off = jnp.where(valid, pos % bs, 0)
         ctx = (t0 + n_valid)[None]
-        if win_row is None:
-            # a mixer_pattern model's chunk starts from its slot's state,
-            # from zero where the chunk is the request's first
-            state = (None if slot is None else
-                     (slot[None], (t0 == 0)[None], n_valid[None]))
-            pools, x, load = self._forward(params, pools, tokens[None],
-                                           positions, table_row[None], ctx,
-                                           blk[None], off[None], valid[None],
-                                           state)
-        else:
-            win_blk = jnp.where(valid, win_row[pos // bs], 0)
-            pools, x, load = self._forward(
-                params, pools, tokens[None], positions,
-                (table_row[None], win_row[None]), ctx,
-                (blk[None], win_blk[None]), off[None], valid[None])
+        where = {name: (row[None], jnp.where(valid, row[pos // bs], 0)[None],
+                        off[None])
+                 for name, row in addr.items() if name != "slot"}
+        if "slot" in addr:
+            where["slot"] = {"slots": addr["slot"][None],
+                             "fresh": (t0 == 0)[None],
+                             "n_valid": n_valid[None]}
+        pools, x, load = self._forward(params, pools, tokens[None],
+                                       positions, where, ctx, valid[None])
         if not is_final:
             return pools, None, load
         with jax.named_scope("head"):
-            if self.mixers:
-                # the last valid row alone through the head: a chunk's
-                # logits over this family's vocabulary are 256 MiB of
-                # float32 beside pools that leave no such room
+            if self._head_last_row:
                 row = jnp.take_along_axis(
                     x, jnp.maximum(n_valid - 1, 0)[None, None, None], axis=1)
                 return pools, head_logits(self.cfg, params, row)[0, 0], load
@@ -984,39 +460,38 @@ class PagedDecoder:
                 axis=0)[0]                                         # [V]
         return pools, last, load
 
-    def _prefill_batch_impl(self, params, pools, table_rows, t0s, tokens,
-                            n_valids, win_rows=None, slots=None):
+    def _prefill_batch_impl(self, params, pools, addr, t0s, tokens,
+                            n_valids):
         """One chunk each of up to ``prefill_batch`` DISTINCT sequences
-        in one program: ``table_rows`` [PB, MB], ``t0s``/``n_valids``
+        in one program: ``addr`` the rows' table rows [PB, MB] by name,
+        ``t0s``/``n_valids``
         [PB] (0 valid = padded row: runs on the null block, output
         discarded), ``tokens`` [PB, C].  Returns the last valid row's
         logits per sequence [PB, V] — the only rows anyone reads (final
         rows sample their first token from them; non-final and padded
         rows are ignored by the host), so the head is a [PB, H] x
         [H, V] matmul, not the full-chunk head, and final-vs-non-final
-        needs no static flag: trace count is 1.  ``slots`` [PB] are the
-        rows' slots in a ``mixer_pattern`` model's state pools."""
+        needs no static flag: trace count is 1.  ``addr['slot']`` [PB]
+        are the rows' slots where a kind keeps state by slot (the null
+        slot for a padded row, which starts fresh and is read by no
+        one)."""
         bs, c = self.block_size, self.chunk
         i = jnp.arange(c, dtype=jnp.int32)[None, :]              # [1, C]
         valid = i < n_valids[:, None]                            # [PB, C]
         pos = t0s[:, None] + i
         last_pos = jnp.maximum(t0s + n_valids - 1, 0)[:, None]
         positions = jnp.where(valid, pos, last_pos)              # [PB, C]
-        blk = jnp.where(
-            valid, jnp.take_along_axis(table_rows, pos // bs, axis=1), 0)
         off = jnp.where(valid, pos % bs, 0)
         ctx = t0s + n_valids                                     # [PB]
-        if win_rows is not None:
-            blk = (blk, jnp.where(valid, jnp.take_along_axis(
-                win_rows, pos // bs, axis=1), 0))
-            table_rows = (table_rows, win_rows)
-        # (a mixer_pattern model: the rows' slots — the null slot for a
-        # padded row, which starts fresh and is read by no one)
-        state = (None if slots is None else
-                 (slots, (t0s == 0) | (n_valids == 0), n_valids))
+        where = {name: (rows, jnp.where(valid, jnp.take_along_axis(
+            rows, pos // bs, axis=1), 0), off)
+                 for name, rows in addr.items() if name != "slot"}
+        if "slot" in addr:
+            where["slot"] = {"slots": addr["slot"],
+                             "fresh": (t0s == 0) | (n_valids == 0),
+                             "n_valid": n_valids}
         pools, x, load = self._forward(params, pools, tokens, positions,
-                                       table_rows, ctx, blk, off, valid,
-                                       state)
+                                       where, ctx, valid)
         with jax.named_scope("head"):
             last = jnp.take_along_axis(
                 x, jnp.maximum(n_valids - 1, 0)[:, None, None], axis=1)
@@ -1030,7 +505,8 @@ class PagedDecoder:
         token must re-run (its logits seed the first sampled token) and
         its k/v write needs a block this sequence owns; everything
         before it stays shared."""
-        return tuple(p.at[:, dst].set(p[:, src]) for p in pools)
+        return {name: p.at[:, dst].set(p[:, src])
+                for name, p in pools.items()}
 
     def _sample_first_impl(self, logits, key, temp, top_k, top_p):
         with jax.named_scope("sample"):
@@ -1210,7 +686,7 @@ class Scheduler:
         self.prefix = (PrefixIndex(serve_cfg.block_size)
                        if serve_cfg.prefix_cache else None)
         self.pool = BlockPool(serve_cfg.num_blocks, index=self.prefix)
-        # (k, v) stacks, or the one latent stack: a tuple either way,
+        # the pools of the model's kinds of layer by name (serve/kinds.py),
         # donated to and returned by every step
         self.pools = make_pools(model_cfg, serve_cfg)
         s = serve_cfg.max_slots
@@ -1230,7 +706,7 @@ class Scheduler:
         # a model with window layers: their blocks, held only while the
         # window reaches them, and their table
         self.window = None
-        if self.decoder.two_kinds:
+        if "window" in self.decoder.by:
             self.window = WindowBlocks(
                 num_window_blocks(model_cfg, serve_cfg),
                 serve_cfg.block_size, model_cfg.window[0],
@@ -1239,14 +715,15 @@ class Scheduler:
                                     serve_cfg.block_size))
             self.win_tables = np.zeros_like(self.tables)
             self._dev_win = None
-        # a model with state-space layers: bytes one slot's state takes
-        # in all of them (what a decode step reads and writes a slot)
-        self._ssm_layers = self._slot_state_bytes = 0
-        if self.decoder.mixers and "mamba" in model_cfg.mixer_pattern:
-            from torchacc_tpu.models import mamba2
-            self._ssm_layers = layer_kinds(model_cfg).count("mamba")
-            self._slot_state_bytes = (self._ssm_layers
-                                      * mamba2.state_bytes(model_cfg))
+        # a model with state-space layers (what is kept by slot): how
+        # many, and the bytes one slot's state takes in all of them (what
+        # a decode step reads and writes a slot)
+        by_slot = [(record, n) for record, _, n in self.decoder.kinds.values()
+                   if record.by == "slot"]
+        self._ssm_layers = sum(n for _, n in by_slot)
+        self._slot_state_bytes = sum(
+            self.pools[name].nbytes // self.pools[name].shape[1]
+            for record, _ in by_slot for name in record.names)
         self.seq_lens = np.zeros((s,), np.int32)
         self.active = np.zeros((s,), bool)
         self.temp = np.zeros((s,), np.float32)
@@ -1263,9 +740,8 @@ class Scheduler:
         self._iter = 0            # decode iterations dispatched
         self._step_idx = 0        # step() calls completed
         self._resolved = 0        # decode iterations resolved
-        self._deferred: List[Tuple[int, List[int]]] = []
-        # the same for an evicted sequence's window-layer blocks
-        self._deferred_window: List[Tuple[int, List[int]]] = []
+        # an evicted sequence's blocks, each kind's with who takes it back
+        self._deferred: List[Tuple[int, List[int], Any]] = []
         # newly finished sequences, drained by the engine each step —
         # completion accounting stays O(finished this step), never a
         # scan over every request the process has served
@@ -1301,11 +777,6 @@ class Scheduler:
         if self.prefix is None:
             return total
         return max(1, total - seq.prompt_len // self.serve_cfg.block_size)
-
-    def can_admit(self, seq: Sequence) -> bool:
-        return (self.free_slot() is not None
-                and self.pool.can_alloc(self.blocks_for(seq))
-                and (self.window is None or self.window.can_reserve()))
 
     def admit(self, seq: Sequence) -> bool:
         """Give ``seq`` a decode slot + its whole block reservation, or
@@ -1527,6 +998,38 @@ class Scheduler:
         self._step_idx += 1
         return did
 
+    def _addr(self, seqs=None):
+        """The addressing pytree a step of PagedDecoder takes: every
+        table by name and, in a prefill of a model that keeps state by
+        slot, 'slot'.  None: a decode step — every slot's rows, the
+        device copies kept until something changes them.  A Sequence:
+        its own row and slot.  A list of them: a row each, padded to
+        ``prefill_batch`` rows (a padded row runs on the null block and
+        on the null slot, behind the real ones)."""
+        host = {"blocks": self.tables}
+        if self.window is not None:
+            host["window"] = self.win_tables
+        if seqs is None:
+            addr = {"blocks": self._dev_stable_arrays()[0]}
+            if self.window is not None:
+                if self._dev_win is None:
+                    self._dev_win = _upload(self.win_tables)
+                addr["window"] = self._dev_win
+            return addr
+        if isinstance(seqs, Sequence):
+            slots = np.int32(seqs.slot)
+        else:
+            slots = np.full((self.serve_cfg.prefill_batch,),
+                            self.serve_cfg.max_slots, np.int32)
+            slots[:len(seqs)] = [seq.slot for seq in seqs]
+        # (the null slot's row names the null block alone)
+        addr = {name: jnp.asarray(
+            np.concatenate([t, np.zeros_like(t[:1])])[slots])
+            for name, t in host.items()}
+        if "slot" in self.decoder.by:
+            addr["slot"] = jnp.asarray(slots)
+        return addr
+
     def _prefill_one(self, seq: Sequence) -> None:
         c = self.serve_cfg.prefill_chunk
         t0 = seq.prefilled
@@ -1535,19 +1038,16 @@ class Scheduler:
         if n_valid < c:
             chunk = np.pad(chunk, (0, c - n_valid))
         final = (t0 + n_valid) >= seq.prompt_len
-        win, state = (), {}
         if self.window is not None:
             self._before_prefill(seq, t0, n_valid)
-            win = (_upload(self.win_tables[seq.slot]),)
-        if self.decoder.mixers:
-            state = {"slot": jnp.asarray(seq.slot, jnp.int32)}
+        addr = self._addr(seq)
         with tracing.span("serve/prefill", sid=seq.sid, t0=t0,
                           tokens=n_valid, batched=False,
                           trace=seq.trace_id):
             self.pools, last_logits, load = self.decoder._prefill(
-                self.params, self.pools, _upload(self.tables[seq.slot]),
+                self.params, self.pools, addr,
                 jnp.asarray(t0, jnp.int32), jnp.asarray(chunk, jnp.int32),
-                jnp.asarray(n_valid, jnp.int32), final, *win, **state)
+                jnp.asarray(n_valid, jnp.int32), final)
         if load is not None:
             seq.loads.append(load)
         seq.prefill_programs += 1
@@ -1564,7 +1064,6 @@ class Scheduler:
         discarded) so the program traces exactly once."""
         pb = self.serve_cfg.prefill_batch
         c = self.serve_cfg.prefill_chunk
-        tables = np.zeros((pb, self.max_blocks_per_seq), np.int32)
         t0s = np.zeros((pb,), np.int32)
         toks = np.zeros((pb, c), np.int32)
         n_valids = np.zeros((pb,), np.int32)
@@ -1573,32 +1072,20 @@ class Scheduler:
             t0 = seq.prefilled
             chunk = seq.prompt[t0:t0 + c]
             n = int(chunk.shape[0])
-            tables[r] = self.tables[seq.slot]
             t0s[r] = t0
             toks[r, :n] = chunk
             n_valids[r] = n
             taken.append(n)
             if self.window is not None:
                 self._before_prefill(seq, t0, n)
-        win, state = (), {}
-        if self.window is not None:
-            win_rows = np.zeros_like(tables)
-            for r, seq in enumerate(seqs):
-                win_rows[r] = self.win_tables[seq.slot]
-            win = (jnp.asarray(win_rows),)
-        if self.decoder.mixers:
-            # a padded row runs on the null slot, behind the real ones
-            slots = np.full((pb,), self.serve_cfg.max_slots, np.int32)
-            slots[:len(seqs)] = [seq.slot for seq in seqs]
-            state = {"slots": jnp.asarray(slots)}
+        addr = self._addr(seqs)
         with tracing.span("serve/prefill", batched=True,
                           sids=[s.sid for s in seqs],
                           traces=[s.trace_id for s in seqs],
                           tokens=int(sum(taken))):
             self.pools, logits, load = self.decoder._prefill_batch(
-                self.params, self.pools, jnp.asarray(tables),
-                jnp.asarray(t0s), jnp.asarray(toks), jnp.asarray(n_valids),
-                *win, **state)
+                self.params, self.pools, addr,
+                jnp.asarray(t0s), jnp.asarray(toks), jnp.asarray(n_valids))
         if load is not None:
             seqs[0].loads.append(load)       # one program, counted once
         for r, seq in enumerate(seqs):
@@ -1662,7 +1149,7 @@ class Scheduler:
         failpoint("serve.decode", iter=self._iter)
         snapshot = [(i, s) for i, s in enumerate(self.slot_seq)
                     if self.active[i] and s is not None]
-        win, selected = (), None
+        selected = None
         if self.window is not None:
             for slot, seq in snapshot:
                 n = int(self.seq_lens[slot])
@@ -1670,17 +1157,15 @@ class Scheduler:
             selected = self._note_attended(
                 self.seq_lens[[slot for slot, _ in snapshot]].astype(
                     np.int64) + 1)           # cached positions a query
-            if self._dev_win is None:
-                self._dev_win = _upload(self.win_tables)
-            win = (self._dev_win,)
         state = None
-        if self.decoder.mixers:
+        if self._ssm_layers:
             # every decoding slot's state is read and written once a
             # state-space layer; an attention layer's query sees the
             # slot's cached positions and its own
             state = (len(snapshot), int(self.seq_lens[
                 [slot for slot, _ in snapshot]].sum()) + len(snapshot))
-        tables, active, temp, top_k, top_p = self._dev_stable_arrays()
+        addr = self._addr()
+        _, active, temp, top_k, top_p = self._dev_stable_arrays()
         all_greedy = bool((self.temp[self.active] <= 0.0).all())
         # per-request trace ids on the batched span: built only while
         # tracing records (the list comprehension must cost nothing on
@@ -1691,8 +1176,8 @@ class Scheduler:
                           slots=len(snapshot), traces=_traces):
             self.pools, self.carry, toks, load = self.decoder._decode(
                 self.params, self.pools, self.carry,
-                tables, _upload(self.seq_lens),
-                active, temp, top_k, top_p, all_greedy, *win)
+                addr, _upload(self.seq_lens),
+                active, temp, top_k, top_p, all_greedy)
         # host mirror: every active slot banked one more token
         self.seq_lens[self.active] += 1
         self._ring.append(_InFlight(
@@ -1764,29 +1249,21 @@ class Scheduler:
         if self.window is not None:
             self.win_tables[slot, :] = 0
             self._dev_win = None
-            self._deferred_window.append(
-                (self._iter, list(seq.win_blocks.values())))
-        self._deferred.append((self._iter, seq.blocks))
+            self._deferred.append((self._iter, list(seq.win_blocks.values()),
+                                   self.window.release))
+        self._deferred.append((self._iter, seq.blocks, self.pool.free))
         seq.blocks, seq.win_blocks = [], {}
         self._release_matured()
 
     def _release_matured(self) -> None:
         ring_empty = not any(e.kind == "decode" for e in self._ring)
         keep = []
-        for after, blocks in self._deferred:
+        for after, blocks, release in self._deferred:
             if self._resolved >= after or ring_empty:
-                self.pool.free(blocks)
+                release(blocks)
             else:
-                keep.append((after, blocks))
+                keep.append((after, blocks, release))
         self._deferred = keep
-        if self.window is not None:
-            keep = []
-            for after, blocks in self._deferred_window:
-                if self._resolved >= after or ring_empty:
-                    self.window.release(blocks)
-                else:
-                    keep.append((after, blocks))
-            self._deferred_window = keep
 
     def _resolve_one(self) -> None:
         entry = self._ring.popleft()
