@@ -17,10 +17,23 @@ from torchacc_tpu.parallel.sharding import (
 
 def test_spec_for_basic():
     rules = make_rules()
-    assert spec_for(("embed", "mlp"), rules) == P("fsdp", "tp")
+    # ZeRO-3's hidden dim over fsdp and, since PR 46, over ep too: the
+    # chips that hold the experts hold the rest of the state in shares
+    assert spec_for(("embed", "mlp"), rules) == P(("fsdp", "ep"), "tp")
     assert spec_for(("batch", "seq", None), rules) == P(
-        ("dp", "fsdp"), ("sp", "spu"), None)
+        ("dp", "fsdp", "ep"), ("sp", "spu"), None)
     assert spec_for(("kv",), rules) == P(None)
+
+
+def test_an_expert_leafs_expert_dim_takes_ep_before_its_hidden_dim():
+    """``experts/gate`` [layers, expert, embed, expert_mlp]: the expert
+    dim comes first and takes 'ep', so the hidden dim keeps 'fsdp' alone
+    (a mesh axis shards one dim of a leaf)."""
+    rules = make_rules()
+    assert spec_for(("layers", "expert", "embed", "expert_mlp"), rules) \
+        == P(None, "ep", ("fsdp",), "tp")
+    assert spec_for(("layers", "expert", "expert_mlp", "embed"), rules) \
+        == P(None, "ep", "tp", ("fsdp",))
 
 
 def test_spec_no_duplicate_mesh_axes():
@@ -31,7 +44,7 @@ def test_spec_no_duplicate_mesh_axes():
 
 
 def test_batch_spec():
-    assert batch_spec() == P(("dp", "fsdp"), ("sp", "spu"))
+    assert batch_spec() == P(("dp", "fsdp", "ep"), ("sp", "spu"))
 
 
 def test_tree_shardings_divisibility_and_min_size(devices):
@@ -46,7 +59,7 @@ def test_tree_shardings_divisibility_and_min_size(devices):
     }
     axes = {"w": ("embed", "mlp"), "scale": ("embed",), "odd": ("embed", "mlp")}
     sh = tree_shardings(mesh, abstract, axes, rules, min_weight_size=1024)
-    assert sh["w"].spec == P("fsdp", "tp")
+    assert sh["w"].spec == P(("fsdp", "ep"), "tp")
     # below min_weight_size -> replicated
     assert sh["scale"].spec == P(None)
     # 63 not divisible by fsdp=2 -> that dim falls back to replicated

@@ -85,6 +85,17 @@ FIRST_TOKEN_ATTRS = ("sid", "prefill_programs", "queue_steps",
                      "wait_steps", "queue_ms", "prefill_ms", "lag_ms",
                      "ttft_ms")
 
+# a train step of a model with dropless expert layers, its experts over
+# 'ep' (PR 46): the exchange's scope beside the expert layer's others,
+# and the backward kernels by name under `experts`
+ROUTED_TRAIN_SCOPES = ("router", "moe_dispatch", "moe_exchange", "experts",
+                       "moe_combine", "flash_fwd", "flash_dq", "flash_dkv",
+                       "fused_ce")
+ROUTED_TRAIN_KERNELS = ("grouped_matmul", "gmm_dx", "gmm_dw")
+# what its train/step spans carry while a sink listens
+EXPERT_STEP_ATTRS = ("resolved_step", "moe_pairs", "moe_max", "moe_hit",
+                     "moe_slots", "moe_layer_steps", "aux_loss")
+
 # the spans a traced serve loop / fit has to leave on the host plane
 SERVE_SPANS = ("serve/step", "serve/sweep", "serve/admit", "serve/prefill",
                "serve/decode", "serve/deliver", "serve/wait")
@@ -111,6 +122,41 @@ def _latent_model_cfg():
         moe_scoring="sigmoid", moe_n_group=2, moe_topk_group=1,
         moe_route_scale=2.5, moe_shared_experts=1, moe_router_width=8,
         moe_first_expert=2, moe_dispatch="grouped")
+
+
+def _routed_model_cfg():
+    """A toy of the mellum family (sliding and YaRN full layers 3:1 under
+    softmax-routed experts on the dropless path), one expert a device."""
+    import types
+
+    from torchacc_tpu.models.hf import config_from_hf
+    n = len(jax.devices())
+    pub = dict(
+        model_type="mellum", hidden_size=64, intermediate_size=128,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        vocab_size=256, num_hidden_layers=4, rms_norm_eps=1e-6,
+        layer_types=["sliding_attention"] * 3 + ["full_attention"],
+        mlp_layer_types=["sparse"] * 4, sliding_window=8,
+        num_experts=n, num_experts_per_tok=2, moe_intermediate_size=32,
+        norm_topk_prob=True, tie_word_embeddings=False,
+        rope_parameters={
+            "sliding_attention": {"rope_type": "default",
+                                  "rope_theta": 500000},
+            "full_attention": {"rope_type": "yarn", "rope_theta": 500000,
+                               "factor": 16, "beta_fast": 32, "beta_slow": 1,
+                               "original_max_position_embeddings": 16,
+                               "attention_factor": 1.2772588722239782}})
+    return config_from_hf(types.SimpleNamespace(**pub), max_seq_len=32,
+                          dtype=jnp.float32)
+
+
+def _routed_trainer():
+    cfg = ta.Config()
+    cfg.compute.attention_impl = "pallas"
+    cfg.dist.ep.size = len(jax.devices())
+    trainer, _ = accelerate(_routed_model_cfg(), None, cfg,
+                            optimizer=optax.adamw(1e-3))
+    return trainer
 
 
 def _sparse_model():
@@ -240,6 +286,16 @@ def program_scopes():
         train = trainer._train_step.lower(trainer.state, batch) \
             .compile().as_text()
 
+    rtrainer = _routed_trainer()
+    rbatch = {"input_ids": jnp.zeros((len(jax.devices()), 32), jnp.int32)}
+    shardings = rtrainer._batch_shardings(rbatch)
+    rbatch = {k: jax.device_put(v, shardings[k]) for k, v in rbatch.items()}
+    rtrainer.init()
+    rtrainer._ensure_compiled(rbatch)
+    with jax.sharding.set_mesh(rtrainer.mesh):
+        routed_train = rtrainer._train_step.lower(rtrainer.state, rbatch) \
+            .compile().as_text()
+
     from torchacc_tpu.serve.scheduler import PagedDecoder
     mc = _model_cfg()
     sc = ta.config.ServeConfig(block_size=8, num_blocks=16, max_slots=2,
@@ -302,6 +358,11 @@ def program_scopes():
         {"blocks": row, "slot": i32}, i32,
         sds((8,), jnp.int32), i32, True).compile().as_text()
     return {"train": _scopes_in(train), "decode": _scopes_in(decode),
+            "routed_train": _scopes_in(routed_train),
+            # the kernels by the names the traced ops carry under the
+            # `experts` scope: .../experts/gmm_dw/...
+            "routed_train_kernels": set(re.findall(
+                r'op_name="[^"]*/experts/(\w+)/', routed_train)),
             "ssm_decode": _scopes_in(ssm_decode),
             "ssm_prefill": _scopes_in(ssm_prefill),
             "prefill": _scopes_in(prefill),
@@ -318,6 +379,7 @@ def program_scopes():
 
 @pytest.mark.parametrize("program,scope", [
     *(("train", s) for s in TRAIN_SCOPES),
+    *(("routed_train", s) for s in ROUTED_TRAIN_SCOPES),
     *(("decode", s) for s in DECODE_SCOPES),
     *(("prefill", s) for s in PREFILL_SCOPES),
     *(("latent_decode", s) for s in LATENT_DECODE_SCOPES),
@@ -328,6 +390,15 @@ def program_scopes():
 def test_device_scope_in_compiled_program(program_scopes, program, scope):
     assert scope in tracing.DEVICE_SCOPES
     assert scope in program_scopes[program]
+
+
+@pytest.mark.parametrize("kernel", ROUTED_TRAIN_KERNELS)
+def test_grouped_matmul_kernels_are_told_apart_by_name(program_scopes,
+                                                       kernel):
+    """``expert_matmul_train_roofline`` reads the scope ``experts``; a
+    trace tells the forward product from dX and from dW by the kernel's
+    own name under it."""
+    assert kernel in program_scopes["routed_train_kernels"]
 
 
 def test_every_pool_write_of_the_sparse_program_is_a_kv_write(
@@ -341,7 +412,8 @@ def test_every_pool_write_of_the_sparse_program_is_a_kv_write(
 
 
 def test_every_registered_scope_is_placed():
-    placed = (set(TRAIN_SCOPES) | set(DECODE_SCOPES) | set(PREFILL_SCOPES)
+    placed = (set(TRAIN_SCOPES) | set(ROUTED_TRAIN_SCOPES)
+              | set(DECODE_SCOPES) | set(PREFILL_SCOPES)
               | set(LATENT_DECODE_SCOPES) | set(SPARSE_DECODE_SCOPES)
               | set(WINDOW_DECODE_SCOPES) | set(SSM_DECODE_SCOPES)
               | set(SSM_PREFILL_SCOPES))
@@ -399,6 +471,12 @@ def traced(tmp_path_factory):
                 .astype(np.int32)} for _ in range(3)]
     trainer.fit(batches[:1], log_every=1)  # compile outside the trace
 
+    rtrainer = _routed_trainer()
+    rbatches = [{"input_ids": rng.integers(
+        0, 256, size=(len(jax.devices()), 32)).astype(np.int32)}
+        for _ in range(3)]
+    rtrainer.fit(rbatches[:1], log_every=1)  # compile outside the trace
+
     lmodel = TransformerLM(_latent_model_cfg())
     lengine = ServeEngine(
         lmodel, lmodel.init(jax.random.PRNGKey(1),
@@ -443,6 +521,7 @@ def traced(tmp_path_factory):
     try:
         engine.generate(reqs)
         trainer.fit(batches, log_every=1)
+        rtrainer.fit(rbatches, log_every=1)
         lengine.generate(lreqs)
         sengine.generate(sreqs)
         wengine.generate(wreqs)
@@ -462,6 +541,23 @@ def traced(tmp_path_factory):
 def test_span_on_the_profilers_host_plane(traced, span):
     assert span in tracing.SPAN_NAMES
     assert any(name == span for name, *_ in traced["events"])
+
+
+@pytest.mark.parametrize("attr", EXPERT_STEP_ATTRS)
+def test_train_step_span_carries_the_expert_layers_load(traced, attr):
+    """``expert_load_max_over_mean.train`` and
+    ``expert_matmul_train_roofline`` read them
+    (chipbench/readers/expert_load.py): every pair is on some shard's
+    held expert, so a resolved step counts rows x top-k pairs a layer."""
+    spans = [st for name, _, _, st in traced["events"]
+             if name == "train/step" and "moe_pairs" in st]
+    assert spans and all(attr in st for st in spans)
+    n = len(jax.devices())
+    for st in spans:
+        assert int(st["moe_layer_steps"]) == 4
+        assert int(st["moe_slots"]) == 4 * n
+        assert int(st["moe_pairs"]) == 4 * n * 32 * 2
+        assert int(st["moe_max"]) * n >= int(st["moe_pairs"])
 
 
 def _covered(events, child, parent):

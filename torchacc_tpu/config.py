@@ -41,9 +41,12 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 MESH_AXES: Tuple[str, ...] = ("dp", "pp", "fsdp", "sp", "spu", "ep", "tp")
 
 # Axes along which the *batch* is split.  ``fsdp`` shards data as well as
-# params (ZeRO data parallelism); ``ep`` ranks also consume distinct data
-# when experts are laid out across otherwise-data-parallel workers.
-DATA_AXES: Tuple[str, ...] = ("dp", "fsdp")
+# params (ZeRO data parallelism); ``ep`` ranks also consume distinct data:
+# the experts are laid out across otherwise-data-parallel workers, which
+# exchange their rows inside the expert layer (models/moe.routed_experts)
+# and hold every other parameter's state as ``fsdp`` ranks do
+# (parallel/sharding.DEFAULT_RULES: ``embed`` over ``fsdp`` then ``ep``).
+DATA_AXES: Tuple[str, ...] = ("dp", "fsdp", "ep")
 
 
 class ConfigError(ValueError):

@@ -389,6 +389,69 @@ def config_from_hf(hf_config: Any, **overrides) -> ModelConfig:
             moe_router_width=int(get("router_n_experts", held)),
             moe_first_expert=int(get("first_held_expert", 0)),
             moe_dispatch="grouped")
+    if mt == "mellum":
+        # Mellum 2 (JetBrains): the Qwen3-MoE block — grouped-query
+        # attention with RMSNorm over each head of q and k before rope
+        # (the family's block has it without a config key), pre-norm,
+        # every MLP `sparse`: softmax scores over `num_experts`, the
+        # `num_experts_per_tok` largest, renormalised (`norm_topk_prob`),
+        # no shared expert — under `layer_types` that mix
+        # 'sliding_attention' layers (`sliding_window` positions, plain
+        # rope) with 'full_attention' layers whose rope is YaRN-stretched:
+        # `rope_parameters` holds one section a kind.  The experts run on
+        # the dropless grouped path, which a train step spreads over
+        # 'ep' (models/moe.routed_experts).  The multi-token-prediction
+        # head the release describes has no key here and is not built.
+        types_ = list(get("layer_types") or [])
+        n = int(overrides.get("num_layers", kw["num_layers"]))
+        if len(types_) < n:
+            raise ValueError(
+                f"mellum layer_types names {len(types_)} layers, "
+                f"num_layers is {n}")
+        if any(t != "sparse" for t in (get("mlp_layer_types") or [])[:n]):
+            raise NotImplementedError(
+                "mellum with dense MLP layers (mlp_layer_types)")
+        if get("hidden_act", "silu") != "silu":
+            raise NotImplementedError("mellum hidden_act must be silu")
+        rp = get("rope_parameters") or {}
+        full = rp.get("full_attention") or {}
+        slide = rp.get("sliding_attention") or {}
+        if slide.get("rope_type", "default") != "default" \
+                or full.get("rope_type", "default") not in ("default",
+                                                            "yarn"):
+            raise NotImplementedError(
+                f"mellum rope types {slide.get('rope_type')!r} (sliding) "
+                f"/ {full.get('rope_type')!r} (full)")
+        theta = float(full.get("rope_theta", kw["rope_theta"]))
+        local = float(slide.get("rope_theta", theta))
+        kw.update(
+            rope_theta=theta,
+            rope_local_theta=None if local == theta else local,
+            qk_norm=True,
+            layer_pattern=tuple(
+                "sliding" if t == "sliding_attention" else "global"
+                for t in types_[:n]),
+            num_experts=int(get("num_experts")),
+            num_experts_per_tok=int(get("num_experts_per_tok")),
+            moe_intermediate_size=int(get("moe_intermediate_size")),
+            moe_scoring="softmax",
+            moe_renorm_topk=bool(get("norm_topk_prob", True)),
+            # the family's load-balance loss is one mean over the layers;
+            # the program SUMS the layers' terms
+            router_aux_weight=float(get("router_aux_loss_coef", 0.001)) / n,
+            moe_dispatch="grouped")
+        if full.get("rope_type") == "yarn":
+            af = full.get("attention_factor")
+            kw.update(
+                rope_yarn=(
+                    float(full["factor"]),
+                    float(full.get("original_max_position_embeddings")
+                          or kw["max_seq_len"]),
+                    float(full.get("beta_fast") or 32.0),
+                    float(full.get("beta_slow") or 1.0),
+                    None if af is None else float(af),
+                    bool(full.get("truncate", True))),
+                rope_yarn_kinds=("global",))
     if mt == "nemotron_h":
         # Nemotron-H / Nemotron 3 (NVIDIA): every layer is ONE mixer under
         # a pre-norm, x + mixer(RMSNorm(x)), named by a character of

@@ -11,11 +11,21 @@ style) rather than a hand-written NCCL a2a.
 
 from __future__ import annotations
 
+import dataclasses
+import functools
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from torchacc_tpu.ops._common import ambient_mesh, batch_axes, needs_shard_map
 from torchacc_tpu.ops.grouped_matmul import grouped_matmul
+
+# the most (token, expert) pairs the dropless layer sorts at once when it
+# trains: the sorted buffer is sized for the worst case — every pair of
+# the rows at hand on the experts held here — so the rows are taken in
+# chunks whose worst case is this many (routed_experts)
+MAX_SORTED_PAIRS = 64 * 1024
 
 
 def _sort_dispatch(xf, sel_f, w_f, e, cap):
@@ -148,6 +158,70 @@ def _gated(cfg) -> bool:
     return cfg.activation == "swiglu"
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_pairs(x, order, unsort, k):
+    """``x[order // k]``: the sorted pairs' rows out of ``x`` [n, h]
+    (pair ``p`` is token ``p // k``).  ``unsort`` is ``order``'s inverse
+    permutation, so the transpose is a gather too — ``g[unsort]`` summed
+    over a token's ``k`` pairs — where autodiff's would be a scatter-add
+    of ``n * k`` rows."""
+    return x[(order // k).astype(jnp.int32)]
+
+
+def _take_pairs_fwd(x, order, unsort, k):
+    return _take_pairs(x, order, unsort, k), (order, unsort)
+
+
+def _take_pairs_bwd(k, res, g):
+    order, unsort = res
+    picked = g[unsort].reshape(g.shape[0] // k, k, -1)
+    return (jnp.sum(picked.astype(jnp.float32), axis=1).astype(g.dtype),
+            None, None)
+
+
+_take_pairs.defvjp(_take_pairs_fwd, _take_pairs_bwd)
+
+
+@jax.custom_vjp
+def _combine(out, weights, order, unsort, total):
+    """``sum_i w_i out_i`` a token: ``out`` [n * k, h] holds the sorted
+    pairs' results, defined in its first ``total`` rows; ``weights``
+    [n, k] float32 -> [n, h] float32.  The results are gathered back to
+    (token, slot) order as they are and weighed, masked and summed in
+    one pass over them — a pair past ``total`` (on no held expert) adds
+    nothing whatever its row holds, masked, not multiplied by zero.  The
+    backward is written out so that it gathers too (autodiff would
+    scatter-add ``n * k`` rows) and carries the cotangent's rows in
+    ``out``'s dtype."""
+    n, k = weights.shape
+    held = (unsort < total).reshape(n, k)
+    picked = out[unsort].reshape(n, k, -1).astype(jnp.float32)
+    return jnp.sum(jnp.where(held[..., None], picked * weights[..., None],
+                             0.0), axis=1)
+
+
+def _combine_fwd(out, weights, order, unsort, total):
+    return (_combine(out, weights, order, unsort, total),
+            (out, weights, order, unsort, total))
+
+
+def _combine_bwd(res, dy):
+    out, weights, order, unsort, total = res
+    n, k = weights.shape
+    in_group = jnp.arange(n * k) < total
+    dys = dy.astype(out.dtype)[(order // k).astype(jnp.int32)]
+    dys = dys.astype(jnp.float32)
+    w_sorted = weights.reshape(n * k)[order]
+    d_out = jnp.where(in_group[:, None], dys * w_sorted[:, None], 0.0)
+    d_w = jnp.where(in_group,
+                    jnp.sum(dys * out.astype(jnp.float32), axis=-1), 0.0)
+    return (d_out.astype(out.dtype), d_w[unsort].reshape(n, k), None, None,
+            None)
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 def held_experts_ffn(cfg, x, sel, weights, w_gate, w_up, w_down,
                      valid=None, layer=None):
     """The held experts' part of ``sum_i w_i E_i(x)``, dropless.
@@ -164,7 +238,10 @@ def held_experts_ffn(cfg, x, sel, weights, w_gate, w_up, w_down,
     with ``layer`` (an int32 scalar, traced in a layer scan), every
     expert layer's [L, e, in, out] in ``cfg.dtype``, which the grouped
     matmul reads at ``layer`` where they lie.  Nothing is dropped under any
-    imbalance: the sorted buffer holds all ``n * k`` pairs.  Pairs on
+    imbalance: the sorted buffer holds all ``n * k`` pairs.  One layer's
+    kernels (no ``layer``) make it differentiable in ``x``, ``weights``
+    and the kernels: the grouped matmuls bring their own backward
+    kernels, the sort's gathers transpose into gathers.  Pairs on
     experts held elsewhere (and tokens with ``valid`` False: padding,
     free serving slots) sort behind the held groups, are computed by no
     group and add nothing.  What the absent experts would add is left
@@ -184,32 +261,64 @@ def held_experts_ffn(cfg, x, sel, weights, w_gate, w_up, w_down,
         key = jnp.where(held, local, e).reshape(nk).astype(jnp.int32)
         order = jnp.argsort(key, stable=True)
         counts = jnp.bincount(key, length=e + 1)[:e].astype(jnp.int32)
-        tok_sorted = (order // k).astype(jnp.int32)
-        xs = x.astype(cfg.dtype)[tok_sorted]                    # [nk, h]
+        unsort = jnp.zeros((nk,), jnp.int32).at[order].set(
+            jnp.arange(nk, dtype=jnp.int32))
+        xs = _take_pairs(x.astype(cfg.dtype), order, unsort, k)  # [nk, h]
     with jax.named_scope("experts"):
         dt = cfg.dtype
+        # rows past the held groups belong to no group: what the kernel
+        # left there is masked BEFORE the activation (a select, fused
+        # into it), so neither it nor its derivative sees it
+        in_group = (jnp.arange(nk) < jnp.sum(counts))[:, None]
+        held_rows = lambda a: jnp.where(  # noqa: E731
+            in_group, a, jnp.zeros_like(a))
+        up = held_rows(grouped_matmul(xs, w_up, counts, layer=layer))
         if w_gate is not None:
-            gate = grouped_matmul(xs, w_gate, counts, layer=layer)
-            up = grouped_matmul(xs, w_up, counts, layer=layer)
+            gate = held_rows(grouped_matmul(xs, w_gate, counts,
+                                            layer=layer))
             ff = nn.silu(gate) * up
         else:
-            ff = jnp.square(nn.relu(
-                grouped_matmul(xs, w_up, counts, layer=layer)))
+            ff = jnp.square(nn.relu(up))
         out = grouped_matmul(ff.astype(dt), w_down, counts,
                              layer=layer)                       # [nk, h]
     with jax.named_scope("moe_combine"):
         # rows past the held groups belong to no group: whatever the
         # kernel left there is masked, not multiplied by a zero weight
-        in_group = jnp.arange(nk) < jnp.sum(counts)
-        w_sorted = weights.reshape(nk)[order]
-        contrib = jnp.where(in_group[:, None],
-                            out.astype(jnp.float32) * w_sorted[:, None], 0.0)
-        unsort = jnp.zeros((nk,), jnp.int32).at[order].set(
-            jnp.arange(nk, dtype=jnp.int32))
-        y = jnp.sum(contrib[unsort].reshape(n, k, -1), axis=1)
+        y = _combine(out, weights.astype(jnp.float32), order, unsort,
+                     jnp.sum(counts))
     load = jnp.stack([jnp.sum(counts), jnp.max(counts),
                       jnp.sum(counts > 0)]).astype(jnp.int32)
     return y, load
+
+
+def _router(cfg, p, x):
+    """``(logits, sel, weights, scores)`` of the rows ``x`` [n, h]."""
+    with jax.named_scope("router"):
+        # float32 at full precision: a TPU's default float32 product
+        # rounds its operands to bfloat16, and a rounded router input
+        # flips near-tied experts
+        logits = jnp.dot(x.astype(jnp.float32),
+                         p["router"]["kernel"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        return (logits, *route(cfg, logits, p.get("router_bias")))
+
+
+def _add_shared(cfg, p, x, y):
+    """``y`` [n, h] float32 plus the shared experts' FFN of ``x``, where
+    the model has them."""
+    if not cfg.moe_shared_experts:
+        return y
+    with jax.named_scope("shared_expert"):
+        sh = p["shared"]
+        if _gated(cfg):
+            shared = swiglu(x, sh["gate_proj"]["kernel"],
+                            sh["up_proj"]["kernel"],
+                            sh["down_proj"]["kernel"], cfg.dtype)
+        else:
+            shared = relu2(x, sh["up_proj"]["kernel"],
+                           sh["down_proj"]["kernel"], cfg.dtype)
+    with jax.named_scope("moe_combine"):
+        return y + shared.astype(jnp.float32)
 
 
 def moe_ffn(cfg, p, x, valid=None, layer=None):
@@ -223,32 +332,179 @@ def moe_ffn(cfg, p, x, valid=None, layer=None):
     ``layer`` the index into them (:func:`held_experts_ffn`); the other
     leaves are always one layer's.  ``x`` [n, h] ->
     ``(y [n, h] in cfg.dtype, scores, sel, load)``."""
-    with jax.named_scope("router"):
-        # float32 at full precision: a TPU's default float32 product
-        # rounds its operands to bfloat16, and a rounded router input
-        # flips near-tied experts
-        logits = jnp.dot(x.astype(jnp.float32),
-                         p["router"]["kernel"].astype(jnp.float32),
-                         precision=jax.lax.Precision.HIGHEST)
-        sel, weights, scores = route(cfg, logits, p.get("router_bias"))
-    gated = _gated(cfg)
+    _, sel, weights, scores = _router(cfg, p, x)
     y, load = held_experts_ffn(cfg, x, sel, weights,
-                               p["experts/gate"] if gated else None,
+                               p["experts/gate"] if _gated(cfg) else None,
                                p["experts/up"], p["experts/down"], valid,
                                layer)
-    if cfg.moe_shared_experts:
-        with jax.named_scope("shared_expert"):
-            sh = p["shared"]
-            if gated:
-                shared = swiglu(x, sh["gate_proj"]["kernel"],
-                                sh["up_proj"]["kernel"],
-                                sh["down_proj"]["kernel"], cfg.dtype)
-            else:
-                shared = relu2(x, sh["up_proj"]["kernel"],
-                               sh["down_proj"]["kernel"], cfg.dtype)
-        with jax.named_scope("moe_combine"):
-            y = y + shared.astype(jnp.float32)
+    y = _add_shared(cfg, p, x, y)
     return y.astype(cfg.dtype), scores, sel, load
+
+
+def _chunk_rows(n: int, shards: int, k: int) -> int:
+    """Rows of a shard taken at once by :func:`routed_experts`: ``n``
+    halved until the worst case of a chunk — ``shards * rows * k``
+    pairs, all on the experts held here — is at most
+    ``MAX_SORTED_PAIRS`` (or the rows stop halving)."""
+    rows = n
+    while shards * rows * k > MAX_SORTED_PAIRS and rows % 2 == 0:
+        rows //= 2
+    return rows
+
+
+def _exchange(ep, dtype):
+    """``(gather, gather_rows, scatter_sum)`` over the mesh axis ``ep``
+    that holds the experts: the two halves of the expert layer's
+    exchange.  ``gather`` is the plain all-gather of a chunk's leading
+    dimension (``sel``, ``weights``); ``gather_rows`` the same for the
+    rows ([c, h] -> [shards * c, h], in ``dtype``) with its backward
+    written out; ``scatter_sum`` adds the shards'
+    partial results and leaves each the sum for its own rows (float32
+    [shards * c, h] -> [c, h]).  Each is the other's transpose, so the
+    backward is written out: what crosses the chips as a COPY crosses in
+    ``dtype`` (the rows forward, the result's cotangent backward, which
+    arrives rounded to it anyway), what is SUMMED across them is summed
+    in float32.  ``ep`` None (every expert held here): identities."""
+    if ep is None:
+        same = lambda x: x  # noqa: E731
+        return same, same, same
+
+    def gather(x):
+        return jax.lax.all_gather(x, ep, axis=0, tiled=True)
+
+    def scatter(y):
+        return jax.lax.psum_scatter(y.astype(jnp.float32), ep,
+                                    scatter_dimension=0, tiled=True)
+
+    @jax.custom_vjp
+    def gather_rows(x):
+        return gather(x)
+
+    @jax.custom_vjp
+    def scatter_sum(y):
+        return scatter(y)
+
+    gather_rows.defvjp(lambda x: (gather(x), None),
+                       lambda _, g: (scatter(g).astype(g.dtype),))
+    scatter_sum.defvjp(lambda y: (scatter(y), None),
+                       lambda _, g: (gather(g.astype(dtype))
+                                     .astype(jnp.float32),))
+    return gather, gather_rows, scatter_sum
+
+
+def _routed_rows(cfg, p, x, ep, data_axes):
+    """:func:`routed_experts` on one shard's rows ``x`` [b, s, h]
+    (``ep`` / ``data_axes``: the manual mesh axes that hold the experts
+    and split the rows; none on one device)."""
+    b, s, h = x.shape
+    n, k = b * s, cfg.num_experts_per_tok
+    width = cfg.router_width
+    xf = x.reshape(n, h)
+    logits, sel, weights, scores = _router(cfg, p, xf)
+    w_gate = p["experts/gate"] if _gated(cfg) else None
+    w_up, w_down = p["experts/up"], p["experts/down"]
+    held = w_up.shape[0]                    # experts held by this shard
+    shards = jax.lax.axis_size(ep) if ep else 1
+    first = cfg.moe_first_expert
+    if ep:
+        first = first + jax.lax.axis_index(ep) * held
+    shard_cfg = dataclasses.replace(cfg, moe_first_expert=first)
+    gather, gather_rows, scatter_sum = _exchange(ep, cfg.dtype)
+
+    def chunk(xc, sc, wc):
+        with jax.named_scope("moe_exchange"):
+            xg, sc, wc = gather_rows(xc), gather(sc), gather(wc)
+        y, _ = held_experts_ffn(shard_cfg, xg, sc, wc, w_gate, w_up, w_down)
+        with jax.named_scope("moe_exchange"):
+            return scatter_sum(y)
+
+    c = _chunk_rows(n, shards, k)
+    if c == n:
+        y = chunk(xf.astype(cfg.dtype), sel, weights)
+    else:
+        # a chunk keeps nothing for the backward but its rows: the
+        # worst-case buffers exist once, forward and backward
+        _, y = jax.lax.scan(
+            lambda _, rows: (None, jax.checkpoint(chunk)(*rows)), None,
+            (xf.astype(cfg.dtype).reshape(n // c, c, h),
+             sel.reshape(n // c, c, k), weights.reshape(n // c, c, k)))
+        y = y.reshape(n, h)
+    y = _add_shared(cfg, p, xf, y)
+    with jax.named_scope("router"):
+        # load-balance signal, a row (sequence) at a time over the
+        # router's whole width: width * sum_e f_e P_e, f_e the share of
+        # the row's s * k pairs on expert e, P_e the row's mean score
+        # (normalised to a distribution); the mean over the rows
+        picked = jnp.sum(jax.nn.one_hot(sel.reshape(b, s * k), width,
+                                        dtype=jnp.float32), axis=1)
+        probs = (scores / (jnp.sum(scores, axis=-1, keepdims=True) + 1e-20)
+                 if cfg.moe_scoring == "sigmoid"
+                 else jax.nn.softmax(logits, -1)).reshape(b, s, width)
+        aux = jnp.sum(width * jnp.sum(
+            picked / (s * k) * jnp.mean(probs, axis=1), axis=-1))
+        counts = jnp.sum(picked, axis=0)
+        rows = jnp.asarray(b, jnp.float32)
+        if data_axes:
+            aux, counts, rows = jax.lax.psum((aux, counts, rows), data_axes)
+        aux = aux / rows
+        # pairs on the held experts (of every shard), the busiest one's,
+        # held experts that drew a pair
+        counts = jax.lax.dynamic_slice_in_dim(
+            counts, cfg.moe_first_expert, held * shards).astype(jnp.int32)
+        load = jnp.stack([jnp.sum(counts), jnp.max(counts),
+                          jnp.sum(counts > 0)])
+    return y.astype(cfg.dtype).reshape(b, s, h), aux, load
+
+
+def routed_experts(cfg, p, x):
+    """The dropless expert layer as a train step runs it: ``x`` [b, s, h]
+    -> ``(y [b, s, h] in cfg.dtype, aux, load int32[3])`` on the raw
+    parameter tree of :class:`MoEMlp`'s 'grouped' path, differentiable.
+
+    Under a mesh whose ``ep`` axis is larger than one the experts stay
+    where they are — ``experts/*`` enter a ``shard_map`` over the whole
+    mesh split on their expert dimension, ``cfg.num_experts / ep`` a
+    shard, and are never gathered; their gradients leave it the same
+    way — and the rows, which the data axes (``ep`` among them) split,
+    are routed where they lie.  Every (token, expert) pair then has to
+    reach the shard that holds its expert and its result to come back.
+    With ``k`` of ``E`` experts a token over few shards nearly every
+    token visits every shard (8 of 64 over 4: nine in ten), so the
+    exchange copies ROWS, not pairs: an all-gather of the shards' rows
+    (and of their ``sel`` / ``weights``), :func:`held_experts_ffn` on the
+    held experts over all of them, and a reduce-scatter of the partial
+    sums — ``2 (ep - 1) / ep`` rows a token each way against
+    ``2 k (ep - 1) / ep`` for an all-to-all of pairs.
+
+    Static shapes and nothing dropped: a shard's sorted buffer has to
+    hold the case that every pair lands on its experts, so the rows are
+    taken ``_chunk_rows`` at a time in a scan — gathered, computed,
+    scattered, a chunk rematerialised in the backward — and the buffers
+    are ``MAX_SORTED_PAIRS`` rows whatever the batch.
+
+    ``aux`` is the mean over the rows (sequences) of ``width * sum_e f_e
+    P_e``; ``load`` the (token, expert) pairs on held experts over all
+    shards, the busiest held expert's, and the held experts that drew
+    one."""
+    mesh = ambient_mesh()
+    if not needs_shard_map(mesh):
+        return _routed_rows(cfg, p, x, None, ())
+    from jax.sharding import PartitionSpec as P
+    axes = batch_axes(mesh, x.shape[0])
+    ep = "ep" if mesh.shape.get("ep", 1) > 1 else None
+    if ep and ep not in axes:
+        raise ValueError(
+            f"routed_experts: a batch of {x.shape[0]} rows does not "
+            f"split over the data axes of mesh {dict(mesh.shape)} down "
+            f"to 'ep': the shards that hold the experts exchange their "
+            f"OWN rows")
+    specs = {name: (P(ep) if name.startswith("experts/") else P())
+             for name in p}
+    rows = P(axes or None)
+    return jax.shard_map(
+        lambda p_, x_: _routed_rows(cfg, p_, x_, ep, axes), mesh=mesh,
+        in_specs=(specs, rows), out_specs=(rows, P(), P()),
+        check_vma=False)(p, x)
 
 
 class _Kernel(nn.Module):
@@ -429,8 +685,9 @@ class MoEMlp(nn.Module):
         return y.astype(cfg.dtype)
 
     def _grouped(self, x):
-        """moe_dispatch='grouped': the dropless held-expert layer
-        (:func:`moe_ffn`) on this module's parameters."""
+        """moe_dispatch='grouped': the dropless held-expert layer as a
+        train step runs it (:func:`routed_experts`: the experts over
+        'ep' under a mesh) on this module's parameters."""
         cfg = self.cfg
         if cfg.moe_capacity_factor is not None:
             raise ValueError("moe_dispatch='grouped' is dropless: it takes "
@@ -458,16 +715,7 @@ class MoEMlp(nn.Module):
             p["shared"] = _SwigluKernels(h, cfg.shared_ffn_size,
                                          cfg.param_dtype, gated,
                                          name="shared")()
-        b, s, _ = x.shape
-        y, scores, sel, _ = moe_ffn(cfg, p, x.reshape(b * s, h))
-        # load-balance signal over the router's whole width (the same
-        # switch-style product as the dense paths, on the router's
-        # scores normalised to a distribution)
-        k = cfg.num_experts_per_tok
-        frac_tokens = jnp.mean(jnp.sum(jax.nn.one_hot(
-            sel, width, dtype=jnp.float32), axis=-2), axis=0) / k
-        probs = scores / (jnp.sum(scores, axis=-1, keepdims=True) + 1e-20) \
-            if cfg.moe_scoring == "sigmoid" else jax.nn.softmax(scores, -1)
-        self.sow("intermediates", "moe_aux_loss",
-                 width * jnp.sum(frac_tokens * jnp.mean(probs, axis=0)))
-        return y.reshape(b, s, h)
+        y, aux, load = routed_experts(cfg, p, x)
+        self.sow("intermediates", "moe_aux_loss", aux)
+        self.sow("intermediates", "moe_load", load)
+        return y

@@ -156,6 +156,12 @@ class ModelConfig:
     # arrangement: rope on the windowed layers, NO position signal at all
     # on the global ones (pattern_cfg gives those pos_emb='none')
     rope_kinds: Optional[Tuple[str, ...]] = None
+    # the layer_pattern kinds whose rotary embedding takes rope_yarn;
+    # None = every layer that rotates does.  ('global',) is the mellum
+    # family's arrangement: the full-attention layers stretch their
+    # frequencies, the windowed ones keep the plain ones (pattern_cfg
+    # gives those rope_yarn=None)
+    rope_yarn_kinds: Optional[Tuple[str, ...]] = None
     # KV-cache decode mode (models/generate.py): __call__ consumes one
     # token per step, appending rotated k / raw v into the 'cache'
     # collection and attending over the filled prefix
@@ -761,16 +767,22 @@ def pp_block_appliers(cfg: "ModelConfig", wrap):
                  for j in range(per_stage)), True
 
 
-def _raw_block_fn(block_cfg):
+def _raw_block_fn(block_cfg, with_load: bool = False):
     """``fn(p, carry, seed) -> (carry, aux)`` applying ONE block via raw
     ``ScanBlock.apply``.  The raw apply drops sown intermediates unless
     the collection is mutable, so the MoE router aux is collected
     explicitly and returned — the single place this subtlety lives (the
-    pp / unrolled / split-remat paths all build on it)."""
+    pp / unrolled / split-remat paths all build on it).  ``with_load``:
+    ``aux`` is ``(aux, load)``, the layer's sown expert load beside it
+    (``moe_load`` int32[3], models/moe.routed_experts), so a per-layer
+    loop can stack and sow the counts as ``nn.scan`` does."""
     def fn(p, carry, s):
         (new_carry, _), vs = ScanBlock(block_cfg).apply(
             {"params": p}, carry, s, mutable=["intermediates"])
-        return new_carry, _sown_aux_sum(vs)
+        aux = _sown_aux_sum(vs)
+        if with_load:
+            aux = (aux, _sown(vs, "moe_load")[0])
+        return new_carry, aux
     return fn
 
 
@@ -974,8 +986,10 @@ class TransformerLM(nn.Module):
             aux_total = jnp.zeros((), jnp.float32)
             carry = (x, positions, segment_ids)
             from torchacc_tpu.utils.remat import remat_policy as _rp
+            loads = []
+            with_load = cfg.num_experts > 0 and cfg.moe_dispatch == "grouped"
             for i in range(cfg.num_layers):
-                fn = _raw_block_fn(pattern_cfg(cfg, i))
+                fn = _raw_block_fn(pattern_cfg(cfg, i), with_load)
                 if _block_remat(cfg):
                     fn = jax.checkpoint(fn, policy=_rp(cfg.remat_policy),
                                         prevent_cse=False)
@@ -983,9 +997,14 @@ class TransformerLM(nn.Module):
                     p_i = jax.tree.map(lambda a, i=i: a[i], layer_params)
                     s_i = None if seeds_xs is None else seeds_xs[i]
                     carry, aux = fn(p_i, carry, s_i)
+                if with_load:
+                    aux, load = aux
+                    loads.append(load)
                 aux_total = aux_total + aux
             if cfg.num_experts > 0:
                 self.sow("intermediates", "moe_aux_loss", aux_total)
+            if loads:
+                self.sow("intermediates", "moe_load", jnp.stack(loads))
             x = carry[0]
         elif cfg.pp_size > 1:
             # pipeline path: drive the stacked layer params through the
@@ -1098,10 +1117,12 @@ class TransformerLM(nn.Module):
             # at the module tail threads through normal flax mutation)
             quant_blocks = quant_on and (
                 quant_site_on(cfg, "attn") or quant_site_on(cfg, "mlp"))
+            with_load = (not quant_blocks and cfg.num_experts > 0
+                         and cfg.moe_dispatch == "grouped")
             raw_gc = (_raw_block_fn_quant(cfg) if quant_blocks
-                      else _raw_block_fn(cfg))
+                      else _raw_block_fn(cfg, with_load))
             raw_plain = (_raw_block_fn_quant(cfg_off) if quant_blocks
-                         else _raw_block_fn(cfg_off))
+                         else _raw_block_fn(cfg_off, with_load))
             if overlap_active:
                 from torchacc_tpu.parallel.sharding import (
                     DEFAULT_RULES,
@@ -1154,7 +1175,7 @@ class TransformerLM(nn.Module):
                 lambda a, i=i: a[i], tree)
             carry = (x, positions, segment_ids)
             aux_total = jnp.zeros((), jnp.float32)
-            new_quant = []
+            new_quant, loads = [], []
             n_gc = cfg.num_layers if split_n is None else split_n
             for i in range(cfg.num_layers):
                 fn = raw_gc if (i < n_gc and cfg.remat) else raw_plain
@@ -1167,7 +1188,12 @@ class TransformerLM(nn.Module):
                         new_quant.append(q_i)
                     else:
                         carry, aux = fn(p_i, carry, seed_i)
+                if with_load:
+                    aux, load = aux
+                    loads.append(load)
                 aux_total = aux_total + aux
+            if loads:
+                self.sow("intermediates", "moe_load", jnp.stack(loads))
             if quant_blocks and self.is_mutable_collection("quant"):
                 self.put_variable(
                     "quant", "layers",
@@ -1322,7 +1348,8 @@ def kind_cfg(cfg: ModelConfig, kind: str) -> ModelConfig:
     """The config a ``layer_pattern`` layer of ``kind`` computes under:
     'sliding' keeps cfg.window, 'global' lifts it to full attention; a
     kind outside ``cfg.rope_kinds`` (where those are named) has no
-    rotary embedding."""
+    rotary embedding, one outside ``cfg.rope_yarn_kinds`` the plain
+    frequencies."""
     if kind in MIXER_KINDS and cfg.mixer_pattern:
         # a mixer_pattern layer: the kinds differ in what they own, not
         # in the config they compute under
@@ -1343,6 +1370,8 @@ def kind_cfg(cfg: ModelConfig, kind: str) -> ModelConfig:
             raise ValueError("rope_kinds names the layers that carry "
                              "rope: it needs pos_emb='rope'")
         cfg = dataclasses.replace(cfg, pos_emb="none")
+    if cfg.rope_yarn_kinds is not None and kind not in cfg.rope_yarn_kinds:
+        cfg = dataclasses.replace(cfg, rope_yarn=None)
     return cfg
 
 
@@ -1499,14 +1528,29 @@ def _micro_seed(base, micro_idx):
     return (b + m * jnp.uint32(0x85EBCA6B)).astype(jnp.int32)
 
 
-def _sown_aux_sum(vs) -> jax.Array:
-    """Sum every sown '*aux_loss*' intermediate (MoE router load-balance,
-    models/moe.py) out of a raw .apply's mutated variables."""
+def _sown(vs, name: str):
+    """Every sown intermediate whose path holds ``name``, out of a raw
+    .apply's mutated variables."""
     paths = jax.tree_util.tree_flatten_with_path(
         vs.get("intermediates", {}))[0]
-    vals = [jnp.sum(v) for path, v in paths
-            if "aux_loss" in jax.tree_util.keystr(path)]
+    return [v for path, v in paths if name in jax.tree_util.keystr(path)]
+
+
+def _sown_aux_sum(vs) -> jax.Array:
+    """Sum every sown '*aux_loss*' intermediate (MoE router load-balance,
+    models/moe.py)."""
+    vals = [jnp.sum(v) for v in _sown(vs, "aux_loss")]
     return sum(vals) if vals else jnp.zeros((), jnp.float32)
+
+
+def sown_expert_load(vs):
+    """The expert layers' sown load, int32 [expert layers, 3] (pairs on
+    held experts, the busiest one's, held experts that drew a pair;
+    models/moe.routed_experts), or None for a model that sows none."""
+    vals = _sown(vs, "moe_load")
+    if not vals:
+        return None
+    return jnp.concatenate([v.reshape(-1, 3) for v in vals], axis=0)
 
 
 class _MicroBatchView(dict):

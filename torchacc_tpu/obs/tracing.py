@@ -46,7 +46,12 @@ from jax.profiler import TraceAnnotation
 #: every host span name -> where it is emitted
 SPANS: Dict[str, str] = {
     "train/step": "Trainer.step — one whole step call (parent of "
-                  "dispatch, resolve and wait)",
+                  "dispatch, resolve and wait).  Attrs, for a model with "
+                  "dropless expert layers and only while a sink listens: "
+                  "resolved_step and that step's moe_pairs, moe_max, "
+                  "moe_hit, moe_slots, moe_layer_steps (as on "
+                  "serve/deliver, over every shard's held experts) and "
+                  "aux_loss (the layers' summed load-balance terms)",
     "train/dispatch": "Trainer.step — enqueue of one jitted train step",
     "train/resolve": "Trainer.resolve_oldest — lagged readback of step "
                      "N-k",
@@ -160,8 +165,14 @@ SCOPES: Dict[str, str] = {
               "top-k, weights",
     "moe_dispatch": "expert layer: sort of the (token, expert) pairs on "
                     "held experts and the gather of their rows",
+    "moe_exchange": "expert layer under training, experts over 'ep': the "
+                    "all-gather of the shards' rows (and their sel / "
+                    "weights) before the held experts work them and the "
+                    "reduce-scatter of the partial sums after; in the "
+                    "backward the same two the other way round",
     "experts": "expert layer: the grouped matmuls over the held experts "
-               "(three of a SwiGLU expert, two of a relu2 one)",
+               "(three of a SwiGLU expert, two of a relu2 one; training: "
+               "their backward too, the kernels gmm_dx and gmm_dw)",
     "shared_expert": "expert layer: the shared experts' FFN",
     "moe_combine": "expert layer: unsort, weight and sum the pairs' "
                    "outputs, add the shared expert",

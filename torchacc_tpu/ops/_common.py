@@ -100,13 +100,15 @@ def dropout_keep(seed, b_idx, h_idx, q_pos, k_pos, dropout_p: float):
 
 def batch_axes(mesh, batch: int) -> tuple:
     """The data axes a ``[batch, ...]`` activation is sharded over under
-    ``mesh``: the longest prefix of ``("dp", "fsdp")`` whose extent
-    divides ``batch`` (``parallel/sharding._divisible``'s rule, which
+    ``mesh``: the longest prefix of ``config.DATA_AXES`` (``dp``,
+    ``fsdp``, ``ep``) whose extent divides ``batch``
+    (``parallel/sharding._divisible``'s rule, which
     ``ops/attn.py::_sharded_flash`` follows too), the axes of extent 1
     left out.  A manual axis counts as extent 1: inside its region the
     arrays are already per shard."""
+    from torchacc_tpu.config import DATA_AXES
     axes, n = [], 1
-    for a in ("dp", "fsdp"):
+    for a in DATA_AXES:
         extent = (1 if a in mesh.manual_axes
                   else int(mesh.shape.get(a, 1)))
         if batch % (n * extent):

@@ -22,13 +22,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from torchacc_tpu.config import DATA_AXES as _DATA_AXES
 from torchacc_tpu.ops._common import ambient_mesh, needs_shard_map
 from torchacc_tpu.ops._common import on_tpu as _on_tpu
 from torchacc_tpu.ops.attention import attention_reference
 
 # the mesh axes [b, s, h, d] activations are sharded over (the 'batch'
 # and 'heads' rows of parallel/sharding.DEFAULT_RULES)
-_DATA_AXES = ("dp", "fsdp")
 _HEAD_AXIS = "tp"
 
 

@@ -494,8 +494,11 @@ def _head_rows(hidden, w_head, labels, chunk_rows: int,
     from jax.sharding import PartitionSpec as P
 
     mesh = ambient_mesh()
-    scatter = ("fsdp" if "fsdp" in axes
-               and w_head.shape[0] % mesh.shape["fsdp"] == 0 else None)
+    # the row axes over which the head parameter's hidden dim is sharded
+    # (parallel/sharding's 'embed' rule)
+    scatter = tuple(a for a in ("fsdp", "ep") if a in axes)
+    if w_head.shape[0] % math.prod(mesh.shape[a] for a in scatter):
+        scatter = ()
 
     def local(h, w, y):
         out = _head_chunks(h, w, y, chunk_rows, logit_softcap, scan_free,
@@ -512,7 +515,7 @@ def _head_rows(hidden, w_head, labels, chunk_rows: int,
         if scatter:
             part = jax.lax.psum_scatter(part, scatter, scatter_dimension=0,
                                         tiled=True)
-        rest = tuple(a for a in axes if a != scatter)
+        rest = tuple(a for a in axes if a not in scatter)
         if rest:
             part = jax.lax.psum(part, rest)
         return (*sums, dx, part.astype(dw.dtype))
@@ -520,7 +523,7 @@ def _head_rows(hidden, w_head, labels, chunk_rows: int,
     rows = P(axes)
     out_specs = (P(), P())
     if with_grads:
-        out_specs += (rows, P(scatter))
+        out_specs += (rows, P(scatter or None))
     return jax.shard_map(
         local, mesh=mesh, in_specs=(rows, P(), rows), out_specs=out_specs,
         axis_names=_region_axes(mesh, axes), check_vma=False,
@@ -607,7 +610,8 @@ def fused_linear_cross_entropy(
     rows inside one ``shard_map`` over those axes (``_head_rows``: the
     head weight gathered once a step, the sums ``psum``-ed, d(hidden)
     left row-sharded, dW reduce-scattered once into the parameter's
-    ``[H / fsdp, V]`` shards, summed across the shards in float32); on
+    ``[H / fsdp, V]`` shards (``fsdp`` and ``ep`` where both split
+    it), summed across the shards in float32); on
     one device, under a batch the data extent does not divide, or
     inside a region that has made some mesh axis manual (the 1F1B
     tick), the rows are taken whole and the partitioner shards what it

@@ -35,6 +35,20 @@ instructions that carry no ``op_name`` (my chip run, PR 26: a quarter of
 the serving window's device time under no scope), so the program's
 ``experts`` scope could not be read from a trace.  A Pallas call keeps
 the scope it was traced under.
+
+Training differentiates the one-layer form (``[g, k, n]`` weights, no
+layer index) through a ``custom_vjp`` whose backward is two more Pallas
+kernels over the same schedule: ``gmm_dx``, the same grouped product
+against the weights read TRANSPOSED where they lie (``dX_g = dY_g
+W_g^T``; no transposed copy of the experts is made), and ``gmm_dw``,
+``dW_g = X_g^T dY_g``, which walks a group's row tiles with its
+``[tk, tn]`` block of the result resident in VMEM and writes it once
+(a group with no rows takes one step that writes zeros and fetches
+nothing new).  The rows of no group: the result stays UNDEFINED there
+under differentiation too (a caller that feeds it to a nonlinearity
+masks it first, as ``held_experts_ffn`` does: a select fused into the
+activation), the cotangent's rows there are never read, and dX is zero
+there (one select, which XLA fuses into dX's consumer).
 """
 
 from __future__ import annotations
@@ -54,6 +68,8 @@ from torchacc_tpu.ops._common import (
 )
 
 ROW_TILE = 128           # rows of x a grid step multiplies
+DW_ROW_TILE = 256        # rows a gmm_dw step contracts over
+DW_BLOCK = 1024 * 1024   # elements of gmm_dw's resident [tk, tn] block
 WEIGHT_BLOCK = 2 * 1024 * 1024   # elements of the [tk, tn] weight block
 
 
@@ -80,7 +96,7 @@ def weight_tiles(k: int, n: int) -> tuple[int, int]:
     return _divisor_tile(k, max(128, WEIGHT_BLOCK // tn)), tn
 
 
-def tile_schedule(group_sizes, m: int, tm: int):
+def tile_schedule(group_sizes, m: int, tm: int, visit_empty: bool = False):
     """Which (group, row tile) each grid step works on.
 
     ``group_sizes`` int32 [g] (its sum may be less than ``m``).  Returns
@@ -89,16 +105,27 @@ def tile_schedule(group_sizes, m: int, tm: int):
     (each group boundary inside a tile adds one visit) and ``num_steps``
     the steps that do work.  Steps past ``num_steps`` repeat the last
     working step's indices (and the index maps hold them to its last k
-    block), so the pipeline fetches nothing for them."""
+    block), so the pipeline fetches nothing for them.
+
+    ``visit_empty`` (``gmm_dw``, which has a block of its result to
+    write for every group) gives a group with no rows one step too, on
+    the row tile the step before it was on, so nothing new is fetched
+    for it; the bound is then ``m // tm + 2 * g - 1`` steps."""
     g = group_sizes.shape[0]
     ends = jnp.cumsum(group_sizes)
     starts = ends - group_sizes
     first = starts // tm
     tiles = jnp.where(group_sizes > 0, (ends - 1) // tm - first + 1, 0)
+    bound = m // tm + g - 1
+    if visit_empty:
+        first = jnp.where(group_sizes > 0, first,
+                          jnp.maximum(starts - 1, 0) // tm)
+        tiles = jnp.maximum(tiles, 1)
+        bound += g
     step_ends = jnp.cumsum(tiles)
     step_starts = step_ends - tiles
     num_steps = step_ends[-1]
-    step = jnp.minimum(jnp.arange(m // tm + g - 1, dtype=jnp.int32),
+    step = jnp.minimum(jnp.arange(bound, dtype=jnp.int32),
                        jnp.maximum(num_steps - 1, 0))
     group_of = jnp.minimum(
         jnp.searchsorted(step_ends, step, side="right"), g - 1)
@@ -107,7 +134,7 @@ def tile_schedule(group_sizes, m: int, tm: int):
 
 
 def _kernel(group_of, tile_of, starts, ends, num_steps, layer, x_ref, w_ref,
-            o_ref, acc_ref, *, tm: int, k_steps: int):
+            o_ref, acc_ref, *, tm: int, k_steps: int, transposed: bool):
     s, ki = pl.program_id(1), pl.program_id(2)
 
     @pl.when(s < num_steps[0])
@@ -116,8 +143,12 @@ def _kernel(group_of, tile_of, starts, ends, num_steps, layer, x_ref, w_ref,
         def _zero():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
-                                preferred_element_type=jnp.float32)
+        # transposed: the rows meet the weight block's SECOND dimension
+        # (x [tm, tn] . w [tk, tn]^T), the block read as it lies
+        acc_ref[...] += jax.lax.dot_general(
+            x_ref[...], w_ref[...],
+            (((1,), (1 if transposed else 0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
         @pl.when(ki == k_steps - 1)
         def _store():
@@ -129,9 +160,14 @@ def _kernel(group_of, tile_of, starts, ends, num_steps, layer, x_ref, w_ref,
                                    o_ref[...])
 
 
-def _grouped_matmul_pallas(x, w, group_sizes, layer, *, tk, tn):
-    m, k = x.shape
-    _, g, _, n = w.shape
+def _grouped_matmul_pallas(x, w, group_sizes, layer, *, tk, tn,
+                           transposed=False):
+    """``x [m, k] @ w[layer, g] [k, n]`` a group, or ``transposed``:
+    ``x [m, n] @ w[layer, g]^T``.  One kernel body and one weight block
+    ``[tk, tn]`` for both; the transposed product contracts over the
+    block's second dimension and its result is ``tk`` wide."""
+    _, g, k, n = w.shape
+    m = x.shape[0]
     tm = min(ROW_TILE, round_up(m, 16))
     m_pad = round_up(m, tm)
     if m_pad != m:
@@ -139,42 +175,180 @@ def _grouped_matmul_pallas(x, w, group_sizes, layer, *, tk, tn):
     auto = weight_tiles(k, n)
     tk = _divisor_tile(k, tk) if tk else auto[0]
     tn = _divisor_tile(n, tn) if tn else auto[1]
-    k_steps = k // tk
+    # (contraction tile, result tile, result width): the weight block's
+    # two dimensions swap roles in the transposed product
+    tc, to_, width = (tn, tk, k) if transposed else (tk, tn, n)
+    c_steps = (n if transposed else k) // tc
     schedule = tile_schedule(group_sizes.astype(jnp.int32), m_pad, tm)
 
-    def k_block(s, ki, num_steps):
+    def c_block(s, ci, num_steps):
         # an idle step stays on the last working step's last k block: an
         # index that moved would fetch a weight block for nothing (my chip
         # run, PR 26: 3.5 us an idle step, 1.5 of a chunk's 2.0 ms)
-        return jnp.where(s < num_steps[0], ki, k_steps - 1)
+        return jnp.where(s < num_steps[0], ci, c_steps - 1)
+
+    def w_index(oi, s, ci, go, to, st, en, num, layer):
+        c = c_block(s, ci, num)
+        return ((layer[0], go[s], oi, c) if transposed
+                else (layer[0], go[s], c, oi))
 
     out = pl.pallas_call(
-        functools.partial(_kernel, tm=tm, k_steps=k_steps),
-        out_shape=jax.ShapeDtypeStruct((m_pad, n), x.dtype),
+        functools.partial(_kernel, tm=tm, k_steps=c_steps,
+                          transposed=transposed),
+        out_shape=jax.ShapeDtypeStruct((m_pad, width), x.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             # six scalar-prefetch operands: the schedule's five and the
             # layer index, so the weight block's index map can address
             # (layer, group, k block, n block) in the stack before the
             # body runs (the pattern of ops/paged_attention.py's pools)
             num_scalar_prefetch=6,
-            grid=(n // tn, m_pad // tm + g - 1, k_steps),
+            grid=(width // to_, m_pad // tm + g - 1, c_steps),
             in_specs=[
-                pl.BlockSpec((tm, tk),
-                             lambda ni, s, ki, go, to, st, en, num, layer:
-                             (to[s], k_block(s, ki, num))),
-                pl.BlockSpec((None, None, tk, tn),
-                             lambda ni, s, ki, go, to, st, en, num, layer:
-                             (layer[0], go[s], k_block(s, ki, num), ni)),
+                pl.BlockSpec((tm, tc),
+                             lambda oi, s, ci, go, to, st, en, num, layer:
+                             (to[s], c_block(s, ci, num))),
+                pl.BlockSpec((None, None, tk, tn), w_index),
             ],
             out_specs=pl.BlockSpec(
-                (tm, tn), lambda ni, s, ki, go, to, *_: (to[s], ni)),
-            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
+                (tm, to_), lambda oi, s, ci, go, to, *_: (to[s], oi)),
+            scratch_shapes=[pltpu.VMEM((tm, to_), jnp.float32)]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=_interpret(),
-        name="grouped_matmul",
+        name="gmm_dx" if transposed else "grouped_matmul",
     )(*schedule, layer.reshape(1), x, w)
     return out[:m]
+
+
+def _dw_kernel(group_of, tile_of, starts, ends, num_steps, x_ref, dy_ref,
+               o_ref, acc_ref, *, tm: int, bound: int):
+    s = pl.program_id(2)
+
+    @pl.when(s < num_steps[0])
+    def _work():
+        g = group_of[s]
+        first = (s == 0) | (group_of[jnp.maximum(s - 1, 0)] != g)
+        last = ((s == num_steps[0] - 1)
+                | (group_of[jnp.minimum(s + 1, bound - 1)] != g))
+
+        @pl.when(first)
+        def _zero():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        # rows of the tile that belong to another group (or to none:
+        # whatever lies there) leave both factors as zeros
+        rows = tile_of[s] * tm + jax.lax.broadcasted_iota(
+            jnp.int32, (tm, 1), 0)
+        mine = (rows >= starts[g]) & (rows < ends[g])
+        xs = jnp.where(mine, x_ref[...], jnp.zeros_like(x_ref))
+        dys = jnp.where(mine, dy_ref[...], jnp.zeros_like(dy_ref))
+        acc_ref[...] += jax.lax.dot_general(
+            xs, dys, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+        @pl.when(last)
+        def _store():
+            o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def dw_tiles(k: int, n: int) -> tuple[int, int]:
+    """``gmm_dw``'s resident block: the narrower dimension whole (up to
+    1024), the other as wide as ``DW_BLOCK`` allows — 4 MiB of float32
+    accumulator beside two result blocks and the row tiles in flight."""
+    if k <= n:
+        tk = _divisor_tile(k, 1024)
+        return tk, _divisor_tile(n, max(128, DW_BLOCK // tk))
+    tn = _divisor_tile(n, 1024)
+    return _divisor_tile(k, max(128, DW_BLOCK // tn)), tn
+
+
+def _gmm_dw_pallas(x, dy, group_sizes, dtype, *, tk, tn):
+    """``dW[g] = x[rows of g]^T @ dy[rows of g]`` -> [g, k, n] in
+    ``dtype``, float32 accumulation over a group's row tiles."""
+    m, k = x.shape
+    n = dy.shape[1]
+    g = group_sizes.shape[0]
+    tm = min(DW_ROW_TILE, round_up(m, 16))
+    m_pad = round_up(m, tm)
+    if m_pad != m:
+        x = jnp.pad(x, ((0, m_pad - m), (0, 0)))
+        dy = jnp.pad(dy, ((0, m_pad - m), (0, 0)))
+    auto = dw_tiles(k, n)
+    tk = _divisor_tile(k, tk) if tk else auto[0]
+    tn = _divisor_tile(n, tn) if tn else auto[1]
+    schedule = tile_schedule(group_sizes.astype(jnp.int32), m_pad, tm,
+                             visit_empty=True)
+    bound = m_pad // tm + 2 * g - 1
+    return pl.pallas_call(
+        functools.partial(_dw_kernel, tm=tm, bound=bound),
+        out_shape=jax.ShapeDtypeStruct((g, k, n), dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            # the steps innermost: a group's steps follow one another,
+            # so its result block stays in VMEM from its first row tile
+            # to its last and is written back when the group changes
+            grid=(k // tk, n // tn, bound),
+            in_specs=[
+                pl.BlockSpec((tm, tk),
+                             lambda ki, ni, s, go, to, *_: (to[s], ki)),
+                pl.BlockSpec((tm, tn),
+                             lambda ki, ni, s, go, to, *_: (to[s], ni)),
+            ],
+            out_specs=pl.BlockSpec(
+                (None, tk, tn), lambda ki, ni, s, go, *_: (go[s], ki, ni)),
+            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=_interpret(),
+        name="gmm_dw",
+    )(*schedule, x, dy)
+
+
+def _replicated(fn, n_args: int):
+    """``fn`` under the ambient mesh.  Outside a region that has made
+    every mesh axis manual the call is one chip's share, the same on
+    every shard: each holds the same rows and weights and runs the whole
+    call."""
+    mesh = ambient_mesh()
+    if not needs_shard_map(mesh):
+        return fn
+    from jax.sharding import PartitionSpec as P
+    return jax.shard_map(fn, mesh=mesh, in_specs=(P(),) * n_args,
+                         out_specs=P(), check_vma=False)
+
+
+def _in_group(m: int, group_sizes):
+    """[m, 1] bool: the rows that belong to some group."""
+    return (jnp.arange(m, dtype=jnp.int32) < jnp.sum(group_sizes))[:, None]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _gmm(x, w, group_sizes, tk, tn):
+    """One layer's ``[g, k, n]`` weights: the differentiable form."""
+    fn = functools.partial(_grouped_matmul_pallas, tk=tk, tn=tn)
+    # a stack of one (a bitcast), so there is one kernel
+    return _replicated(fn, 4)(x, w.astype(x.dtype)[None], group_sizes,
+                              jnp.zeros((), jnp.int32))
+
+
+def _gmm_fwd(x, w, group_sizes, tk, tn):
+    return _gmm(x, w, group_sizes, tk, tn), (x, w, group_sizes)
+
+
+def _gmm_bwd(tk, tn, res, dy):
+    x, w, group_sizes = res
+    dy = dy.astype(x.dtype)
+    dx_fn = functools.partial(_grouped_matmul_pallas, tk=tk, tn=tn,
+                              transposed=True)
+    dx = _replicated(dx_fn, 4)(dy, w.astype(x.dtype)[None], group_sizes,
+                               jnp.zeros((), jnp.int32))
+    dx = jnp.where(_in_group(x.shape[0], group_sizes), dx,
+                   jnp.zeros_like(dx))
+    dw_fn = functools.partial(_gmm_dw_pallas, dtype=w.dtype, tk=tk, tn=tn)
+    return dx, _replicated(dw_fn, 3)(x, dy, group_sizes), None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
 def grouped_matmul(x, w, group_sizes, *, layer=None, tk: int | None = None,
@@ -184,12 +358,15 @@ def grouped_matmul(x, w, group_sizes, *, layer=None, tk: int | None = None,
     are multiplied by ``w[i]`` (float32 accumulation, result in
     ``x.dtype``).  Rows past ``sum(group_sizes)`` belong to no group:
     the result there is UNDEFINED (possibly not finite) — mask it, do
-    not multiply it by zero.
+    not multiply it by zero.  This form is differentiable in ``x`` and
+    ``w`` (``gmm_dx`` / ``gmm_dw``: module docstring): dX is zero in the
+    rows of no group and the cotangent's rows there are not read.
 
     ``w`` may also be a layer stack [L, g, k, n] with ``layer`` an int32
     scalar (traced inside a layer scan): the call computes with
     ``w[layer]`` and reads only that layer's hit groups out of the stack
-    where it lies.  A stack has to be in ``x.dtype`` already — converting
+    where it lies (serving; no derivative).  A stack has to be in
+    ``x.dtype`` already — converting
     it here would convert every layer of it on every call — and one of
     another dtype is a ``TypeError``; the caller converts once, outside
     its scan.  (A [g, k, n] ``w`` of another dtype is converted, as
@@ -201,19 +378,11 @@ def grouped_matmul(x, w, group_sizes, *, layer=None, tk: int | None = None,
             f"{layer}; a stack [L, g, k, n] takes a layer index, one "
             f"layer's [g, k, n] takes none")
     if layer is None:
-        # a stack of one (a bitcast), so there is one kernel
-        w, layer = w.astype(x.dtype)[None], 0
-    elif w.dtype != x.dtype:
+        return _gmm(x, w, group_sizes, tk, tn)
+    if w.dtype != x.dtype:
         raise TypeError(
             f"grouped_matmul: a layer stack in {w.dtype} with rows in "
             f"{x.dtype}; convert the stack once, outside the layer scan")
-    layer = jnp.asarray(layer, jnp.int32)
     fn = functools.partial(_grouped_matmul_pallas, tk=tk, tn=tn)
-    mesh = ambient_mesh()
-    if needs_shard_map(mesh):
-        # the held-expert layer is one chip's share: every shard holds
-        # the same rows and weights and runs the whole call
-        from jax.sharding import PartitionSpec as P
-        fn = jax.shard_map(fn, mesh=mesh, in_specs=(P(),) * 4,
-                           out_specs=P(), check_vma=False)
-    return fn(x, w, group_sizes, layer)
+    return _replicated(fn, 4)(x, w, group_sizes,
+                              jnp.asarray(layer, jnp.int32))

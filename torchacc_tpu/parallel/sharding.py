@@ -16,9 +16,10 @@ fsdp+tensor 2D mesh spmd_fsdp.py:75-84 extended with sp/ep/pp):
 =============  ===============  =====================================
 logical axis   mesh axes        role
 =============  ===============  =====================================
-``batch``      ('dp','fsdp')    batch split across all data axes
+``batch``      ('dp','fsdp',   batch split across all data axes
+               'ep')
 ``seq``        'sp'             activation sequence dim (context par.)
-``embed``      'fsdp'           param hidden dim — ZeRO-3 shard
+``embed``      ('fsdp','ep')    param hidden dim — ZeRO-3 shard
 ``mlp``        'tp'             ffn hidden — megatron column/row
 ``heads``      'tp'             attention heads — megatron
 ``kv``         None             head_dim stays replicated
@@ -37,16 +38,21 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from torchacc_tpu.config import Config
+from torchacc_tpu.config import DATA_AXES, Config
 
 # A rule maps a logical axis name to a mesh axis, a tuple of mesh axes, or
 # None (replicated).
 LogicalRules = Sequence[Tuple[str, Union[str, Tuple[str, ...], None]]]
 
 DEFAULT_RULES: LogicalRules = (
-    ("batch", ("dp", "fsdp")),
+    ("batch", DATA_AXES),
     ("seq", ("sp", "spu")),
-    ("embed", "fsdp"),
+    # ZeRO-3: the hidden dim of every parameter (and of its optimizer
+    # state) over fsdp, and over ep where a leaf's expert dim has not
+    # taken it: the chips that hold the experts hold the rest of the
+    # state in shares too (spec_for gives a mesh axis to one dim only,
+    # and an expert leaf's 'expert' dim comes first)
+    ("embed", ("fsdp", "ep")),
     ("mlp", "tp"),
     ("heads", "tp"),
     ("kv", None),
@@ -278,15 +284,15 @@ def fsdp_gather_params(tree: Any, specs: Any = None) -> Any:
     return jax.tree.map(one, tree, specs)
 
 
-def fsdp_gather_specs(tree: Any, rules: LogicalRules,
-                      unshard: Tuple[str, ...] = ("fsdp",)) -> Any:
+def fsdp_gather_specs(tree: Any, rules: LogicalRules) -> Any:
     """Per-leaf PartitionSpecs for :func:`fsdp_gather_params`: each
     param leaf's logical axes (models/axes.py path rules) mapped
-    through ``rules`` with the ``unshard`` mesh axes dropped — i.e.
-    "this weight's layout, minus its ZeRO-3 dim".  Constraining to
-    these gathers ONLY the fsdp shard; tp/ep dims keep their megatron
-    layout.  ``tree`` must be the per-layer (sliced) param tree so the
-    leaf ranks match the axes rules."""
+    through ``rules`` with its ZeRO-3 dim — the ``embed`` one, over
+    ``fsdp`` and ``ep`` — left whole: "this weight's layout, minus its
+    ZeRO-3 shard".  Constraining to these gathers ONLY that shard;
+    tp dims keep their megatron layout and an expert leaf's ``expert``
+    dim stays on ``ep``.  ``tree`` must be the per-layer (sliced) param
+    tree so the leaf ranks match the axes rules."""
     from torchacc_tpu.models.axes import param_axes
     axes_tree = param_axes(tree)
 
@@ -294,16 +300,9 @@ def fsdp_gather_specs(tree: Any, rules: LogicalRules,
         if axes is None or not hasattr(leaf, "ndim"):
             return None
         spec = spec_for(axes, rules)
-        parts = []
-        for p in tuple(spec) + (None,) * (leaf.ndim - len(spec)):
-            if p is None:
-                parts.append(None)
-            elif isinstance(p, tuple):
-                kept = tuple(a for a in p if a not in unshard)
-                parts.append(kept or None)
-            else:
-                parts.append(None if p in unshard else p)
-        return PartitionSpec(*parts)
+        parts = tuple(spec) + (None,) * (leaf.ndim - len(spec))
+        return PartitionSpec(*(None if ax == "embed" else part
+                               for ax, part in zip(axes, parts)))
     return jax.tree.map(one, tree, axes_tree,
                         is_leaf=lambda x: x is None)
 

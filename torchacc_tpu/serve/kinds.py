@@ -110,6 +110,11 @@ _AUDITED_MODEL_FIELDS = frozenset({
     # ssm_* sizes reach only models/mamba2 and the state pools' shapes
     "mixer_pattern", "ssm_heads", "ssm_head_dim", "ssm_state", "ssm_groups",
     "ssm_conv", "ssm_chunk", "moe_shared_intermediate_size",
+    # PR-46 audit: YaRN on named kinds only reaches the block as
+    # rope_kinds does, through models/transformer.kind_cfg (a kind
+    # outside rope_yarn_kinds computes under rope_yarn=None, which
+    # block._rope reads)
+    "rope_yarn_kinds",
 })
 
 

@@ -105,15 +105,41 @@ class Trainer:
         mesh: Optional[Mesh] = None,
     ):
         mcfg = getattr(model, "cfg", None)
-        if getattr(mcfg, "kv_lora_rank", 0) or getattr(
-                mcfg, "moe_dispatch", "") == "grouped":
+        if getattr(mcfg, "kv_lora_rank", 0):
             raise ConfigError(
-                "training a latent-attention / held-expert model is not "
-                "supported: the grouped expert layer computes one chip's "
-                "share without the exchange across 'ep', its auxiliary "
-                "loss and sharding rules are untested under a train step, "
-                "and the attention has no flash path.  ServeEngine serves "
-                "it; TransformerLM.apply runs its forward")
+                "training a latent-attention model is not supported: the "
+                "latent attention has no flash path and no backward "
+                "under a train step.  ServeEngine serves it; "
+                "TransformerLM.apply runs its forward")
+        if getattr(mcfg, "moe_dispatch", "") == "grouped":
+            # the dropless expert layer trains with its experts over
+            # 'ep' and the rows over the data axes (models/moe.
+            # routed_experts); what nobody has run is refused here, not
+            # found in a trace
+            dist = config.dist
+            refused = None
+            if mcfg.mixer_pattern or (mcfg.layer_pattern
+                                      and mcfg.first_dense_layers):
+                refused = ("a mixer_pattern, or a layer_pattern beside "
+                           "leading dense layers: the module's forward "
+                           "does not build them (ServeEngine serves them)")
+            elif dist.tp.size > 1 or dist.pp.size > 1 or dist.sp.size > 1:
+                refused = ("the dropless expert layer under tp, pp or sp: "
+                           "it trains under dp / fsdp / ep")
+            elif mcfg.moe_router_width not in (None, mcfg.num_experts) \
+                    or mcfg.moe_first_expert:
+                refused = ("a held SHARE of the router's experts "
+                           "(moe_router_width / moe_first_expert): what "
+                           "the absent experts add would be left out; a "
+                           "train step holds every expert, spread over "
+                           "'ep'")
+            elif mcfg.num_experts % dist.ep.size:
+                refused = (f"{mcfg.num_experts} experts, which do not "
+                           f"spread over ep.size={dist.ep.size}")
+            if refused:
+                raise ConfigError(f"training a model with moe_dispatch="
+                                  f"'grouped' and {refused} is not "
+                                  f"supported")
         self.model = model
         self.config = config
         self.optimizer = optimizer or optax.adamw(1e-4)
@@ -269,7 +295,7 @@ class Trainer:
             # dummy input sized so every sharded dim divides the mesh
             # (params do not depend on batch/seq; this only drives tracing)
             m = self.mesh.shape
-            bs = m.get("dp", 1) * m.get("fsdp", 1)
+            bs = m.get("dp", 1) * m.get("fsdp", 1) * m.get("ep", 1)
             sq = 8 * m.get("sp", 1) * m.get("spu", 1)
             sample_input = jnp.zeros((bs, sq), jnp.int32)
         use_scaler = self.config.compute.dtype == "float16"
@@ -467,8 +493,13 @@ class Trainer:
 
     def _forward_sum_count(self, params, batch, dropout_seed=None,
                            quant=None):
-        """(loss_sum, token_count, new_quant) incl. sown auxiliary losses
-        (MoE router load-balance — models/moe.py) weighted per token.
+        """(loss_sum, token_count, new_quant, stats) incl. sown auxiliary
+        losses (MoE router load-balance — models/moe.py) weighted per
+        token.  ``stats``: what the forward counted beside the loss, for
+        the step's metrics — of a model with dropless expert layers
+        ``moe_load`` (int32 [expert layers, 3]: pairs on held experts,
+        the busiest one's, held experts hit) and ``moe_aux_loss`` (the
+        layers' summed load-balance terms, unweighted); else empty.
 
         ``dropout_seed`` is passed only on train steps of zoo models with
         attn_dropout configured — eval/inference stays deterministic.
@@ -497,7 +528,7 @@ class Trainer:
                               else None),
                 use_fused_ce=self._use_fused_ce,
                 custom_loss=(self.loss if self._custom_loss else None))
-            return l_sum, count, None
+            return l_sum, count, None, {}
         extra = {}
         variables = {"params": params}
         mutable = ["intermediates"]
@@ -584,11 +615,19 @@ class Trainer:
                 l_sum, count = res
             else:
                 l_sum, count = res, jnp.asarray(1.0, jnp.float32)
+        from torchacc_tpu.models.transformer import (
+            _sown_aux_sum,
+            sown_expert_load,
+        )
         if self._aux_weight:
-            from torchacc_tpu.models.transformer import _sown_aux_sum
             l_sum = l_sum + self._aux_weight * _sown_aux_sum(mutated) * count
+        stats = {}
+        load = sown_expert_load(mutated)
+        if load is not None:
+            stats = {"moe_load": load,
+                     "moe_aux_loss": _sown_aux_sum(mutated)}
         return l_sum, count, (mutated.get("quant")
-                              if quant is not None else None)
+                              if quant is not None else None), stats
 
     # 'sharded' | 'whole': where the fused head's rows lived in the
     # program traced last (ops/fused.head_row_axes reads the mesh and
@@ -642,6 +681,7 @@ class Trainer:
             # (reference GradScaler core/amp.py; here fully in-jit)
             scale = (state.scaler["scale"] if use_scaler
                      else jnp.asarray(1.0, jnp.float32))
+            fwd_stats = {}
             if accum > 1:
                 bsz = batch["input_ids"].shape[0]
                 if bsz % accum != 0:
@@ -653,13 +693,13 @@ class Trainer:
                     # micro i quantizes with the history micro i-1 left
                     # (same sequencing an unaccumulated loop would see)
                     def scaled_sum_q(p, mb, mi, q):
-                        l, c, q2 = fsc(p, mb, mi, q)
+                        l, c, q2, _ = fsc(p, mb, mi, q)
                         return l * scale, (c, q2)
                     grad_sum_q = jax.value_and_grad(scaled_sum_q,
                                                     has_aux=True)
                 else:
                     def scaled_sum(p, mb, mi):
-                        l, c, _ = fsc(p, mb, mi)
+                        l, c, _, _ = fsc(p, mb, mi)
                         return l * scale, c
 
                     grad_sum = jax.value_and_grad(scaled_sum, has_aux=True)
@@ -712,7 +752,7 @@ class Trainer:
             else:
                 if quant_on:
                     def scalar_q(p):
-                        l, c, q2 = fsc(p, batch, q=state.quant)
+                        l, c, q2, _ = fsc(p, batch, q=state.quant)
                         return (l / jnp.maximum(c, 1.0)) * scale, q2
                     (loss_s, new_quant), grads = jax.value_and_grad(
                         scalar_q, has_aux=True)(fwd_params)
@@ -720,9 +760,10 @@ class Trainer:
                     new_quant = None
 
                     def scalar(p):
-                        l, c, _ = fsc(p, batch)
-                        return (l / jnp.maximum(c, 1.0)) * scale
-                    loss_s, grads = jax.value_and_grad(scalar)(fwd_params)
+                        l, c, _, stats = fsc(p, batch)
+                        return (l / jnp.maximum(c, 1.0)) * scale, stats
+                    (loss_s, fwd_stats), grads = jax.value_and_grad(
+                        scalar, has_aux=True)(fwd_params)
                 grads = jax.tree.map(lambda g: g / scale, grads)
                 loss_val = loss_s / scale
 
@@ -828,6 +869,9 @@ class Trainer:
             metrics = {
                 "loss": loss_val,
                 "grad_norm": grad_norm,
+                # what the forward counted (the expert layers' load; the
+                # unaccumulated, unquantized step only)
+                **fwd_stats,
             }
             if use_scaler:
                 metrics["loss_scale"] = new_scaler["scale"]
@@ -995,8 +1039,33 @@ class Trainer:
         resolved by this call (None while the pipeline is filling).  At
         the default depth 1 every step resolves immediately — exactly
         the pre-pipelining behaviour, fetch-for-fetch."""
-        with tracing.span("train/step"):
-            return self._step_impl(batch)
+        with tracing.span("train/step") as sp:
+            metrics = self._step_impl(batch)
+            if sp.live:
+                self._note_expert_load(sp)
+            return metrics
+
+    def _note_expert_load(self, sp) -> None:
+        """On a live ``train/step`` span: the expert layers' load of the
+        step this call RESOLVED (``moe_*``, the names ``serve/deliver``
+        carries: whole numbers, which the benchmark's reader sums) and
+        its load-balance loss (``aux_loss``).  The fetch reads a finished
+        step at ``dispatch_depth`` > 1 and happens only while a sink is
+        listening; untraced steps fetch nothing."""
+        entry = self.last_resolved
+        if entry is None or "moe_load" not in entry.metrics:
+            return
+        with self._wait():
+            load, aux = jax.device_get((entry.metrics["moe_load"],
+                                        entry.metrics["moe_aux_loss"]))
+        layers = int(load.shape[0])
+        sp.set(resolved_step=entry.step,
+               moe_pairs=int(load[:, 0].sum()),
+               moe_max=int(load[:, 1].sum()),
+               moe_hit=int(load[:, 2].sum()),
+               moe_layer_steps=layers,
+               moe_slots=layers * int(self.model.cfg.num_experts),
+               aux_loss=float(aux))
 
     def _step_impl(self, batch):
         from torchacc_tpu.resilience.chaos import failpoint
@@ -2132,7 +2201,7 @@ class Trainer:
             def ev(state, batch):
                 # eval reads the trained delayed scales without mutating
                 # them (the returned histories are discarded)
-                l, c, _ = fsc(state.params, batch, quant=state.quant)
+                l, c, _, _ = fsc(state.params, batch, quant=state.quant)
                 return l / jnp.maximum(c, 1.0)
             self._eval_step = jax.jit(
                 ev, in_shardings=(self.state_shardings,
