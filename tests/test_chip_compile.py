@@ -34,6 +34,7 @@ from jax.sharding import PartitionSpec as P
 import torchacc_tpu.ops.flash_attention as flash_mod
 import torchacc_tpu.ops.fused as fused_mod
 import torchacc_tpu.ops.grouped_matmul as grouped_mod
+import torchacc_tpu.ops.moe_rows as rows_mod
 import torchacc_tpu.ops.paged_attention as paged_mod
 import torchacc_tpu.ops.quantized_matmul as quant_mod
 import torchacc_tpu.ops.ssm_scan as ssm_mod
@@ -74,7 +75,7 @@ def for_the_chip(monkeypatch):
     """The kernels ask ``interpret_mode()``, which sees the CPU backend
     here; steer them to the Mosaic lowering for the described chip."""
     for mod in (flash_mod, fused_mod, grouped_mod, paged_mod, quant_mod,
-                ssm_mod):
+                rows_mod, ssm_mod):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -383,6 +384,74 @@ def test_grouped_expert_matmul_compiles_for_the_chip(one_chip, for_the_chip,
         sds((12,), jnp.int32), **layer).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+@pytest.mark.parametrize("mover", ["rows_out_weighed", "rows_back_weighed",
+                                   "rows_back"])
+def test_moe_row_movers_compile_for_the_chip(one_chip, for_the_chip, mover):
+    """The dropless layer's movers (PR 47) at the train cell's chunk:
+    8,192 gathered token rows of 2,304 bf16, 65,536 sorted rows, top-8.
+    Rows out, weighed: XLA's gather and arithmetic a live tile at a time
+    into a buffer nobody zeroed (``moe_rows_alloc``), no temporary of
+    the buffer's size.
+    Rows back: the live prefix re-tiled to rows a DMA can take
+    (``moe_rows_pack``: ONE buffer-sized temporary, written in its live
+    tiles only), then the sum."""
+    from torchacc_tpu.models import moe
+    sds = functools.partial(_sds, sharding=one_chip)
+    n, k, h = 8192, 8, 2304
+    nk = n * k
+    assert nk == moe.MAX_SORTED_PAIRS > moe.LIVE_ROWS_FROM
+    i32, f32 = jnp.int32, jnp.float32
+    rows, buf = sds((n, h), BF16), sds((nk, h), BF16)
+    idx, total = sds((nk,), i32), sds((), i32)
+    if mover == "rows_out_weighed":
+        lowered = jax.jit(rows_mod.take_rows_weighed).lower(
+            rows, idx, total, sds((nk,), f32), buf)
+        names = ["moe_rows_alloc"]
+    else:
+        weights = (sds((n, k), f32),) if mover == "rows_back_weighed" else ()
+        lowered = jax.jit(lambda src, unsort, total, *w: rows_mod.sum_rows(
+            src, unsort, total, *w, k=k,
+            dtype=f32 if w else BF16)).lower(buf, idx, total, *weights)
+        names = ["moe_rows_pack", "moe_rows_back"]
+    compiled = lowered.compile()
+    assert [re.sub(r"\.\d+$", "", name)
+            for name in _kernels(compiled.as_text(), "moe_rows")] == names
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if mover.startswith("rows_out"):
+        assert temp < 2**20        # the loop fills its result in place
+        assert "while" in compiled.as_text()
+    else:
+        assert nk * h * 2 <= temp < nk * h * 2 + 2**20
+
+
+def test_a_serve_chunks_expert_layer_keeps_xlas_gathers(one_chip,
+                                                        for_the_chip):
+    """A prefill chunk of 512 tokens x 8 (4,096 sorted pairs, 16 held
+    experts out of a layer stack) lies under the size rule: the expert
+    layer's only custom calls are its three grouped matmuls."""
+    import dataclasses
+
+    from torchacc_tpu.models import get_preset, moe
+    sds = functools.partial(_sds, sharding=one_chip)
+    n, k, h, f, held = 512, 8, 2304, 896, 16
+    assert n * k <= moe.LIVE_ROWS_FROM
+    cfg = dataclasses.replace(
+        get_preset("llama-tiny", dtype=BF16, param_dtype=BF16),
+        hidden_size=h, num_experts=held, num_experts_per_tok=k,
+        moe_router_width=256, moe_first_expert=32, activation="swiglu")
+
+    def layer(x, sel, weights, w_gate, w_up, w_down, valid, index):
+        return moe.held_experts_ffn(cfg, x, sel, weights, w_gate, w_up,
+                                    w_down, valid, index)
+    stack = lambda a, b: sds((5, held, a, b), BF16)  # noqa: E731
+    text = jax.jit(layer).lower(
+        sds((n, h), BF16), sds((n, k), jnp.int32), sds((n, k), jnp.float32),
+        stack(h, f), stack(h, f), stack(f, h), sds((n,), jnp.bool_),
+        sds((), jnp.int32)).compile().as_text()
+    assert len(_kernels(text, "grouped_matmul")) == 3
+    assert len(_kernels(text, "")) == 3
 
 
 def _axk1_program(name, one_chip):

@@ -3,7 +3,9 @@
 shares, the exchange across a mesh, the auxiliary loss, the ingest of
 the mellum family and YaRN on one kind of layer — each against the plain
 reference of the family (chipbench/reference, which imports nothing of
-the program) or against ``jax.grad`` of a dense product.
+the program) or against ``jax.grad`` of a dense product.  Since PR 47 the
+layer's row movers (``ops/moe_rows.py``: live rows only) against the XLA
+gathers they replace, to the last bit.
 
 Toy sizes; Pallas kernels in interpret mode at 128-wide tiles.
 """
@@ -28,6 +30,7 @@ from torchacc_tpu.config import ConfigError
 from torchacc_tpu.models import TransformerLM, block, moe
 from torchacc_tpu.models.hf import config_from_hf
 from torchacc_tpu.models.transformer import kind_cfg
+from torchacc_tpu.ops import moe_rows
 from torchacc_tpu.ops.grouped_matmul import grouped_matmul, tile_schedule
 from torchacc_tpu.train.trainer import Trainer
 
@@ -115,6 +118,186 @@ def test_dw_schedule_gives_an_empty_group_one_step_on_a_tile_at_hand():
     assert list(np.asarray(group_of[:n])) == [0, 0, 1, 2, 3]
     assert list(np.asarray(tile_of[:n])) == [0, 1, 1, 1, 1]
     assert group_of.shape[0] == 256 // 128 + 2 * 4 - 1
+
+
+# -- the row movers against the gathers they replace -------------------------
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view(np.uint16 if a.dtype.itemsize == 2 else np.uint32)
+
+
+def _sorted_pairs(n, k, live, seed):
+    """A sorted buffer's bookkeeping for ``n`` tokens of ``k`` slots over
+    16 experts of which this shard holds ``[4, 8)``: pairs on experts
+    held elsewhere on both sides of the held ones, a tenth of the tokens
+    not ``valid``.  ``live``: the share of pairs on held experts (0 and
+    1 exactly; ``"tile"`` a few pairs, one partial row tile)."""
+    rng = np.random.default_rng(seed)
+    sel = rng.integers(0, 16, size=(n, k))
+    elsewhere = np.where(rng.uniform(size=(n, k)) < 0.5, sel % 4,
+                         8 + sel % 8)
+    here = 4 + sel % 4
+    if live == "tile":
+        pick = np.zeros((n, k), bool)
+        pick.reshape(-1)[rng.choice(n * k, size=5, replace=False)] = True
+    else:
+        pick = rng.uniform(size=(n, k)) < live
+    sel = np.where(pick, here, elsewhere)
+    valid = rng.uniform(size=(n,)) < 0.9 if live != 1.0 else np.ones(n, bool)
+    local = sel - 4
+    held = (local >= 0) & (local < 4) & valid[:, None]
+    key = jnp.asarray(np.where(held, local, 4).reshape(n * k), jnp.int32)
+    order = jnp.argsort(key, stable=True)
+    unsort = jnp.zeros((n * k,), jnp.int32).at[order].set(
+        jnp.arange(n * k, dtype=jnp.int32))
+    total = jnp.sum(key < 4).astype(jnp.int32)
+    return (jnp.asarray(sel, jnp.int32), jnp.asarray(valid), order, unsort,
+            total)
+
+
+LIVE = pytest.mark.parametrize("live", [0.0, "tile", 0.25, 1.0],
+                               ids=["no_rows", "one_partial_tile",
+                                    "quarter", "all"])
+SLOTS = pytest.mark.parametrize("k", [2, 8])
+DTYPES = pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                                 ids=["bf16", "f32"])
+
+
+@LIVE
+@SLOTS
+@DTYPES
+def test_rows_out_weighed_equal_the_gather_in_the_live_rows(live, k, dtype):
+    """``take_rows_weighed`` (``_combine_bwd``: the cotangent's rows
+    gathered, weighed, reduced against the results) against the XLA
+    expression over the whole buffer, bitwise in the rows ``[0,
+    total)``; ``d_out`` is zero up to the end of the last live tile and
+    ``d_w`` everywhere past ``total``; the results' rows past ``total``
+    may hold anything."""
+    n, h = 200, 128
+    _, _, order, unsort, total = _sorted_pairs(n, k, live, seed=k)
+    t, nk = int(total), n * k
+    rng = np.random.default_rng(7)
+    tok = (order // k).astype(jnp.int32)
+    out = jnp.asarray(rng.normal(size=(nk, h)), dtype)
+    w = jnp.asarray(rng.uniform(size=(n, k)), jnp.float32)
+    dy = jnp.asarray(rng.normal(size=(n, h)), jnp.float32)
+    in_group = jnp.arange(nk) < total
+    dys = dy.astype(dtype)[tok].astype(jnp.float32)
+    w_sorted = w.reshape(nk)[order]
+    want_out = jnp.where(in_group[:, None], dys * w_sorted[:, None],
+                         0.0).astype(dtype)
+    want_w = jnp.where(in_group,
+                       jnp.sum(dys * out.astype(jnp.float32), axis=-1), 0.0)
+    got_out, got_w = moe_rows.take_rows_weighed(
+        dy.astype(dtype), tok, total, w_sorted, out.at[t:].set(jnp.nan))
+    np.testing.assert_array_equal(_bits(got_out[:t]), _bits(want_out[:t]))
+    np.testing.assert_array_equal(_bits(got_w[:t]), _bits(want_w[:t]))
+    tile = min(moe_rows.ROW_TILE, nk)
+    last = min(-(-t // tile) * tile, nk)
+    assert not np.any(np.asarray(got_out[t:last], np.float32))
+    assert not np.any(np.asarray(got_w[t:]))
+
+
+@LIVE
+@SLOTS
+@DTYPES
+def test_rows_back_equal_the_gathered_sum(live, k, dtype):
+    """``sum_rows`` with weights (``_combine`` forward) and without
+    (``_take_pairs_bwd``) against the XLA expressions, bitwise: the same
+    float32 sum over a token's slots in slot order; the buffer's rows
+    past ``total`` are not finite and never read."""
+    n, h = 200, 128
+    _, _, _, unsort, total = _sorted_pairs(n, k, live, seed=10 + k)
+    t, nk = int(total), n * k
+    rng = np.random.default_rng(8)
+    out = jnp.asarray(rng.normal(size=(nk, h)), dtype)
+    garbage = out.at[t:].set(jnp.nan)
+    w = jnp.asarray(rng.uniform(size=(n, k)), jnp.float32)
+    held = (unsort < total).reshape(n, k)
+    picked = out[unsort].reshape(n, k, -1).astype(jnp.float32)
+    want = jnp.sum(jnp.where(held[..., None], picked * w[..., None], 0.0),
+                   axis=1)
+    got = moe_rows.sum_rows(garbage, unsort, total, w, k=k,
+                            dtype=jnp.float32)
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    # the transpose of the take: the cotangent is zero past the total
+    zeros = out.at[t:].set(0)
+    want = jnp.sum(zeros[unsort].reshape(n, k, -1).astype(jnp.float32),
+                   axis=1).astype(dtype)
+    got = moe_rows.sum_rows(garbage, unsort, total, k=k, dtype=dtype)
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+def _layer_case(live, dtype, seed):
+    """A shard's expert layer at toy widths (4 held experts of 16,
+    top-8, 96 tokens): ``(cfg, sel, valid, ct, args)`` with ``args`` the
+    rows, the weights and the three kernels."""
+    n, k, h, f = 96, 8, 128, 64
+    sel, valid, *_ = _sorted_pairs(n, k, live, seed=seed)
+    cfg = dataclasses.replace(
+        _program_cfg(toy_published(hidden_size=h, moe_intermediate_size=f,
+                                   num_experts=16, num_experts_per_tok=k)),
+        dtype=dtype, num_experts=4, moe_router_width=16, moe_first_expert=4)
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.normal(size=(n, h)), jnp.float32)
+    wts = jax.nn.softmax(jnp.asarray(rng.normal(size=(n, k)), jnp.float32))
+    wg, wu = (jnp.asarray(rng.normal(size=(4, h, f)) * 0.1, jnp.float32)
+              for _ in range(2))
+    wd = jnp.asarray(rng.normal(size=(4, f, h)) * 0.1, jnp.float32)
+    ct = jnp.asarray(rng.normal(size=(n, h)), jnp.float32)
+    return cfg, sel, valid, ct, (x, wts, wg, wu, wd)
+
+
+def _layer_call(case, threshold, monkeypatch):
+    """``held_experts_ffn``'s value, load and gradients on a
+    :func:`_layer_case` with the size rule at ``threshold`` rows."""
+    cfg, sel, valid, ct, args = case
+    monkeypatch.setattr(moe, "LIVE_ROWS_FROM", threshold)
+
+    def run(x, wts, wg, wu, wd):
+        y, load = moe.held_experts_ffn(cfg, x, sel, wts, wg, wu, wd, valid)
+        return jnp.sum(y * ct), (y, load)
+    (_, (y, load)), grads = jax.value_and_grad(
+        run, (0, 1, 2, 3, 4), has_aux=True)(*args)
+    return y, load, grads
+
+
+@LIVE
+@DTYPES
+def test_layer_above_the_size_rule_equals_the_layer_below_it(
+        live, dtype, monkeypatch):
+    """The same call with the movers engaged (the rule patched to 0 rows)
+    and with XLA's gathers (a rule past the buffer): the value to the
+    last bit, the gradients in the rows, the weights and the three
+    kernels within the file's gradient tolerance."""
+    case = _layer_case(live, dtype, seed=3)
+    y0, load0, g0 = _layer_call(case, 1 << 30, monkeypatch)
+    y1, load1, g1 = _layer_call(case, 0, monkeypatch)
+    np.testing.assert_array_equal(_bits(y1), _bits(y0))
+    np.testing.assert_array_equal(load1, load0)
+    for got, want in zip(g1, g0):
+        assert np.all(np.isfinite(np.asarray(got, np.float32)))
+        np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_rows_past_the_total_change_nothing(monkeypatch):
+    """A grouped matmul that leaves non-finite rows where no group is
+    (its contract: UNDEFINED there) changes no output and no gradient of
+    the layer with the movers engaged."""
+    from torchacc_tpu.ops import grouped_matmul as gm
+    case = _layer_case(0.25, jnp.float32, seed=4)
+    y0, _, g0 = _layer_call(case, 0, monkeypatch)
+    plain = gm._grouped_matmul_pallas
+
+    def poisoned(x, w, group_sizes, layer, **kw):
+        out = plain(x, w, group_sizes, layer, **kw)
+        return jnp.where(gm._in_group(x.shape[0], group_sizes), out, jnp.nan)
+    monkeypatch.setattr(gm, "_grouped_matmul_pallas", poisoned)
+    y1, _, g1 = _layer_call(case, 0, monkeypatch)
+    np.testing.assert_array_equal(_bits(y1), _bits(y0))
+    for got, want in zip(g1, g0):
+        np.testing.assert_array_equal(_bits(got), _bits(want))
 
 
 # -- the held-expert layer's shares add up ----------------------------------
@@ -213,7 +396,10 @@ def test_auxiliary_loss_and_its_gradient_against_the_reference(one_layer):
     np.testing.assert_allclose(jax.grad(ours)(lw["router"]),
                                jax.grad(theirs)(lw["router"]), atol=1e-6)
     load = moe.routed_experts(mc, p, rows)[2]
+    assert load.shape == (5,)
     assert int(load[0]) == 2 * SEQ * 2 and int(load[2]) <= 8
+    # one shard holds every expert: all of its buffers' rows are live
+    assert int(load[3]) == int(load[4]) == 2 * SEQ * 2
 
 
 # -- ingest and rope -----------------------------------------------------------
@@ -341,15 +527,37 @@ def test_trainer_follows_the_reference(steps, which):
         assert got["grad_norms"][name] == pytest.approx(norm, rel=1e-4), name
 
 
-def test_the_step_on_an_ep_mesh_equals_the_one_device_step(steps):
+@pytest.fixture(scope="module")
+def mesh_with_movers():
+    """The mesh's two steps again with the size rule at 0 rows: every
+    sorted buffer filled and read back by ``ops/moe_rows.py``'s movers
+    inside the layer's ``shard_map``, scan and rematerialised chunk."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(moe, "LIVE_ROWS_FROM", 0)
+        return _readings(4)
+
+
+@pytest.mark.parametrize("movers", [False, True], ids=["gathers", "movers"])
+def test_the_step_on_an_ep_mesh_equals_the_one_device_step(
+        steps, movers, request):
     one, mesh, _ = steps
+    if movers:
+        mesh = request.getfixturevalue("mesh_with_movers")
     assert mesh["mesh"]["ep"] == 4 and one["mesh"]["ep"] == 1
     np.testing.assert_allclose(mesh["losses"], one["losses"], rtol=2e-6)
     for name, norm in one["grad_norms"].items():
         assert mesh["grad_norms"][name] == pytest.approx(norm, rel=2e-5)
     # every pair lands on some shard's held expert: the counts agree
-    np.testing.assert_array_equal(mesh["load"], one["load"])
-    assert mesh["load"][:, 0].tolist() == [ROWS * SEQ * 2] * DEPTH
+    assert mesh["load"].shape == one["load"].shape == (DEPTH, 5)
+    np.testing.assert_array_equal(mesh["load"][:, :3], one["load"][:, :3])
+    pairs = ROWS * SEQ * 2
+    assert mesh["load"][:, 0].tolist() == [pairs] * DEPTH
+    # the busiest shard's sorted buffers: sized for every pair of the
+    # rows it saw, live in its own experts' pairs — all of them on one
+    # device, between a quarter and all over four shards
+    assert one["load"][:, 3:].tolist() == [[pairs, pairs]] * DEPTH
+    assert mesh["load"][:, 4].tolist() == [pairs] * DEPTH
+    assert all(pairs <= 4 * live <= 4 * pairs for live in mesh["load"][:, 3])
     assert mesh["aux"] == pytest.approx(one["aux"], rel=1e-5)
 
 

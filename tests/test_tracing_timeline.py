@@ -94,7 +94,8 @@ ROUTED_TRAIN_SCOPES = ("router", "moe_dispatch", "moe_exchange", "experts",
 ROUTED_TRAIN_KERNELS = ("grouped_matmul", "gmm_dx", "gmm_dw")
 # what its train/step spans carry while a sink listens
 EXPERT_STEP_ATTRS = ("resolved_step", "moe_pairs", "moe_max", "moe_hit",
-                     "moe_slots", "moe_layer_steps", "aux_loss")
+                     "moe_slots", "moe_layer_steps", "aux_loss",
+                     "moe_live_rows", "moe_buffer_rows")
 
 # the spans a traced serve loop / fit has to leave on the host plane
 SERVE_SPANS = ("serve/step", "serve/sweep", "serve/admit", "serve/prefill",
@@ -558,6 +559,12 @@ def test_train_step_span_carries_the_expert_layers_load(traced, attr):
         assert int(st["moe_slots"]) == 4 * n
         assert int(st["moe_pairs"]) == 4 * n * 32 * 2
         assert int(st["moe_max"]) * n >= int(st["moe_pairs"])
+        # the busiest shard's sorted buffers (PR 47): sized for every
+        # pair of the rows it saw, live in its own experts' pairs — at
+        # least the mean shard's, at most all
+        assert int(st["moe_buffer_rows"]) == int(st["moe_pairs"])
+        assert (int(st["moe_pairs"]) <= int(st["moe_live_rows"]) * n
+                and int(st["moe_live_rows"]) <= int(st["moe_buffer_rows"]))
 
 
 def _covered(events, child, parent):

@@ -18,6 +18,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from torchacc_tpu.ops import moe_rows
 from torchacc_tpu.ops._common import ambient_mesh, batch_axes, needs_shard_map
 from torchacc_tpu.ops.grouped_matmul import grouped_matmul
 
@@ -26,6 +27,17 @@ from torchacc_tpu.ops.grouped_matmul import grouped_matmul
 # the rows at hand on the experts held here — so the rows are taken in
 # chunks whose worst case is this many (routed_experts)
 MAX_SORTED_PAIRS = 64 * 1024
+
+# a sorted buffer of more rows than this is read back (and its cotangent
+# weighed) by ops/moe_rows.py's movers, which touch its LIVE rows only
+# (the pairs on the experts held here: a quarter of a training chunk's
+# buffer at balance, a sixteenth of a serving one's); XLA's gathers,
+# which move every row, stay below it.  The crossover on a v5e (my chip
+# run, PR 47, rows of 2,304 bf16): at 16,384 rows XLA's fused gather and
+# sum takes 0.36 ms and the movers 0.36-0.53; at 32,768 rows 1.73 ms
+# against 0.69-1.04 — XLA holds a 75 MB buffer in VMEM and gathers from
+# there, and a 151 MB one it cannot
+LIVE_ROWS_FROM = 16 * 1024
 
 
 def _sort_dispatch(xf, sel_f, w_f, e, cap):
@@ -158,25 +170,41 @@ def _gated(cfg) -> bool:
     return cfg.activation == "swiglu"
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _take_pairs(x, order, unsort, k):
+def _moves_live_rows(pairs: int, dtype) -> bool:
+    """Whether a sorted buffer of ``pairs`` rows is moved by the movers
+    of ``ops/moe_rows.py`` (live rows only) or by XLA's gathers (every
+    row): a rule of the buffer's SIZE, the same for whoever calls."""
+    return pairs > LIVE_ROWS_FROM and moe_rows.supports(dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _take_pairs(x, order, unsort, counts, k):
     """``x[order // k]``: the sorted pairs' rows out of ``x`` [n, h]
-    (pair ``p`` is token ``p // k``).  ``unsort`` is ``order``'s inverse
-    permutation, so the transpose is a gather too — ``g[unsort]`` summed
-    over a token's ``k`` pairs — where autodiff's would be a scatter-add
-    of ``n * k`` rows."""
+    (pair ``p`` is token ``p // k``).  One gather of every row, live or
+    not: XLA copies the rows of so small an array at the HBM's pace, and
+    nothing that stopped at the live rows was faster (PERF.md section 6,
+    PR 47).  ``unsort`` is ``order``'s inverse permutation, so the
+    transpose is a gather too — ``g[unsort]`` summed over a token's
+    ``k`` pairs, the live ones (``sum(counts)`` of them) — where
+    autodiff's would be a scatter-add of ``n * k`` rows."""
     return x[(order // k).astype(jnp.int32)]
 
 
-def _take_pairs_fwd(x, order, unsort, k):
-    return _take_pairs(x, order, unsort, k), (order, unsort)
+def _take_pairs_fwd(x, order, unsort, counts, k):
+    return _take_pairs(x, order, unsort, counts, k), (unsort, counts)
 
 
 def _take_pairs_bwd(k, res, g):
-    order, unsort = res
-    picked = g[unsort].reshape(g.shape[0] // k, k, -1)
-    return (jnp.sum(picked.astype(jnp.float32), axis=1).astype(g.dtype),
-            None, None)
+    unsort, counts = res
+    if _moves_live_rows(g.shape[0], g.dtype):
+        dx = moe_rows.sum_rows(g, unsort, jnp.sum(counts), k=k,
+                               dtype=g.dtype)
+    else:
+        # the cotangent is zero in the rows past the total (the grouped
+        # matmul's dX): summed with the rest
+        picked = g[unsort].reshape(g.shape[0] // k, k, -1)
+        dx = jnp.sum(picked.astype(jnp.float32), axis=1).astype(g.dtype)
+    return dx, None, None, None
 
 
 _take_pairs.defvjp(_take_pairs_fwd, _take_pairs_bwd)
@@ -189,11 +217,16 @@ def _combine(out, weights, order, unsort, total):
     [n, k] float32 -> [n, h] float32.  The results are gathered back to
     (token, slot) order as they are and weighed, masked and summed in
     one pass over them — a pair past ``total`` (on no held expert) adds
-    nothing whatever its row holds, masked, not multiplied by zero.  The
-    backward is written out so that it gathers too (autodiff would
-    scatter-add ``n * k`` rows) and carries the cotangent's rows in
-    ``out``'s dtype."""
+    nothing whatever its row holds, masked, not multiplied by zero (in a
+    large buffer it is not even copied).  The backward is written out so
+    that it gathers too (autodiff would scatter-add ``n * k`` rows) and
+    carries the cotangent's rows in ``out``'s dtype; of a large buffer
+    it leaves the rows past the last live tile UNDEFINED, which the
+    grouped matmul's backward never reads."""
     n, k = weights.shape
+    if _moves_live_rows(n * k, out.dtype):
+        return moe_rows.sum_rows(out, unsort, total, weights, k=k,
+                                 dtype=jnp.float32)
     held = (unsort < total).reshape(n, k)
     picked = out[unsort].reshape(n, k, -1).astype(jnp.float32)
     return jnp.sum(jnp.where(held[..., None], picked * weights[..., None],
@@ -208,15 +241,22 @@ def _combine_fwd(out, weights, order, unsort, total):
 def _combine_bwd(res, dy):
     out, weights, order, unsort, total = res
     n, k = weights.shape
-    in_group = jnp.arange(n * k) < total
-    dys = dy.astype(out.dtype)[(order // k).astype(jnp.int32)]
-    dys = dys.astype(jnp.float32)
+    tok = (order // k).astype(jnp.int32)
     w_sorted = weights.reshape(n * k)[order]
-    d_out = jnp.where(in_group[:, None], dys * w_sorted[:, None], 0.0)
-    d_w = jnp.where(in_group,
-                    jnp.sum(dys * out.astype(jnp.float32), axis=-1), 0.0)
-    return (d_out.astype(out.dtype), d_w[unsort].reshape(n, k), None, None,
-            None)
+    dy = dy.astype(out.dtype)
+    if _moves_live_rows(n * k, out.dtype):
+        d_out, d_w = moe_rows.take_rows_weighed(dy, tok, total, w_sorted,
+                                                out)
+        d_w = jnp.where(unsort < total, d_w[unsort], 0.0)
+    else:
+        in_group = jnp.arange(n * k) < total
+        dys = dy[tok].astype(jnp.float32)
+        d_out = jnp.where(in_group[:, None], dys * w_sorted[:, None],
+                          0.0).astype(out.dtype)
+        d_w = jnp.where(in_group,
+                        jnp.sum(dys * out.astype(jnp.float32), axis=-1),
+                        0.0)[unsort]
+    return d_out, d_w.reshape(n, k), None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -238,7 +278,15 @@ def held_experts_ffn(cfg, x, sel, weights, w_gate, w_up, w_down,
     with ``layer`` (an int32 scalar, traced in a layer scan), every
     expert layer's [L, e, in, out] in ``cfg.dtype``, which the grouped
     matmul reads at ``layer`` where they lie.  Nothing is dropped under any
-    imbalance: the sorted buffer holds all ``n * k`` pairs.  One layer's
+    imbalance: the sorted buffer holds all ``n * k`` pairs.  Its rows on
+    held experts are the prefix ``[0, sum(counts))``; a buffer of more
+    than ``LIVE_ROWS_FROM`` rows is read back (:func:`_combine`, and the
+    transpose of :func:`_take_pairs`) and its cotangent weighed by the
+    movers of ``ops/moe_rows.py``, which touch that prefix alone — the
+    rows behind it are masked wherever a value is made of them, never
+    multiplied by zero — a smaller one by XLA's gathers, which move
+    every row.  Same pairs, same sort, same
+    sums either way.  One layer's
     kernels (no ``layer``) make it differentiable in ``x``, ``weights``
     and the kernels: the grouped matmuls bring their own backward
     kernels, the sort's gathers transpose into gathers.  Pairs on
@@ -263,7 +311,8 @@ def held_experts_ffn(cfg, x, sel, weights, w_gate, w_up, w_down,
         counts = jnp.bincount(key, length=e + 1)[:e].astype(jnp.int32)
         unsort = jnp.zeros((nk,), jnp.int32).at[order].set(
             jnp.arange(nk, dtype=jnp.int32))
-        xs = _take_pairs(x.astype(cfg.dtype), order, unsort, k)  # [nk, h]
+        xs = _take_pairs(x.astype(cfg.dtype), order, unsort, counts,
+                         k)                                     # [nk, h]
     with jax.named_scope("experts"):
         dt = cfg.dtype
         # rows past the held groups belong to no group: what the kernel
@@ -448,17 +497,21 @@ def _routed_rows(cfg, p, x, ep, data_axes):
             aux, counts, rows = jax.lax.psum((aux, counts, rows), data_axes)
         aux = aux / rows
         # pairs on the held experts (of every shard), the busiest one's,
-        # held experts that drew a pair
+        # held experts that drew a pair; then what the sorted buffers of
+        # the busiest SHARD held: its pairs — the live rows — and the
+        # rows the buffers were sized for, every pair of the rows it saw
         counts = jax.lax.dynamic_slice_in_dim(
             counts, cfg.moe_first_expert, held * shards).astype(jnp.int32)
+        by_shard = jnp.sum(counts.reshape(shards, held), axis=-1)
         load = jnp.stack([jnp.sum(counts), jnp.max(counts),
-                          jnp.sum(counts > 0)])
+                          jnp.sum(counts > 0), jnp.max(by_shard),
+                          (rows * (s * k)).astype(jnp.int32)])
     return y.astype(cfg.dtype).reshape(b, s, h), aux, load
 
 
 def routed_experts(cfg, p, x):
     """The dropless expert layer as a train step runs it: ``x`` [b, s, h]
-    -> ``(y [b, s, h] in cfg.dtype, aux, load int32[3])`` on the raw
+    -> ``(y [b, s, h] in cfg.dtype, aux, load int32[5])`` on the raw
     parameter tree of :class:`MoEMlp`'s 'grouped' path, differentiable.
 
     Under a mesh whose ``ep`` axis is larger than one the experts stay
@@ -480,12 +533,18 @@ def routed_experts(cfg, p, x):
     hold the case that every pair lands on its experts, so the rows are
     taken ``_chunk_rows`` at a time in a scan — gathered, computed,
     scattered, a chunk rematerialised in the backward — and the buffers
-    are ``MAX_SORTED_PAIRS`` rows whatever the batch.
+    are ``MAX_SORTED_PAIRS`` rows whatever the batch.  The buffer is
+    sized for the worst case; what is MOVED is the live rows (the pairs
+    on this shard's experts, a quarter of the buffer at balance over four
+    shards): buffers of this size are filled and read back by the
+    movers of ``ops/moe_rows.py`` (:func:`held_experts_ffn`).
 
     ``aux`` is the mean over the rows (sequences) of ``width * sum_e f_e
     P_e``; ``load`` the (token, expert) pairs on held experts over all
-    shards, the busiest held expert's, and the held experts that drew
-    one."""
+    shards, the busiest held expert's, the held experts that drew one,
+    the busiest SHARD's pairs (the live rows of its sorted buffers, over
+    the layer's chunks and the shard's data-parallel replicas) and the
+    rows those buffers were sized for."""
     mesh = ambient_mesh()
     if not needs_shard_map(mesh):
         return _routed_rows(cfg, p, x, None, ())

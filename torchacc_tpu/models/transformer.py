@@ -774,7 +774,7 @@ def _raw_block_fn(block_cfg, with_load: bool = False):
     explicitly and returned — the single place this subtlety lives (the
     pp / unrolled / split-remat paths all build on it).  ``with_load``:
     ``aux`` is ``(aux, load)``, the layer's sown expert load beside it
-    (``moe_load`` int32[3], models/moe.routed_experts), so a per-layer
+    (``moe_load`` int32[5], models/moe.routed_experts), so a per-layer
     loop can stack and sow the counts as ``nn.scan`` does."""
     def fn(p, carry, s):
         (new_carry, _), vs = ScanBlock(block_cfg).apply(
@@ -1544,13 +1544,15 @@ def _sown_aux_sum(vs) -> jax.Array:
 
 
 def sown_expert_load(vs):
-    """The expert layers' sown load, int32 [expert layers, 3] (pairs on
-    held experts, the busiest one's, held experts that drew a pair;
+    """The expert layers' sown load, int32 [expert layers, 5] (pairs on
+    held experts, the busiest one's, held experts that drew a pair, the
+    busiest shard's pairs, its sorted buffers' rows;
     models/moe.routed_experts), or None for a model that sows none."""
     vals = _sown(vs, "moe_load")
     if not vals:
         return None
-    return jnp.concatenate([v.reshape(-1, 3) for v in vals], axis=0)
+    return jnp.concatenate([v.reshape(-1, v.shape[-1]) for v in vals],
+                           axis=0)
 
 
 class _MicroBatchView(dict):

@@ -50,7 +50,10 @@ SPANS: Dict[str, str] = {
                   "dropless expert layers and only while a sink listens: "
                   "resolved_step and that step's moe_pairs, moe_max, "
                   "moe_hit, moe_slots, moe_layer_steps (as on "
-                  "serve/deliver, over every shard's held experts) and "
+                  "serve/deliver, over every shard's held experts), "
+                  "moe_live_rows of moe_buffer_rows (the busiest shard's "
+                  "pairs — the rows its sorted buffers' movers copy — of "
+                  "the rows those buffers were sized for) and "
                   "aux_loss (the layers' summed load-balance terms)",
     "train/dispatch": "Trainer.step — enqueue of one jitted train step",
     "train/resolve": "Trainer.resolve_oldest — lagged readback of step "
@@ -164,7 +167,10 @@ SCOPES: Dict[str, str] = {
     "router": "expert layer: router matmul in f32, scores, group-limited "
               "top-k, weights",
     "moe_dispatch": "expert layer: sort of the (token, expert) pairs on "
-                    "held experts and the gather of their rows",
+                    "held experts and the gather of their rows (a large "
+                    "buffer's live rows alone: XLA's gather a live tile "
+                    "at a time into moe_rows_alloc's buffer; in the "
+                    "backward the kernels moe_rows_pack + moe_rows_back)",
     "moe_exchange": "expert layer under training, experts over 'ep': the "
                     "all-gather of the shards' rows (and their sel / "
                     "weights) before the held experts work them and the "
@@ -175,7 +181,9 @@ SCOPES: Dict[str, str] = {
                "their backward too, the kernels gmm_dx and gmm_dw)",
     "shared_expert": "expert layer: the shared experts' FFN",
     "moe_combine": "expert layer: unsort, weight and sum the pairs' "
-                   "outputs, add the shared expert",
+                   "outputs (a large buffer's live rows alone: kernels "
+                   "moe_rows_pack + moe_rows_back; in the backward a "
+                   "loop over the live tiles), add the shared expert",
     "ssm_mixer": "state-space (Mamba-2) layer outside the three scopes "
                  "below: input projection, skip term, gate, grouped norm, "
                  "output projection (serving)",

@@ -497,8 +497,9 @@ class Trainer:
         losses (MoE router load-balance — models/moe.py) weighted per
         token.  ``stats``: what the forward counted beside the loss, for
         the step's metrics — of a model with dropless expert layers
-        ``moe_load`` (int32 [expert layers, 3]: pairs on held experts,
-        the busiest one's, held experts hit) and ``moe_aux_loss`` (the
+        ``moe_load`` (int32 [expert layers, 5]: pairs on held experts,
+        the busiest one's, held experts hit, the busiest shard's pairs,
+        its sorted buffers' rows) and ``moe_aux_loss`` (the
         layers' summed load-balance terms, unweighted); else empty.
 
         ``dropout_seed`` is passed only on train steps of zoo models with
@@ -1048,8 +1049,11 @@ class Trainer:
     def _note_expert_load(self, sp) -> None:
         """On a live ``train/step`` span: the expert layers' load of the
         step this call RESOLVED (``moe_*``, the names ``serve/deliver``
-        carries: whole numbers, which the benchmark's reader sums) and
-        its load-balance loss (``aux_loss``).  The fetch reads a finished
+        carries: whole numbers, which the benchmark's reader sums; and
+        ``moe_live_rows`` of ``moe_buffer_rows``, the share of the
+        busiest shard's sorted buffers that held pairs — what the row
+        movers of ops/moe_rows.py were asked to move) and its
+        load-balance loss (``aux_loss``).  The fetch reads a finished
         step at ``dispatch_depth`` > 1 and happens only while a sink is
         listening; untraced steps fetch nothing."""
         entry = self.last_resolved
@@ -1065,6 +1069,8 @@ class Trainer:
                moe_hit=int(load[:, 2].sum()),
                moe_layer_steps=layers,
                moe_slots=layers * int(self.model.cfg.num_experts),
+               moe_live_rows=int(load[:, 3].sum()),
+               moe_buffer_rows=int(load[:, 4].sum()),
                aux_loss=float(aux))
 
     def _step_impl(self, batch):
